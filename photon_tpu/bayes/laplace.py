@@ -99,7 +99,7 @@ class StreamedLaplace:
         from jax.sharding import PartitionSpec as P
 
         from photon_tpu.optim.hier import (
-            _mesh_factors,
+            _num_shards,
             _sample_axes,
             _staged_all_psum,
         )
@@ -108,21 +108,18 @@ class StreamedLaplace:
         mesh, obj = self.mesh, self.objective
         zero = Hyper(l2_weight=0.0)
         sample_axes = _sample_axes(mesh)
-        self._n_shards, self._replicas = _mesh_factors(mesh, sample_axes)
+        self._n_shards = _num_shards(mesh, sample_axes)
         spec_axis = sample_axes if len(sample_axes) > 1 else sample_axes[0]
         carry_spec = P(spec_axis, None)
         self._carry_sharding = NamedSharding(mesh, carry_spec)
-        replicas = self._replicas
 
         def partial_body(cd, coef, batch):
             # shard-local accumulate: cd [1, dim] — NO collectives
             return (cd[0] + obj.hessian_diagonal(coef, batch, zero))[None]
 
         def finalize_body(cd, l2):
-            # the pass's single reduction: one staged ICI-then-DCN psum;
-            # model-axis replicas hold identical copies, so the all-psum
-            # overcounts by exactly that factor
-            diag = _staged_all_psum(cd[0], mesh) / replicas
+            # the pass's single reduction: one staged ICI-then-DCN psum
+            diag = _staged_all_psum(cd[0], mesh)
             return _variance_from_diag(diag, l2)
 
         def partial(carry, coef, batch):
@@ -131,13 +128,13 @@ class StreamedLaplace:
             return M.shard_map(partial_body, mesh=mesh,
                                in_specs=(carry_spec, P(), specs),
                                out_specs=carry_spec,
-                               check_rep=False)(carry, coef, batch)
+                               check_vma=False)(carry, coef, batch)
 
         def finalize(carry, l2):
             return M.shard_map(finalize_body, mesh=mesh,
                                in_specs=(carry_spec, P()),
                                out_specs=P(),
-                               check_rep=False)(carry, l2)
+                               check_vma=False)(carry, l2)
 
         self._partial = jit_donating(partial, donate_argnums=(0,))
         self._finalize = jax.jit(finalize)

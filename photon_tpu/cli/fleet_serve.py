@@ -20,7 +20,12 @@ Two shard attachments:
   routed ``SHARD_UNAVAILABLE`` degradation at the router, never an
   exception; per-shard metrics snapshots are pulled over the pipe
   (``{"control": "stats"}``) and merged via
-  ``obs/metrics.merge_snapshots``.
+  ``obs/metrics.merge_snapshots``. One process per chip: the router
+  scores fixed effects with JAX, so when it runs on an accelerator it
+  holds the chip and the children cannot have it — ``--spawn-shards``
+  is then refused (`ShardSpawnRefused`) unless ``--shard-platform cpu``
+  puts the children on CPU explicitly. Each child's stderr goes to
+  ``<--shard-log-dir>/shard-K.stderr``; the stats output names both.
 
 Control lines::
 
@@ -30,7 +35,8 @@ Control lines::
 Usage::
 
     python -m photon_tpu.cli.fleet_serve --fleet-manifest /path/to/fleet \
-        [--spawn-shards] [--hedge-timeout-ms 5] [--stats-output stats.json] \
+        [--spawn-shards [--shard-platform cpu] [--shard-log-dir logs]] \
+        [--hedge-timeout-ms 5] [--stats-output stats.json] \
         < requests.jsonl > scores.jsonl
 """
 
@@ -43,6 +49,7 @@ import os
 import queue
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 from typing import List, Optional, Sequence
@@ -52,28 +59,60 @@ logger = logging.getLogger("photon_tpu.fleet_serve")
 _TICK_S = 0.05
 
 
+class ShardSpawnRefused(RuntimeError):
+    """``--spawn-shards`` cannot give every shard child a device."""
+
+
+def shard_child_platform(requested: Optional[str],
+                         parent_backend: str) -> Optional[str]:
+    """``JAX_PLATFORMS`` for the shard children, or None to leave their
+    environment exactly as inherited. CPU children are an explicit
+    request, never a default; a router that holds an accelerator
+    refuses to start children that would need the same chip (they would
+    die at start-up and every request would come back a routed
+    ``SHARD_UNAVAILABLE`` — fixed-effect-only scores, exit 0)."""
+    if requested is not None:
+        return requested
+    if parent_backend != "cpu":
+        raise ShardSpawnRefused(
+            f"--spawn-shards refused: this router process runs on "
+            f"{parent_backend!r} and holds the chip, and a chip belongs "
+            f"to one process — shard children inheriting this environment "
+            f"could not initialise it. Pass --shard-platform cpu to run "
+            f"the children on CPU, or drop --spawn-shards for in-process "
+            f"shards on the router's device.")
+    return None
+
+
 class PipeShardClient:
     """A fleet shard behind a child ``cli/serve`` process and two JSONL
     pipes. Implements the same client surface as `LocalShardClient`:
     ``serve`` returns None (never raises) when the child is dead or the
     response does not arrive in time — the router's typed-degradation
-    signal."""
+    signal. ``platform`` is the child's ``JAX_PLATFORMS`` (None = the
+    parent's environment, untouched); the child's stderr is kept in
+    ``log_dir``."""
 
     def __init__(self, shard_id: int, fleet_dir: str,
                  serve_args: Sequence[str] = (),
-                 response_timeout_s: float = 30.0):
+                 response_timeout_s: float = 30.0, *,
+                 platform: Optional[str], log_dir: str):
         self.shard_id = int(shard_id)
         self.alive = True
         self.response_timeout_s = response_timeout_s
+        self.platform = platform
+        self.stderr_path = os.path.join(log_dir, f"shard-{shard_id}.stderr")
         self._lock = threading.Lock()
-        self._proc = subprocess.Popen(
-            [sys.executable, "-m", "photon_tpu.cli.serve",
-             "--fleet-manifest", fleet_dir, "--shard-id", str(shard_id),
-             *serve_args],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, text=True,
-            env={**os.environ, "JAX_PLATFORMS":
-                 os.environ.get("JAX_PLATFORMS", "cpu")})
+        env = dict(os.environ)
+        if platform is not None:
+            env["JAX_PLATFORMS"] = platform
+        with open(self.stderr_path, "wb") as errf:
+            self._proc = subprocess.Popen(
+                [sys.executable, "-m", "photon_tpu.cli.serve",
+                 "--fleet-manifest", fleet_dir, "--shard-id", str(shard_id),
+                 *serve_args],
+                stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+                stderr=errf, text=True, env=env)
         self._lines: "queue.Queue" = queue.Queue()
         threading.Thread(target=self._read, daemon=True,
                          name=f"shard{shard_id}-reader").start()
@@ -208,6 +247,14 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--spawn-shards", action="store_true",
                    help="one child serve process per shard over JSONL "
                         "pipes (default: in-process shard engines)")
+    p.add_argument("--shard-platform", default=None, choices=("cpu",),
+                   help="JAX_PLATFORMS for --spawn-shards children "
+                        "(default: the router's environment, which is "
+                        "refused when the router holds an accelerator)")
+    p.add_argument("--shard-log-dir", default=None, metavar="DIR",
+                   help="where each --spawn-shards child's stderr is kept "
+                        "(default: beside --stats-output, else a fresh "
+                        "temporary directory, logged at start)")
     p.add_argument("--max-batch", type=int, default=64)
     p.add_argument("--max-wait-ms", type=float, default=2.0)
     p.add_argument("--hot-capacity", type=int, default=None,
@@ -248,6 +295,15 @@ def build_fleet(args: argparse.Namespace):
         return ShardedServingFleet.from_fleet_dir(
             args.fleet_manifest, config,
             model_dir=args.model_input_directory)
+    import jax
+    platform = shard_child_platform(args.shard_platform,
+                                    jax.default_backend())
+    log_dir = args.shard_log_dir or (
+        os.path.dirname(os.path.abspath(args.stats_output))
+        if args.stats_output else tempfile.mkdtemp(prefix="fleet-shards-"))
+    os.makedirs(log_dir, exist_ok=True)
+    logger.info("shard children: JAX_PLATFORMS=%s, stderr under %s",
+                platform or "(inherited)", log_dir)
     manifest = read_fleet_manifest(args.fleet_manifest)
     from photon_tpu.serving.fleet import _load_base
     base, ordered = _load_base(manifest, args.model_input_directory)
@@ -260,10 +316,23 @@ def build_fleet(args: argparse.Namespace):
         serve_args += ["--model-input-directory",
                        args.model_input_directory]
     clients = [PipeShardClient(sh["shard_id"], args.fleet_manifest,
-                               serve_args)
+                               serve_args, platform=platform,
+                               log_dir=log_dir)
                for sh in manifest["shards"]]
     coords = [(re.coordinate_id, re.random_effect_type) for re in ordered]
     return ShardedServingFleet(front, clients, coords, config)
+
+
+def _fleet_stats(fleet) -> dict:
+    """``fleet.stats()`` plus, for ``--spawn-shards``, what each child
+    process runs on and where its stderr is."""
+    stats = fleet.stats()
+    children = {c.shard_id: {"platform": c.platform or "(inherited)",
+                             "stderr": c.stderr_path}
+                for c in fleet.clients if isinstance(c, PipeShardClient)}
+    if children:
+        stats["shard_children"] = children
+    return stats
 
 
 def run(args: argparse.Namespace, stdin=None, stdout=None) -> int:
@@ -319,7 +388,7 @@ def run(args: argparse.Namespace, stdin=None, stdout=None) -> int:
                 if cmd == "stats":
                     stdout.write(json.dumps(
                         {"control": "stats", "ok": True,
-                         "stats": fleet.stats()}) + "\n")
+                         "stats": _fleet_stats(fleet)}) + "\n")
                 elif cmd == "drain":
                     stdout.write(json.dumps(
                         {"control": "drain", "ok": True}) + "\n")
@@ -369,7 +438,7 @@ def run(args: argparse.Namespace, stdin=None, stdout=None) -> int:
         stdout.flush()
         if args.stats_output:
             with open(args.stats_output, "w") as f:
-                json.dump(fleet.stats(), f, indent=1)
+                json.dump(_fleet_stats(fleet), f, indent=1)
                 f.write("\n")
         fleet.shutdown()
         shutdown.uninstall()

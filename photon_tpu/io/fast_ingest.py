@@ -330,6 +330,10 @@ def read_frame_with_fallback(
         raise
     except Exception as e:  # noqa: BLE001 — the fast path must never be fatal
         logger.warning("fast ingest failed (%r), using generic path", e)
+    # which arm ran is observable (chip_smoke.py prints it)
+    from photon_tpu.obs.metrics import registry
+    registry.counter("ingest.frames",
+                     path="native" if out is not None else "python").inc()
     if out is not None:
         return out
     records = read_records(list(input_dirs))  # raises on empty, both arms

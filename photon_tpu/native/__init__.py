@@ -7,12 +7,14 @@ currently the Avro binary block decoder that feeds ingest
 or compile failures degrade to the pure-Python implementations.
 
 Build: a single ``cc -O2 -shared -fPIC`` invocation against the running
-interpreter's headers, cached next to the source; no pip, no setuptools.
+interpreter's headers, cached next to the source under a name that
+carries the source's hash; no pip, no setuptools.
 Set ``PHOTON_TPU_NO_NATIVE=1`` to disable entirely.
 """
 
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
 import subprocess
@@ -28,12 +30,17 @@ _mods: dict = {}          # stem -> module | _SENTINEL_BROKEN
 
 
 def _build_extension(stem: str) -> Optional[str]:
-    """Compile <stem>.c -> _<stem><ext_suffix>.so next to the source.
-    Returns the path, or None when no compiler / unwritable directory."""
+    """Compile <stem>.c -> _<stem>-<source sha>.<ext_suffix> next to the
+    source. Returns the path, or None when no compiler / unwritable
+    directory. The built file is keyed on a hash of the source it was
+    built from: a copied or checked-out tree keeps no mtime promise, and
+    a stale .so must never be loaded for a newer .c."""
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    out = os.path.join(_DIR, f"_{stem}{suffix}")
     src = os.path.join(_DIR, f"{stem}.c")
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    out = os.path.join(_DIR, f"_{stem}-{digest}{suffix}")
+    if os.path.exists(out):
         return out
     include = sysconfig.get_paths()["include"]
     cc = os.environ.get("CC", "cc")

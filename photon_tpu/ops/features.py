@@ -27,13 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-# jax.shard_map only exists from 0.5; this tree pins 0.4.x where the
-# implementation lives under jax.experimental (keyword-argument API).
-try:
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 Array = jax.Array
 
 
@@ -147,9 +140,9 @@ def matvec(x: FeatureMatrix, theta: Array) -> Array:
             part = jnp.sum(val[0] * gathered, axis=-1)
             return jax.lax.psum(part, x.model_axis)
 
-        return _shard_map(f, mesh=x.mesh,
-                          in_specs=(ell, ell, model_vec),
-                          out_specs=data_vec)(x.indices, x.values, theta)
+        return jax.shard_map(f, mesh=x.mesh,
+                             in_specs=(ell, ell, model_vec),
+                             out_specs=data_vec)(x.indices, x.values, theta)
     if isinstance(x, SparseFeatures):
         return jnp.sum(x.values * theta[x.indices], axis=-1)
     return x @ theta
@@ -200,9 +193,9 @@ def _ms_scatter(x: ModelShardedSparse, w: Array, square: bool) -> Array:
         g = g.at[idx[0].ravel()].add(contrib)
         return _ms_data_psum(x, g)
 
-    return _shard_map(f, mesh=x.mesh,
-                      in_specs=(ell, ell, data_vec),
-                      out_specs=model_vec)(x.indices, x.values, w)
+    return jax.shard_map(f, mesh=x.mesh,
+                         in_specs=(ell, ell, data_vec),
+                         out_specs=model_vec)(x.indices, x.values, w)
 
 
 def _ms_segment_reduce(x: ModelShardedSparse, w: Array, square: bool) -> Array:
@@ -236,10 +229,10 @@ def _ms_segment_reduce(x: ModelShardedSparse, w: Array, square: bool) -> Array:
              - z.at[p[:-1]].get(mode="promise_in_bounds"))
         return _ms_data_psum(x, g)
 
-    return _shard_map(f, mesh=x.mesh,
-                      in_specs=(csc, csc, csc, data_vec),
-                      out_specs=model_vec)(x.csc_rows, x.csc_vals,
-                                           x.csc_ptr, w)
+    return jax.shard_map(f, mesh=x.mesh,
+                         in_specs=(csc, csc, csc, data_vec),
+                         out_specs=model_vec)(x.csc_rows, x.csc_vals,
+                                              x.csc_ptr, w)
 
 
 def rmatvec(x: FeatureMatrix, w: Array, dim: int) -> Array:

@@ -171,7 +171,7 @@ def minimize_lanes_meshed(objective: GLMObjective,
     from photon_tpu.parallel import mesh as M
 
     sample_axes = hier._sample_axes(mesh)
-    p_shards, replicas = hier._mesh_factors(mesh, sample_axes)
+    p_shards = hier._num_shards(mesh, sample_axes)
 
     def lanes_body(x0_l, l2_l, l1_l, batch):
         def lane_vg(c, hyper):
@@ -179,7 +179,7 @@ def minimize_lanes_meshed(objective: GLMObjective,
                                                       p_shards)
             packed = hier._staged_all_psum(
                 jnp.concatenate([g, f[None]]), mesh)
-            return packed[-1] / replicas, packed[:-1] / replicas
+            return packed[-1], packed[:-1]
 
         if use_owlqn:
             def one_lane(x0, l2k, l1k):
@@ -194,13 +194,14 @@ def minimize_lanes_meshed(objective: GLMObjective,
 
     specs = hier._batch_specs(sharded_batch, sample_axes)
     l1_lanes = l1 if l1 is not None else jnp.zeros_like(l2)
-    # check_rep=False: the rep checker has no rule for the vmapped
-    # solver while_loop; the staged all-axis psum establishes the P()
-    # output replication it would otherwise verify (hier precedent).
+    # check_vma=False: the vmapped solver while_loop mixes replicated
+    # and shard-varying carries, which the varying-axes checker refuses;
+    # the staged psum establishes the P() output replication it would
+    # verify (hier precedent).
     return M.shard_map(lanes_body, mesh=mesh,
                        in_specs=(P(), P(), P(), specs),
                        out_specs=P(),
-                       check_rep=False)(x0_lanes, l2, l1_lanes,
+                       check_vma=False)(x0_lanes, l2, l1_lanes,
                                         sharded_batch)
 
 

@@ -137,28 +137,23 @@ def _batch_specs(batch: DataBatch, sample_axes: Tuple[str, ...]):
 
 
 def _staged_all_psum(x, mesh):
-    """Replicate ``x``'s shard-sum over EVERY mesh axis, staging the DCN
-    hop last so it is exactly one countable psum over ``DCN_AXIS``."""
-    names = tuple(mesh.axis_names)
-    if M.DCN_AXIS in names:
-        ici = tuple(a for a in names if a != M.DCN_AXIS)
-        return jax.lax.psum(jax.lax.psum(x, ici), M.DCN_AXIS)
-    return jax.lax.psum(x, names)
+    """Sum ``x`` over the mesh's sample axes — the only axes a
+    data-parallel shard-local value varies on; devices along any other
+    axis already hold identical copies, and a psum over an axis the
+    operand does not vary on is a type error under shard_map. The DCN
+    hop is staged last so it is exactly one countable psum over
+    ``DCN_AXIS``."""
+    if M.DCN_AXIS in mesh.axis_names:
+        return M.staged_psum(x)
+    return jax.lax.psum(x, M.DATA_AXIS)
 
 
-def _mesh_factors(mesh, sample_axes) -> Tuple[int, int]:
-    """(p_shards, replicas): number of data shards, and the product of
-    the mesh-axis sizes the data is NOT sharded over — those replicas
-    compute identical local quantities, and the all-axis psum multiplies
-    every shard-sum by this factor."""
+def _num_shards(mesh, sample_axes) -> int:
+    """Number of data shards: the product of the sample-axis sizes."""
     p_shards = 1
     for a in sample_axes:
         p_shards *= M.axis_size(mesh, a)
-    replicas = 1
-    for name in mesh.axis_names:
-        if name not in sample_axes:
-            replicas *= M.axis_size(mesh, name)
-    return p_shards, replicas
+    return p_shards
 
 
 def build_round_fn(objective: GLMObjective, mesh,
@@ -182,7 +177,7 @@ def build_round_fn(objective: GLMObjective, mesh,
     mu, hyper, batch)``); the default keeps the classic arity.
     """
     sample_axes = _sample_axes(mesh)
-    p_shards, replicas = _mesh_factors(mesh, sample_axes)
+    p_shards = _num_shards(mesh, sample_axes)
     inner = int(config.inner_chunks)
     if inner < 1:
         raise ValueError(f"inner_chunks must be >= 1, got {inner}")
@@ -244,21 +239,20 @@ def build_round_fn(objective: GLMObjective, mesh,
         delta = res.coef - c
         packed = _staged_all_psum(
             jnp.concatenate([delta, g0_raw, f0_raw[None]]), mesh)
-        return (packed[:d] / (p_shards * replicas),
-                packed[d:2 * d] / replicas,
-                packed[2 * d] / replicas)
+        return packed[:d] / p_shards, packed[d:2 * d], packed[2 * d]
 
     def make(chunk_idx, c, c_prev, g_prev, mu, hyper, batch):
         specs = _batch_specs(batch, sample_axes)
-        # check_rep=False: the rep checker has no rule for the inner
-        # L-BFGS while_loop; the all-axis psum above establishes the
-        # P() output replication it would otherwise verify
+        # check_vma=False: the inner L-BFGS while_loop starts from
+        # replicated values and carries shard-varying ones, which the
+        # varying-axes checker refuses; the staged psum above
+        # establishes the P() output replication it would verify
         return M.shard_map(round_body, mesh=mesh,
                            in_specs=(P(), P(), P(), P(), P(),
                                      jax.tree.map(lambda _: P(), hyper),
                                      specs),
                            out_specs=(P(), P(), P()),
-                           check_rep=False)(chunk_idx, c, c_prev, g_prev,
+                           check_vma=False)(chunk_idx, c, c_prev, g_prev,
                                             mu, hyper, batch)
 
     jitted = jax.jit(make)
@@ -271,18 +265,18 @@ def build_round_fn(objective: GLMObjective, mesh,
 
 def build_global_vg(objective: GLMObjective, mesh):
     """Shard-map-explicit global ``(f, g)`` over the same layout, with
-    the identical staged all-axis psum — the reference arm and the
+    the identical staged psum — the reference arm and the
     bootstrap/closing evaluation. Its jaxpr carries exactly ONE
     DCN-stage psum, so a reference L-BFGS solve issues one DCN
     reduction PER FUNCTION EVALUATION (vs per round for the
     hierarchical program)."""
     sample_axes = _sample_axes(mesh)
-    p_shards, replicas = _mesh_factors(mesh, sample_axes)
+    p_shards = _num_shards(mesh, sample_axes)
 
     def vg_body(c, hyper, batch):
         f, g = objective.local_value_and_gradient(c, batch, hyper, p_shards)
         packed = _staged_all_psum(jnp.concatenate([g, f[None]]), mesh)
-        return packed[-1] / replicas, packed[:-1] / replicas
+        return packed[-1], packed[:-1]
 
     def make(c, hyper, batch):
         specs = _batch_specs(batch, sample_axes)

@@ -481,7 +481,7 @@ class _SdcaPrograms:
         from jax.sharding import PartitionSpec as P
 
         from photon_tpu.optim.hier import (
-            _mesh_factors,
+            _num_shards,
             _sample_axes,
             _staged_all_psum,
         )
@@ -489,7 +489,7 @@ class _SdcaPrograms:
 
         mesh = self.mesh
         sample_axes = _sample_axes(mesh)
-        self._p_shards, self._replicas = _mesh_factors(mesh, sample_axes)
+        self._p_shards = _num_shards(mesh, sample_axes)
         spec_axis = (sample_axes if len(sample_axes) > 1
                      else sample_axes[0])
         if self.chunk_rows % self._p_shards:
@@ -505,7 +505,6 @@ class _SdcaPrograms:
         alpha_spec, vloc_spec, acc_spec = (P(None, spec_axis),
                                            P(spec_axis, None),
                                            P(spec_axis, None))
-        replicas = self._replicas
 
         def shard_pos():
             i = jnp.zeros((), jnp.int32)
@@ -537,7 +536,7 @@ class _SdcaPrograms:
                 in_specs=(alpha_spec, vloc_spec, P(), acc_spec, specs,
                           P(), P(), P(), P()),
                 out_specs=(alpha_spec, vloc_spec, acc_spec),
-                check_rep=False,
+                check_vma=False,
             )(alpha_all, vloc, vg, acc, batch, rows, epoch, chunk_id,
               damping)
 
@@ -548,7 +547,7 @@ class _SdcaPrograms:
             # ICI-then-DCN psum. Shards own DISJOINT rows, so the add
             # merge preserves v = sum alpha_i x_i exactly.
             packed = _staged_all_psum(
-                jnp.concatenate([vloc[0] - vg, acc[0]]), mesh) / replicas
+                jnp.concatenate([vloc[0] - vg, acc[0]]), mesh)
             return vg + packed[:-4], packed[-4:]
 
         def merge(vloc, vg, acc):
@@ -556,7 +555,7 @@ class _SdcaPrograms:
                 merge_body, mesh=mesh,
                 in_specs=(vloc_spec, P(), acc_spec),
                 out_specs=(P(), P()),
-                check_rep=False,
+                check_vma=False,
             )(vloc, vg, acc)
 
         self._merge = jax.jit(merge)

@@ -103,7 +103,7 @@ class StreamedProblem:
         from jax.sharding import PartitionSpec as P
 
         from photon_tpu.optim.hier import (
-            _mesh_factors,
+            _num_shards,
             _sample_axes,
             _staged_all_psum,
         )
@@ -111,12 +111,11 @@ class StreamedProblem:
 
         mesh, obj = self.mesh, self.objective
         sample_axes = _sample_axes(mesh)
-        self._n_shards, self._replicas = _mesh_factors(mesh, sample_axes)
+        self._n_shards = _num_shards(mesh, sample_axes)
         spec_axis = sample_axes if len(sample_axes) > 1 else sample_axes[0]
         cv_spec, cg_spec = P(spec_axis), P(spec_axis, None)
         self._carry_shardings = (NamedSharding(mesh, cv_spec),
                                  NamedSharding(mesh, cg_spec))
-        replicas = self._replicas
 
         def partial_body(cv, cg, coef, batch):
             # shard-local accumulate: cv [1], cg [1, d] — NO collectives
@@ -126,7 +125,7 @@ class StreamedProblem:
         def finalize_body(cv, cg, coef, l2):
             # the pass's single reduction: one staged ICI-then-DCN psum
             packed = _staged_all_psum(jnp.concatenate([cg[0], cv]), mesh)
-            carry = (packed[-1] / replicas, packed[:-1] / replicas)
+            carry = (packed[-1], packed[:-1])
             return obj.finalize_streamed(carry, coef, Hyper(l2_weight=l2))
 
         def partial(carry, coef, batch):
@@ -135,14 +134,14 @@ class StreamedProblem:
             return M.shard_map(partial_body, mesh=mesh,
                                in_specs=(cv_spec, cg_spec, P(), specs),
                                out_specs=(cv_spec, cg_spec),
-                               check_rep=False)(carry[0], carry[1], coef,
+                               check_vma=False)(carry[0], carry[1], coef,
                                                 batch)
 
         def finalize(carry, coef, l2):
             return M.shard_map(finalize_body, mesh=mesh,
                                in_specs=(cv_spec, cg_spec, P(), P()),
                                out_specs=(P(), P()),
-                               check_rep=False)(carry[0], carry[1], coef, l2)
+                               check_vma=False)(carry[0], carry[1], coef, l2)
 
         self._partial = jit_donating(partial, donate_argnums=(0,))
         self._finalize = jax.jit(finalize)

@@ -59,7 +59,7 @@ from typing import Optional, Sequence, Tuple
 
 ENV_BUDGET = "PHOTON_TPU_RE_HBM_BUDGET"
 
-# host/CPU fallback when the backend reports no bytes_limit: big enough
+# CPU-only fallback (a CPU backend reports no bytes_limit): big enough
 # that tests and CPU benches only degrade when they *force* a budget
 _FALLBACK_BUDGET_BYTES = 1 << 30            # 1 GiB
 # fraction of the backend's bytes_limit the sweep may claim — the rest
@@ -79,23 +79,24 @@ def default_hbm_budget_bytes(device=None) -> Tuple[int, str]:
     """(budget bytes, source) — source is ``env`` | ``backend`` |
     ``fallback``. Reads ``PHOTON_TPU_RE_HBM_BUDGET`` first, then the
     backend's ``memory_stats()['bytes_limit']`` (scaled by the safety
-    fraction), else a nominal host figure (CPU backends usually report
-    no limit)."""
+    fraction). Only a CPU, which reports no limit, gets the nominal host
+    figure; an accelerator that reports none is an error — planning a
+    16 GB chip against 1 GiB would be a silent 16x under-use."""
     env = os.environ.get(ENV_BUDGET)
     if env:
         return max(1, int(env)), "env"
-    try:
-        if device is None:
-            import jax
-            device = jax.local_devices()[0]
-        stats = device.memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return int(limit * _BACKEND_BUDGET_FRACTION), "backend"
-    except Exception:  # hygiene-ok: any backend-probe failure (not yet
-        # initialized, no memory_stats on this platform) means "budget
-        # unknown" — the typed answer is the nominal fallback source
-        pass
+    if device is None:
+        import jax
+        device = jax.local_devices()[0]
+    stats = device.memory_stats() or {}
+    limit = int(stats.get("bytes_limit", 0))
+    if limit > 0:
+        return int(limit * _BACKEND_BUDGET_FRACTION), "backend"
+    if device.platform != "cpu":
+        raise RuntimeError(
+            f"{device.platform} device {device.device_kind!r} reports no "
+            f"memory_stats()['bytes_limit'] to plan random-effect blocks "
+            f"against; set {ENV_BUDGET} to its HBM bytes")
     return _FALLBACK_BUDGET_BYTES, "fallback"
 
 

@@ -41,13 +41,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from photon_tpu.data.dataset import DataBatch
 from photon_tpu.ops import features as F
 
-# jax.shard_map only exists from 0.5; this tree pins 0.4.x where the
-# implementation lives under jax.experimental. Re-exported so shard_map
-# callers (tests, bench bodies) have one version-stable spelling.
-try:
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+# the repo's one import of shard_map: solver modules, tests and bench
+# bodies all spell it ``M.shard_map``
+from jax import shard_map  # noqa: F401
 
 DATA_AXIS = "data"
 # cross-slice (DCN) factor of a two-level data axis; see staged_psum
@@ -258,7 +254,7 @@ def count_axis_psums(fn, axis: str, *example_args) -> int:
         return n
 
     def _sub_jaxprs(v):
-        core = jax.core
+        from jax.extend import core
         if isinstance(v, core.ClosedJaxpr):
             return [v.jaxpr]
         if isinstance(v, core.Jaxpr):

@@ -43,7 +43,7 @@ from photon_tpu.serving.model_state import DeviceResidentModel
 from photon_tpu.serving.scorer import (build_scorer_fn, get_scorer,
                                        mode_args, program_key,
                                        serving_modes)
-from photon_tpu.utils import compile_cache, jitcache
+from photon_tpu.utils import jitcache
 
 _logger = logging.getLogger("photon_tpu.serving.programs")
 
@@ -58,18 +58,37 @@ def _refuse(reason: str, detail: str = "") -> dict:
     return {"loaded": 0, "refused": reason, "detail": detail}
 
 
+def _host_fingerprint() -> str:
+    """Short token for (machine, CPU features): XLA's AOT loader will load
+    an executable compiled for a different feature set with only a warning
+    ('could lead to ... SIGILL'), so a bundle records the host it was
+    built on."""
+    import hashlib
+    import platform
+
+    bits = [platform.machine(), platform.processor() or ""]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith("flags"):
+                    bits.append(" ".join(sorted(line.split()[2:])))
+                    break
+    except OSError:
+        pass
+    return hashlib.sha256("|".join(bits).encode()).hexdigest()[:12]
+
+
 def _jax_fingerprint() -> dict:
     """Everything an executable is pinned to besides model shapes: jax
     version, backend, device count, and the host CPU-feature fingerprint
-    (XLA loads foreign-host executables with only a SIGILL warning —
-    same reason the persistent cache dir is host-keyed)."""
+    (XLA loads foreign-host executables with only a SIGILL warning)."""
     import jax
 
     return {
         "jax": jax.__version__,
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
-        "host": compile_cache._host_fingerprint(),
+        "host": _host_fingerprint(),
         "pallas_serving": os.environ.get("PHOTON_TPU_PALLAS_SERVING") == "1",
     }
 

@@ -20,52 +20,48 @@ from photon_tpu.obs.metrics import registry as _metrics
 
 _logger = logging.getLogger("photon_tpu.compile_cache")
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "photon_tpu", "xla_cache")
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+ENV_OPT_OUT = "PHOTON_TPU_NO_XLA_CACHE"
 
-_enabled = False
-
-
-def _host_fingerprint() -> str:
-    """Short token for (machine, CPU features): XLA's AOT loader will load
-    an executable compiled for a different feature set with only a warning
-    ('could lead to ... SIGILL'), so the cache directory itself must be
-    host-specific."""
-    import hashlib
-    import platform
-
-    bits = [platform.machine(), platform.processor() or ""]
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    bits.append(" ".join(sorted(line.split()[2:])))
-                    break
-    except OSError:
-        pass
-    return hashlib.sha256("|".join(bits).encode()).hexdigest()[:12]
+# a cache that moves between runs never hits, so the default is one fixed
+# place per checkout: resolved from the package location, never from
+# $HOME, a pid, a temp name or the time (gitignored; tests/conftest.py
+# points the test suite at the same directory)
+_CHECKOUT_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    ".jax_compile_cache"))
 
 
-def enable_persistent_cache(cache_dir: str | None = None) -> str:
-    """Enable JAX's on-disk compilation cache (idempotent).
+def cache_dir() -> str:
+    """Where this run's compiled programs are kept: exactly the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set (the machine's
+    owner placed the cache), else ``<checkout>/.jax_compile_cache``."""
+    return os.environ.get(ENV_CACHE_DIR) or _CHECKOUT_DIR
 
-    Returns the cache directory in use. Call before the first jit
-    compilation for maximum effect; later calls still help future jits.
+
+def enable_persistent_cache() -> str:
+    """Enable JAX's on-disk compilation cache (idempotent) and return the
+    directory in use. With ``JAX_COMPILATION_CACHE_DIR`` set JAX itself
+    reads the directory from the environment and this function sets only
+    thresholds; no directory is ever set in code over the variable.
+
+    Call before the first jit compilation for maximum effect; later
+    calls still help future jits.
     """
-    global _enabled
     import jax
 
-    base = cache_dir or os.environ.get("PHOTON_TPU_XLA_CACHE", _DEFAULT_DIR)
-    path = os.path.join(base, _host_fingerprint())
+    path = cache_dir()
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # cache aggressively: GAME programs are many medium-sized executables
-    # (one solve per coordinate x block-shape set); tracing/lowering is
-    # NOT covered by this cache, so skipping even fast compiles just adds
-    # to the uncacheable floor
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    if not os.environ.get(ENV_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", path)
+    # cache everything: a GAME run is many small executables (one solve
+    # per coordinate x block-shape set, one scorer per bucket). On a v5e
+    # 128 of the smoke's 146 programs compiled in under 0.2 s each and
+    # together were the 11 s a warm cache still paid at that threshold;
+    # tracing/lowering is NOT covered by this cache, so every skipped
+    # compile just adds to the uncacheable floor
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _enabled = True
     # activation is observable: the gauge says whether the persistent cache
     # is on, and the log line says where it lives (debuggability contract —
     # "was the cache even active for this run?")
@@ -76,28 +72,31 @@ def enable_persistent_cache(cache_dir: str | None = None) -> str:
 
 
 def maybe_enable() -> str | None:
-    """Entry-point hook: enable the cache unless the user opted out via
-    ``PHOTON_TPU_NO_XLA_CACHE``. One opt-out semantic for every driver.
-    The cache is a pure optimization — any failure (unwritable HOME,
-    missing jax config flags) is logged, never fatal."""
-    if os.environ.get("PHOTON_TPU_NO_XLA_CACHE"):
+    """Entry-point hook every driver goes through: enable the cache
+    unless the user opted out via ``PHOTON_TPU_NO_XLA_CACHE``. On a CPU
+    run a cache that cannot be enabled (unwritable directory) is a
+    warning; on any other backend it is an error — a chip run that
+    silently recompiles everything pays minutes per process."""
+    if os.environ.get(ENV_OPT_OUT):
         _metrics.counter("compile_cache.disabled", reason="env_opt_out").inc()
         _metrics.gauge("compile_cache.enabled").set(0)
-        _logger.info("persistent XLA cache disabled via PHOTON_TPU_NO_XLA_CACHE")
+        _logger.info("persistent XLA cache disabled via %s", ENV_OPT_OUT)
         return None
     try:
         return enable_persistent_cache()
-    except Exception as e:  # noqa: BLE001 — optional feature must not kill a driver
+    except OSError as e:
+        import jax
+
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"persistent XLA cache at {cache_dir()!r} cannot be enabled "
+                f"on backend {jax.default_backend()!r}: {e!r} (set "
+                f"{ENV_CACHE_DIR} to a writable directory, or {ENV_OPT_OUT}=1 "
+                f"to run without one)") from e
         _metrics.counter("compile_cache.disabled", reason="error").inc()
         _metrics.gauge("compile_cache.enabled").set(0)
-        import logging
-        logging.getLogger("photon_tpu").warning(
-            "persistent XLA cache unavailable: %r", e)
+        _logger.warning("persistent XLA cache unavailable: %r", e)
         return None
-
-
-def is_enabled() -> bool:
-    return _enabled
 
 
 # ---------------------------------------------------------------------------

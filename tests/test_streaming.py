@@ -451,16 +451,32 @@ class TestOverlapGauges:
         from photon_tpu.obs.metrics import registry
         from photon_tpu.utils.flops import stream_overlap_utilization
 
+        class V5e:           # a device the peaks table knows
+            platform, device_kind = "tpu", "TPU v5 lite"
+
         rec = stream_overlap_utilization(
             reader_busy_s=2.0, consumer_stall_s=0.5, wall_s=4.0,
-            bytes_h2d=10 * 2**20)
+            bytes_h2d=10 * 2**20, device=V5e(), phase="on-chip")
         assert rec["hidden_s"] == pytest.approx(1.5)
         assert rec["overlap_efficiency"] == pytest.approx(0.75)
+        assert rec["peak_h2d_bw"] == 32e9
         assert rec["h2d_bw_utilization"] == pytest.approx(
-            10 * 2**20 / 4.0 / rec["peak_h2d_bw"])
+            10 * 2**20 / 4.0 / 32e9)
+        # the CPU the tests run on has no peak: the overlap ratio (host
+        # clocks) is still reported, the utilization figure is not
+        cpu = stream_overlap_utilization(
+            reader_busy_s=2.0, consumer_stall_s=0.5, wall_s=4.0,
+            bytes_h2d=10 * 2**20, phase="on-cpu")
+        assert cpu["overlap_efficiency"] == pytest.approx(0.75)
+        assert cpu["h2d_bw_utilization"] is None
+        assert cpu["peak_h2d_bw"] is None
         gauges = registry.snapshot()["gauges"]
-        assert any("perf.stream_overlap" in k for k in gauges)
-        assert any("perf.h2d_bw_util" in k for k in gauges)
+        assert any("perf.stream_overlap" in k and "on-cpu" in k
+                   for k in gauges)
+        assert any("perf.h2d_bw_util" in k and "on-chip" in k
+                   for k in gauges)
+        assert not any("perf.h2d_bw_util" in k and "on-cpu" in k
+                       for k in gauges)
         # an idle reader hid everything there was to hide
         assert stream_overlap_utilization(0.0, 0.0, 1.0, 0)[
             "overlap_efficiency"] == 1.0
