@@ -1,0 +1,17 @@
+"""Median seconds from submit to response in the closed loop; by Little's
+law it is the callers over the completed rate."""
+
+import numpy as np
+
+LAYER = "load_generator"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "host_clock"
+MOVES = "serve_rps"
+
+
+def read(run):
+    samples = run.window["latency"]
+    if len(samples) < 1000:
+        return None
+    return float(np.percentile(samples, 50)) * 1e3

@@ -1,0 +1,9 @@
+"""``device_idle_share`` in the steady serving cell."""
+
+from benchmark.layer_metrics.device_idle_share import read  # noqa: F401
+
+LAYER = "device"
+UNIT = "%"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "serve_p99_ms"
