@@ -5949,11 +5949,13 @@ def main():
     log(f"devices: {devs}")
     from photon_tpu.utils.compile_cache import maybe_enable
     log(f"persistent XLA cache: {maybe_enable()}")
+    from photon_tpu.obs import memory as _obs_memory
     from photon_tpu.obs.spans import span as _obs_span
 
     if args.mode != "train":
         with _obs_span(f"bench/{args.mode}"):
             rec = MODES[args.mode](args)
+        _obs_memory.record_phase(f"bench/{args.mode}")
         emit(rec)
         _DONE.set()     # the record above IS the summary
         if "error" in rec:
@@ -5976,6 +5978,7 @@ def main():
         try:
             with _obs_span(f"bench/{name}"):
                 emit(fn(args.scale))
+            _obs_memory.record_phase(f"bench/{name}")
         except Exception as e:  # noqa: BLE001 — keep the configs already
             # measured; the failure is recorded and fails the run below
             import traceback

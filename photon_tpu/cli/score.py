@@ -137,6 +137,7 @@ def _run(args: argparse.Namespace) -> np.ndarray:
         payload={"num_scored": int(len(scores)),
                  "evaluation": evaluations}))
     _root_span.__exit__(None, None, None)
+    obs.memory.record_phase("score")
     if obs.enabled():
         try:
             obs.write_run_report(

@@ -434,6 +434,7 @@ def _run(args: argparse.Namespace) -> List:
         # failure trail, then let main() map it to a distinct exit code
         logger.warning("training interrupted: %s", e)
         _root_span.__exit__(None, None, None)
+        obs.memory.record_phase("train")
         _write_telemetry_artifacts(out_dir, mesh, len(sweeps),
                                    update_sequence)
         raise
@@ -507,6 +508,9 @@ def _run(args: argparse.Namespace) -> List:
         else dict(best.evaluation)))
     save_models(args, estimator, results, tuned, index_maps, out_dir)
     _root_span.__exit__(None, None, None)
+    # the driver's root is the phase boundary the RunReport's memory
+    # watermarks are sampled at (a span itself samples nothing)
+    obs.memory.record_phase("train")
     _write_telemetry_artifacts(out_dir, mesh, len(sweeps), update_sequence)
     return results + tuned
 

@@ -49,6 +49,7 @@ from photon_tpu.game.random_effect import (
 from photon_tpu.game.scoring import GameScorer
 from photon_tpu.optim.problem import GLMOptimizationConfiguration
 from photon_tpu.types import TaskType
+from photon_tpu.utils.timing import Timed
 
 Array = jax.Array
 logger = logging.getLogger(__name__)
@@ -186,6 +187,13 @@ class GameEstimator:
 
     def _prepare(self, df: GameDataFrame, vocab: EntityVocabulary,
                  sampling_seed: int = 0):
+        """Every coordinate's dataset and solver object, from the frame.
+        The host seconds of each step are ``Timed`` phases, recorded with
+        telemetry on or off (a job's set-up is measured with it off):
+        ``ingest/prepare/<coordinate id>/<step>`` for host work,
+        ``ingest/h2d/<coordinate id>`` for placements, ``ingest/stats``
+        (game/random_effect.py, game/dataset.py; PERF.md §3). A fit on a
+        frame ``_prepare_cached`` already holds records none."""
         coordinates: Dict[str, object] = {}
         re_datasets: Dict[str, RandomEffectDataset] = {}
         # original (pre-RANDOM-projection) feature dims per RE coordinate —
@@ -207,24 +215,30 @@ class GameEstimator:
                     norm, icpt = None, None
                 self._original_dims[cid] = df.feature_shards[shard_id].dim
                 ds = build_random_effect_dataset(
-                    df, cfg.data, vocab, dtype=np.dtype(self.dtype).type)
+                    df, cfg.data, vocab, dtype=np.dtype(self.dtype).type,
+                    coordinate=cid)
                 re_datasets[cid] = ds
-                coordinates[cid] = RandomEffectCoordinate(
-                    ds, df.num_samples, cfg.data.random_effect_type,
-                    cfg.data.feature_shard_id, self.task, cfg.optimization,
-                    mesh=self.mesh,
-                    variance_type=self.variance_computation_type,
-                    norm=norm, intercept_index=icpt)
+                with Timed(f"ingest/prepare/{cid}/coordinate",
+                           level=logging.DEBUG):
+                    coordinates[cid] = RandomEffectCoordinate(
+                        ds, df.num_samples, cfg.data.random_effect_type,
+                        cfg.data.feature_shard_id, self.task,
+                        cfg.optimization, mesh=self.mesh,
+                        variance_type=self.variance_computation_type,
+                        norm=norm, intercept_index=icpt)
             else:
                 batch = df.fixed_effect_batch(
                     shard_id, dtype=np.dtype(self.dtype).type,
-                    feature_dtype=self.feature_dtype)
-                key = jax.random.PRNGKey(sampling_seed + i)
-                coordinates[cid] = FixedEffectCoordinate(
-                    batch, df.feature_shards[shard_id].dim, shard_id, self.task,
-                    cfg.optimization, sampling_key=key, mesh=self.mesh,
-                    variance_type=self.variance_computation_type,
-                    norm=norm, intercept_index=icpt)
+                    feature_dtype=self.feature_dtype, coordinate=cid)
+                with Timed(f"ingest/prepare/{cid}/coordinate",
+                           level=logging.DEBUG):
+                    key = jax.random.PRNGKey(sampling_seed + i)
+                    coordinates[cid] = FixedEffectCoordinate(
+                        batch, df.feature_shards[shard_id].dim, shard_id,
+                        self.task, cfg.optimization, sampling_key=key,
+                        mesh=self.mesh,
+                        variance_type=self.variance_computation_type,
+                        norm=norm, intercept_index=icpt)
         return coordinates, re_datasets
 
     def _prepare_cached(self, df: GameDataFrame):

@@ -14,6 +14,17 @@ L-BFGS over the entity-blocked dataset (per-entity convergence masking via
 the while_loop batching rule) — the reference's millions of independent
 Breeze solves become one SPMD program on the entity-sharded mesh axis.
 Residual injection is a gather; score emission is a scatter-add.
+
+Names (PERF.md §3; they are an interface). Inside the programs, by
+``jax.named_scope``: ``fe/score``, ``re/score``, and in the ladder solve
+``re/b<index>`` around each size bucket's block with ``re/gather`` (warm
+start and residual rows in) and ``re/scatter`` (solved rows out) inside it;
+the per-entity solve in between carries the solver's own ``optim/`` and
+``agg/`` names. On the host, by ``obs.annotate`` (events of a profiler trace
+when telemetry is on, nothing otherwise): ``fe/args`` / ``re/args`` (the
+eager programs that build a solve's arguments), ``fe/solve`` / ``re/solve``
+(the dispatch), ``fe/outcome`` / ``re/outcome`` (the blocking scalar read of
+the failure code), ``fe/score`` / ``re/score``.
 """
 
 from __future__ import annotations
@@ -51,14 +62,16 @@ Array = jax.Array
 def _fixed_score(feats, coef: Array) -> Array:
     # data enters as an argument, never a closure: closed-over arrays
     # would be baked into the HLO as giant literal constants
-    return F.matvec(feats, coef)
+    with jax.named_scope("fe/score"):
+        return F.matvec(feats, coef)
 
 
 @jax.jit
 def _fixed_score_lanes(feats, coefs: Array) -> Array:
     # lane-batched validation/score pass for the sweep path: one shared
     # data read for all K coefficient lanes (ops/features.matvec_lanes)
-    return F.matvec_lanes(feats, coefs)
+    with jax.named_scope("fe/score"):
+        return F.matvec_lanes(feats, coefs)
 
 
 class FixedEffectCoordinate:
@@ -154,15 +167,11 @@ class FixedEffectCoordinate:
         self._update_count = 0
         self.mesh = mesh
 
-    def update_model(
-        self, prev: Optional[FixedEffectModel], residual_scores: Optional[Array]
-    ) -> FixedEffectModel:
-        """Train against residual-injected offsets
-        (= dataset.addScoresToOffsets + runWithSampling).
-
-        ``residual_scores`` is either the live partial score (sequential
-        sweep) or a frozen group-entry snapshot (parallel sweep) — the
-        solve is a pure function of it either way."""
+    def _solve_args(self, prev: Optional[FixedEffectModel],
+                    residual_scores: Optional[Array]):
+        """(batch, initial coefficients) of one update: the residual into
+        the offsets, the down-sample, the warm start placed. Each step is
+        an eager device program of its own."""
         batch = self.batch
         if residual_scores is not None:
             extra = batch.num_samples - residual_scores.shape[0]
@@ -192,6 +201,19 @@ class FixedEffectCoordinate:
                 if init is None else jnp.asarray(init)
             init = M.shard_coef_model_parallel(init, self.mesh,
                                                padded_dim=self._dim_padded)
+        return batch, init
+
+    def update_model(
+        self, prev: Optional[FixedEffectModel], residual_scores: Optional[Array]
+    ) -> FixedEffectModel:
+        """Train against residual-injected offsets
+        (= dataset.addScoresToOffsets + runWithSampling).
+
+        ``residual_scores`` is either the live partial score (sequential
+        sweep) or a frozen group-entry snapshot (parallel sweep) — the
+        solve is a pure function of it either way."""
+        with _obs_annotate("fe/args"):
+            batch, init = self._solve_args(prev, residual_scores)
         with _obs_annotate("fe/solve"):
             model, result = self.problem.run(
                 batch, initial=init, dim=self.dim, dtype=batch.labels.dtype,
@@ -210,7 +232,8 @@ class FixedEffectCoordinate:
         # roll the coordinate back
         self.last_failure = None
         if result.failure is not None:
-            code = int(np.asarray(result.failure))
+            with _obs_annotate("fe/outcome"):
+                code = int(np.asarray(result.failure))
             if code != FailureMode.NONE:
                 self.last_failure = FailureMode(code)
         from photon_tpu.types import VarianceComputationType
@@ -272,24 +295,10 @@ class FixedEffectCoordinate:
                 "this coordinate sequentially")
         from photon_tpu.obs.metrics import registry
         from photon_tpu.optim import batched
-        batch = self.batch
-        if residual_scores is not None:
-            extra = batch.num_samples - residual_scores.shape[0]
-            if extra:  # mesh padding: zero residual on zero-weight pad rows
-                residual_scores = jnp.pad(residual_scores, (0, extra))
-            batch = batch.add_scores_to_offsets(residual_scores)
-        if getattr(self, "_chaos_poison_once", False):
-            # fault injection (resilience/chaos.py): poisons every lane's
-            # shared data term, like a corrupt upstream residual
-            self._chaos_poison_once = False
-            batch = batch.add_scores_to_offsets(
-                jnp.full((batch.num_samples,), jnp.nan, batch.labels.dtype))
-        if self._sampling_key is not None and self.config.down_sampling_rate < 1.0:
-            key = jax.random.fold_in(self._sampling_key, self._update_count)
-            self._update_count += 1
-            batch = maybe_downsample(batch, self.task,
-                                     self.config.down_sampling_rate, key)
-        init = prev.model.coefficients.means if prev is not None else None
+        # the same arguments as update_model's: a poisoned residual
+        # poisons every lane's shared data term
+        with _obs_annotate("fe/args"):
+            batch, init = self._solve_args(prev, residual_scores)
         with _obs_annotate("fe/solve_swept"):
             # the coordinate's batch was (possibly) sharded at
             # construction, so the solve gets mesh=None: GSPMD follows
@@ -658,20 +667,25 @@ class RandomEffectCoordinate:
                       norm_f: Optional[Array] = None,
                       norm_s: Optional[Array] = None,
                       norm_islot: Optional[Array] = None):
-                out = coef0  # entities with no active data keep warm start
-                E = coef0.shape[0]
-                # per-entity solver stats (-1 = entity never trained)
-                iters = jnp.full((E,), -1, jnp.int32)
-                reasons = jnp.full((E,), -1, jnp.int32)
-                fails = jnp.zeros((E,), jnp.int32)
-                for blk, dense in zip(ds.blocks, dense_flags):
-                    offsets = blk.offsets
-                    if residual_flat is not None:
-                        # gather residuals by flat row; pad rows -> fill 0
-                        res = residual_flat.at[blk.sample_rows].get(
+            out = coef0  # entities with no active data keep warm start
+            E = coef0.shape[0]
+            # per-entity solver stats (-1 = entity never trained)
+            iters = jnp.full((E,), -1, jnp.int32)
+            reasons = jnp.full((E,), -1, jnp.int32)
+            fails = jnp.zeros((E,), jnp.int32)
+            for bi, (blk, dense) in enumerate(zip(ds.blocks, dense_flags)):
+                # one scope a bucket, so a bucket's device seconds can be
+                # set beside its entity count and padded shape
+                with jax.named_scope(f"re/b{bi}"):
+                    with jax.named_scope("re/gather"):
+                        offsets = blk.offsets
+                        if residual_flat is not None:
+                            # gather residuals by flat row; pad rows -> fill 0
+                            res = residual_flat.at[blk.sample_rows].get(
+                                mode="fill", fill_value=0.0)
+                            offsets = offsets + res
+                        x0 = coef0.at[blk.entity_rows].get(
                             mode="fill", fill_value=0.0)
-                        offsets = offsets + res
-                    x0 = coef0.at[blk.entity_rows].get(mode="fill", fill_value=0.0)
                     if dense:
                         fn = solve_dense
                         args = [blk.features.values,
@@ -683,26 +697,29 @@ class RandomEffectCoordinate:
                                 blk.labels, offsets, blk.weights, x0, l2, l1]
                         axes = [0, 0, 0, 0, 0, 0, None, None]
                     if norm_f is not None:
-                        args.append(norm_f.at[blk.entity_rows].get(
-                            mode="fill", fill_value=1.0))
-                        axes.append(0)
-                        if norm_s is not None:
-                            args.append(norm_s.at[blk.entity_rows].get(
-                                mode="fill", fill_value=0.0))
-                            args.append(norm_islot.at[blk.entity_rows].get(
-                                mode="fill", fill_value=-1))
-                            axes.extend([0, 0])
+                        with jax.named_scope("re/gather"):
+                            args.append(norm_f.at[blk.entity_rows].get(
+                                mode="fill", fill_value=1.0))
+                            axes.append(0)
+                            if norm_s is not None:
+                                args.append(norm_s.at[blk.entity_rows].get(
+                                    mode="fill", fill_value=0.0))
+                                args.append(norm_islot.at[blk.entity_rows].get(
+                                    mode="fill", fill_value=-1))
+                                axes.extend([0, 0])
                     solved, it_b, reason_b, fail_b = jax.vmap(
                         fn, in_axes=tuple(axes))(*args)
-                    # per-entity isolation: a failed entity keeps its warm
-                    # start; healthy lanes in the same block keep their
-                    # fresh solves (no host branch — pure select)
-                    solved = jnp.where((fail_b != 0)[:, None], x0, solved)
-                    out = out.at[blk.entity_rows].set(solved, mode="drop")
-                    iters = iters.at[blk.entity_rows].set(it_b, mode="drop")
-                    reasons = reasons.at[blk.entity_rows].set(reason_b, mode="drop")
-                    fails = fails.at[blk.entity_rows].set(fail_b, mode="drop")
-                return out, iters, reasons, fails
+                    with jax.named_scope("re/scatter"):
+                        # per-entity isolation: a failed entity keeps its
+                        # warm start; healthy lanes in the same block keep
+                        # their fresh solves (no host branch — pure select)
+                        solved = jnp.where((fail_b != 0)[:, None], x0, solved)
+                        out = out.at[blk.entity_rows].set(solved, mode="drop")
+                        iters = iters.at[blk.entity_rows].set(it_b, mode="drop")
+                        reasons = reasons.at[blk.entity_rows].set(
+                            reason_b, mode="drop")
+                        fails = fails.at[blk.entity_rows].set(fail_b, mode="drop")
+            return out, iters, reasons, fails
 
         return solve_all
 
@@ -745,18 +762,24 @@ class RandomEffectCoordinate:
             iters = jnp.full((K, E), -1, jnp.int32)
             reasons = jnp.full((K, E), -1, jnp.int32)
             fails = jnp.zeros((K, E), jnp.int32)
-            for blk, dense in zip(ds.blocks, dense_flags):
-                x0 = coef0_lanes.at[:, blk.entity_rows].get(
-                    mode="fill", fill_value=0.0)
-                core = core_dense if dense else core_sparse
-                solved, it_b, reason_b, fail_b = core(
-                    blk, residual_flat, x0, l2_lanes, l1_lanes,
-                    norm_f, norm_s, norm_islot)
-                out = out.at[:, blk.entity_rows].set(solved, mode="drop")
-                iters = iters.at[:, blk.entity_rows].set(it_b, mode="drop")
-                reasons = reasons.at[:, blk.entity_rows].set(
-                    reason_b, mode="drop")
-                fails = fails.at[:, blk.entity_rows].set(fail_b, mode="drop")
+            for bi, (blk, dense) in enumerate(zip(ds.blocks, dense_flags)):
+                with jax.named_scope(f"re/b{bi}"):
+                    with jax.named_scope("re/gather"):
+                        x0 = coef0_lanes.at[:, blk.entity_rows].get(
+                            mode="fill", fill_value=0.0)
+                    core = core_dense if dense else core_sparse
+                    solved, it_b, reason_b, fail_b = core(
+                        blk, residual_flat, x0, l2_lanes, l1_lanes,
+                        norm_f, norm_s, norm_islot)
+                    with jax.named_scope("re/scatter"):
+                        out = out.at[:, blk.entity_rows].set(
+                            solved, mode="drop")
+                        iters = iters.at[:, blk.entity_rows].set(
+                            it_b, mode="drop")
+                        reasons = reasons.at[:, blk.entity_rows].set(
+                            reason_b, mode="drop")
+                        fails = fails.at[:, blk.entity_rows].set(
+                            fail_b, mode="drop")
             return out, iters, reasons, fails
 
         return solve_all_lanes
@@ -788,24 +811,27 @@ class RandomEffectCoordinate:
         self, prev: Optional[RandomEffectModel], residual_scores: Optional[Array]
     ) -> RandomEffectModel:
         ds = self.dataset
-        dtype = (prev.coefficients.dtype if prev is not None
-                 else (ds.blocks[0].labels.dtype if ds.blocks else jnp.float32))
-        coef0 = (prev.coefficients if prev is not None
-                 else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
-        coef0 = self._pad_entity_rows(coef0)
-        lam = self.config.regularization_weight
-        l2 = jnp.asarray(self.config.regularization.l2_weight(lam), dtype)
-        l1 = jnp.asarray(self.config.regularization.l1_weight(lam), dtype)
-        norm_args = ()
-        if self._norm_local is not None:
-            f, s, islot = self._norm_local
-            norm_args = (f,) if s is None else (f, s, islot)
-        if getattr(self, "_chaos_poison_once", False):
-            # fault injection (resilience/chaos.py): NaN residuals poison
-            # every entity's objective, like a corrupt upstream score pass
-            self._chaos_poison_once = False
-            residual_scores = jnp.full((self.n,), jnp.nan,
-                                       coef0.dtype)
+        with _obs_annotate("re/args"):
+            dtype = (prev.coefficients.dtype if prev is not None
+                     else (ds.blocks[0].labels.dtype if ds.blocks
+                           else jnp.float32))
+            coef0 = (prev.coefficients if prev is not None
+                     else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
+            coef0 = self._pad_entity_rows(coef0)
+            lam = self.config.regularization_weight
+            l2 = jnp.asarray(self.config.regularization.l2_weight(lam), dtype)
+            l1 = jnp.asarray(self.config.regularization.l1_weight(lam), dtype)
+            norm_args = ()
+            if self._norm_local is not None:
+                f, s, islot = self._norm_local
+                norm_args = (f,) if s is None else (f, s, islot)
+            if getattr(self, "_chaos_poison_once", False):
+                # fault injection (resilience/chaos.py): NaN residuals
+                # poison every entity's objective, like a corrupt upstream
+                # score pass
+                self._chaos_poison_once = False
+                residual_scores = jnp.full((self.n,), jnp.nan,
+                                           coef0.dtype)
         with _obs_annotate("re/solve"):
             coefs, iters, reasons, fails = self._solve_fn(
                 self.dataset, residual_scores, coef0, l2, l1, *norm_args)
@@ -820,8 +846,9 @@ class RandomEffectCoordinate:
         # failure isolation already happened device-side (failed entities
         # kept their warm start inside solve_all); here only the counts
         # cross to the host — one scalar at the coordinate boundary
-        fails_orig = fails[:e_orig]
-        n_failed = int(np.asarray(jnp.sum(fails_orig != 0)))
+        with _obs_annotate("re/outcome"):
+            fails_orig = fails[:e_orig]
+            n_failed = int(np.asarray(jnp.sum(fails_orig != 0)))
         self.last_failed_entities = n_failed
         self.last_failure = None
         if n_failed and e_orig and n_failed == e_orig:
@@ -992,7 +1019,12 @@ class RandomEffectCoordinate:
         """One size bucket's solve body, UNJITTED — the scalar blocked
         program (``_block_solve_fn``). The λ-lane blocked program
         (``_block_solve_swept_fn``) shares the per-entity solvers and
-        the exact vmap structure via ``_make_block_solver_swept``."""
+        the exact vmap structure via ``_make_block_solver_swept``.
+
+        One compiled program serves every bucket of its flavour, so it
+        cannot carry a ``re/b<index>`` scope: in a trace the bucket is the
+        ``block`` attribute of the host span ``re/solve_block`` around each
+        launch."""
         solve_sparse, solve_dense = self._make_entity_solvers()
 
         def solve_block(blk: EntityBlock, residual_flat: Optional[Array],
@@ -1002,8 +1034,9 @@ class RandomEffectCoordinate:
                         norm_islot: Optional[Array] = None):
             offsets = blk.offsets
             if residual_flat is not None:
-                offsets = offsets + residual_flat.at[blk.sample_rows].get(
-                    mode="fill", fill_value=0.0)
+                with jax.named_scope("re/gather"):
+                    offsets = offsets + residual_flat.at[
+                        blk.sample_rows].get(mode="fill", fill_value=0.0)
             if dense:
                 fn = solve_dense
                 args = [blk.features.values,
@@ -1085,8 +1118,9 @@ class RandomEffectCoordinate:
                     else (lambda a: a))
             offsets = blk.offsets
             if residual_flat is not None:
-                offsets = offsets + residual_flat.at[blk.sample_rows].get(
-                    mode="fill", fill_value=0.0)
+                with jax.named_scope("re/gather"):
+                    offsets = offsets + residual_flat.at[
+                        blk.sample_rows].get(mode="fill", fill_value=0.0)
             x0 = x0_lanes.reshape((c * E,) + x0_lanes.shape[2:])
             l2e = jnp.repeat(l2_lanes, E)
             l1e = jnp.repeat(l1_lanes, E)
@@ -1700,6 +1734,7 @@ class RandomEffectCoordinate:
 
 
 def _re_score_builder(n: int, dense_flags=()):
+    @jax.named_scope("re/score")
     def score(ds: RandomEffectDataset, coef_block: Array) -> Array:
         flat = jnp.zeros((n,), coef_block.dtype)
         flags = (dense_flags if len(dense_flags) == len(ds.blocks)

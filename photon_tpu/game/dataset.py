@@ -16,15 +16,26 @@ uids never leave the host.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from photon_tpu.data.dataset import DataBatch
+from photon_tpu.obs.metrics import registry
 from photon_tpu.ops import features as F
+from photon_tpu.utils.timing import Timed
 
 SparseRows = List[Tuple[np.ndarray, np.ndarray]]  # per-row (indices, values)
+
+
+def count_placed(coordinate: str, tree) -> None:
+    """Add the bytes of the device arrays in ``tree``, just placed for
+    ``coordinate``, to the always-on counter ``ingest.h2d_bytes``."""
+    registry.counter("ingest.h2d_bytes", coordinate=coordinate).inc(
+        sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)))
 
 
 class CsrRows:
@@ -123,7 +134,8 @@ class GameDataFrame:
         return F.from_rows(shard.rows, shard.dim, dtype=dtype)
 
     def fixed_effect_batch(self, shard_id: str, dtype=np.float32,
-                           feature_dtype=None) -> DataBatch:
+                           feature_dtype=None,
+                           coordinate: Optional[str] = None) -> DataBatch:
         """Reference: FixedEffectDataset — flat uid-major batch over one
         feature shard.
 
@@ -131,13 +143,22 @@ class GameDataFrame:
         bfloat16 under an f32 solve): matvec/rmatvec promote to the
         accumulation dtype in-register, so a bandwidth-bound solve reads
         half the HBM bytes while the optimizer math stays full-precision.
-        """
-        return DataBatch(
-            features=self.shard_features(shard_id, feature_dtype or dtype),
-            labels=jnp.asarray(self.response, dtype),
-            offsets=None if self.offsets is None else jnp.asarray(self.offsets, dtype),
-            weights=None if self.weights is None else jnp.asarray(self.weights, dtype),
-        )
+
+        The host seconds of the placement are the ``Timed`` phase
+        ``ingest/h2d/<coordinate>`` (the shard id when the caller gives no
+        coordinate; a sparse shard's padded fill, which ``ops/features``
+        does in the same call, is inside it), and the placed bytes go to
+        the counter ``ingest.h2d_bytes{coordinate}``."""
+        coordinate = coordinate or shard_id
+        with Timed(f"ingest/h2d/{coordinate}", level=logging.DEBUG):
+            batch = DataBatch(
+                features=self.shard_features(shard_id, feature_dtype or dtype),
+                labels=jnp.asarray(self.response, dtype),
+                offsets=None if self.offsets is None else jnp.asarray(self.offsets, dtype),
+                weights=None if self.weights is None else jnp.asarray(self.weights, dtype),
+            )
+        count_placed(coordinate, batch)
+        return batch
 
 
 class EntityVocabulary:

@@ -327,7 +327,8 @@ def run_coordinate_descent(
         residual = partial if len(config.update_sequence) > 1 else None
         with _obs_spans.span("cd/update", coordinate=cid):
             new_model = coord.update_model(models.get(cid), residual)
-        _record_solver_obs(cid, coord, it)
+        with _obs_spans.annotate("cd/record", coordinate=cid):
+            _record_solver_obs(cid, coord, it)
         failure = getattr(coord, "last_failure", None)
         if failure is not None:
             # coordinate-level failure: discard the new model, keep the
@@ -341,8 +342,10 @@ def run_coordinate_descent(
                 raise CoordinateFailureError(
                     cid, it, consecutive[cid], checkpoint_path=path)
             return False
-        new_score = coord.score(new_model)
-        _commit(cid, it, new_model, new_score)
+        with _obs_spans.annotate("cd/score", coordinate=cid):
+            new_score = coord.score(new_model)
+        with _obs_spans.annotate("cd/commit", coordinate=cid):
+            _commit(cid, it, new_model, new_score)
         return True
 
     def _run_group(it: int, gi: int, g_start: int, members: List[str],

@@ -23,6 +23,7 @@ recomputation exactly like the reference's byteswap64 trick.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -32,8 +33,13 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-from photon_tpu.game.dataset import EntityVocabulary, GameDataFrame
+from photon_tpu.game.dataset import (
+    EntityVocabulary,
+    GameDataFrame,
+    count_placed,
+)
 from photon_tpu.ops import features as F
+from photon_tpu.utils.timing import Timed
 
 Array = jax.Array
 
@@ -171,103 +177,120 @@ def build_random_effect_dataset(
     vocab: EntityVocabulary,
     dtype=np.float32,
     scores_offsets: Optional[np.ndarray] = None,
+    coordinate: Optional[str] = None,
 ) -> RandomEffectDataset:
     """Fully-vectorized ingest: grouping, deterministic reservoir capping,
     Pearson feature selection, per-entity projection, bucketed ELL fill,
-    passive split — no per-sample Python loops."""
+    passive split — no per-sample Python loops.
+
+    Its host seconds are recorded, telemetry on or off, as ``Timed`` phases
+    named for ``coordinate`` (the random-effect type when the caller gives
+    none): ``ingest/prepare/<coordinate>/group`` (vocabulary, ordering,
+    active/passive split, projection table, local slots),
+    ``.../bucket`` (the size ladder), ``.../pad`` (one record a bucket: the
+    padded fill, which rescans every nonzero) and ``.../passive``;
+    ``ingest/h2d/<coordinate>`` around each placement (what the host
+    spends in ``jnp.asarray``: nothing waits for the copy), the placed
+    bytes going to the counter ``ingest.h2d_bytes{coordinate}``;
+    ``ingest/stats`` around the padding-waste count, which compiles a
+    tiny program a bucket shape."""
     re_type = config.random_effect_type
-    shard = df.feature_shards[config.feature_shard_id]
-    # sparse row lists, columnar CsrRows, and dense [n, d] matrices all
-    # funnel through _csr_of into the same columnar pipeline
-    shard = _maybe_random_project(shard, config)
-    n = df.num_samples
-    D = shard.dim
+    coordinate = coordinate or re_type
+    prepare, h2d = f"ingest/prepare/{coordinate}", f"ingest/h2d/{coordinate}"
+    phase = functools.partial(Timed, level=logging.DEBUG)
+    with phase(f"{prepare}/group"):
+        shard = df.feature_shards[config.feature_shard_id]
+        # sparse row lists, columnar CsrRows, and dense [n, d] matrices all
+        # funnel through _csr_of into the same columnar pipeline
+        shard = _maybe_random_project(shard, config)
+        n = df.num_samples
+        D = shard.dim
 
-    entity_idx = vocab.build(re_type, df.id_tags[re_type]).astype(np.int64)
-    E = vocab.size(re_type)
-    base_offsets = np.zeros(n) if df.offsets is None else np.asarray(df.offsets, np.float64)
-    if scores_offsets is not None:
-        base_offsets = base_offsets + np.asarray(scores_offsets, np.float64)
-    weights = np.ones(n) if df.weights is None else np.asarray(df.weights, np.float64)
-    resp = np.asarray(df.response, np.float64)
+        entity_idx = vocab.build(re_type, df.id_tags[re_type]).astype(np.int64)
+        E = vocab.size(re_type)
+        base_offsets = np.zeros(n) if df.offsets is None else np.asarray(df.offsets, np.float64)
+        if scores_offsets is not None:
+            base_offsets = base_offsets + np.asarray(scores_offsets, np.float64)
+        weights = np.ones(n) if df.weights is None else np.asarray(df.weights, np.float64)
+        resp = np.asarray(df.response, np.float64)
 
-    indptr, cols, vals = _csr_of(shard.rows)
-    nnz = np.diff(indptr)
+        indptr, cols, vals = _csr_of(shard.rows)
+        nnz = np.diff(indptr)
 
-    # -- deterministic ordering within entities + active/passive split -------
-    counts = np.bincount(entity_idx, minlength=E)
-    keys = _splitmix64(np.arange(n, dtype=np.uint64))
-    order = np.lexsort((keys, entity_idx))           # by (entity, hash)
-    starts = np.concatenate([[0], np.cumsum(counts)])
-    pos = np.arange(n) - np.repeat(starts[:-1], counts)  # rank within entity
+        # -- deterministic ordering within entities + active/passive split -------
+        counts = np.bincount(entity_idx, minlength=E)
+        keys = _splitmix64(np.arange(n, dtype=np.uint64))
+        order = np.lexsort((keys, entity_idx))           # by (entity, hash)
+        starts = np.concatenate([[0], np.cumsum(counts)])
+        pos = np.arange(n) - np.repeat(starts[:-1], counts)  # rank within entity
 
-    e_sorted = entity_idx[order]
-    active_sorted = np.ones(n, bool)
-    if config.active_data_lower_bound is not None:
-        active_sorted &= counts[e_sorted] >= config.active_data_lower_bound
-    if config.active_data_upper_bound is not None:
-        active_sorted &= pos < config.active_data_upper_bound
-    passive_sorted = ~active_sorted
-    if config.active_data_upper_bound is not None and not config.keep_passive_data:
-        # over-cap samples are dropped entirely; below-lower-bound samples
-        # stay passive (they are scored, just never trained on)
-        over_cap = pos >= config.active_data_upper_bound
+        e_sorted = entity_idx[order]
+        active_sorted = np.ones(n, bool)
         if config.active_data_lower_bound is not None:
-            over_cap &= counts[e_sorted] >= config.active_data_lower_bound
-        passive_sorted &= ~over_cap
+            active_sorted &= counts[e_sorted] >= config.active_data_lower_bound
+        if config.active_data_upper_bound is not None:
+            active_sorted &= pos < config.active_data_upper_bound
+        passive_sorted = ~active_sorted
+        if config.active_data_upper_bound is not None and not config.keep_passive_data:
+            # over-cap samples are dropped entirely; below-lower-bound samples
+            # stay passive (they are scored, just never trained on)
+            over_cap = pos >= config.active_data_upper_bound
+            if config.active_data_lower_bound is not None:
+                over_cap &= counts[e_sorted] >= config.active_data_lower_bound
+            passive_sorted &= ~over_cap
 
-    active = np.zeros(n, bool)
-    active[order] = active_sorted
-    passive = np.zeros(n, bool)
-    passive[order] = passive_sorted
-    act_counts = np.bincount(entity_idx[active], minlength=E)
+        active = np.zeros(n, bool)
+        active[order] = active_sorted
+        passive = np.zeros(n, bool)
+        passive[order] = passive_sorted
+        act_counts = np.bincount(entity_idx[active], minlength=E)
 
-    # -- observed (entity, feature) pairs over ACTIVE data -------------------
-    s_nz = np.repeat(np.arange(n), nnz)              # sample id per nonzero
-    keep_nz = active[s_nz]
-    e_nz = entity_idx[s_nz]
-    pair = e_nz * D + cols                            # int64 composite key
-    uniq = np.unique(pair[keep_nz]) if keep_nz.any() else np.zeros(0, np.int64)
+        # -- observed (entity, feature) pairs over ACTIVE data -------------------
+        s_nz = np.repeat(np.arange(n), nnz)              # sample id per nonzero
+        keep_nz = active[s_nz]
+        e_nz = entity_idx[s_nz]
+        pair = e_nz * D + cols                            # int64 composite key
+        uniq = np.unique(pair[keep_nz]) if keep_nz.any() else np.zeros(0, np.int64)
 
-    # -- optional Pearson feature selection (reference: LocalDataset:122) ----
-    if config.features_to_samples_ratio is not None and len(uniq):
-        ratio = config.features_to_samples_ratio
-        k_per_entity = np.maximum((ratio * act_counts).astype(np.int64), 1)
-        scores = _pearson_scores_vectorized(
-            uniq, pair, keep_nz, vals, s_nz, entity_idx, resp, weights,
-            active, E, D)
+        # -- optional Pearson feature selection (reference: LocalDataset:122) ----
+        if config.features_to_samples_ratio is not None and len(uniq):
+            ratio = config.features_to_samples_ratio
+            k_per_entity = np.maximum((ratio * act_counts).astype(np.int64), 1)
+            scores = _pearson_scores_vectorized(
+                uniq, pair, keep_nz, vals, s_nz, entity_idx, resp, weights,
+                active, E, D)
+            u_e = uniq // D
+            sel_order = np.lexsort((-scores, u_e))
+            u_starts = np.searchsorted(u_e[sel_order], np.arange(E))
+            sel_pos = np.arange(len(uniq)) - u_starts[u_e[sel_order]]
+            need_cap = k_per_entity[u_e[sel_order]]
+            keep_pair = np.zeros(len(uniq), bool)
+            keep_pair[sel_order[sel_pos < need_cap]] = True
+            # entities whose feature count is within bound keep everything
+            feat_counts = np.bincount(u_e, minlength=E)
+            within = feat_counts[u_e] <= np.maximum(
+                (ratio * act_counts[u_e]).astype(np.int64), 1)
+            keep_pair |= within
+            uniq = uniq[keep_pair]
+
+        # -- projection table ----------------------------------------------------
         u_e = uniq // D
-        sel_order = np.lexsort((-scores, u_e))
-        u_starts = np.searchsorted(u_e[sel_order], np.arange(E))
-        sel_pos = np.arange(len(uniq)) - u_starts[u_e[sel_order]]
-        need_cap = k_per_entity[u_e[sel_order]]
-        keep_pair = np.zeros(len(uniq), bool)
-        keep_pair[sel_order[sel_pos < need_cap]] = True
-        # entities whose feature count is within bound keep everything
-        feat_counts = np.bincount(u_e, minlength=E)
-        within = feat_counts[u_e] <= np.maximum(
-            (ratio * act_counts[u_e]).astype(np.int64), 1)
-        keep_pair |= within
-        uniq = uniq[keep_pair]
+        u_f = uniq % D
+        d_loc_per_entity = np.bincount(u_e, minlength=E) if len(uniq) else np.zeros(E, np.int64)
+        D_loc = max(int(d_loc_per_entity.max()) if E else 1, 1)
+        u_starts = np.searchsorted(u_e, np.arange(E + 1))
+        slot_of_pair = np.arange(len(uniq)) - u_starts[u_e]
+        projection = np.full((E, D_loc), -1, np.int32)
+        if len(uniq):
+            projection[u_e, slot_of_pair] = u_f.astype(np.int32)
 
-    # -- projection table ----------------------------------------------------
-    u_e = uniq // D
-    u_f = uniq % D
-    d_loc_per_entity = np.bincount(u_e, minlength=E) if len(uniq) else np.zeros(E, np.int64)
-    D_loc = max(int(d_loc_per_entity.max()) if E else 1, 1)
-    u_starts = np.searchsorted(u_e, np.arange(E + 1))
-    slot_of_pair = np.arange(len(uniq)) - u_starts[u_e]
-    projection = np.full((E, D_loc), -1, np.int32)
-    if len(uniq):
-        projection[u_e, slot_of_pair] = u_f.astype(np.int32)
-
-    # -- per-nonzero local slots (kept nonzeros only) ------------------------
-    rank = np.searchsorted(uniq, pair) if len(uniq) else np.zeros(len(pair), np.int64)
-    rank = np.minimum(rank, max(len(uniq) - 1, 0))
-    kept_nz_mask = np.zeros(len(pair), bool)
-    if len(uniq):
-        kept_nz_mask = uniq[rank] == pair
-    slot_nz = slot_of_pair[rank] if len(uniq) else np.zeros(len(pair), np.int64)
+        # -- per-nonzero local slots (kept nonzeros only) ------------------------
+        rank = np.searchsorted(uniq, pair) if len(uniq) else np.zeros(len(pair), np.int64)
+        rank = np.minimum(rank, max(len(uniq) - 1, 0))
+        kept_nz_mask = np.zeros(len(pair), bool)
+        if len(uniq):
+            kept_nz_mask = uniq[rank] == pair
+        slot_nz = slot_of_pair[rank] if len(uniq) else np.zeros(len(pair), np.int64)
 
     # position of each kept nonzero within its sample
     def _slot_positions(mask: np.ndarray) -> np.ndarray:
@@ -282,109 +305,116 @@ def build_random_effect_dataset(
         return excl - base
 
     # -- bucketed active blocks ---------------------------------------------
-    has_active = act_counts > 0
-    bucket_id = np.where(has_active, _bucket_of(act_counts), -1)
-    uniq_buckets = np.unique(bucket_id[bucket_id >= 0])
-    cap = config.max_entity_buckets
-    if cap and len(uniq_buckets) > cap:
-        # coarsen: merge adjacent pow-2 buckets into at most `cap` groups
-        # (each group pads to its largest member's S_b) — bounded compile
-        # count at the cost of extra padding, both reported below
-        groups = np.array_split(uniq_buckets, cap)
-        lut = np.arange(int(uniq_buckets.max()) + 1)
-        for g in groups:
-            lut[g] = g[-1]
-        bucket_id = np.where(bucket_id >= 0, lut[np.maximum(bucket_id, 0)], -1)
-    blocks: List[EntityBlock] = []
+    with phase(f"{prepare}/bucket"):
+        has_active = act_counts > 0
+        bucket_id = np.where(has_active, _bucket_of(act_counts), -1)
+        uniq_buckets = np.unique(bucket_id[bucket_id >= 0])
+        cap = config.max_entity_buckets
+        if cap and len(uniq_buckets) > cap:
+            # coarsen: merge adjacent pow-2 buckets into at most `cap` groups
+            # (each group pads to its largest member's S_b) — bounded compile
+            # count at the cost of extra padding, both reported below
+            groups = np.array_split(uniq_buckets, cap)
+            lut = np.arange(int(uniq_buckets.max()) + 1)
+            for g in groups:
+                lut[g] = g[-1]
+            bucket_id = np.where(bucket_id >= 0, lut[np.maximum(bucket_id, 0)], -1)
+        blocks: List[EntityBlock] = []
 
-    # active samples sorted by (entity, hash) and within cap
-    act_idx_sorted = order[active_sorted]             # flat rows, grouped
-    act_pos = pos[active_sorted]                      # rank within entity
-    act_entity = entity_idx[act_idx_sorted]
+        # active samples sorted by (entity, hash) and within cap
+        act_idx_sorted = order[active_sorted]             # flat rows, grouped
+        act_pos = pos[active_sorted]                      # rank within entity
+        act_entity = entity_idx[act_idx_sorted]
 
-    k_nz_pos_all = _slot_positions(kept_nz_mask & active[s_nz])
+        k_nz_pos_all = _slot_positions(kept_nz_mask & active[s_nz])
 
     for b in np.unique(bucket_id[bucket_id >= 0]):
-        ents = np.flatnonzero(bucket_id == b)         # global entity rows
-        E_b = len(ents)
-        S_b = int(act_counts[ents].max())
-        # block row per global entity
-        row_of_entity = np.full(E, -1, np.int64)
-        row_of_entity[ents] = np.arange(E_b)
+        with phase(f"{prepare}/pad"):
+            ents = np.flatnonzero(bucket_id == b)         # global entity rows
+            E_b = len(ents)
+            S_b = int(act_counts[ents].max())
+            # block row per global entity
+            row_of_entity = np.full(E, -1, np.int64)
+            row_of_entity[ents] = np.arange(E_b)
 
-        in_b = row_of_entity[act_entity] >= 0
-        rows_flat = act_idx_sorted[in_b]              # flat sample rows
-        r_idx = row_of_entity[act_entity[in_b]]
-        c_idx = act_pos[in_b]
+            in_b = row_of_entity[act_entity] >= 0
+            rows_flat = act_idx_sorted[in_b]              # flat sample rows
+            r_idx = row_of_entity[act_entity[in_b]]
+            c_idx = act_pos[in_b]
 
-        labels_b = np.zeros((E_b, S_b), dtype)
-        offsets_b = np.zeros((E_b, S_b), dtype)
-        weights_b = np.zeros((E_b, S_b), dtype)
-        rows_b = np.full((E_b, S_b), n, np.int32)
-        labels_b[r_idx, c_idx] = resp[rows_flat]
-        offsets_b[r_idx, c_idx] = base_offsets[rows_flat]
-        weights_b[r_idx, c_idx] = weights[rows_flat]
-        rows_b[r_idx, c_idx] = rows_flat
+            labels_b = np.zeros((E_b, S_b), dtype)
+            offsets_b = np.zeros((E_b, S_b), dtype)
+            weights_b = np.zeros((E_b, S_b), dtype)
+            rows_b = np.full((E_b, S_b), n, np.int32)
+            labels_b[r_idx, c_idx] = resp[rows_flat]
+            offsets_b[r_idx, c_idx] = base_offsets[rows_flat]
+            weights_b[r_idx, c_idx] = weights[rows_flat]
+            rows_b[r_idx, c_idx] = rows_flat
 
-        # ELL features: nonzeros of this bucket's active samples
-        nz_mask = kept_nz_mask & active[s_nz] & (row_of_entity[e_nz] >= 0)
-        nz_sample = s_nz[nz_mask]
-        nz_r = row_of_entity[e_nz[nz_mask]]
-        # column of the sample within the block
-        pos_of_sample = np.full(n, -1, np.int64)
-        pos_of_sample[act_idx_sorted[in_b]] = c_idx
-        nz_c = pos_of_sample[nz_sample]
-        nz_k = k_nz_pos_all[nz_mask]
-        K_b = max(int(nz_k.max()) + 1 if len(nz_k) else 1, 1)
+            # ELL features: nonzeros of this bucket's active samples
+            nz_mask = kept_nz_mask & active[s_nz] & (row_of_entity[e_nz] >= 0)
+            nz_sample = s_nz[nz_mask]
+            nz_r = row_of_entity[e_nz[nz_mask]]
+            # column of the sample within the block
+            pos_of_sample = np.full(n, -1, np.int64)
+            pos_of_sample[act_idx_sorted[in_b]] = c_idx
+            nz_c = pos_of_sample[nz_sample]
+            nz_k = k_nz_pos_all[nz_mask]
+            K_b = max(int(nz_k.max()) + 1 if len(nz_k) else 1, 1)
 
-        f_idx = np.zeros((E_b, S_b, K_b), np.int32)
-        f_val = np.zeros((E_b, S_b, K_b), dtype)
-        f_idx[nz_r, nz_c, nz_k] = slot_nz[nz_mask].astype(np.int32)
-        f_val[nz_r, nz_c, nz_k] = vals[nz_mask]
+            f_idx = np.zeros((E_b, S_b, K_b), np.int32)
+            f_val = np.zeros((E_b, S_b, K_b), dtype)
+            f_idx[nz_r, nz_c, nz_k] = slot_nz[nz_mask].astype(np.int32)
+            f_val[nz_r, nz_c, nz_k] = vals[nz_mask]
 
-        blocks.append(EntityBlock(
-            features=F.SparseFeatures(jnp.asarray(f_idx), jnp.asarray(f_val)),
-            labels=jnp.asarray(labels_b),
-            offsets=jnp.asarray(offsets_b),
-            weights=jnp.asarray(weights_b),
-            sample_rows=jnp.asarray(rows_b),
-            entity_rows=jnp.asarray(ents.astype(np.int32)),
-        ))
+        with phase(h2d):
+            blocks.append(EntityBlock(
+                features=F.SparseFeatures(jnp.asarray(f_idx), jnp.asarray(f_val)),
+                labels=jnp.asarray(labels_b),
+                offsets=jnp.asarray(offsets_b),
+                weights=jnp.asarray(weights_b),
+                sample_rows=jnp.asarray(rows_b),
+                entity_rows=jnp.asarray(ents.astype(np.int32)),
+            ))
 
     # -- passive block (projected through each entity's local map) -----------
-    pas_rows = np.flatnonzero(passive)
-    P = max(len(pas_rows), 1)
-    pas_nz_mask = kept_nz_mask & passive[s_nz]
-    pas_k = _slot_positions(pas_nz_mask)
-    K_p = max(int(pas_k[pas_nz_mask].max()) + 1 if pas_nz_mask.any() else 1, 1)
-    p_idx = np.zeros((P, K_p), np.int32)
-    p_val = np.zeros((P, K_p), dtype)
-    p_entity = np.full(P, E, np.int32)
-    p_rows = np.full(P, n, np.int32)
-    if len(pas_rows):
-        row_rank = np.full(n, -1, np.int64)
-        row_rank[pas_rows] = np.arange(len(pas_rows))
-        p_entity[: len(pas_rows)] = entity_idx[pas_rows]
-        p_rows[: len(pas_rows)] = pas_rows
-        sel = pas_nz_mask
-        p_idx[row_rank[s_nz[sel]], pas_k[sel]] = slot_nz[sel].astype(np.int32)
-        p_val[row_rank[s_nz[sel]], pas_k[sel]] = vals[sel]
+    with phase(f"{prepare}/passive"):
+        pas_rows = np.flatnonzero(passive)
+        P = max(len(pas_rows), 1)
+        pas_nz_mask = kept_nz_mask & passive[s_nz]
+        pas_k = _slot_positions(pas_nz_mask)
+        K_p = max(int(pas_k[pas_nz_mask].max()) + 1 if pas_nz_mask.any() else 1, 1)
+        p_idx = np.zeros((P, K_p), np.int32)
+        p_val = np.zeros((P, K_p), dtype)
+        p_entity = np.full(P, E, np.int32)
+        p_rows = np.full(P, n, np.int32)
+        if len(pas_rows):
+            row_rank = np.full(n, -1, np.int64)
+            row_rank[pas_rows] = np.arange(len(pas_rows))
+            p_entity[: len(pas_rows)] = entity_idx[pas_rows]
+            p_rows[: len(pas_rows)] = pas_rows
+            sel = pas_nz_mask
+            p_idx[row_rank[s_nz[sel]], pas_k[sel]] = slot_nz[sel].astype(np.int32)
+            p_val[row_rank[s_nz[sel]], pas_k[sel]] = vals[sel]
 
-    ds = RandomEffectDataset(
-        blocks=tuple(blocks),
-        passive_features=F.SparseFeatures(jnp.asarray(p_idx), jnp.asarray(p_val)),
-        passive_entity=jnp.asarray(p_entity),
-        passive_rows=jnp.asarray(p_rows),
-        projection=jnp.asarray(projection),
-    )
+    with phase(h2d):
+        ds = RandomEffectDataset(
+            blocks=tuple(blocks),
+            passive_features=F.SparseFeatures(jnp.asarray(p_idx), jnp.asarray(p_val)),
+            passive_entity=jnp.asarray(p_entity),
+            passive_rows=jnp.asarray(p_rows),
+            projection=jnp.asarray(projection),
+        )
+    count_placed(coordinate, ds)
     # ingest telemetry (VERDICT r2 weak #8): block count == distinct XLA
     # compiles for this coordinate's solve; padding_waste == padded/real
     # sample cells
+    with phase("ingest/stats"):
+        waste = ds.padding_waste()
     logger.info(
         "random-effect %r ingest: %d entities, %d block(s) (bucket cap %s), "
         "padding waste %.3f, shapes %s",
-        re_type, E, len(ds.blocks), cap,
-        ds.padding_waste(),
+        re_type, E, len(ds.blocks), cap, waste,
         [(b.num_rows, b.max_samples, b.features.values.shape[-1])
          for b in ds.blocks])
     return ds

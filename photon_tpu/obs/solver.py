@@ -11,21 +11,27 @@ to a JSON-safe dict, and empties the buffer.
 Multi-process runs keep this per-process; the RunReport aggregation
 (obs/aggregate.py) ships the drained host dicts to process 0 — no
 collectives ride in the recording path.
+
+The buffer is bounded by the shape of a fit, not by how long the process
+lives: one entry a (coordinate, sweep), a later fit's update replacing an
+earlier fit's. A process that fits again and again with telemetry on and
+never builds a report (a traced benchmark window, a tuning loop) would
+otherwise pin every update's device arrays.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Tuple
 
 from photon_tpu.obs import _config
 
 _LOCK = threading.Lock()
-# entries: {"kind", "coordinate", "tracker", "unix", **meta} — tracker is a
-# live OptimizationStatesTracker / RandomEffectOptimizationTracker whose
-# arrays may still be device-resident
-_BUFFER: List[Dict[str, Any]] = []
+# (coordinate, sweep) -> {"kind", "coordinate", "tracker", "unix", **meta};
+# tracker is a live OptimizationStatesTracker / RandomEffectOptimization
+# Tracker whose arrays may still be device-resident
+_BUFFER: Dict[Tuple[str, Any], Dict[str, Any]] = {}
 
 
 def record(coordinate: str, tracker, **meta: Any) -> None:
@@ -35,9 +41,11 @@ def record(coordinate: str, tracker, **meta: Any) -> None:
         return
     kind = ("random_effect" if hasattr(tracker, "reason_counts")
             else "states")
+    key = (coordinate, meta.get("sweep"))
     with _LOCK:
-        _BUFFER.append({"kind": kind, "coordinate": coordinate,
-                        "tracker": tracker, "unix": time.time(), **meta})
+        _BUFFER.pop(key, None)     # re-inserted last: drain keeps time order
+        _BUFFER[key] = {"kind": kind, "coordinate": coordinate,
+                        "tracker": tracker, "unix": time.time(), **meta}
 
 
 def pending() -> int:
@@ -57,7 +65,7 @@ def drain() -> Dict[str, List[Dict[str, Any]]]:
     boundaries only (RunReport build, end of fit), never inside a sweep.
     """
     with _LOCK:
-        entries = list(_BUFFER)
+        entries = list(_BUFFER.values())
         _BUFFER.clear()
     out: Dict[str, List[Dict[str, Any]]] = {
         "trajectories": [], "random_effects": []}

@@ -1,10 +1,17 @@
 """Nested trace spans with Chrome-trace-event / Perfetto JSON export.
 
 Subsumes ``utils/timing.Timed`` (which is now a shim over this module):
-every span records wall-clock start/end, monotonic duration, thread and
-nesting parent, and — when JAX is already loaded — wraps the body in a
-``jax.profiler.TraceAnnotation`` so host spans line up with device
-activity in a captured device trace (``--profile-dir``).
+every span records wall-clock start/end, the host seconds between them,
+thread and nesting parent, and — when JAX is already loaded — wraps the
+body in a ``jax.profiler.TraceAnnotation`` carrying the span's attributes,
+so host spans line up with device activity in a captured device trace
+(``--profile-dir``) and a gap under ``cd/update`` names its coordinate.
+
+What a span times is the HOST: JAX dispatches asynchronously, so a span
+around a jitted call that nothing blocks on ends when the call is
+enqueued, not when the device is done (``dur_us`` is enqueue time there).
+Device seconds come from the device trace, by ``jax.named_scope``
+(PERF.md §3); the annotations are what put the two on one clock.
 
 Zero-overhead-when-disabled: :class:`span` checks ``_config.enabled()``
 once on ``__enter__`` and becomes two attribute writes when telemetry is
@@ -22,7 +29,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 from photon_tpu.obs import _config
 
@@ -42,14 +49,16 @@ def _stack() -> List["span"]:
     return st
 
 
-def _jax_annotation(name: str):
+def _jax_annotation(name: str, attrs: Dict[str, Any]):
     """A jax.profiler.TraceAnnotation when jax is ALREADY imported (a
-    telemetry span must never be the thing that pulls in the backend)."""
+    telemetry span must never be the thing that pulls in the backend).
+    ``attrs`` become the event's arguments in the trace; its name stays
+    ``name``, which is what readers group by."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
     try:
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **attrs)
     except Exception:  # pragma: no cover - profiler unavailable
         return None
 
@@ -64,13 +73,12 @@ class span:
     """
 
     __slots__ = ("name", "attrs", "_on", "_t0", "_wall0", "_parent",
-                 "_depth", "_ann", "seconds")
+                 "_depth", "_ann")
 
     def __init__(self, name: str, **attrs: Any):
         self.name = name
         self.attrs = attrs
         self._on = False
-        self.seconds: Optional[float] = None
 
     def __enter__(self) -> "span":
         if not _config.enabled():
@@ -80,7 +88,7 @@ class span:
         self._parent = st[-1].name if st else None
         self._depth = len(st)
         st.append(self)
-        self._ann = _jax_annotation(self.name)
+        self._ann = _jax_annotation(self.name, self.attrs)
         if self._ann is not None:
             self._ann.__enter__()
         self._wall0 = time.time()
@@ -96,13 +104,13 @@ class span:
         st = _stack()
         if st and st[-1] is self:
             st.pop()
-        self.seconds = t1 - self._t0
+        seconds = t1 - self._t0
         rec = {
             "name": self.name,
             "ts_us": (self._t0 - _EPOCH_PERF) * 1e6,
-            "dur_us": self.seconds * 1e6,
+            "dur_us": seconds * 1e6,
             "start_unix": self._wall0,
-            "end_unix": self._wall0 + self.seconds,
+            "end_unix": self._wall0 + seconds,
             "tid": threading.get_ident(),
             "parent": self._parent,
             "depth": self._depth,
@@ -113,22 +121,16 @@ class span:
             rec["error"] = True
         with _LOCK:
             _RECORDS.append(rec)
-        if self._depth == 0:
-            # top-level phase boundary: sample memory watermarks here so
-            # the RunReport gets per-phase host/device numbers without any
-            # sampling inside nested (possibly hot) scopes
-            from photon_tpu.obs import memory
-            memory.record_phase(self.name)
 
 
-def annotate(name: str):
+def annotate(name: str, **attrs: Any):
     """Device-trace-only annotation for hot call sites: aligns a named
     region with device activity under ``jax.profiler`` without recording
     a host span (no lock, no list growth when called per CD update).
     Returns a no-op context when telemetry is off."""
     if not _config.enabled():
         return _NULL_CONTEXT
-    ann = _jax_annotation(name)
+    ann = _jax_annotation(name, attrs)
     return ann if ann is not None else _NULL_CONTEXT
 
 
@@ -141,11 +143,6 @@ class _NullContext:
 
 
 _NULL_CONTEXT = _NullContext()
-
-
-def current_span() -> Optional[str]:
-    st = getattr(_TLS, "stack", None)
-    return st[-1].name if st else None
 
 
 def records() -> List[Dict[str, Any]]:

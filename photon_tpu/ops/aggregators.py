@@ -18,6 +18,13 @@ effective-coefficient + prefactor trick (ValueAndGradientAggregator
     d value / d coef_j = factor_j [ sum_i w_i l'_i x_ij ] - (sum_i w_i l'_i) factor_j shift_j
 
 so the raw data is never rescaled on device.
+
+Every public function runs under a ``jax.named_scope`` ``agg/<function>``
+(``agg/margins`` for ``compute_margins``; the ``*_from_weights`` halves
+share their whole's name), on the XLA path and the Pallas path alike: the
+names are compile-time metadata, cost nothing at run time, and are what a
+device trace's seconds are grouped by (PERF.md §3). They are an interface;
+rename one and the per-layer metrics that read it go blind.
 """
 
 from __future__ import annotations
@@ -52,7 +59,7 @@ def _kernel_counter(name: str, path: str) -> None:
     registry.counter(f"kernels.{name}", path=path).inc()
 
 
-def _warn_kernel_refused(path: str) -> None:
+def _warn_kernel_refused(path: str, stacklevel: int = 3) -> None:
     """Warn ONCE per path when PHOTON_TPU_PALLAS_GLM=1 asked for the
     fused kernel but ``_supported`` refused the operands — a silent
     performance downgrade the counters record and this makes audible."""
@@ -65,7 +72,7 @@ def _warn_kernel_refused(path: str) -> None:
         f"the {path} operands were refused (dtype/normalization/vmap/"
         f"mesh or dimension gate); falling back to the two-pass XLA "
         f"path. kernels.xla_fallbacks{{path={path}}} counts these.",
-        RuntimeWarning, stacklevel=3)
+        RuntimeWarning, stacklevel=stacklevel)
 
 
 def effective_coefficients(coef: Array, norm: NormalizationContext) -> Tuple[Array, Array]:
@@ -78,6 +85,7 @@ def effective_coefficients(coef: Array, norm: NormalizationContext) -> Tuple[Arr
     return e, shift
 
 
+@jax.named_scope("agg/margins")
 def compute_margins(
     x: FeatureMatrix,
     coef: Array,
@@ -105,6 +113,7 @@ def _apply_factor_and_shift(
     return out
 
 
+@jax.named_scope("agg/value_and_gradient")
 def value_and_gradient(
     loss: PointwiseLoss,
     x: FeatureMatrix,
@@ -145,7 +154,8 @@ def value_and_gradient(
         if not pallas_glm._TRACE_DISABLED.get():
             # a disabled() region is a deliberate routing decision (mesh
             # solves); only an unexpected refusal warrants the warning
-            _warn_kernel_refused(path)
+            # (one level further up: the scope decorator is a frame)
+            _warn_kernel_refused(path, stacklevel=4)
     dim = coef.shape[0]
     margins = compute_margins(x, coef, offsets, norm)
     l, dz = loss.loss_and_dz(margins, labels)
@@ -171,6 +181,7 @@ def _weighted_loss_and_dz(
     return jnp.sum(l), dz
 
 
+@jax.named_scope("agg/margin_value_and_gradient")
 def margin_value_and_gradient(
     loss: PointwiseLoss,
     x: FeatureMatrix,
@@ -191,6 +202,7 @@ def margin_value_and_gradient(
     return value, grad
 
 
+@jax.named_scope("agg/margin_trial")
 def margin_trial(
     loss: PointwiseLoss,
     labels: Array,
@@ -207,6 +219,7 @@ def margin_trial(
     return value, jnp.dot(dz, dir_margins)
 
 
+@jax.named_scope("agg/hessian_weights")
 def hessian_weights(
     loss: PointwiseLoss,
     x: FeatureMatrix,
@@ -230,6 +243,7 @@ def hessian_weights(
     return d2
 
 
+@jax.named_scope("agg/hessian_vector")
 def hessian_vector_from_weights(
     x: FeatureMatrix,
     d2: Array,
@@ -247,6 +261,7 @@ def hessian_vector_from_weights(
     return _apply_factor_and_shift(vector_sum, jnp.sum(coeffs), norm)
 
 
+@jax.named_scope("agg/hessian_matrix")
 def hessian_matrix_from_weights(
     x: FeatureMatrix,
     d2: Array,
@@ -267,6 +282,7 @@ def hessian_matrix_from_weights(
     return h
 
 
+@jax.named_scope("agg/hessian_vector")
 def hessian_vector(
     loss: PointwiseLoss,
     x: FeatureMatrix,
@@ -284,6 +300,7 @@ def hessian_vector(
     return hessian_vector_from_weights(x, d2, vector, norm, dim)
 
 
+@jax.named_scope("agg/hessian_diagonal")
 def hessian_diagonal(
     loss: PointwiseLoss,
     x: FeatureMatrix,
@@ -312,6 +329,7 @@ def hessian_diagonal(
     return diag
 
 
+@jax.named_scope("agg/hessian_matrix")
 def hessian_matrix(
     loss: PointwiseLoss,
     x: FeatureMatrix,

@@ -17,6 +17,10 @@ makes bench comparisons apples-to-apples.
 Requires positive-definite H: lambda > 0, or full-rank (weighted)
 features. Entities with no data keep their starting coefficients (the
 iterative solvers' behavior at a zero gradient).
+
+The steps run under ``jax.named_scope`` ``optim/direct/<step>``: ``init``
+(value and gradient at the start), ``hessian`` (the call into ``agg/``),
+``factor_solve`` and ``update`` (PERF.md §3; the names are an interface).
 """
 
 from __future__ import annotations
@@ -45,31 +49,33 @@ def _newton_step(x0: Array, f0: Array, g: Array, h: Array) -> SolverResult:
     entity must not read as converged in the per-entity trackers. The
     ``failure`` code distinguishes a bad input (non-finite f0/g, e.g. a
     poisoned residual) from a non-finite Cholesky step."""
-    chol = jax.scipy.linalg.cho_factor(h)
-    step = -jax.scipy.linalg.cho_solve(chol, g)
-    ok = jnp.all(jnp.isfinite(step))
-    step = jnp.where(ok, step, 0.0)
-    hs = h @ step
-    init_fail = nonfinite_code(f0, jnp.all(jnp.isfinite(g)))
-    failure = jnp.where(
-        init_fail != FailureMode.NONE,
-        init_fail,
-        jnp.where(ok,
-                  jnp.asarray(FailureMode.NONE, jnp.int32),
-                  jnp.asarray(FailureMode.NON_FINITE_STEP, jnp.int32)))
-    return SolverResult(
-        coef=x0 + step,
-        value=f0 + jnp.dot(g, step) + 0.5 * jnp.dot(step, hs),
-        gradient=g + hs,
-        iterations=jnp.asarray(1, jnp.int32),
-        reason=jnp.where(
-            ok,
-            jnp.asarray(ConvergenceReason.GRADIENT_CONVERGED, jnp.int32),
-            jnp.asarray(ConvergenceReason.NOT_CONVERGED, jnp.int32)),
-        num_fun_evals=jnp.asarray(1, jnp.int32),
-        loss_history=None, gnorm_history=None,
-        failure=failure,
-    )
+    with jax.named_scope("optim/direct/factor_solve"):
+        chol = jax.scipy.linalg.cho_factor(h)
+        step = -jax.scipy.linalg.cho_solve(chol, g)
+    with jax.named_scope("optim/direct/update"):
+        ok = jnp.all(jnp.isfinite(step))
+        step = jnp.where(ok, step, 0.0)
+        hs = h @ step
+        init_fail = nonfinite_code(f0, jnp.all(jnp.isfinite(g)))
+        failure = jnp.where(
+            init_fail != FailureMode.NONE,
+            init_fail,
+            jnp.where(ok,
+                      jnp.asarray(FailureMode.NONE, jnp.int32),
+                      jnp.asarray(FailureMode.NON_FINITE_STEP, jnp.int32)))
+        return SolverResult(
+            coef=x0 + step,
+            value=f0 + jnp.dot(g, step) + 0.5 * jnp.dot(step, hs),
+            gradient=g + hs,
+            iterations=jnp.asarray(1, jnp.int32),
+            reason=jnp.where(
+                ok,
+                jnp.asarray(ConvergenceReason.GRADIENT_CONVERGED, jnp.int32),
+                jnp.asarray(ConvergenceReason.NOT_CONVERGED, jnp.int32)),
+            num_fun_evals=jnp.asarray(1, jnp.int32),
+            loss_history=None, gnorm_history=None,
+            failure=failure,
+        )
 
 
 def minimize_path(value_and_grad_noreg, hessian_matrix_noreg, x0: Array,
@@ -85,9 +91,11 @@ def minimize_path(value_and_grad_noreg, hessian_matrix_noreg, x0: Array,
     ModelTraining.scala:134-147.) Returns a SolverResult whose leaves
     are stacked on a leading [L] axis.
     """
-    f0, g0 = value_and_grad_noreg(x0)
-    gram = hessian_matrix_noreg(x0)
-    eye = jnp.eye(x0.shape[0], dtype=x0.dtype)
+    with jax.named_scope("optim/direct/init"):
+        f0, g0 = value_and_grad_noreg(x0)
+    with jax.named_scope("optim/direct/hessian"):
+        gram = hessian_matrix_noreg(x0)
+        eye = jnp.eye(x0.shape[0], dtype=x0.dtype)
 
     def one(lam):
         # full-objective value/gradient at x0 for this lambda
@@ -100,5 +108,8 @@ def minimize_path(value_and_grad_noreg, hessian_matrix_noreg, x0: Array,
 def minimize(value_and_grad, hessian_matrix, x0: Array) -> SolverResult:
     """``value_and_grad(x) -> (f, g)``; ``hessian_matrix(x) -> [d, d]``
     constant in ``x`` for a quadratic objective (evaluated at ``x0``)."""
-    f0, g0 = value_and_grad(x0)
-    return _newton_step(x0, f0, g0, hessian_matrix(x0))
+    with jax.named_scope("optim/direct/init"):
+        f0, g0 = value_and_grad(x0)
+    with jax.named_scope("optim/direct/hessian"):
+        h = hessian_matrix(x0)
+    return _newton_step(x0, f0, g0, h)

@@ -8,6 +8,12 @@ alg. 3.5/3.6, with quadratic interpolation and bisection safeguards).
 Written entirely with lax control flow so it jits once and vmaps over
 entity blocks (the random-effect path) with per-entity masking handled by
 the while_loop batching rule.
+
+Inside whichever solver's ``optim/<solver>/linesearch`` scope it runs, the
+search names its own steps ``optim/linesearch/<step>``: ``init``, ``trial``
+(one evaluation of the objective along the direction: the call into
+``agg/``), ``zoom`` (the bracket-and-zoom bookkeeping) and ``loop`` around
+the ``while_loop`` itself (PERF.md §3; the names are an interface).
 """
 
 from __future__ import annotations
@@ -87,7 +93,8 @@ def wolfe_linesearch(
     to reset curvature history.
     """
     dtype = x.dtype
-    d0 = jnp.dot(g0, direction)
+    with jax.named_scope("optim/linesearch/init"):
+        d0 = jnp.dot(g0, direction)
 
     def phi(a):
         f, g = fg(x + a * direction, *fg_args)
@@ -105,123 +112,125 @@ def wolfe_linesearch(
         return jnp.where(bad, mid, a_q)
 
     def body(c: _Carry) -> _Carry:
-        f_a, g_a, d_a = phi(c.a_next)
-        i = c.i + 1
-        a = c.a_next
+        with jax.named_scope("optim/linesearch/trial"):
+            f_a, g_a, d_a = phi(c.a_next)
+        with jax.named_scope("optim/linesearch/zoom"):
+            i = c.i + 1
+            a = c.a_next
 
-        # best strict-decrease tracker (failure fallback); a -inf "best"
-        # would poison the caller's carry, so non-finite trials never win
-        better = (f_a < c.f_best) & jnp.isfinite(f_a)
-        a_best = jnp.where(better, a, c.a_best)
-        f_best = jnp.where(better, f_a, c.f_best)
-        g_best = jnp.where(better, g_a, c.g_best)
+            # best strict-decrease tracker (failure fallback); a -inf "best"
+            # would poison the caller's carry, so non-finite trials never win
+            better = (f_a < c.f_best) & jnp.isfinite(f_a)
+            a_best = jnp.where(better, a, c.a_best)
+            f_best = jnp.where(better, f_a, c.f_best)
+            g_best = jnp.where(better, g_a, c.g_best)
 
-        # a non-finite trial classifies as an Armijo failure: the bracket
-        # shrinks back toward the finite region instead of growing into it
-        armijo_fail = (f_a > f0 + c1 * a * d0) | ~jnp.isfinite(f_a)
-        wolfe_ok = jnp.abs(d_a) <= -c2 * d0
-        # approximate-Wolfe acceptance (Hager-Zhang style): near the
-        # optimum the true decrease underflows f0's ulp, strict Armijo
-        # reads it as failure, and the zoom stage burns the whole eval
-        # budget shrinking a bracket around machine noise (measured: 55
-        # evals for a 6-iteration f32 Poisson solve). When f is flat to
-        # within rounding AND the directional derivative satisfies the
-        # two-sided slope test, the step is as converged as the dtype
-        # can express — accept it.
-        slack = 8.0 * jnp.finfo(dtype).eps * jnp.abs(f0)
-        approx_conv = ((f_a <= f0 + slack)
-                       & (d_a >= c2 * d0)
-                       & (d_a <= (2.0 * c1 - 1.0) * d0)
-                       & jnp.isfinite(f_a))
-        # the slack is a CLASSIFICATION device only: a candidate inside the
-        # flatness window but with f_a > f0 is a rounding-level ascent —
-        # report converged (success) without moving the iterate off the
-        # best point seen (see the LineSearchResult contract)
-        approx_take = approx_conv & (f_a <= f0)
-        approx_stop = approx_conv & ~approx_take
+            # a non-finite trial classifies as an Armijo failure: the bracket
+            # shrinks back toward the finite region instead of growing into it
+            armijo_fail = (f_a > f0 + c1 * a * d0) | ~jnp.isfinite(f_a)
+            wolfe_ok = jnp.abs(d_a) <= -c2 * d0
+            # approximate-Wolfe acceptance (Hager-Zhang style): near the
+            # optimum the true decrease underflows f0's ulp, strict Armijo
+            # reads it as failure, and the zoom stage burns the whole eval
+            # budget shrinking a bracket around machine noise (measured: 55
+            # evals for a 6-iteration f32 Poisson solve). When f is flat to
+            # within rounding AND the directional derivative satisfies the
+            # two-sided slope test, the step is as converged as the dtype
+            # can express — accept it.
+            slack = 8.0 * jnp.finfo(dtype).eps * jnp.abs(f0)
+            approx_conv = ((f_a <= f0 + slack)
+                           & (d_a >= c2 * d0)
+                           & (d_a <= (2.0 * c1 - 1.0) * d0)
+                           & jnp.isfinite(f_a))
+            # the slack is a CLASSIFICATION device only: a candidate inside the
+            # flatness window but with f_a > f0 is a rounding-level ascent —
+            # report converged (success) without moving the iterate off the
+            # best point seen (see the LineSearchResult contract)
+            approx_take = approx_conv & (f_a <= f0)
+            approx_stop = approx_conv & ~approx_take
 
-        in_bracket = c.stage == _BRACKET
-        # --- bracket-stage classification ---
-        br_to_zoom1 = armijo_fail | ((i > 1) & (f_a >= c.f_prev))
-        br_accept = (~br_to_zoom1) & wolfe_ok
-        br_to_zoom2 = (~br_to_zoom1) & (~wolfe_ok) & (d_a >= 0)
-        br_grow = (~br_to_zoom1) & (~br_accept) & (~br_to_zoom2)
+            in_bracket = c.stage == _BRACKET
+            # --- bracket-stage classification ---
+            br_to_zoom1 = armijo_fail | ((i > 1) & (f_a >= c.f_prev))
+            br_accept = (~br_to_zoom1) & wolfe_ok
+            br_to_zoom2 = (~br_to_zoom1) & (~wolfe_ok) & (d_a >= 0)
+            br_grow = (~br_to_zoom1) & (~br_accept) & (~br_to_zoom2)
 
-        # --- zoom-stage classification ---
-        zm_shrink_hi = armijo_fail | (f_a >= c.f_lo)
-        zm_accept = (~zm_shrink_hi) & wolfe_ok
-        zm_flip = (~zm_shrink_hi) & (~wolfe_ok) & (d_a * (c.a_hi - c.a_lo) >= 0)
+            # --- zoom-stage classification ---
+            zm_shrink_hi = armijo_fail | (f_a >= c.f_lo)
+            zm_accept = (~zm_shrink_hi) & wolfe_ok
+            zm_flip = (~zm_shrink_hi) & (~wolfe_ok) & (d_a * (c.a_hi - c.a_lo) >= 0)
 
-        accept = jnp.where(in_bracket, br_accept, zm_accept) | approx_take
+            accept = jnp.where(in_bracket, br_accept, zm_accept) | approx_take
 
-        # new bracket for the zoom stage
-        z1 = br_to_zoom1
-        new_a_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.a_prev, a),
-            jnp.where(zm_shrink_hi, c.a_lo, a),
-        )
-        new_f_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.f_prev, f_a),
-            jnp.where(zm_shrink_hi, c.f_lo, f_a),
-        )
-        new_d_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.d_prev, d_a),
-            jnp.where(zm_shrink_hi, c.d_lo, d_a),
-        )
-        new_g_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.g_prev, g_a),
-            jnp.where(zm_shrink_hi, c.g_lo, g_a),
-        )
-        new_a_hi = jnp.where(
-            in_bracket,
-            jnp.where(z1, a, c.a_prev),
-            jnp.where(zm_shrink_hi, a, jnp.where(zm_flip, c.a_lo, c.a_hi)),
-        )
-        new_f_hi = jnp.where(
-            in_bracket,
-            jnp.where(z1, f_a, c.f_prev),
-            jnp.where(zm_shrink_hi, f_a, jnp.where(zm_flip, c.f_lo, c.f_hi)),
-        )
-        new_d_hi = jnp.where(
-            in_bracket,
-            jnp.where(z1, d_a, c.d_prev),
-            jnp.where(zm_shrink_hi, d_a, jnp.where(zm_flip, c.d_lo, c.d_hi)),
-        )
+            # new bracket for the zoom stage
+            z1 = br_to_zoom1
+            new_a_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.a_prev, a),
+                jnp.where(zm_shrink_hi, c.a_lo, a),
+            )
+            new_f_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.f_prev, f_a),
+                jnp.where(zm_shrink_hi, c.f_lo, f_a),
+            )
+            new_d_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.d_prev, d_a),
+                jnp.where(zm_shrink_hi, c.d_lo, d_a),
+            )
+            new_g_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.g_prev, g_a),
+                jnp.where(zm_shrink_hi, c.g_lo, g_a),
+            )
+            new_a_hi = jnp.where(
+                in_bracket,
+                jnp.where(z1, a, c.a_prev),
+                jnp.where(zm_shrink_hi, a, jnp.where(zm_flip, c.a_lo, c.a_hi)),
+            )
+            new_f_hi = jnp.where(
+                in_bracket,
+                jnp.where(z1, f_a, c.f_prev),
+                jnp.where(zm_shrink_hi, f_a, jnp.where(zm_flip, c.f_lo, c.f_hi)),
+            )
+            new_d_hi = jnp.where(
+                in_bracket,
+                jnp.where(z1, d_a, c.d_prev),
+                jnp.where(zm_shrink_hi, d_a, jnp.where(zm_flip, c.d_lo, c.d_hi)),
+            )
 
-        # next stage
-        entering_zoom = in_bracket & (br_to_zoom1 | br_to_zoom2)
-        staying_zoom = (~in_bracket)
-        interval = jnp.abs(new_a_hi - new_a_lo)
-        interval_dead = (entering_zoom | staying_zoom) & (
-            interval <= 1e-10 * jnp.maximum(jnp.abs(new_a_hi), 1.0)
-        )
-        # accept lo when the zoom interval collapses (best we have there)
-        collapse_accept = interval_dead & ~accept
+            # next stage
+            entering_zoom = in_bracket & (br_to_zoom1 | br_to_zoom2)
+            staying_zoom = (~in_bracket)
+            interval = jnp.abs(new_a_hi - new_a_lo)
+            interval_dead = (entering_zoom | staying_zoom) & (
+                interval <= 1e-10 * jnp.maximum(jnp.abs(new_a_hi), 1.0)
+            )
+            # accept lo when the zoom interval collapses (best we have there)
+            collapse_accept = interval_dead & ~accept
 
-        stage = jnp.where(
-            accept | collapse_accept | approx_stop | (i >= max_evals),
-            _DONE,
-            jnp.where(in_bracket & br_grow, _BRACKET, _ZOOM),
-        ).astype(jnp.int32)
+            stage = jnp.where(
+                accept | collapse_accept | approx_stop | (i >= max_evals),
+                _DONE,
+                jnp.where(in_bracket & br_grow, _BRACKET, _ZOOM),
+            ).astype(jnp.int32)
 
-        # next candidate step
-        grow_a = jnp.minimum(2.0 * a, max_step)
-        zoom_a = zoom_candidate(new_a_lo, new_f_lo, new_d_lo, new_a_hi, new_f_hi)
-        a_next = jnp.where(in_bracket & br_grow, grow_a, zoom_a)
+            # next candidate step
+            grow_a = jnp.minimum(2.0 * a, max_step)
+            zoom_a = zoom_candidate(new_a_lo, new_f_lo, new_d_lo, new_a_hi, new_f_hi)
+            a_next = jnp.where(in_bracket & br_grow, grow_a, zoom_a)
 
-        # accepted result
-        acc_a = jnp.where(accept, a, new_a_lo)
-        acc_f = jnp.where(accept, f_a, new_f_lo)
-        acc_g = jnp.where(accept, g_a, new_g_lo)
-        take = accept | collapse_accept
-        a_best = jnp.where(take, acc_a, a_best)
-        f_best = jnp.where(take, acc_f, f_best)
-        g_best = jnp.where(take, acc_g, g_best)
-        success = c.success | accept | approx_stop
+            # accepted result
+            acc_a = jnp.where(accept, a, new_a_lo)
+            acc_f = jnp.where(accept, f_a, new_f_lo)
+            acc_g = jnp.where(accept, g_a, new_g_lo)
+            take = accept | collapse_accept
+            a_best = jnp.where(take, acc_a, a_best)
+            f_best = jnp.where(take, acc_f, f_best)
+            g_best = jnp.where(take, acc_g, g_best)
+            success = c.success | accept | approx_stop
 
         return _Carry(
             stage=stage, i=i, a_next=a_next,
@@ -231,19 +240,21 @@ def wolfe_linesearch(
             a_best=a_best, f_best=f_best, g_best=g_best, success=success,
         )
 
-    zero = jnp.zeros((), dtype)
-    init = _Carry(
-        stage=jnp.asarray(_BRACKET, jnp.int32),
-        i=jnp.asarray(0, jnp.int32),
-        a_next=jnp.asarray(initial_step, dtype),
-        a_lo=zero, f_lo=f0, d_lo=d0, g_lo=g0,
-        a_hi=zero, f_hi=f0, d_hi=d0,
-        a_prev=zero, f_prev=f0, d_prev=d0, g_prev=g0,
-        a_best=zero, f_best=f0, g_best=g0,
-        success=jnp.asarray(False),
-    )
+    with jax.named_scope("optim/linesearch/init"):
+        zero = jnp.zeros((), dtype)
+        init = _Carry(
+            stage=jnp.asarray(_BRACKET, jnp.int32),
+            i=jnp.asarray(0, jnp.int32),
+            a_next=jnp.asarray(initial_step, dtype),
+            a_lo=zero, f_lo=f0, d_lo=d0, g_lo=g0,
+            a_hi=zero, f_hi=f0, d_hi=d0,
+            a_prev=zero, f_prev=f0, d_prev=d0, g_prev=g0,
+            a_best=zero, f_best=f0, g_best=g0,
+            success=jnp.asarray(False),
+        )
 
-    out = lax.while_loop(lambda c: c.stage != _DONE, body, init)
+    with jax.named_scope("optim/linesearch/loop"):
+        out = lax.while_loop(lambda c: c.stage != _DONE, body, init)
     return LineSearchResult(
         step=out.a_best, f=out.f_best, g=out.g_best,
         num_evals=out.i, success=out.success,
@@ -319,97 +330,99 @@ def wolfe_linesearch_directional(
         return jnp.where(bad, mid, a_q)
 
     def body(c: _DirCarry) -> _DirCarry:
-        f_a, d_a = phi(c.a_next)
-        i = c.i + 1
-        a = c.a_next
+        with jax.named_scope("optim/linesearch/trial"):
+            f_a, d_a = phi(c.a_next)
+        with jax.named_scope("optim/linesearch/zoom"):
+            i = c.i + 1
+            a = c.a_next
 
-        # same non-finite handling as wolfe_linesearch: bad trials never
-        # become the fallback best, and they shrink the bracket
-        better = (f_a < c.f_best) & jnp.isfinite(f_a)
-        a_best = jnp.where(better, a, c.a_best)
-        f_best = jnp.where(better, f_a, c.f_best)
-        d_best = jnp.where(better, d_a, c.d_best)
+            # same non-finite handling as wolfe_linesearch: bad trials never
+            # become the fallback best, and they shrink the bracket
+            better = (f_a < c.f_best) & jnp.isfinite(f_a)
+            a_best = jnp.where(better, a, c.a_best)
+            f_best = jnp.where(better, f_a, c.f_best)
+            d_best = jnp.where(better, d_a, c.d_best)
 
-        armijo_fail = (f_a > f0 + c1 * a * d0) | ~jnp.isfinite(f_a)
-        wolfe_ok = jnp.abs(d_a) <= -c2 * d0
-        slack = 8.0 * jnp.finfo(dtype).eps * jnp.abs(f0)
-        approx_conv = ((f_a <= f0 + slack)
-                       & (d_a >= c2 * d0)
-                       & (d_a <= (2.0 * c1 - 1.0) * d0)
-                       & jnp.isfinite(f_a))
-        approx_take = approx_conv & (f_a <= f0)
-        approx_stop = approx_conv & ~approx_take
+            armijo_fail = (f_a > f0 + c1 * a * d0) | ~jnp.isfinite(f_a)
+            wolfe_ok = jnp.abs(d_a) <= -c2 * d0
+            slack = 8.0 * jnp.finfo(dtype).eps * jnp.abs(f0)
+            approx_conv = ((f_a <= f0 + slack)
+                           & (d_a >= c2 * d0)
+                           & (d_a <= (2.0 * c1 - 1.0) * d0)
+                           & jnp.isfinite(f_a))
+            approx_take = approx_conv & (f_a <= f0)
+            approx_stop = approx_conv & ~approx_take
 
-        in_bracket = c.stage == _BRACKET
-        br_to_zoom1 = armijo_fail | ((i > 1) & (f_a >= c.f_prev))
-        br_accept = (~br_to_zoom1) & wolfe_ok
-        br_to_zoom2 = (~br_to_zoom1) & (~wolfe_ok) & (d_a >= 0)
-        br_grow = (~br_to_zoom1) & (~br_accept) & (~br_to_zoom2)
+            in_bracket = c.stage == _BRACKET
+            br_to_zoom1 = armijo_fail | ((i > 1) & (f_a >= c.f_prev))
+            br_accept = (~br_to_zoom1) & wolfe_ok
+            br_to_zoom2 = (~br_to_zoom1) & (~wolfe_ok) & (d_a >= 0)
+            br_grow = (~br_to_zoom1) & (~br_accept) & (~br_to_zoom2)
 
-        zm_shrink_hi = armijo_fail | (f_a >= c.f_lo)
-        zm_accept = (~zm_shrink_hi) & wolfe_ok
-        zm_flip = (~zm_shrink_hi) & (~wolfe_ok) & (d_a * (c.a_hi - c.a_lo) >= 0)
+            zm_shrink_hi = armijo_fail | (f_a >= c.f_lo)
+            zm_accept = (~zm_shrink_hi) & wolfe_ok
+            zm_flip = (~zm_shrink_hi) & (~wolfe_ok) & (d_a * (c.a_hi - c.a_lo) >= 0)
 
-        accept = jnp.where(in_bracket, br_accept, zm_accept) | approx_take
+            accept = jnp.where(in_bracket, br_accept, zm_accept) | approx_take
 
-        z1 = br_to_zoom1
-        new_a_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.a_prev, a),
-            jnp.where(zm_shrink_hi, c.a_lo, a),
-        )
-        new_f_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.f_prev, f_a),
-            jnp.where(zm_shrink_hi, c.f_lo, f_a),
-        )
-        new_d_lo = jnp.where(
-            in_bracket,
-            jnp.where(z1, c.d_prev, d_a),
-            jnp.where(zm_shrink_hi, c.d_lo, d_a),
-        )
-        new_a_hi = jnp.where(
-            in_bracket,
-            jnp.where(z1, a, c.a_prev),
-            jnp.where(zm_shrink_hi, a, jnp.where(zm_flip, c.a_lo, c.a_hi)),
-        )
-        new_f_hi = jnp.where(
-            in_bracket,
-            jnp.where(z1, f_a, c.f_prev),
-            jnp.where(zm_shrink_hi, f_a, jnp.where(zm_flip, c.f_lo, c.f_hi)),
-        )
-        new_d_hi = jnp.where(
-            in_bracket,
-            jnp.where(z1, d_a, c.d_prev),
-            jnp.where(zm_shrink_hi, d_a, jnp.where(zm_flip, c.d_lo, c.d_hi)),
-        )
+            z1 = br_to_zoom1
+            new_a_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.a_prev, a),
+                jnp.where(zm_shrink_hi, c.a_lo, a),
+            )
+            new_f_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.f_prev, f_a),
+                jnp.where(zm_shrink_hi, c.f_lo, f_a),
+            )
+            new_d_lo = jnp.where(
+                in_bracket,
+                jnp.where(z1, c.d_prev, d_a),
+                jnp.where(zm_shrink_hi, c.d_lo, d_a),
+            )
+            new_a_hi = jnp.where(
+                in_bracket,
+                jnp.where(z1, a, c.a_prev),
+                jnp.where(zm_shrink_hi, a, jnp.where(zm_flip, c.a_lo, c.a_hi)),
+            )
+            new_f_hi = jnp.where(
+                in_bracket,
+                jnp.where(z1, f_a, c.f_prev),
+                jnp.where(zm_shrink_hi, f_a, jnp.where(zm_flip, c.f_lo, c.f_hi)),
+            )
+            new_d_hi = jnp.where(
+                in_bracket,
+                jnp.where(z1, d_a, c.d_prev),
+                jnp.where(zm_shrink_hi, d_a, jnp.where(zm_flip, c.d_lo, c.d_hi)),
+            )
 
-        entering_zoom = in_bracket & (br_to_zoom1 | br_to_zoom2)
-        staying_zoom = (~in_bracket)
-        interval = jnp.abs(new_a_hi - new_a_lo)
-        interval_dead = (entering_zoom | staying_zoom) & (
-            interval <= 1e-10 * jnp.maximum(jnp.abs(new_a_hi), 1.0)
-        )
-        collapse_accept = interval_dead & ~accept
+            entering_zoom = in_bracket & (br_to_zoom1 | br_to_zoom2)
+            staying_zoom = (~in_bracket)
+            interval = jnp.abs(new_a_hi - new_a_lo)
+            interval_dead = (entering_zoom | staying_zoom) & (
+                interval <= 1e-10 * jnp.maximum(jnp.abs(new_a_hi), 1.0)
+            )
+            collapse_accept = interval_dead & ~accept
 
-        stage = jnp.where(
-            accept | collapse_accept | approx_stop | (i >= max_evals),
-            _DONE,
-            jnp.where(in_bracket & br_grow, _BRACKET, _ZOOM),
-        ).astype(jnp.int32)
+            stage = jnp.where(
+                accept | collapse_accept | approx_stop | (i >= max_evals),
+                _DONE,
+                jnp.where(in_bracket & br_grow, _BRACKET, _ZOOM),
+            ).astype(jnp.int32)
 
-        grow_a = jnp.minimum(2.0 * a, max_step)
-        zoom_a = zoom_candidate(new_a_lo, new_f_lo, new_d_lo, new_a_hi, new_f_hi)
-        a_next = jnp.where(in_bracket & br_grow, grow_a, zoom_a)
+            grow_a = jnp.minimum(2.0 * a, max_step)
+            zoom_a = zoom_candidate(new_a_lo, new_f_lo, new_d_lo, new_a_hi, new_f_hi)
+            a_next = jnp.where(in_bracket & br_grow, grow_a, zoom_a)
 
-        acc_a = jnp.where(accept, a, new_a_lo)
-        acc_f = jnp.where(accept, f_a, new_f_lo)
-        acc_d = jnp.where(accept, d_a, new_d_lo)
-        take = accept | collapse_accept
-        a_best = jnp.where(take, acc_a, a_best)
-        f_best = jnp.where(take, acc_f, f_best)
-        d_best = jnp.where(take, acc_d, d_best)
-        success = c.success | accept | approx_stop
+            acc_a = jnp.where(accept, a, new_a_lo)
+            acc_f = jnp.where(accept, f_a, new_f_lo)
+            acc_d = jnp.where(accept, d_a, new_d_lo)
+            take = accept | collapse_accept
+            a_best = jnp.where(take, acc_a, a_best)
+            f_best = jnp.where(take, acc_f, f_best)
+            d_best = jnp.where(take, acc_d, d_best)
+            success = c.success | accept | approx_stop
 
         return _DirCarry(
             stage=stage, i=i, a_next=a_next,
@@ -419,19 +432,21 @@ def wolfe_linesearch_directional(
             a_best=a_best, f_best=f_best, d_best=d_best, success=success,
         )
 
-    zero = jnp.zeros((), dtype)
-    init = _DirCarry(
-        stage=jnp.asarray(_BRACKET, jnp.int32),
-        i=jnp.asarray(0, jnp.int32),
-        a_next=jnp.asarray(initial_step, dtype),
-        a_lo=zero, f_lo=f0, d_lo=d0,
-        a_hi=zero, f_hi=f0, d_hi=d0,
-        a_prev=zero, f_prev=f0, d_prev=d0,
-        a_best=zero, f_best=f0, d_best=d0,
-        success=jnp.asarray(False),
-    )
+    with jax.named_scope("optim/linesearch/init"):
+        zero = jnp.zeros((), dtype)
+        init = _DirCarry(
+            stage=jnp.asarray(_BRACKET, jnp.int32),
+            i=jnp.asarray(0, jnp.int32),
+            a_next=jnp.asarray(initial_step, dtype),
+            a_lo=zero, f_lo=f0, d_lo=d0,
+            a_hi=zero, f_hi=f0, d_hi=d0,
+            a_prev=zero, f_prev=f0, d_prev=d0,
+            a_best=zero, f_best=f0, d_best=d0,
+            success=jnp.asarray(False),
+        )
 
-    out = lax.while_loop(lambda c: c.stage != _DONE, body, init)
+    with jax.named_scope("optim/linesearch/loop"):
+        out = lax.while_loop(lambda c: c.stage != _DONE, body, init)
     return DirectionalLineSearchResult(
         step=out.a_best, f=out.f_best, dphi=out.d_best,
         num_evals=out.i, success=out.success,

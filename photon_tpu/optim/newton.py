@@ -30,6 +30,14 @@ Safeguards:
   * tolerance semantics match the other solvers (absolute-from-relative
     at the initial state, Optimizer.scala:36-190 convention), so NEWTON
     drops into any config where LBFGS/TRON run today.
+
+Each step of an iteration runs under a ``jax.named_scope``
+``optim/newton/<step>``: ``init``, ``hessian`` (the call into ``agg/``),
+``factor_solve`` (the Cholesky factorisation and its two triangular
+solves), ``direction`` (the descent safeguard), ``linesearch``, ``update``,
+``converged``, and ``loop`` around the ``while_loop`` itself. The names are
+what a device trace's seconds are grouped by (PERF.md §3) and are an
+interface.
 """
 
 from __future__ import annotations
@@ -75,8 +83,9 @@ def minimize(
     """``value_and_grad(x) -> (f, g)``; ``hess_matrix(x) -> [d, d]`` full
     (regularized) Hessian at x. Both are re-evaluated every outer
     iteration — unlike DIRECT, no quadratic assumption is made."""
-    f0, g0 = value_and_grad(x0)
-    tols = absolute_tolerances(f0, g0, config.tolerance)
+    with jax.named_scope("optim/newton/init"):
+        f0, g0 = value_and_grad(x0)
+        tols = absolute_tolerances(f0, g0, config.tolerance)
 
     def linesearch(x, f, g, direction):
         """Armijo backtracking from t=1 (the Newton-natural step). The
@@ -108,65 +117,73 @@ def minimize(
                 & (c.failure == FailureMode.NONE))
 
     def body(c: _Carry):
-        h = hess_matrix(c.x)
-        chol = jax.scipy.linalg.cho_factor(h)
-        step = -jax.scipy.linalg.cho_solve(chol, c.g)
-        # descent safeguard: a non-PD factorization yields NaN/inf or an
-        # ascent direction; steepest descent keeps the iteration alive
-        newton_ok = (jnp.all(jnp.isfinite(step))
-                     & (jnp.dot(c.g, step) < 0.0))
-        direction = jnp.where(newton_ok, step, -c.g)
-        t, f_new, g_new, ls_evals, accepted = linesearch(
-            c.x, c.f, c.g, direction)
-        # the slack is a CLASSIFICATION device only: a step it admits with
-        # f_new > f is a rounding-level ascent — keep `accepted` (the solve
-        # is converged to the dtype's resolution and classifies as
-        # FUNCTION_VALUES_CONVERGED below) but never move the iterate
-        # uphill (same contract as linesearch.LineSearchResult)
-        # non-finite guard: the Armijo test already screens f_t, but a
-        # finite trial value can still carry a NaN/Inf gradient (saturated
-        # margins) — never admit one into the carry, and terminate with a
-        # typed failure (retrying the same step cannot help)
-        g_fin = jnp.all(jnp.isfinite(g_new))
-        take = accepted & (f_new <= c.f) & g_fin
-        failure = jnp.where(
-            accepted & ~g_fin,
-            jnp.asarray(FailureMode.NON_FINITE_GRADIENT, jnp.int32),
-            jnp.asarray(FailureMode.NONE, jnp.int32))
-        x_new = jnp.where(take, c.x + t * direction, c.x)
-        f_new = jnp.where(take, f_new, c.f)
-        g_new = jnp.where(take, g_new, c.g)
-        it = c.it + 1
-        reason = convergence_reason(it, c.f, f_new, g_new, tols,
-                                    config.max_iterations, improved=accepted)
-        # an exhausted line search means no further progress is possible
-        # (TRON reports the analogous state as OBJECTIVE_NOT_IMPROVING)
-        reason = jnp.where(
-            (reason == ConvergenceReason.NOT_CONVERGED) & ~accepted,
-            jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
-            reason)
-        reason = jnp.where(
-            failure != FailureMode.NONE,
-            jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
-            reason)
-        tracking = (None if c.tracking is None
-                    else c.tracking.record(c.it, f_new, g_new))
+        with jax.named_scope("optim/newton/hessian"):
+            h = hess_matrix(c.x)
+        with jax.named_scope("optim/newton/factor_solve"):
+            chol = jax.scipy.linalg.cho_factor(h)
+            step = -jax.scipy.linalg.cho_solve(chol, c.g)
+        with jax.named_scope("optim/newton/direction"):
+            # descent safeguard: a non-PD factorization yields NaN/inf or an
+            # ascent direction; steepest descent keeps the iteration alive
+            newton_ok = (jnp.all(jnp.isfinite(step))
+                         & (jnp.dot(c.g, step) < 0.0))
+            direction = jnp.where(newton_ok, step, -c.g)
+        with jax.named_scope("optim/newton/linesearch"):
+            t, f_new, g_new, ls_evals, accepted = linesearch(
+                c.x, c.f, c.g, direction)
+        with jax.named_scope("optim/newton/update"):
+            # the slack is a CLASSIFICATION device only: a step it admits with
+            # f_new > f is a rounding-level ascent — keep `accepted` (the solve
+            # is converged to the dtype's resolution and classifies as
+            # FUNCTION_VALUES_CONVERGED below) but never move the iterate
+            # uphill (same contract as linesearch.LineSearchResult)
+            # non-finite guard: the Armijo test already screens f_t, but a
+            # finite trial value can still carry a NaN/Inf gradient (saturated
+            # margins) — never admit one into the carry, and terminate with a
+            # typed failure (retrying the same step cannot help)
+            g_fin = jnp.all(jnp.isfinite(g_new))
+            take = accepted & (f_new <= c.f) & g_fin
+            failure = jnp.where(
+                accepted & ~g_fin,
+                jnp.asarray(FailureMode.NON_FINITE_GRADIENT, jnp.int32),
+                jnp.asarray(FailureMode.NONE, jnp.int32))
+            x_new = jnp.where(take, c.x + t * direction, c.x)
+            f_new = jnp.where(take, f_new, c.f)
+            g_new = jnp.where(take, g_new, c.g)
+            tracking = (None if c.tracking is None
+                        else c.tracking.record(c.it, f_new, g_new))
+        with jax.named_scope("optim/newton/converged"):
+            it = c.it + 1
+            reason = convergence_reason(it, c.f, f_new, g_new, tols,
+                                        config.max_iterations, improved=accepted)
+            # an exhausted line search means no further progress is possible
+            # (TRON reports the analogous state as OBJECTIVE_NOT_IMPROVING)
+            reason = jnp.where(
+                (reason == ConvergenceReason.NOT_CONVERGED) & ~accepted,
+                jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
+                reason)
+            reason = jnp.where(
+                failure != FailureMode.NONE,
+                jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
+                reason)
         return _Carry(x_new, f_new, g_new, it,
                       c.n_evals + ls_evals, reason, failure, tracking)
 
-    # sentinel f_prev far from f0 so the initial check can only fire on
-    # the gradient (an already-stationary start) or max_iterations=0
-    f_far = f0 + 2.0 * tols.value_tol + 1.0
-    init = _Carry(
-        x=x0, f=f0, g=g0,
-        it=jnp.asarray(0, jnp.int32),
-        n_evals=jnp.asarray(1, jnp.int32),
-        reason=jnp.asarray(
-            convergence_reason(jnp.asarray(0, jnp.int32), f_far, f0, g0,
-                               tols, config.max_iterations), jnp.int32),
-        failure=nonfinite_code(f0, jnp.all(jnp.isfinite(g0))),
-        tracking=StateTracking.init(config.track_states, x0.dtype))
-    out = jax.lax.while_loop(cond, body, init)
+    with jax.named_scope("optim/newton/init"):
+        # sentinel f_prev far from f0 so the initial check can only fire on
+        # the gradient (an already-stationary start) or max_iterations=0
+        f_far = f0 + 2.0 * tols.value_tol + 1.0
+        init = _Carry(
+            x=x0, f=f0, g=g0,
+            it=jnp.asarray(0, jnp.int32),
+            n_evals=jnp.asarray(1, jnp.int32),
+            reason=jnp.asarray(
+                convergence_reason(jnp.asarray(0, jnp.int32), f_far, f0, g0,
+                                   tols, config.max_iterations), jnp.int32),
+            failure=nonfinite_code(f0, jnp.all(jnp.isfinite(g0))),
+            tracking=StateTracking.init(config.track_states, x0.dtype))
+    with jax.named_scope("optim/newton/loop"):
+        out = jax.lax.while_loop(cond, body, init)
     return SolverResult(
         coef=out.x, value=out.f, gradient=out.g,
         iterations=out.it, reason=out.reason, num_fun_evals=out.n_evals,

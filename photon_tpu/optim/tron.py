@@ -10,6 +10,14 @@ maxIter=15, tol=1e-5, CG cap 20 (TRON.scala:256-262).
 
 Each Hv product is one fused aggregator pass (ops/aggregators.py) — the
 reference's extra treeAggregate per CG step becomes an extra XLA matvec.
+
+Each step of an iteration runs under a ``jax.named_scope``
+``optim/tron/<step>``: ``init``, ``hessian`` (the once-an-iteration
+operator set-up: the call into ``agg/``), ``direction`` (the truncated CG,
+Hessian-vector products included), ``trial`` (the evaluation at the trial
+point), ``update`` (trust radius and acceptance), ``converged``, and
+``loop`` around the outer ``while_loop`` itself (PERF.md §3; the names are
+an interface).
 """
 
 from __future__ import annotations
@@ -131,8 +139,9 @@ def minimize(
     The GLM Hessian at fixed x is fully determined by per-sample curvature
     weights, so this removes one full data pass from every CG step
     (reference pays it: HessianVectorAggregator.scala:37)."""
-    f0, g0 = value_and_grad(x0, *args)
-    tols = absolute_tolerances(f0, g0, config.tolerance)
+    with jax.named_scope("optim/tron/init"):
+        f0, g0 = value_and_grad(x0, *args)
+        tols = absolute_tolerances(f0, g0, config.tolerance)
     dtype = x0.dtype
 
     def cond(c: _Carry):
@@ -141,98 +150,104 @@ def minimize(
 
     def body(c: _Carry) -> _Carry:
         if hess_setup is not None:
-            hstate = hess_setup(c.x, *args)
+            with jax.named_scope("optim/tron/hessian"):
+                hstate = hess_setup(c.x, *args)
             hv = lambda v: hess_apply(hstate, v, *args)
         else:
             hv = lambda v: hess_vec(c.x, v, *args)
-        s, r = _trcg(lambda v, *_: hv(v), c.g, c.delta,
-                     config.max_cg_iterations, cg_tol_factor)
+        with jax.named_scope("optim/tron/direction"):
+            s, r = _trcg(lambda v, *_: hv(v), c.g, c.delta,
+                         config.max_cg_iterations, cg_tol_factor)
 
-        gs = jnp.dot(c.g, s)
-        prered = -0.5 * (gs - jnp.dot(s, r))
-        x_try = c.x + s
-        f_try, g_try = value_and_grad(x_try, *args)
-        actred = c.f - f_try
-        snorm = jnp.linalg.norm(s)
+        with jax.named_scope("optim/tron/trial"):
+            gs = jnp.dot(c.g, s)
+            prered = -0.5 * (gs - jnp.dot(s, r))
+            x_try = c.x + s
+            f_try, g_try = value_and_grad(x_try, *args)
+            actred = c.f - f_try
+            snorm = jnp.linalg.norm(s)
 
-        # trust-radius update (LIBLINEAR/TRON.scala constants)
-        denom = f_try - c.f - gs
-        alpha = jnp.where(denom <= 0, _SIGMA3,
-                          jnp.maximum(_SIGMA1, -0.5 * (gs / jnp.where(denom != 0, denom, 1.0))))
-        asn = alpha * snorm
-        delta = jnp.where(
-            actred < _ETA0 * prered,
-            jnp.minimum(jnp.maximum(asn, _SIGMA1 * snorm), _SIGMA2 * c.delta),
-            jnp.where(
-                actred < _ETA1 * prered,
-                jnp.maximum(_SIGMA1 * c.delta, jnp.minimum(asn, _SIGMA2 * c.delta)),
+        with jax.named_scope("optim/tron/update"):
+            # trust-radius update (LIBLINEAR/TRON.scala constants)
+            denom = f_try - c.f - gs
+            alpha = jnp.where(denom <= 0, _SIGMA3,
+                              jnp.maximum(_SIGMA1, -0.5 * (gs / jnp.where(denom != 0, denom, 1.0))))
+            asn = alpha * snorm
+            delta = jnp.where(
+                actred < _ETA0 * prered,
+                jnp.minimum(jnp.maximum(asn, _SIGMA1 * snorm), _SIGMA2 * c.delta),
                 jnp.where(
-                    actred < _ETA2 * prered,
-                    jnp.maximum(_SIGMA1 * c.delta, jnp.minimum(asn, _SIGMA3 * c.delta)),
-                    jnp.maximum(c.delta, jnp.minimum(asn, _SIGMA3 * c.delta)),
+                    actred < _ETA1 * prered,
+                    jnp.maximum(_SIGMA1 * c.delta, jnp.minimum(asn, _SIGMA2 * c.delta)),
+                    jnp.where(
+                        actred < _ETA2 * prered,
+                        jnp.maximum(_SIGMA1 * c.delta, jnp.minimum(asn, _SIGMA3 * c.delta)),
+                        jnp.maximum(c.delta, jnp.minimum(asn, _SIGMA3 * c.delta)),
+                    ),
                 ),
-            ),
-        )
+            )
 
-        # Non-finite guard: a NaN actred fails `>` on its own, but a -Inf
-        # f_try makes actred = +Inf and would be accepted — gate acceptance
-        # on full finiteness of the trial, and keep the trust radius finite
-        # (a NaN prered/asn poisons delta even on a rejected step) so the
-        # shrunken region can recover from transient overflow.
-        g_fin = jnp.all(jnp.isfinite(g_try))
-        fin = jnp.isfinite(f_try) & g_fin
-        accept = fin & (actred > _ETA0 * prered)
-        delta = jnp.where(jnp.isfinite(delta), delta, 0.5 * c.delta)
-        x_new = jnp.where(accept, x_try, c.x)
-        f_new = jnp.where(accept, f_try, c.f)
-        g_new = jnp.where(accept, g_try, c.g)
-        failures = jnp.where(accept, 0, c.failures + 1).astype(jnp.int32)
-        nf_count = jnp.where(fin, 0, c.nf_count + 1).astype(jnp.int32)
-        failure = jnp.where(
-            nf_count >= 2,
-            nonfinite_code(f_try, g_fin),
-            jnp.asarray(FailureMode.NONE, jnp.int32),
-        )
+            # Non-finite guard: a NaN actred fails `>` on its own, but a -Inf
+            # f_try makes actred = +Inf and would be accepted — gate acceptance
+            # on full finiteness of the trial, and keep the trust radius finite
+            # (a NaN prered/asn poisons delta even on a rejected step) so the
+            # shrunken region can recover from transient overflow.
+            g_fin = jnp.all(jnp.isfinite(g_try))
+            fin = jnp.isfinite(f_try) & g_fin
+            accept = fin & (actred > _ETA0 * prered)
+            delta = jnp.where(jnp.isfinite(delta), delta, 0.5 * c.delta)
+            x_new = jnp.where(accept, x_try, c.x)
+            f_new = jnp.where(accept, f_try, c.f)
+            g_new = jnp.where(accept, g_try, c.g)
+            failures = jnp.where(accept, 0, c.failures + 1).astype(jnp.int32)
+            nf_count = jnp.where(fin, 0, c.nf_count + 1).astype(jnp.int32)
+            failure = jnp.where(
+                nf_count >= 2,
+                nonfinite_code(f_try, g_fin),
+                jnp.asarray(FailureMode.NONE, jnp.int32),
+            )
+            trk = None if c.trk is None else c.trk.record(c.it, f_new, g_new)
 
-        it = c.it + 1
-        reason = convergence_reason(it, c.f, f_new, g_new, tols,
-                                    config.max_iterations, improved=accept)
-        reason = jnp.where(
-            (reason == ConvergenceReason.NOT_CONVERGED)
-            & (failures >= config.max_improvement_failures),
-            jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
-            reason,
-        )
-        reason = jnp.where(
-            failure != FailureMode.NONE,
-            jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
-            reason,
-        )
+        with jax.named_scope("optim/tron/converged"):
+            it = c.it + 1
+            reason = convergence_reason(it, c.f, f_new, g_new, tols,
+                                        config.max_iterations, improved=accept)
+            reason = jnp.where(
+                (reason == ConvergenceReason.NOT_CONVERGED)
+                & (failures >= config.max_improvement_failures),
+                jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
+                reason,
+            )
+            reason = jnp.where(
+                failure != FailureMode.NONE,
+                jnp.asarray(ConvergenceReason.OBJECTIVE_NOT_IMPROVING, jnp.int32),
+                reason,
+            )
 
         return _Carry(x=x_new, f=f_new, g=g_new, f_prev=c.f, delta=delta,
                       it=it, failures=failures, reason=reason,
                       n_evals=c.n_evals + 1, nf_count=nf_count,
-                      failure=failure,
-                      trk=None if c.trk is None
-                      else c.trk.record(c.it, f_new, g_new))
+                      failure=failure, trk=trk)
 
-    init = _Carry(
-        x=x0, f=f0, g=g0, f_prev=f0,
-        delta=jnp.linalg.norm(g0).astype(dtype),
-        it=jnp.asarray(0, jnp.int32),
-        failures=jnp.asarray(0, jnp.int32),
-        reason=jnp.where(
-            jnp.linalg.norm(g0) <= tols.gradient_tol,
-            jnp.asarray(ConvergenceReason.GRADIENT_CONVERGED, jnp.int32),
-            jnp.asarray(ConvergenceReason.NOT_CONVERGED, jnp.int32),
-        ),
-        n_evals=jnp.asarray(1, jnp.int32),
-        nf_count=jnp.asarray(0, jnp.int32),
-        failure=nonfinite_code(f0, jnp.all(jnp.isfinite(g0))),
-        trk=StateTracking.init(config.track_states, dtype),
-    )
+    with jax.named_scope("optim/tron/init"):
+        init = _Carry(
+            x=x0, f=f0, g=g0, f_prev=f0,
+            delta=jnp.linalg.norm(g0).astype(dtype),
+            it=jnp.asarray(0, jnp.int32),
+            failures=jnp.asarray(0, jnp.int32),
+            reason=jnp.where(
+                jnp.linalg.norm(g0) <= tols.gradient_tol,
+                jnp.asarray(ConvergenceReason.GRADIENT_CONVERGED, jnp.int32),
+                jnp.asarray(ConvergenceReason.NOT_CONVERGED, jnp.int32),
+            ),
+            n_evals=jnp.asarray(1, jnp.int32),
+            nf_count=jnp.asarray(0, jnp.int32),
+            failure=nonfinite_code(f0, jnp.all(jnp.isfinite(g0))),
+            trk=StateTracking.init(config.track_states, dtype),
+        )
 
-    out = lax.while_loop(cond, body, init)
+    with jax.named_scope("optim/tron/loop"):
+        out = lax.while_loop(cond, body, init)
     return SolverResult(
         coef=out.x, value=out.f, gradient=out.g,
         iterations=out.it, reason=out.reason, num_fun_evals=out.n_evals,

@@ -43,6 +43,11 @@ once per process; ``warmup_scorers`` dispatches each (mode, bucket)
 program on dummy inputs inside ``compile_cache.warmup`` so the full
 ladder is compiled at model-load time and steady-state traffic never
 traces.
+
+Inside every program the table look-ups run under ``jax.named_scope``
+``serve/gather`` and the multiply-and-sum under ``serve/score`` (PERF.md §3;
+the names are what a device trace's seconds are grouped by, and are an
+interface).
 """
 
 from __future__ import annotations
@@ -225,25 +230,30 @@ def build_scorer_fn(model: DeviceResidentModel, mode: str,
                 for j, pos in enumerate(fixed_pos):
                     idx = fixed_idx[pos]
                     val = fixed_val[pos].astype(dtype)
-                    theta = thetas[j][idx].astype(dtype)
-                    sigma = jnp.sqrt(var_thetas[j][idx].astype(dtype))
-                    z = z_normal(idx.astype(jnp.uint32), 2 * j + 1)
-                    total = total + jnp.sum(
-                        val * (theta + sigma * z), axis=-1)
+                    with jax.named_scope("serve/gather"):
+                        theta = thetas[j][idx].astype(dtype)
+                        sigma = jnp.sqrt(var_thetas[j][idx].astype(dtype))
+                    with jax.named_scope("serve/score"):
+                        z = z_normal(idx.astype(jnp.uint32), 2 * j + 1)
+                        total = total + jnp.sum(
+                            val * (theta + sigma * z), axis=-1)
                 for j, (coef, vcoef, sidx, sval, ent) in enumerate(
                         zip(re_tables, re_var_tables, re_sidx,
                             re_sval, re_ent)):
-                    rows = coef.at[ent].get(mode="fill", fill_value=0.0)
-                    vrows = vcoef.at[ent].get(mode="fill", fill_value=0.0)
-                    mu = jnp.take_along_axis(
-                        rows, sidx, axis=1).astype(dtype)
-                    sigma = jnp.sqrt(jnp.take_along_axis(
-                        vrows, sidx, axis=1).astype(dtype))
-                    key = (_mix(ent.astype(jnp.uint32)[:, None])
-                           ^ sidx.astype(jnp.uint32))
-                    z = z_normal(key, 2 * j + 2)
-                    total = total + jnp.sum(
-                        sval.astype(dtype) * (mu + sigma * z), axis=-1)
+                    with jax.named_scope("serve/gather"):
+                        rows = coef.at[ent].get(mode="fill", fill_value=0.0)
+                        vrows = vcoef.at[ent].get(mode="fill",
+                                                  fill_value=0.0)
+                        mu = jnp.take_along_axis(
+                            rows, sidx, axis=1).astype(dtype)
+                        sigma = jnp.sqrt(jnp.take_along_axis(
+                            vrows, sidx, axis=1).astype(dtype))
+                    with jax.named_scope("serve/score"):
+                        key = (_mix(ent.astype(jnp.uint32)[:, None])
+                               ^ sidx.astype(jnp.uint32))
+                        z = z_normal(key, 2 * j + 2)
+                        total = total + jnp.sum(
+                            sval.astype(dtype) * (mu + sigma * z), axis=-1)
                 return total
 
             return fn
@@ -256,37 +266,40 @@ def build_scorer_fn(model: DeviceResidentModel, mode: str,
         def fn(fixed_idx, fixed_val, re_sidx, re_sval, re_ent, offsets,
                thetas, re_tables):
             if fused_fixed is not None:
-                total = fused_fixed(fixed_idx, fixed_val, offsets,
-                                    thetas).astype(dtype)
+                with jax.named_scope("serve/score"):
+                    total = fused_fixed(fixed_idx, fixed_val, offsets,
+                                        thetas).astype(dtype)
             else:
                 total = offsets.astype(dtype)
                 for theta, pos in zip(thetas, fixed_pos):
                     # ops/features.matvec on the padded ELL layout: pad
                     # slots are (0, 0.0) so they contribute nothing
-                    total = total + jnp.sum(
-                        fixed_val[pos].astype(dtype)
-                        * theta[fixed_idx[pos]],
-                        axis=-1)
+                    with jax.named_scope("serve/gather"):
+                        picked = theta[fixed_idx[pos]]
+                    with jax.named_scope("serve/score"):
+                        total = total + jnp.sum(
+                            fixed_val[pos].astype(dtype) * picked, axis=-1)
             if with_random:
                 for coef, sidx, sval, ent in zip(re_tables, re_sidx,
                                                  re_sval, re_ent):
-                    if isinstance(coef, tuple):
-                        # int8 arm: (quantized rows, per-row scales) —
-                        # gather both and dequantize in-register; the
-                        # unknown/zero rows quantize to (0, scale 1.0)
-                        # so they still contribute exactly nothing
-                        q, s = coef
-                        rows = (q.at[ent].get(mode="fill", fill_value=0)
-                                .astype(dtype)
-                                * s.at[ent].get(mode="fill",
-                                                fill_value=0.0))
-                    else:
-                        rows = coef.at[ent].get(mode="fill",
-                                                fill_value=0.0)
-                    total = total + jnp.sum(
-                        sval.astype(dtype)
-                        * jnp.take_along_axis(rows, sidx, axis=1),
-                        axis=-1)
+                    with jax.named_scope("serve/gather"):
+                        if isinstance(coef, tuple):
+                            # int8 arm: (quantized rows, per-row scales) —
+                            # gather both and dequantize in-register; the
+                            # unknown/zero rows quantize to (0, scale 1.0)
+                            # so they still contribute exactly nothing
+                            q, s = coef
+                            rows = (q.at[ent].get(mode="fill", fill_value=0)
+                                    .astype(dtype)
+                                    * s.at[ent].get(mode="fill",
+                                                    fill_value=0.0))
+                        else:
+                            rows = coef.at[ent].get(mode="fill",
+                                                    fill_value=0.0)
+                        picked = jnp.take_along_axis(rows, sidx, axis=1)
+                    with jax.named_scope("serve/score"):
+                        total = total + jnp.sum(
+                            sval.astype(dtype) * picked, axis=-1)
             return total
 
         return fn

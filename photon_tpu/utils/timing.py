@@ -16,23 +16,34 @@ traces via jax.profiler.TraceAnnotation) and lands in the RunReport's
 phase list. The legacy ``_TIMINGS`` registry keeps its exact behavior —
 and is now thread-safe, so concurrent RE solves and the bench harness
 can't corrupt or interleave the summary.
+
+It is the one primitive that records with telemetry OFF, which is why
+the set-up phases of a fit are ``Timed`` (``ingest/prepare/<coordinate>
+/<step>``, ``ingest/h2d/<coordinate>``, ``ingest/stats``: PERF.md §3):
+a job's set-up seconds have to be readable from a run that was measured
+with telemetry off. A phase times what the HOST spends in the block on
+``time.perf_counter``; nothing in it waits for the device. The registry
+keeps the newest ``_MAX_TIMINGS`` records, so a long-lived process that
+prepares datasets round after round (nearline) cannot grow it.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import logging
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Deque, List, Optional, Tuple
 
 from photon_tpu.obs.spans import span as _obs_span
 
 _default_logger = logging.getLogger("photon_tpu.timing")
 
 # (label, seconds) in completion order; guarded by _TIMINGS_LOCK
-_TIMINGS: List[Tuple[str, float]] = []
+_MAX_TIMINGS = 4096
+_TIMINGS: Deque[Tuple[str, float]] = collections.deque(maxlen=_MAX_TIMINGS)
 _TIMINGS_LOCK = threading.Lock()
 
 

@@ -1,0 +1,326 @@
+"""The program's own account of where its device seconds and set-up seconds
+go (PERF.md §3): every solver step, aggregator and score program lowers with
+its ``jax.named_scope``; dataset preparation records its ``Timed`` phases
+and placed bytes once, telemetry off; a span's attributes reach its
+profiler annotation; nothing samples memory or pins device arrays because
+a fit happened to run with telemetry on.
+
+The names are an interface: the per-layer readers in ``benchmark/`` group a
+device trace by them. A renamed or lost scope fails here, on the CPU, before
+it blinds a metric on the chip."""
+
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from photon_tpu.data.dataset import DataBatch
+from photon_tpu.function.objective import L1Regularization, L2Regularization
+from photon_tpu.ops import features as F
+from photon_tpu.optim.problem import (
+    GLMOptimizationConfiguration,
+    GlmOptimizationProblem,
+    OptimizerConfig,
+)
+from photon_tpu.types import OptimizerType, TaskType
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_SCOPE = re.compile(r"(?<![A-Za-z0-9_])(?:optim/[a-z0-9_]+/[a-z0-9_]+"
+                    r"|(?:agg|fe|re|cd|serve)/[a-z0-9_]+)")
+
+LINESEARCH = {f"optim/linesearch/{s}" for s in ("init", "trial", "zoom",
+                                                 "loop")}
+
+
+def _steps(solver, *steps):
+    return {f"optim/{solver}/{s}" for s in steps}
+
+
+# solver -> (task, regularisation, its own steps, the aggregators it calls
+# on dense features, and on sparse ones where they differ)
+SOLVERS = {
+    "LBFGS": (TaskType.LOGISTIC_REGRESSION, L2Regularization,
+              _steps("lbfgs", "init", "direction", "linesearch", "update",
+                     "converged", "loop") | LINESEARCH,
+              {"agg/value_and_gradient", "agg/margins"}, None),
+    "NEWTON": (TaskType.LOGISTIC_REGRESSION, L2Regularization,
+               _steps("newton", "init", "hessian", "factor_solve",
+                      "direction", "linesearch", "update", "converged",
+                      "loop"),
+               {"agg/value_and_gradient", "agg/margins",
+                "agg/hessian_weights", "agg/hessian_matrix"}, None),
+    # dense and small: the explicit Gauss-Newton matrix; sparse: matrix-free
+    "TRON": (TaskType.LOGISTIC_REGRESSION, L2Regularization,
+             _steps("tron", "init", "hessian", "direction", "trial",
+                    "update", "converged", "loop"),
+             {"agg/value_and_gradient", "agg/margins", "agg/hessian_weights",
+              "agg/hessian_matrix"},
+             {"agg/value_and_gradient", "agg/margins", "agg/hessian_weights",
+              "agg/hessian_vector"}),
+    "OWLQN": (TaskType.LOGISTIC_REGRESSION, L1Regularization,
+              _steps("owlqn", "init", "direction", "linesearch", "update",
+                     "converged", "loop"),
+              {"agg/value_and_gradient", "agg/margins"}, None),
+    "DIRECT": (TaskType.LINEAR_REGRESSION, L2Regularization,
+               _steps("direct", "init", "hessian", "factor_solve", "update"),
+               {"agg/value_and_gradient", "agg/margins",
+                "agg/hessian_weights", "agg/hessian_matrix"}, None),
+}
+
+
+def scopes_in(lowered_text):
+    return set(_SCOPE.findall(lowered_text))
+
+
+def _batch(sparse, n=64, d=6):
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(n, d))
+    feats = (F.SparseFeatures(
+        jnp.asarray(np.tile(np.arange(d, dtype=np.int32), (n, 1))),
+        jnp.asarray(x)) if sparse else jnp.asarray(x))
+    return DataBatch(feats, jnp.asarray((rng.random(n) < 0.5).astype(float)),
+                     jnp.zeros(n), jnp.ones(n)), d
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+@pytest.mark.parametrize("solver", sorted(SOLVERS))
+def test_a_solve_lowers_with_every_step_and_aggregator_named(solver, sparse):
+    task, reg, steps, dense_aggs, sparse_aggs = SOLVERS[solver]
+    batch, d = _batch(sparse)
+    problem = GlmOptimizationProblem(task, GLMOptimizationConfiguration(
+        optimizer=OptimizerConfig(optimizer_type=OptimizerType[solver],
+                                  max_iterations=5),
+        regularization=reg, regularization_weight=1.0))
+    one = jnp.asarray(1.0)
+    text = problem._solve_fn.lower(jnp.zeros(d), batch, one, one).as_text(
+        debug_info=True)
+    found = scopes_in(text)
+    want = steps | ((sparse_aggs if sparse else None) or dense_aggs)
+    assert want <= found, sorted(want - found)
+    # and nothing of another solver's leaked in under this one's name
+    others = {s for name, spec in SOLVERS.items() if name != solver
+              for s in spec[2]} - steps
+    assert not found & others, sorted(found & others)
+
+
+def _skewed_frame():
+    """Users with 4, 12, 40 and 130 rows: four size buckets."""
+    from photon_tpu.game.dataset import FeatureShard, GameDataFrame
+
+    rng = np.random.default_rng(11)
+    users = np.repeat(np.arange(8), [4, 4, 12, 12, 40, 40, 130, 130])
+    n = len(users)
+    return GameDataFrame(
+        num_samples=n, response=(rng.random(n) < 0.5).astype(np.float64),
+        feature_shards={"global": FeatureShard(rng.normal(size=(n, 8)), 8),
+                        "user_feats": FeatureShard(rng.normal(size=(n, 4)),
+                                                   4)},
+        id_tags={"userId": [f"u{u}" for u in users]})
+
+
+@pytest.fixture(scope="module")
+def glmix_fit():
+    """A GLMix estimator fitted twice on one frame, telemetry off, with the
+    phases and counters each fit added."""
+    from photon_tpu import obs
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.utils import timing
+    from tests.test_game import glmix_estimator
+
+    obs.reset()
+    frame = _skewed_frame()
+    est = glmix_estimator(num_iterations=1)
+
+    def counters():
+        return {k: v for k, v in registry.snapshot()["counters"].items()
+                if k.startswith("ingest.h2d_bytes")}
+
+    before = counters()
+    timing.clear_timings()
+    est.fit(frame)
+    first = timing.timing_records()
+    placed = {k: v - before.get(k, 0.0) for k, v in counters().items()}
+    timing.clear_timings()
+    after_first = counters()
+    est.fit(frame)
+    second = timing.timing_records()
+    return est, first, second, placed, after_first == counters()
+
+
+def test_the_ladder_program_names_every_bucket(glmix_fit):
+    est = glmix_fit[0]
+    coord = est._coordinates["per-user"]
+    ds = coord.dataset
+    assert len(ds.blocks) == 4
+    coef0 = jnp.zeros((ds.num_entities, ds.projected_dim), jnp.float64)
+    one = jnp.asarray(1.0)
+    text = coord._solve_fn.lower(
+        ds, jnp.zeros(coord.n), coef0, one, one).as_text(debug_info=True)
+    found = scopes_in(text)
+    assert {f"re/b{i}" for i in range(len(ds.blocks))} <= found
+    assert f"re/b{len(ds.blocks)}" not in found
+    assert {"re/gather", "re/scatter", "agg/value_and_gradient",
+            "optim/lbfgs/linesearch"} <= found
+
+
+def test_the_score_programs_are_named(glmix_fit):
+    est = glmix_fit[0]
+    fe, re_ = est._coordinates["fixed"], est._coordinates["per-user"]
+    from photon_tpu.game.coordinate import _fixed_score
+
+    text = _fixed_score.lower(
+        fe.batch.features, jnp.zeros(fe.dim)).as_text(debug_info=True)
+    assert "fe/score" in scopes_in(text)
+    ds = re_.dataset
+    text = re_._score_fn.lower(
+        ds, jnp.zeros((ds.num_entities, ds.projected_dim))).as_text(
+            debug_info=True)
+    assert "re/score" in scopes_in(text)
+
+
+def test_preparation_records_its_phases_once_with_telemetry_off(glmix_fit):
+    _, first, second, _, _ = glmix_fit
+    labels = [label for label, _ in first]
+    for cid in ("fixed", "per-user"):
+        assert f"ingest/h2d/{cid}" in labels
+        assert f"ingest/prepare/{cid}/coordinate" in labels
+    for step in ("group", "bucket", "pad", "passive"):
+        assert labels.count(f"ingest/prepare/per-user/{step}") >= 1
+    assert labels.count("ingest/prepare/per-user/group") == 1
+    assert "ingest/stats" in labels
+    assert all(seconds >= 0 for _, seconds in first)
+    # _prepare_cached holds the frame: a second fit prepares nothing
+    assert [l for l, _ in second if l.startswith("ingest/")] == []
+
+
+def test_the_placed_bytes_are_counted_once(glmix_fit):
+    est, _, _, placed, unchanged_by_second_fit = glmix_fit
+    fe = est._coordinates["fixed"]
+    want = {
+        'ingest.h2d_bytes{coordinate="fixed"}': sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(fe.batch)),
+        'ingest.h2d_bytes{coordinate="per-user"}': sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+                est._re_datasets["per-user"])),
+    }
+    assert placed == want
+    assert unchanged_by_second_fit
+
+
+def test_a_spans_attributes_reach_its_profiler_annotation(tmp_path):
+    from jax.profiler import ProfileData
+
+    from photon_tpu import obs
+
+    obs.reset()
+    obs.configure(enabled=True)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with obs.span("cd/update", coordinate="x", iteration=3):
+            with obs.annotate("re/args", coordinate="x"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+        obs.reset()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = {ev.name: dict(ev.stats)
+              for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name in ("cd/update", "re/args")}
+    # the name stays what idle gaps are labelled by; the attributes ride
+    assert events == {"cd/update": {"coordinate": "x", "iteration": 3},
+                      "re/args": {"coordinate": "x"}}
+
+
+def test_telemetry_off_annotations_are_the_shared_no_op():
+    from photon_tpu import obs
+    from photon_tpu.obs import spans
+
+    obs.reset()
+    os.environ.pop("PHOTON_TPU_TELEMETRY", None)
+    assert obs.annotate("re/args", coordinate="x") is spans._NULL_CONTEXT
+
+
+def test_an_outermost_library_span_samples_no_memory():
+    """``cd/sweep`` is outermost when a library user (the benchmark's traced
+    window) fits with telemetry on; only a driver's root is a phase
+    boundary."""
+    from photon_tpu import obs
+
+    obs.reset()
+    obs.configure(enabled=True)
+    try:
+        with obs.span("cd/sweep", iteration=0):
+            pass
+        assert obs.memory.watermarks() == {}
+        obs.memory.record_phase("train")
+        assert set(obs.memory.watermarks()) == {"train"}
+    finally:
+        obs.reset()
+
+
+def test_solver_telemetry_is_bounded_by_the_fit_not_the_process():
+    from photon_tpu import obs
+    from photon_tpu.obs import solver
+    from tests.test_game import glmix_estimator, make_glmix_frame
+
+    obs.reset()
+    obs.configure(enabled=True)
+    try:
+        frame, _, _ = make_glmix_frame(np.random.default_rng(5), n=300,
+                                       n_users=6)
+        est = glmix_estimator(num_iterations=2)
+        est.fit(frame)
+        # the random effect's tracker, a sweep (the fixed effect has one
+        # only where the configuration tracks states)
+        assert solver.pending() == 2
+        est.fit(frame)
+        est.fit(frame)
+        assert solver.pending() == 2
+        drained = obs.drain_solver_telemetry()["random_effects"]
+        assert [(d["coordinate"], d["sweep"]) for d in drained] == [
+            ("per-user", 0), ("per-user", 1)]
+        assert solver.pending() == 0
+    finally:
+        obs.reset()
+
+
+def test_the_timed_registry_keeps_only_the_newest_records():
+    import logging
+
+    from photon_tpu.utils import timing
+
+    timing.clear_timings()
+    for i in range(timing._MAX_TIMINGS + 10):
+        with timing.Timed(f"p{i}", level=logging.DEBUG):
+            pass
+    records = timing.timing_records()
+    assert len(records) == timing._MAX_TIMINGS
+    assert records[0][0] == "p10" and records[-1][0].endswith(
+        str(timing._MAX_TIMINGS + 9))
+    timing.clear_timings()
+
+
+def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
+    """PERF.md §3 is where a later session looks the names up."""
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        text = f.read()
+    # `optim/lbfgs/{init,loop}` lists `optim/lbfgs/init` and `optim/lbfgs/loop`
+    for stem, leaves in re.findall(r"([a-z_/]+)/\{([a-z_,]+)\}", text):
+        text += " " + " ".join(f"{stem}/{leaf}" for leaf in leaves.split(","))
+    names = {"fe/score", "re/score", "re/gather", "re/scatter", "re/b<index>",
+             "serve/gather", "serve/score", "ingest/prepare/", "ingest/h2d/",
+             "ingest/stats", "ingest.h2d_bytes", "fe/args", "re/args",
+             "fe/outcome", "re/outcome", "cd/score", "cd/commit",
+             "cd/record"} | LINESEARCH
+    for _, _, steps, dense, sparse in SOLVERS.values():
+        names |= steps | dense | (sparse or set())
+    missing = sorted(n for n in names if n not in text)
+    assert not missing, missing

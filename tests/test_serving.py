@@ -223,6 +223,21 @@ def test_zero_steady_state_compiles_after_warmup(served):
     assert after == before
 
 
+def test_scorer_programs_name_their_scopes(served):
+    """Every scorer program lowers with ``serve/gather`` around its table
+    look-ups and ``serve/score`` around the multiply-and-sum (PERF.md §3):
+    what a serving cell's device seconds will be grouped by."""
+    from photon_tpu.serving import scorer
+
+    engine = served[0]
+    model, bucket = engine.model, engine.ladder.buckets[0]
+    for mode in scorer.serving_modes(model):
+        args = scorer.mode_args(model, mode, model.dummy_args(bucket))
+        text = scorer.build_scorer_fn(model, mode, bucket).lower(
+            *args).as_text(debug_info=True)
+        assert "serve/score" in text and "serve/gather" in text, mode
+
+
 def test_load_for_serving_matches_offline_load(served):
     """The serving fast path (one pass, no variances, self-built compact
     index space) scores identically to an engine fed the offline maps."""
