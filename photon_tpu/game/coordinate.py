@@ -583,16 +583,17 @@ class RandomEffectCoordinate:
             vg = lambda c: obj_e.value_and_gradient(c, batch, hyper)
             if opt_type == OptimizerType.DIRECT:
                 # one [K, K] normal-equations solve per entity; under
-                # vmap this is a single batched [E, K, K] Cholesky
-                # (optim/direct.py) — no sequential iterations at all
+                # vmap one batch of E Cholesky solves, entities on the
+                # lanes for small K (optim/spd.py) — no sequential
+                # iterations at all
                 from photon_tpu.optim import direct
                 r = direct.minimize(
                     vg, lambda c: obj_e.hessian_matrix(c, batch, hyper),
                     x0)
             elif opt_type == OptimizerType.NEWTON:
-                # damped Newton/IRLS: DIRECT's [E, K, K] batched
-                # Cholesky machinery for logistic/Poisson — a handful
-                # of outer iterations, each one batched weighted-Gram
+                # damped Newton/IRLS: DIRECT's batched Cholesky solve
+                # (optim/spd.py) for logistic/Poisson — a handful of
+                # outer iterations, each one batched weighted-Gram
                 # contraction + factorization, zero inner CG
                 # (optim/newton.py; replaces per-entity iterative TRON,
                 # SingleNodeOptimizationProblem.scala:40)

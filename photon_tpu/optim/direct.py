@@ -8,9 +8,12 @@ is exactly quadratic, so the minimizer is one linear solve:
     x* = x0 - H^{-1} g(x0)      (exact from ANY starting point)
 
 H is the weighted Gram matrix + lambda*I (one MXU contraction via
-aggregators.hessian_matrix) and the solve is a Cholesky factorization —
-batched over entities under vmap this is one [E, K, K] potrf/trsm
-pipeline instead of thousands of sequential while_loop iterations.
+aggregators.hessian_matrix) and the solve is a Cholesky factorization
+(``optim/spd.py::spd_solve``) instead of thousands of sequential while_loop
+iterations. Batched over entities under vmap, K <= ``spd.LANES_MAX_DIM``
+runs with the entity axis on the TPU's lanes (XLA's batched [E, K, K]
+potrf/trsm custom call walks the batch matrix by matrix, 2.2 us a 20 x 20
+system on a v5e: PERF.md §5, PR 25); a larger K stays on ``cho_factor``.
 sklearn Ridge's own `cholesky` solver is the CPU-world equivalent, which
 makes bench comparisons apples-to-apples.
 
@@ -34,6 +37,7 @@ from photon_tpu.optim.base import (
     SolverResult,
     nonfinite_code,
 )
+from photon_tpu.optim.spd import spd_solve
 
 Array = jax.Array
 
@@ -50,8 +54,7 @@ def _newton_step(x0: Array, f0: Array, g: Array, h: Array) -> SolverResult:
     ``failure`` code distinguishes a bad input (non-finite f0/g, e.g. a
     poisoned residual) from a non-finite Cholesky step."""
     with jax.named_scope("optim/direct/factor_solve"):
-        chol = jax.scipy.linalg.cho_factor(h)
-        step = -jax.scipy.linalg.cho_solve(chol, g)
+        step = -spd_solve(h, g)
     with jax.named_scope("optim/direct/update"):
         ok = jnp.all(jnp.isfinite(step))
         step = jnp.where(ok, step, 0.0)
@@ -86,7 +89,7 @@ def minimize_path(value_and_grad_noreg, hessian_matrix_noreg, x0: Array,
     UN-regularized data objective; the Gram matrix G and the data
     gradient are computed once, then each lambda is one Cholesky of
     (G + lambda I) — vmapped, so an L-point ridge path costs one pass
-    over the samples plus L batched [d, d] factorizations. (The
+    over the samples plus L [d, d] factorizations in one batch. (The
     iterative reference pays a full warm-started solve per lambda:
     ModelTraining.scala:134-147.) Returns a SolverResult whose leaves
     are stacked on a leading [L] axis.

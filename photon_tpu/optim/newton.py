@@ -7,18 +7,22 @@ minimizer is reached by a handful of Newton steps, each one
     H(x) s = -g(x);   x <- x + t s      (t from Armijo backtracking)
 
 where H is the explicit [d, d] GLM Hessian — one curvature-weighted Gram
-contraction (MXU) — and the solve is a Cholesky factorization. Under vmap
-over entity blocks this is a batched [E, K, K] potrf/trsm pipeline per
-OUTER iteration: a logistic GLMix per-entity solve costs ~5 batched
+contraction (MXU) — and the solve is a Cholesky factorization
+(``optim/spd.py::spd_solve``). A logistic GLMix per-entity solve costs ~5
 factorizations total, versus TRON's nested outer x CG sequential
 while_loop steps (the reference runs full iterative TRON/L-BFGS per
 entity: SingleNodeOptimizationProblem.scala:40, TRON.scala:278-338).
 
 This is classic IRLS re-shaped for the hardware: all sequential depth
 that XLA cannot batch is collapsed into the one place it is algorithmically
-irreducible (the outer Newton iteration), and everything inside an
-iteration is a dense contraction or factorization the MXU executes
-natively.
+irreducible (the outer Newton iteration). What the chip showed about the
+solve (PERF.md §5-§6, PR 22-25): vmapped over entities, ``cho_factor`` /
+``cho_solve`` become a batched [E, K, K] custom call that the TPU walks
+matrix by matrix, 2.2 us a 20 x 20 system and 56% of a GLMix fit. So for
+K <= ``spd.LANES_MAX_DIM`` the batched solve now runs with the ENTITY axis
+on the lanes, K elementwise steps over [K, K + 1, E], 85 times less for a
+bucket of 10,360 (3% of the fit); a larger system (a fixed effect's
+unbatched K = 128) stays on XLA's factorization.
 
 Safeguards:
   * non-PD / singular curvature (lambda = 0 with rank-deficient data)
@@ -33,8 +37,8 @@ Safeguards:
 
 Each step of an iteration runs under a ``jax.named_scope``
 ``optim/newton/<step>``: ``init``, ``hessian`` (the call into ``agg/``),
-``factor_solve`` (the Cholesky factorisation and its two triangular
-solves), ``direction`` (the descent safeguard), ``linesearch``, ``update``,
+``factor_solve`` (``spd_solve``: the Cholesky factorisation and its two
+substitutions), ``direction`` (the descent safeguard), ``linesearch``, ``update``,
 ``converged``, and ``loop`` around the ``while_loop`` itself. The names are
 what a device trace's seconds are grouped by (PERF.md §3) and are an
 interface.
@@ -57,6 +61,7 @@ from photon_tpu.optim.base import (
     convergence_reason,
     nonfinite_code,
 )
+from photon_tpu.optim.spd import spd_solve
 
 Array = jax.Array
 
@@ -120,11 +125,11 @@ def minimize(
         with jax.named_scope("optim/newton/hessian"):
             h = hess_matrix(c.x)
         with jax.named_scope("optim/newton/factor_solve"):
-            chol = jax.scipy.linalg.cho_factor(h)
-            step = -jax.scipy.linalg.cho_solve(chol, c.g)
+            step = -spd_solve(h, c.g)
         with jax.named_scope("optim/newton/direction"):
-            # descent safeguard: a non-PD factorization yields NaN/inf or an
-            # ascent direction; steepest descent keeps the iteration alive
+            # descent safeguard: a non-PD factorization yields NaN/inf (both
+            # paths of spd_solve) or an ascent direction; steepest descent
+            # keeps the iteration alive
             newton_ok = (jnp.all(jnp.isfinite(step))
                          & (jnp.dot(c.g, step) < 0.0))
             direction = jnp.where(newton_ok, step, -c.g)

@@ -107,6 +107,58 @@ def test_a_solve_lowers_with_every_step_and_aggregator_named(solver, sparse):
     assert not found & others, sorted(found & others)
 
 
+def _vmapped_solve_names(solver, k):
+    """The named locations of a vmapped NEWTON or DIRECT solve of ``k``
+    coefficients, as lowered."""
+    from photon_tpu.optim import direct, newton
+
+    def vg(w):
+        return 0.5 * jnp.dot(w, w) + jnp.sum(jnp.cos(w)), w - jnp.sin(w)
+
+    def hess(w):
+        return jnp.diag(1.0 - jnp.cos(w)) + jnp.eye(k)
+
+    minimize = {"newton": newton.minimize, "direct": direct.minimize}[solver]
+    text = jax.jit(jax.vmap(lambda w0: minimize(vg, hess, w0).coef)).lower(
+        jnp.ones((3, k))).as_text(debug_info=True)
+    return set(re.findall(r'loc\("(jit\([^"]+)"', text))
+
+
+def _spd_path_ticks():
+    from photon_tpu.obs.metrics import registry
+
+    counters = registry.snapshot()["counters"]
+    return {path: counters.get(f'kernels.spd_solve{{path="{path}"}}', 0.0)
+            for path in ("lanes", "lapack")}
+
+
+@pytest.mark.parametrize("path", ["lanes", "lapack"])
+@pytest.mark.parametrize("solver", ["newton", "direct"])
+def test_the_spd_solve_lowers_under_factor_solve_and_counts_its_path(
+        solver, path, monkeypatch):
+    """What the solve adds to a program, against the same program with the
+    solve stubbed out, is named ``optim/<solver>/factor_solve`` to the last
+    operation (the ``custom_vmap`` rule runs at batching time and must still
+    inherit the scope), and ``kernels.spd_solve{path}`` ticks once a traced
+    program with the path the static shape chose."""
+    import importlib
+
+    from photon_tpu.optim.spd import LANES_MAX_DIM
+
+    k = LANES_MAX_DIM if path == "lanes" else LANES_MAX_DIM + 1
+    before = _spd_path_ticks()
+    with_solve = _vmapped_solve_names(solver, k)
+    ticks = {p: n - before[p] for p, n in _spd_path_ticks().items()}
+    assert ticks == {p: float(p == path) for p in ("lanes", "lapack")}
+    module = importlib.import_module(f"photon_tpu.optim.{solver}")
+    # (the stub reads h, or the Hessian's operations would go with the solve)
+    monkeypatch.setattr(module, "spd_solve", lambda h, g: g + 0.0 * h[0])
+    added = with_solve - _vmapped_solve_names(solver, k)
+    scope = f"optim/{solver}/factor_solve"
+    assert added and all(scope in name for name in added), sorted(
+        name for name in added if scope not in name)
+
+
 def _skewed_frame():
     """Users with 4, 12, 40 and 130 rows: four size buckets."""
     from photon_tpu.game.dataset import FeatureShard, GameDataFrame
