@@ -843,7 +843,8 @@ class RandomEffectCoordinate:
         from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
         e_orig = self._num_entities_orig
         self.last_tracker = RandomEffectOptimizationTracker(
-            iterations=iters[:e_orig], reasons=reasons[:e_orig])
+            iterations=iters[:e_orig], reasons=reasons[:e_orig],
+            bucket_rows=tuple(blk.entity_rows for blk in ds.blocks))
         # failure isolation already happened device-side (failed entities
         # kept their warm start inside solve_all); here only the counts
         # cross to the host — one scalar at the coordinate boundary
@@ -1318,7 +1319,8 @@ class RandomEffectCoordinate:
         from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
         e_orig = self._num_entities_orig
         self.last_tracker = RandomEffectOptimizationTracker(
-            iterations=iters[:e_orig], reasons=reasons[:e_orig])
+            iterations=iters[:e_orig], reasons=reasons[:e_orig],
+            bucket_rows=tuple(blk.entity_rows for blk in ds.blocks))
         n_failed = int(np.sum(fails[:e_orig] != 0))
         self.last_failed_entities = n_failed
         self.last_failure = None

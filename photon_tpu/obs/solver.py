@@ -48,6 +48,24 @@ def record(coordinate: str, tracker, **meta: Any) -> None:
                         "tracker": tracker, "unix": time.time(), **meta}
 
 
+def lane_counts() -> Dict[str, Dict[str, int]]:
+    """``{coordinate: {"sum", "capacity", "trips"}}``: the buffered
+    random-effect updates' ``lane_counts()`` added up, which is ONE fit's
+    (a later fit's updates replace an earlier fit's): what its vmapped
+    per-entity loops ran against what its entities needed. Pays the host
+    transfers and leaves the buffer as it is — ask after a fit, not inside
+    a sweep. Empty with telemetry off."""
+    with _LOCK:
+        entries = [e for e in _BUFFER.values()
+                   if e["kind"] == "random_effect"]
+    out: Dict[str, Dict[str, int]] = {}
+    for e in entries:
+        total = out.setdefault(e["coordinate"], {})
+        for stat, n in e["tracker"].lane_counts().items():
+            total[stat] = total.get(stat, 0) + n
+    return out
+
+
 def pending() -> int:
     with _LOCK:
         return len(_BUFFER)

@@ -86,10 +86,35 @@ class RandomEffectOptimizationTracker:
 
     iterations: np.ndarray   # [E] int (numpy or jax.Array)
     reasons: np.ndarray      # [E] int (ConvergenceReason; numpy or jax.Array)
+    # the size buckets' ``entity_rows`` (one [E_b] array a bucket, pad rows
+    # out of range): which entities one vmapped loop solved together
+    bucket_rows: Tuple[np.ndarray, ...] = ()
 
     @property
     def num_entities(self) -> int:
         return len(self.iterations)
+
+    def lane_counts(self) -> Dict[str, int]:
+        """What the vmapped loops ran against what the entities needed. A
+        bucket's loop trips until its SLOWEST entity is done, every lane
+        riding along: ``sum`` = the entities' iterations, ``trips`` = the
+        buckets' largest counts, summed, ``capacity`` = entities of a
+        bucket x its largest count, summed (``sum / capacity`` is the
+        lanes' occupancy). Pays the host transfers, like every accessor
+        here."""
+        iters, _ = self._host()
+        out = {"sum": 0, "capacity": 0, "trips": 0}
+        for rows in self.bucket_rows:
+            # a jax.Array keeps its host copy: a dataset's rows cross once
+            rows = np.asarray(rows)
+            its = iters[rows[(rows >= 0) & (rows < len(iters))]]
+            its = its[its >= 0]
+            if not len(its):
+                continue
+            out["sum"] += int(its.sum())
+            out["trips"] += int(its.max())
+            out["capacity"] += len(its) * int(its.max())
+        return out
 
     def _host(self) -> Tuple[np.ndarray, np.ndarray]:
         if not isinstance(self.iterations, np.ndarray):
@@ -125,9 +150,12 @@ class RandomEffectOptimizationTracker:
         (this is the drain point: the lazy device->host transfer in
         ``_host`` happens here, at a phase boundary, not in the sweep)."""
         mean_it, lo, hi = self.iteration_stats()
-        return {
+        out = {
             "kind": "random_effect",
             "num_entities": int(self.num_entities),
             "iterations": {"mean": mean_it, "min": lo, "max": hi},
             "reason_counts": self.reason_counts(),
         }
+        if self.bucket_rows:
+            out["lanes"] = self.lane_counts()
+        return out

@@ -219,6 +219,34 @@ def test_the_ladder_program_names_every_bucket(glmix_fit):
             "optim/lbfgs/linesearch"} <= found
 
 
+def test_a_ladder_solved_by_lbfgs_names_its_steps_under_its_buckets(glmix_fit):
+    """The solver the configuration names is the solver that runs: every
+    ``optim/`` operation of an L-BFGS ladder is an L-BFGS or line-search
+    step, stands under its bucket's ``re/b<index>``, and no other solver's
+    step is in the program (``glmix-ml20m-lbfgs.refit``'s scope table by
+    bucket, and its check that no NEWTON ran, read these names)."""
+    coord = glmix_fit[0]._coordinates["per-user"]
+    assert coord.config.optimizer.optimizer_type == OptimizerType.LBFGS
+    ds = coord.dataset
+    coef0 = jnp.zeros((ds.num_entities, ds.projected_dim), jnp.float64)
+    one = jnp.asarray(1.0)
+    text = coord._solve_fn.lower(
+        ds, jnp.zeros(coord.n), coef0, one, one).as_text(debug_info=True)
+    steps = SOLVERS["LBFGS"][2]
+    assert steps <= scopes_in(text), sorted(steps - scopes_in(text))
+    others = {s for name, spec in SOLVERS.items() if name != "LBFGS"
+              for s in spec[2]} - steps
+    assert not scopes_in(text) & others, sorted(scopes_in(text) & others)
+    named = [loc for loc in re.findall(r'loc\("(jit\([^"]+)"', text)
+             if "optim/" in loc]
+    assert named
+    for loc in named:
+        bucket = re.search(r"re/b(\d+)/", loc)
+        assert bucket and loc.index("optim/") > bucket.start(), loc
+    buckets = {int(re.search(r"re/b(\d+)/", loc).group(1)) for loc in named}
+    assert buckets == set(range(len(ds.blocks)))
+
+
 def test_the_score_programs_are_named(glmix_fit):
     est = glmix_fit[0]
     fe, re_ = est._coordinates["fixed"], est._coordinates["per-user"]
@@ -376,3 +404,13 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
         names |= steps | dense | (sparse or set())
     missing = sorted(n for n in names if n not in text)
     assert not missing, missing
+
+
+def test_perf_md_lists_the_lane_counts_and_their_readers():
+    """What counts (``obs/solver.py``, ``optim/tracking.py``) and what reads
+    it (``benchmark/layer_metrics/``) are looked up in PERF.md §3 too."""
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        text = f.read()
+    for name in ("obs.solver.lane_counts", "`sum`", "`capacity`", "`trips`",
+                 "re_lane_occupancy", "re_solver_trips"):
+        assert name in text, name
