@@ -1,7 +1,9 @@
 """L-BFGS as one jittable lax.while_loop (replaces breeze.optimize.LBFGS
 behind the reference's LBFGS adapter, optimization/LBFGS.scala:39).
 
-Two-loop recursion over a fixed-size circular (S, Y) history, strong-Wolfe
+Two-loop recursion over a fixed-size (S, Y) history kept in age order (slot 0
+the newest pair; a ring buffer's write position would be a per-lane value
+under ``vmap`` and make every history read a gather), strong-Wolfe
 line search (optim/linesearch.py), optional box projection after each step
 (the reference projects into the constraint box after each Breeze step —
 LBFGS.scala; LBFGSB.scala:40 gets the same treatment here).
@@ -55,11 +57,10 @@ class _Carry(NamedTuple):
     f: Array
     g: Array
     f_prev: Array
-    s_hist: Array      # [m, d]
+    s_hist: Array      # [m, d], age order: slot 0 the newest pair
     y_hist: Array      # [m, d]
     rho: Array         # [m]
     n_pairs: Array     # int32: number of valid pairs (<= m)
-    head: Array        # int32: next write slot
     it: Array
     reason: Array
     n_evals: Array
@@ -69,37 +70,46 @@ class _Carry(NamedTuple):
     trk: Optional[StateTracking]  # per-iteration ring buffer (None = off)
 
 
-def two_loop_direction(g, s_hist, y_hist, rho, n_pairs, head, m):
-    """Standard two-loop recursion with circular-buffer masking."""
-    dtype = g.dtype
-
-    def bwd(j, carry):
-        q, alphas = carry
-        idx = (head - 1 - j) % m
-        valid = j < n_pairs
-        a = rho[idx] * jnp.dot(s_hist[idx], q)
+def two_loop_direction(g, s_hist, y_hist, rho, n_pairs, m):
+    """Standard two-loop recursion over a history kept in AGE order: slot 0
+    holds the newest pair, slot ``n_pairs - 1`` the oldest, the rest zeros
+    (``push_pair`` keeps it so). Step ``j`` reads slot ``j`` whatever the
+    solve's state, a static slice; only the mask ``j < n_pairs`` is the
+    solve's own. A ring buffer's write position would be a per-lane value
+    under ``vmap`` and turn every read into a gather."""
+    alphas = []
+    q = g
+    for age in range(m):                      # newest to oldest
+        valid = age < n_pairs
+        a = rho[age] * jnp.dot(s_hist[age], q)
         a = jnp.where(valid, a, 0.0)
-        q = q - a * y_hist[idx]
-        return q, alphas.at[idx].set(a)
-
-    q, alphas = lax.fori_loop(0, m, bwd, (g, jnp.zeros((m,), dtype)))
+        q = q - a * y_hist[age]
+        alphas.append(a)
 
     # initial Hessian scaling from the most recent pair
-    last = (head - 1) % m
-    sy = jnp.dot(s_hist[last], y_hist[last])
-    yy = jnp.dot(y_hist[last], y_hist[last])
+    sy = jnp.dot(s_hist[0], y_hist[0])
+    yy = jnp.dot(y_hist[0], y_hist[0])
     gamma = jnp.where((n_pairs > 0) & (yy > 0), sy / jnp.where(yy > 0, yy, 1.0), 1.0)
     r = gamma * q
 
-    def fwd(j, r):
-        idx = (head - n_pairs + j) % m
-        valid = j < n_pairs
-        beta = rho[idx] * jnp.dot(y_hist[idx], r)
-        upd = s_hist[idx] * (alphas[idx] - beta)
-        return r + jnp.where(valid, upd, 0.0)
-
-    r = lax.fori_loop(0, m, fwd, r)
+    for age in reversed(range(m)):            # oldest to newest
+        valid = age < n_pairs
+        beta = rho[age] * jnp.dot(y_hist[age], r)
+        upd = s_hist[age] * (alphas[age] - beta)
+        r = r + jnp.where(valid, upd, 0.0)
     return -r
+
+
+def push_pair(store, s_hist, y_hist, rho, s, y, sy):
+    """The history with the pair ``(s, y)`` (``sy = s . y``) in slot 0 and
+    every older pair one slot further, the oldest dropped, where ``store``
+    holds; unchanged elsewhere. One select over the history, no indexed
+    write: the layout ``two_loop_direction`` reads."""
+    def pushed(hist, new):
+        return jnp.where(store, jnp.concatenate([new[None], hist[:-1]]), hist)
+
+    return (pushed(s_hist, s), pushed(y_hist, y),
+            pushed(rho, 1.0 / jnp.where(sy != 0, sy, 1.0)))
 
 
 def minimize(
@@ -139,7 +149,7 @@ def minimize(
     def body(c: _Carry) -> _Carry:
         with jax.named_scope("optim/lbfgs/direction"):
             direction = two_loop_direction(c.g, c.s_hist, c.y_hist, c.rho,
-                                           c.n_pairs, c.head, m)
+                                           c.n_pairs, m)
             # safeguard: fall back to steepest descent on non-descent directions
             descent = jnp.dot(direction, c.g) < 0
             direction = jnp.where(descent, direction, -c.g)
@@ -187,11 +197,8 @@ def minimize(
             yv = g_kept - c.g
             sy = jnp.dot(s, yv)
             store = decreased & (sy > 1e-10 * jnp.maximum(jnp.dot(yv, yv), 1e-30))
-            write = c.head % m
-            s_hist = jnp.where(store, c.s_hist.at[write].set(s), c.s_hist)
-            y_hist = jnp.where(store, c.y_hist.at[write].set(yv), c.y_hist)
-            rho = jnp.where(store, c.rho.at[write].set(1.0 / jnp.where(sy != 0, sy, 1.0)), c.rho)
-            head = jnp.where(store, (c.head + 1) % m, c.head)
+            s_hist, y_hist, rho = push_pair(store, c.s_hist, c.y_hist, c.rho,
+                                            s, yv, sy)
             n_pairs = jnp.where(store, jnp.minimum(c.n_pairs + 1, m), c.n_pairs)
             trk = None if c.trk is None else c.trk.record(
                 c.it, f_kept, g_kept,
@@ -223,7 +230,7 @@ def minimize(
         return _Carry(
             x=x_new, f=f_kept, g=g_kept, f_prev=c.f,
             s_hist=s_hist, y_hist=y_hist, rho=rho,
-            n_pairs=n_pairs, head=head.astype(jnp.int32),
+            n_pairs=n_pairs,
             it=it, reason=reason,
             n_evals=c.n_evals + ls.num_evals + (1 if has_box else 0),
             ls_failed=~decreased,
@@ -235,7 +242,7 @@ def minimize(
             x=x0, f=f0, g=g0, f_prev=f0 + jnp.asarray(jnp.inf, dtype),
             s_hist=jnp.zeros((m, d), dtype), y_hist=jnp.zeros((m, d), dtype),
             rho=jnp.zeros((m,), dtype),
-            n_pairs=jnp.asarray(0, jnp.int32), head=jnp.asarray(0, jnp.int32),
+            n_pairs=jnp.asarray(0, jnp.int32),
             it=jnp.asarray(0, jnp.int32),
             # handle an already-converged start (zero gradient)
             reason=jnp.where(
@@ -298,9 +305,11 @@ def _compact_direction(sg, yg, gg, sy_gram, yy_gram, rho, n_pairs, head, m):
         direction = -(c_g * g + c_s @ S + c_y @ Y)
 
     so the caller materializes the direction with ONE [m, d] combination.
-    Invalid circular-buffer slots are masked exactly as in
-    ``two_loop_direction``: their alphas/r_s entries stay zero, so garbage
-    Gram entries at dead slots never contribute."""
+    This path keeps a ring buffer (``head`` is the next write slot: it is
+    never vmapped, and at d = 10^7 a store must stay one row's write), so
+    step ``j`` visits slot ``(head - 1 - j) % m``; steps past ``n_pairs``
+    are masked as in ``two_loop_direction``: their alphas/r_s entries stay
+    zero, so garbage Gram entries at dead slots never contribute."""
     dtype = sg.dtype
 
     def bwd(j, alphas):

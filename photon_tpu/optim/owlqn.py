@@ -34,7 +34,7 @@ from photon_tpu.optim.base import (
     convergence_reason,
     nonfinite_code,
 )
-from photon_tpu.optim.lbfgs import two_loop_direction
+from photon_tpu.optim.lbfgs import push_pair, two_loop_direction
 
 Array = jax.Array
 
@@ -56,11 +56,10 @@ class _Carry(NamedTuple):
     g: Array          # smooth gradient
     pg: Array         # pseudo-gradient
     f_prev: Array
-    s_hist: Array
+    s_hist: Array     # age order (lbfgs.push_pair): slot 0 the newest pair
     y_hist: Array
     rho: Array
     n_pairs: Array
-    head: Array
     it: Array
     reason: Array
     n_evals: Array
@@ -103,7 +102,7 @@ def minimize(
     def body(c: _Carry) -> _Carry:
         with jax.named_scope("optim/owlqn/direction"):
             direction = two_loop_direction(c.pg, c.s_hist, c.y_hist, c.rho,
-                                           c.n_pairs, c.head, m)
+                                           c.n_pairs, m)
             # sign alignment: d must agree with -pg componentwise
             direction = jnp.where(direction * (-c.pg) > 0, direction, 0.0)
             descent = jnp.dot(direction, c.pg) < 0
@@ -166,11 +165,8 @@ def minimize(
             yv = g_kept - c.g
             sy = jnp.dot(s, yv)
             store = decreased & (sy > 1e-10 * jnp.maximum(jnp.dot(yv, yv), 1e-30))
-            write = c.head % m
-            s_hist = jnp.where(store, c.s_hist.at[write].set(s), c.s_hist)
-            y_hist = jnp.where(store, c.y_hist.at[write].set(yv), c.y_hist)
-            rho = jnp.where(store, c.rho.at[write].set(1.0 / jnp.where(sy != 0, sy, 1.0)), c.rho)
-            head = jnp.where(store, (c.head + 1) % m, c.head).astype(jnp.int32)
+            s_hist, y_hist, rho = push_pair(store, c.s_hist, c.y_hist, c.rho,
+                                            s, yv, sy)
             n_pairs = jnp.where(store, jnp.minimum(c.n_pairs + 1, m), c.n_pairs)
             trk = None if c.trk is None else c.trk.record(c.it, f_kept, pg_new)
 
@@ -191,7 +187,7 @@ def minimize(
 
         return _Carry(x=x_kept, f=f_kept, g=g_kept, pg=pg_new, f_prev=c.f,
                       s_hist=s_hist, y_hist=y_hist, rho=rho,
-                      n_pairs=n_pairs, head=head, it=it, reason=reason,
+                      n_pairs=n_pairs, it=it, reason=reason,
                       n_evals=c.n_evals + k, failure=failure, trk=trk)
 
     with jax.named_scope("optim/owlqn/init"):
@@ -199,7 +195,7 @@ def minimize(
             x=x0, f=f0, g=g0, pg=pg0, f_prev=f0,
             s_hist=jnp.zeros((m, d), dtype), y_hist=jnp.zeros((m, d), dtype),
             rho=jnp.zeros((m,), dtype),
-            n_pairs=jnp.asarray(0, jnp.int32), head=jnp.asarray(0, jnp.int32),
+            n_pairs=jnp.asarray(0, jnp.int32),
             it=jnp.asarray(0, jnp.int32),
             reason=jnp.where(
                 jnp.linalg.norm(pg0) <= tols.gradient_tol,

@@ -1,6 +1,8 @@
 """Solver tests vs analytic objectives and scipy/sklearn oracles — the role
 of the reference's OptimizerTest/TRON tests against TestObjective."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -171,6 +173,135 @@ def test_solver_vmaps_over_problems(rng):
     for b in range(B):
         single = solve_one(jnp.asarray(Xs[b]), jnp.asarray(ys[b]))
         np.testing.assert_allclose(batched.coef[b], single.coef, rtol=1e-5, atol=1e-7)
+
+
+def _dense_bfgs_direction(g, pairs):
+    """-H g with H the inverse-BFGS matrix built from ``pairs`` (oldest
+    first) on gamma * I, gamma from the newest pair: float64, no recursion."""
+    g = np.asarray(g, np.float64)
+    d = g.shape[0]
+    h = np.eye(d)
+    if pairs:
+        s, y = pairs[-1]
+        h = h * ((s @ y) / (y @ y))
+    for s, y in pairs:
+        rho = 1.0 / (s @ y)
+        v = np.eye(d) - rho * np.outer(s, y)
+        h = v @ h @ v.T + rho * np.outer(s, s)
+    return -h @ g
+
+
+@pytest.mark.parametrize("stored", [0, 1, 3, 6, 9])
+def test_two_loop_direction_over_the_age_ordered_history(rng, stored):
+    """``push_pair`` keeps slot 0 the newest pair and drops the oldest once
+    ``m`` are held (``stored`` = 9 > m = 6); ``two_loop_direction`` over that
+    layout is the dense inverse-BFGS product of the pairs still held."""
+    m, d = 6, 7
+    s_hist, y_hist = jnp.zeros((m, d)), jnp.zeros((m, d))
+    rho = jnp.zeros((m,))
+    pairs = []
+    for _ in range(stored):
+        s = rng.normal(size=d)
+        y = s + 0.3 * rng.normal(size=d)          # s . y > 0
+        pairs.append((s, y))
+        s_hist, y_hist, rho = lbfgs.push_pair(
+            jnp.asarray(True), s_hist, y_hist, rho, jnp.asarray(s),
+            jnp.asarray(y), jnp.asarray(s @ y))
+    # a refused pair leaves the history as it was
+    kept = lbfgs.push_pair(jnp.asarray(False), s_hist, y_hist, rho,
+                           jnp.ones(d), jnp.ones(d), jnp.asarray(float(d)))
+    for was, now in zip((s_hist, y_hist, rho), kept):
+        np.testing.assert_array_equal(now, was)
+    held = pairs[-m:]
+    n_pairs = len(held)
+    for age, (s, y) in enumerate(reversed(held)):
+        np.testing.assert_array_equal(s_hist[age], s)
+        np.testing.assert_array_equal(y_hist[age], y)
+    np.testing.assert_array_equal(s_hist[n_pairs:], 0.0)
+
+    g = rng.normal(size=d)
+    got = lbfgs.two_loop_direction(jnp.asarray(g), s_hist, y_hist, rho,
+                                   jnp.asarray(n_pairs, jnp.int32), m)
+    np.testing.assert_allclose(got, _dense_bfgs_direction(g, held),
+                               rtol=1e-10, atol=1e-12)
+
+
+@pytest.mark.parametrize("solver", ["lbfgs", "owlqn"])
+def test_a_vmapped_solve_gathers_and_scatters_nothing(solver):
+    """The pin of the age-ordered history: no read or write of it is
+    addressed by a value a lane owns, so ``vmap`` over a problem that
+    gathers nothing itself leaves no ``gather`` and no ``scatter`` in the
+    program (a ring buffer's position made every history read a per-lane
+    gather over ``[E, m, K]``: PERF.md, PR 28)."""
+    def vg(x, a, b):
+        r = a * x - b
+        return 0.5 * jnp.dot(r, r), a * r
+
+    if solver == "lbfgs":
+        solve = lambda x, a, b: lbfgs.minimize(vg, x, a, b)
+    else:
+        solve = lambda x, a, b: owlqn.minimize(vg, x, a, b, l1_weight=0.1)
+    a = jnp.linspace(0.5, 3.0, 7 * 8).reshape(7, 8)
+    b = jnp.cos(jnp.arange(7.0 * 8)).reshape(7, 8)
+    x0 = jnp.zeros((7, 8))
+    # the printed jaxpr holds the loops' bodies in full
+    program = str(jax.make_jaxpr(jax.vmap(solve))(x0, a, b))
+    assert "while[" in program and "dot_general[" in program
+    addressed = set(re.findall(
+        r"\b(?:gather|scatter[-\w]*|dynamic_slice|dynamic_update_slice)\[",
+        program))
+    assert not addressed, addressed
+    res = jax.jit(jax.vmap(solve))(x0, a, b)
+    assert np.all(np.asarray(res.reason) != ConvergenceReason.NOT_CONVERGED)
+    if solver == "lbfgs":
+        np.testing.assert_allclose(res.coef, b / a, atol=1e-3)
+
+
+def _rosenbrock_lanes():
+    starts = jnp.asarray(np.linspace(-0.6, 0.6, 7)[:, None] * np.ones((1, 10)))
+    solve = lambda x0: lbfgs.minimize(
+        rosen_vg, x0, config=SolverConfig(max_iterations=300, tolerance=1e-12))
+    return solve, starts
+
+
+def _logistic_lanes():
+    batch, _, _ = make_logistic(np.random.default_rng(42))
+    obj = GLMObjective(LogisticLoss)
+
+    def solve(l2):
+        vg = lambda c: obj.value_and_gradient(
+            c, batch, Hyper.of(l2, dtype=jnp.float64))
+        return lbfgs.minimize(
+            vg, jnp.zeros(D),
+            config=SolverConfig(max_iterations=300, tolerance=1e-12))
+
+    return solve, jnp.asarray([0.01, 0.1, 0.3, 1.0, 3.0, 10.0, 100.0])
+
+
+# what the ring-buffer history gave (the parent of PR 28, float64, this
+# container): the age-ordered recursion is the same arithmetic
+@pytest.mark.parametrize("lanes, iterations, evaluations", [
+    (_rosenbrock_lanes, [68, 67, 66, 60, 58, 56, 36],
+     [83, 81, 81, 79, 76, 69, 40]),
+    (_logistic_lanes, [11, 11, 11, 11, 11, 10, 8],
+     [12, 12, 12, 12, 12, 11, 9]),
+], ids=["rosenbrock", "logistic"])
+def test_lbfgs_counts_are_the_ring_buffers_alone_and_in_a_batch_of_7(
+        lanes, iterations, evaluations):
+    solve, inputs = lanes()
+    batched = jax.jit(jax.vmap(solve))(inputs)
+    np.testing.assert_array_equal(batched.iterations, iterations)
+    np.testing.assert_array_equal(batched.num_fun_evals, evaluations)
+    np.testing.assert_array_equal(
+        batched.reason, ConvergenceReason.FUNCTION_VALUES_CONVERGED)
+    alone = jax.jit(solve)
+    for lane in (0, 3, 6):
+        one = alone(inputs[lane])
+        assert (int(one.iterations), int(one.num_fun_evals), int(one.reason)) \
+            == (iterations[lane], evaluations[lane],
+                ConvergenceReason.FUNCTION_VALUES_CONVERGED)
+        np.testing.assert_allclose(batched.coef[lane], one.coef,
+                                   rtol=1e-7, atol=1e-9)
 
 
 def test_minimize_dispatch_errors():
