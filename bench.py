@@ -2721,11 +2721,12 @@ def run_re_sweep_bench(scale: float, quick: bool = False):
 
       * data passes — a K-point sweep over the bucket ladder stages each
         bucket ONCE (prefetcher ``blocks_staged``), vs K stagings per
-        bucket for K sequential ``update_model_blocked`` fits:
+        bucket for K sequential ``update_model_blocked`` fits (each the
+        same blocked loop and program at one lane):
         swept passes <= (1/K) * sequential + 1 ladder pass;
       * bitwise parity — every λ lane's coefficients equal its
         sequential scalar fit bit-for-bit (the flattened-lane program,
-        game/coordinate._make_block_solver_swept), at the suite's f64;
+        game/coordinate._make_bucket_solver), at the suite's f64;
       * planner honesty — the BlockPlan's per-bucket planned peak bytes
         >= the measured staging+tile accounting on EVERY bucket
         (process RSS high-water is recorded as the CPU proxy);

@@ -228,7 +228,8 @@ def entity_variances_blocked(coord, coefficients,
     Device memory holds ONE staged bucket at a time (+ one in flight
     when ``prefetch``): each size bucket's K entity lanes run as one
     vmapped program while ``BlockPrefetcher`` stages the next bucket,
-    exactly the PR 17 staging discipline of ``update_model_blocked``.
+    exactly the staging discipline of the blocked fits' one host loop
+    (``RandomEffectCoordinate._solve_blocked``).
     Prefetching never changes bytes — staging order and per-bucket
     programs are fixed, so the result is bitwise run-to-run.
     """
@@ -261,12 +262,11 @@ def entity_variances_blocked(coord, coefficients,
             staged = stream.get(bi) if stream is not None else blk
             res_rows = None
             if res_flat is not None:
-                res_rows = res_flat.at[staged.sample_rows].get(
-                    mode="fill", fill_value=0.0)
+                res_rows = staged.rows_from_flat(res_flat)
             var_b = var_fn(staged, res_rows, jnp.asarray(x), l2)
             # the per-bucket host round-trip IS the design (cf.
-            # update_model_blocked): results land in host RAM, device
-            # peak stays one bucket
+            # RandomEffectCoordinate._solve_blocked): results land in
+            # host RAM, device peak stays one bucket
             out[ents[valid]] = np.asarray(var_b)[valid]
             if stream is not None:
                 stream.release()

@@ -1,6 +1,8 @@
 """Double-buffered entity-block staging for blocked random-effect training.
 
-``update_model_blocked`` used to stream buckets strictly sequentially:
+The blocked fits (``RandomEffectCoordinate.update_model_blocked_swept``, and
+``update_model_blocked``, its one-lane case: one host loop,
+``_solve_blocked``) used to stream buckets strictly sequentially:
 host→device copy of bucket b, solve, host copy-back, repeat — the
 staging time of every bucket sat on the critical path. This module moves
 staging onto a prefetch thread with the consumption-token fence pattern

@@ -75,13 +75,13 @@ _SWEEP_PREFIX = "sweep_"
 # Resume handles both (a mid-group index re-enters the group with
 # sequential semantics); v2 checkpoints load unchanged (flag False).
 # v4: adds ``re_block_cursor`` — per-coordinate next-block index for a
-# random effect whose BLOCKED update (coordinate.update_model_blocked,
-# cold-tier streaming) was mid-stream at preemption. The partial
-# checkpoint's model arrays for that coordinate hold the host table as
-# of the cursor (solved blocks fresh, later blocks still warm-start);
-# resume re-enters update_model_blocked(start_block=cursor,
-# warm_start=checkpointed coefficients). v2/v3 checkpoints load
-# unchanged (empty cursor map).
+# random effect whose BLOCKED update (coordinate.update_model_blocked or
+# update_model_blocked_swept: one host loop, cold-tier streaming) was
+# mid-stream at preemption. The partial checkpoint's model arrays for
+# that coordinate hold the host table as of the cursor (solved blocks
+# fresh, later blocks still warm-start); resume re-enters the same method
+# (start_block=cursor, warm_start=checkpointed coefficients, [K, E, d] for
+# a sweep). v2/v3 checkpoints load unchanged (empty cursor map).
 SCHEMA_VERSION = 4
 
 

@@ -15,12 +15,23 @@ the while_loop batching rule) — the reference's millions of independent
 Breeze solves become one SPMD program on the entity-sharded mesh axis.
 Residual injection is a gather; score emission is a scatter-add.
 
+One body solves a size bucket (``RandomEffectCoordinate._make_bucket_
+solver``: the only vmap of the entity solver); the scalar ladder, the λ-lane
+ladder and the blocked program are wrappers around it, and one host loop
+(``_solve_blocked``) streams the blocked fits. How ladder order maps to flat
+sample order and to entity rows is ``EntityBlock``'s (game/random_effect.py:
+``rows_from_flat`` / ``add_rows_to_flat`` / ``rows_from_table`` /
+``set_rows_in_table``); nothing here indexes a block's row maps itself.
+
 Names (PERF.md §3; they are an interface). Inside the programs, by
-``jax.named_scope``: ``fe/score``, ``re/score``, and in the ladder solve
-``re/b<index>`` around each size bucket's block with ``re/gather`` (warm
-start and residual rows in) and ``re/scatter`` (solved rows out) inside it;
-the per-entity solve in between carries the solver's own ``optim/`` and
-``agg/`` names. On the host, by ``obs.annotate`` (events of a profiler trace
+``jax.named_scope``: ``fe/score``, ``re/score``, and in every per-entity
+solve program ``re/gather`` (warm start, residual and normalisation rows in)
+and ``re/scatter`` (the failed-entity select, solved rows out), the
+per-entity solve in between carrying the solver's own ``optim/`` and
+``agg/`` names; the two ladder programs put each size bucket under its
+``re/b<index>``, the blocked program serves every bucket with one executable
+and names none (the bucket is the ``block`` attribute of the host span
+``re/solve_block``). On the host, by ``obs.annotate`` (events of a profiler trace
 when telemetry is on, nothing otherwise): ``fe/args`` / ``re/args`` (the
 eager programs that build a solve's arguments), ``fe/solve`` / ``re/solve``
 (the dispatch), ``fe/outcome`` / ``re/outcome`` (the blocking scalar read of
@@ -557,9 +568,8 @@ class RandomEffectCoordinate:
                     f"explicit_hessian=True to override")
 
     def _make_entity_solvers(self):
-        """(solve_sparse, solve_dense): one entity's local solve, shared
-        by the all-at-once program (``_solve_fn``) and the sequential
-        blocked program (``_block_solve_fn``)."""
+        """(solve_sparse, solve_dense): one entity's local solve, which
+        every program vmaps through ``_make_bucket_solver``."""
         obj = self.objective
         opt = self.config.optimizer
         solver_cfg = opt.solver_config()
@@ -651,14 +661,111 @@ class RandomEffectCoordinate:
 
         return solve_sparse, solve_dense
 
-    def _make_ladder_solver(self):
-        """The whole-ladder solve body, UNJITTED — the scalar program
-        (``_solve_fn``). The λ-lane program (``_solve_swept_fn``) shares
-        the per-entity solvers (``_make_entity_solvers``) and flattens
-        its lanes into this body's one entity-vmap axis, which is what
-        keeps every lane bitwise-equal to this scalar solve."""
-        dense_flags = self._dense_local_blocks
+    def _make_bucket_solver(self, lanes: bool):
+        """One size bucket's solve body, UNJITTED, and the one place the
+        entity solver is vmapped: the scalar ladder (``_solve_fn``), the
+        λ-lane ladder (``_solve_swept_fn``) and the blocked program
+        (``_block_solve_swept_fn``) are wrappers around it. Given a bucket,
+        its residual-injected offsets (``_residual_offsets``) and its
+        warm-start rows, it picks the dense or sparse argument list,
+        gathers the normalisation rows, vmaps the entity solver once and
+        keeps a failed entity's warm start (``dense`` is the bucket's
+        static flag from ``_dense_local_blocks``). ``re/gather`` and
+        ``re/scatter`` are opened here, so every program carries them.
+
+        Two static modes, and no more:
+
+        ``lanes=False`` — ``x0 [E_b, K]``, ``l2`` / ``l1`` scalars the whole
+        batch shares (``in_axes=None``). This is what a fit runs.
+
+        ``lanes=True`` — ``x0 [c, E_b, K]``, ``l2`` / ``l1`` ``[c]``. The c
+        lanes are FLATTENED into the entity axis: the bucket's arrays are
+        tiled c× inside the program (lane-major virtual entities) and
+        the per-entity solver is vmapped over ONE ``c*E``-wide batch
+        axis, exactly the scalar mode's vmap structure.
+
+        Flattening — not a nested ``vmap`` over lanes — is the bitwise
+        contract. The entity-vmap is width-insensitive on every backend
+        we pin (solving a tiled ``2E`` batch reproduces the ``E`` batch
+        bit-for-bit), but NESTING a second vmap re-lowers the batched
+        reductions with an extra batch dimension and reassociates their
+        FP order: lane results then drift ~1e-9 from the scalar solve at
+        f64, and a lane sitting at a convergence-threshold knife edge
+        (observed at strong regularization) splits its ITERATION COUNT.
+        With flattening, every lane of every chunk width — padded tails
+        included — is bitwise-equal to its sequential scalar solve.
+
+        The tile costs ``c×`` block data on device; parallel/memory's
+        planner charges each lane ``data + lane_state`` bytes and chunks
+        the grid when the budget can't carry full K. A streamed block
+        still STAGES once — tiling is a device-side op, so storage→device
+        traffic stays one pass per bucket regardless of K.
+
+        The scalar mode is not the lane mode at ``c == 1``: one lane
+        passes ``l2`` as an ``[E_b]`` array where the scalar program
+        passes a scalar, which is another program (ROADMAP D6b)."""
         solve_sparse, solve_dense = self._make_entity_solvers()
+
+        def solve_bucket(blk: EntityBlock, dense: bool, offsets: Array,
+                         x0: Array, l2: Array, l1: Array,
+                         norm_f: Optional[Array] = None,
+                         norm_s: Optional[Array] = None,
+                         norm_islot: Optional[Array] = None):
+            shape = x0.shape[:-1]  # [E_b], or [c, E_b] with lanes
+            tile = lambda a: a
+            reg_axis = None
+            if lanes:
+                c, E = shape
+                if c > 1:
+                    tile = lambda a: jnp.concatenate([a] * c, axis=0)
+                x0 = x0.reshape((c * E,) + x0.shape[2:])
+                l2 = jnp.repeat(l2, E)
+                l1 = jnp.repeat(l1, E)
+                reg_axis = 0
+            if dense:
+                fn = solve_dense
+                args = [tile(blk.features.values), tile(blk.labels),
+                        tile(offsets), tile(blk.weights), x0, l2, l1]
+                axes = [0, 0, 0, 0, 0, reg_axis, reg_axis]
+            else:
+                fn = solve_sparse
+                args = [tile(blk.features.indices),
+                        tile(blk.features.values), tile(blk.labels),
+                        tile(offsets), tile(blk.weights), x0, l2, l1]
+                axes = [0, 0, 0, 0, 0, 0, reg_axis, reg_axis]
+            if norm_f is not None:
+                with jax.named_scope("re/gather"):
+                    args.append(tile(blk.rows_from_table(norm_f, 1.0)))
+                    axes.append(0)
+                    if norm_s is not None:
+                        args.append(tile(blk.rows_from_table(norm_s, 0.0)))
+                        args.append(tile(blk.rows_from_table(norm_islot, -1)))
+                        axes.extend([0, 0])
+            solved, it_b, reason_b, fail_b = jax.vmap(
+                fn, in_axes=tuple(axes))(*args)
+            with jax.named_scope("re/scatter"):
+                # per-entity isolation (per lane, with lanes): a failed
+                # entity keeps its warm start; healthy entities of the
+                # same bucket keep their fresh solves (no host branch —
+                # pure select)
+                solved = jnp.where((fail_b != 0)[:, None], x0, solved)
+            if lanes:
+                return tuple(a.reshape(shape + a.shape[1:])
+                             for a in (solved, it_b, reason_b, fail_b))
+            return solved, it_b, reason_b, fail_b
+
+        return solve_bucket
+
+    def _make_ladder_solver(self, lanes: bool):
+        """The whole-ladder solve body, UNJITTED: the bucket body under
+        each size bucket's ``re/b<index>``, between the warm-start gather
+        and the scatters back into the tables. ``lanes`` says whether a
+        lane axis leads the tables (``coef0 [c, E, K]``, ``l2`` / ``l1``
+        ``[c]``): per bucket the c lanes then solve against one shared
+        staging of the ladder, every lane bitwise its scalar solve
+        (``_make_bucket_solver``)."""
+        dense_flags = self._dense_local_blocks
+        solve_bucket = self._make_bucket_solver(lanes)
 
         # the dataset enters as a pytree argument, never a closure (a
         # closed-over array would be baked into the HLO as a constant);
@@ -669,121 +776,51 @@ class RandomEffectCoordinate:
                       norm_s: Optional[Array] = None,
                       norm_islot: Optional[Array] = None):
             out = coef0  # entities with no active data keep warm start
-            E = coef0.shape[0]
             # per-entity solver stats (-1 = entity never trained)
-            iters = jnp.full((E,), -1, jnp.int32)
-            reasons = jnp.full((E,), -1, jnp.int32)
-            fails = jnp.zeros((E,), jnp.int32)
+            stats = coef0.shape[:-1]  # [E], or [c, E] with lanes
+            iters = jnp.full(stats, -1, jnp.int32)
+            reasons = jnp.full(stats, -1, jnp.int32)
+            fails = jnp.zeros(stats, jnp.int32)
             for bi, (blk, dense) in enumerate(zip(ds.blocks, dense_flags)):
                 # one scope a bucket, so a bucket's device seconds can be
                 # set beside its entity count and padded shape
                 with jax.named_scope(f"re/b{bi}"):
                     with jax.named_scope("re/gather"):
-                        offsets = blk.offsets
-                        if residual_flat is not None:
-                            # gather residuals by flat row; pad rows -> fill 0
-                            res = residual_flat.at[blk.sample_rows].get(
-                                mode="fill", fill_value=0.0)
-                            offsets = offsets + res
-                        x0 = coef0.at[blk.entity_rows].get(
-                            mode="fill", fill_value=0.0)
-                    if dense:
-                        fn = solve_dense
-                        args = [blk.features.values,
-                                blk.labels, offsets, blk.weights, x0, l2, l1]
-                        axes = [0, 0, 0, 0, 0, None, None]
-                    else:
-                        fn = solve_sparse
-                        args = [blk.features.indices, blk.features.values,
-                                blk.labels, offsets, blk.weights, x0, l2, l1]
-                        axes = [0, 0, 0, 0, 0, 0, None, None]
-                    if norm_f is not None:
-                        with jax.named_scope("re/gather"):
-                            args.append(norm_f.at[blk.entity_rows].get(
-                                mode="fill", fill_value=1.0))
-                            axes.append(0)
-                            if norm_s is not None:
-                                args.append(norm_s.at[blk.entity_rows].get(
-                                    mode="fill", fill_value=0.0))
-                                args.append(norm_islot.at[blk.entity_rows].get(
-                                    mode="fill", fill_value=-1))
-                                axes.extend([0, 0])
-                    solved, it_b, reason_b, fail_b = jax.vmap(
-                        fn, in_axes=tuple(axes))(*args)
+                        offsets = _residual_offsets(blk, residual_flat)
+                        x0 = blk.rows_from_table(coef0, 0.0, lanes)
+                    solved, it_b, reason_b, fail_b = solve_bucket(
+                        blk, dense, offsets, x0, l2, l1,
+                        norm_f, norm_s, norm_islot)
                     with jax.named_scope("re/scatter"):
-                        # per-entity isolation: a failed entity keeps its
-                        # warm start; healthy lanes in the same block keep
-                        # their fresh solves (no host branch — pure select)
-                        solved = jnp.where((fail_b != 0)[:, None], x0, solved)
-                        out = out.at[blk.entity_rows].set(solved, mode="drop")
-                        iters = iters.at[blk.entity_rows].set(it_b, mode="drop")
-                        reasons = reasons.at[blk.entity_rows].set(
-                            reason_b, mode="drop")
-                        fails = fails.at[blk.entity_rows].set(fail_b, mode="drop")
+                        out = blk.set_rows_in_table(out, solved, lanes)
+                        iters = blk.set_rows_in_table(iters, it_b, lanes)
+                        reasons = blk.set_rows_in_table(
+                            reasons, reason_b, lanes)
+                        fails = blk.set_rows_in_table(fails, fail_b, lanes)
             return out, iters, reasons, fails
 
+        # jit names the module for the callable, and the persistent
+        # compile cache's key hashes the module's text with that name:
+        # under another name every machine would compile the ladder anew
+        solve_all.__name__ = solve_all.__qualname__ = (
+            "solve_all_lanes" if lanes else "solve_all")
         return solve_all
+
+    def _solver_program(self, name: str, flavour, make):
+        """One jitted solve program from the process-wide cache, under a
+        key of everything its trace depends on."""
+        self._validate_solver()
+        has_norm = self._norm_local is not None
+        has_shifts = has_norm and self._norm_local[1] is not None
+        key = (name, self.task, solver_cache_key(self.config.optimizer),
+               has_norm, has_shifts, flavour)
+        return jitcache.get_or_build(key, lambda: jax.jit(make()))
 
     @functools.cached_property
     def _solve_fn(self):
-        self._validate_solver()
-        opt = self.config.optimizer
-        dense_flags = self._dense_local_blocks
-        has_norm = self._norm_local is not None
-        has_shifts = has_norm and self._norm_local[1] is not None
-
-        def build():
-            return jax.jit(self._make_ladder_solver())
-
-        key = ("re_solve", self.task, solver_cache_key(opt),
-               has_norm, has_shifts, dense_flags)
-        return jitcache.get_or_build(key, build)
-
-    def _make_ladder_solver_swept(self):
-        """The whole-ladder λ-lane solve body, UNJITTED. Lanes are
-        FLATTENED into the entity axis per bucket (see
-        ``_make_block_solver_swept`` for why — it is the bitwise
-        contract), so per bucket the c lanes' virtual entities solve
-        under the scalar body's single entity-vmap against one shared
-        staging of the ladder, and results scatter back to the
-        ``[K, E_pad, ...]`` lane tables."""
-        dense_flags = self._dense_local_blocks
-        core_dense = self._make_block_solver_swept(True)
-        core_sparse = self._make_block_solver_swept(False)
-
-        def solve_all_lanes(ds: RandomEffectDataset,
-                            residual_flat: Optional[Array],
-                            coef0_lanes: Array, l2_lanes: Array,
-                            l1_lanes: Array,
-                            norm_f: Optional[Array] = None,
-                            norm_s: Optional[Array] = None,
-                            norm_islot: Optional[Array] = None):
-            out = coef0_lanes  # entities with no active data keep warm start
-            K, E = coef0_lanes.shape[0], coef0_lanes.shape[1]
-            iters = jnp.full((K, E), -1, jnp.int32)
-            reasons = jnp.full((K, E), -1, jnp.int32)
-            fails = jnp.zeros((K, E), jnp.int32)
-            for bi, (blk, dense) in enumerate(zip(ds.blocks, dense_flags)):
-                with jax.named_scope(f"re/b{bi}"):
-                    with jax.named_scope("re/gather"):
-                        x0 = coef0_lanes.at[:, blk.entity_rows].get(
-                            mode="fill", fill_value=0.0)
-                    core = core_dense if dense else core_sparse
-                    solved, it_b, reason_b, fail_b = core(
-                        blk, residual_flat, x0, l2_lanes, l1_lanes,
-                        norm_f, norm_s, norm_islot)
-                    with jax.named_scope("re/scatter"):
-                        out = out.at[:, blk.entity_rows].set(
-                            solved, mode="drop")
-                        iters = iters.at[:, blk.entity_rows].set(
-                            it_b, mode="drop")
-                        reasons = reasons.at[:, blk.entity_rows].set(
-                            reason_b, mode="drop")
-                        fails = fails.at[:, blk.entity_rows].set(
-                            fail_b, mode="drop")
-            return out, iters, reasons, fails
-
-        return solve_all_lanes
+        return self._solver_program(
+            "re_solve", self._dense_local_blocks,
+            lambda: self._make_ladder_solver(lanes=False))
 
     @functools.cached_property
     def _solve_swept_fn(self):
@@ -794,45 +831,135 @@ class RandomEffectCoordinate:
         ``minimize_lanes`` data-pass economics applied to the per-entity
         vmap). Per-entity failure isolation carries over per lane, and
         EVERY lane — not just K=1 — is bitwise its scalar solve (see
-        ``_make_block_solver_swept``)."""
-        self._validate_solver()
-        opt = self.config.optimizer
-        dense_flags = self._dense_local_blocks
-        has_norm = self._norm_local is not None
-        has_shifts = has_norm and self._norm_local[1] is not None
+        ``_make_bucket_solver``)."""
+        return self._solver_program(
+            "re_solve_swept", self._dense_local_blocks,
+            lambda: self._make_ladder_solver(lanes=True))
 
-        def build():
-            return jax.jit(self._make_ladder_solver_swept())
+    def _block_solve_swept_fn(self, dense: bool):
+        """One size bucket as a standalone program: the streaming unit of
+        the blocked fits, solving c λ points (one, for
+        ``update_model_blocked``) against ONE staging of the bucket (the
+        tile to ``c*E`` virtual entities is a device-side op inside the
+        program). One program per (bucket flavor, lane-chunk width) serves
+        every bucket of that flavor, so it cannot carry a ``re/b<index>``
+        scope: in a trace the bucket is the ``block`` attribute of the host
+        span ``re/solve_block`` around each launch. Every lane is bitwise
+        the ladder's solve of the same entity (see
+        ``_make_bucket_solver``)."""
+        def make():
+            solve_bucket = self._make_bucket_solver(lanes=True)
 
-        key = ("re_solve_swept", self.task, solver_cache_key(opt),
-               has_norm, has_shifts, dense_flags)
-        return jitcache.get_or_build(key, build)
+            def solve_block_lanes(blk: EntityBlock,
+                                  residual_flat: Optional[Array],
+                                  x0_lanes: Array, l2_lanes: Array,
+                                  l1_lanes: Array,
+                                  norm_f: Optional[Array] = None,
+                                  norm_s: Optional[Array] = None,
+                                  norm_islot: Optional[Array] = None):
+                with jax.named_scope("re/gather"):
+                    offsets = _residual_offsets(blk, residual_flat)
+                return solve_bucket(blk, dense, offsets, x0_lanes, l2_lanes,
+                                    l1_lanes, norm_f, norm_s, norm_islot)
+
+            return solve_block_lanes
+
+        return self._solver_program("re_solve_block_swept", bool(dense), make)
+
+    def _warm_table(self, prev: Optional[RandomEffectModel]):
+        """(dtype, ``[E_pad, K]`` warm-start table) of a resident solve:
+        ``prev``'s coefficients at this coordinate's (possibly mesh-padded)
+        entity count, zeros from scratch."""
+        ds = self.dataset
+        dtype = self._solve_dtype(prev)
+        coef0 = (prev.coefficients if prev is not None
+                 else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
+        return dtype, self._pad_entity_rows(coef0)
+
+    def _solve_dtype(self, prev: Optional[RandomEffectModel] = None):
+        """A solve runs in its warm start's dtype, else the dataset's — the
+        per-entity programs must see identical input dtypes for blocked /
+        all-at-once parity to be bitwise."""
+        ds = self.dataset
+        return (prev.coefficients.dtype if prev is not None
+                else (ds.blocks[0].labels.dtype if ds.blocks
+                      else jnp.float32))
+
+    def _solve_args(self, dtype, residual_scores: Optional[Array],
+                    lams=None):
+        """(residual, l2, l1, normalisation arguments): what every solve
+        takes beside its warm start. Without ``lams`` the weights are the
+        coordinate's own, as device scalars (each an eager program, like
+        ``FixedEffectCoordinate._solve_args``'s steps); with a validated
+        grid they are host ``[K]`` arrays the lane chunks are cut from."""
+        reg = self.config.regularization
+        if lams is None:
+            lam = self.config.regularization_weight
+            l2 = jnp.asarray(reg.l2_weight(lam), dtype)
+            l1 = jnp.asarray(reg.l1_weight(lam), dtype)
+        else:
+            l2 = np.asarray([reg.l2_weight(float(w)) for w in lams], dtype)
+            l1 = np.asarray([reg.l1_weight(float(w)) for w in lams], dtype)
+        norm_args = ()
+        if self._norm_local is not None:
+            f, s, islot = self._norm_local
+            norm_args = (f,) if s is None else (f, s, islot)
+        if getattr(self, "_chaos_poison_once", False):
+            # fault injection (resilience/chaos.py): NaN residuals
+            # poison every entity's objective (every lane's: the residual
+            # is shared), like a corrupt upstream score pass
+            self._chaos_poison_once = False
+            residual_scores = jnp.full((self.n,), jnp.nan, dtype)
+        return residual_scores, l2, l1, norm_args
+
+    def _outcome(self, fails):
+        """(failed entities, the coordinate's typed failure or None) from
+        one fit's per-entity failure codes ``[E_orig]``, on the device or
+        on the host. Failure isolation already happened inside the program
+        (a failed entity kept its warm start), so only the count crosses
+        to the host — one scalar for a device array."""
+        xp = jnp if isinstance(fails, jax.Array) else np
+        n_failed = int(np.asarray(xp.sum(fails != 0)))
+        failure = None
+        if n_failed and n_failed == fails.shape[0]:
+            # EVERY entity failed: the coordinate as a whole is poisoned
+            # (a bad residual pass, not a few degenerate entities)
+            failure = FailureMode(int(np.asarray(xp.max(fails))))
+        return n_failed, failure
+
+    def _publish(self, iters, reasons, fails) -> None:
+        """What a scalar fit leaves behind, from its ``[E_pad]`` solver
+        stats on the device or on the host: ``last_tracker`` (with the
+        buckets' rows, which ``lane_counts()`` needs), ``last_failed_
+        entities`` and ``last_failure`` — one blocking scalar read, under
+        ``re/outcome``, when the stats are on the device."""
+        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
+        e_orig = self._num_entities_orig
+        self.last_tracker = RandomEffectOptimizationTracker(
+            iterations=iters[:e_orig], reasons=reasons[:e_orig],
+            bucket_rows=tuple(blk.entity_rows
+                              for blk in self.dataset.blocks))
+        with _obs_annotate("re/outcome"):
+            self.last_failed_entities, self.last_failure = self._outcome(
+                fails[:e_orig])
+
+    def _model(self, coefficients, variances=None) -> RandomEffectModel:
+        """This coordinate's model around a coefficient table."""
+        return RandomEffectModel(
+            coefficients=coefficients,
+            random_effect_type=self.random_effect_type,
+            feature_shard_id=self.feature_shard_id,
+            task=self.task,
+            variances=variances,
+        )
 
     def update_model(
         self, prev: Optional[RandomEffectModel], residual_scores: Optional[Array]
     ) -> RandomEffectModel:
-        ds = self.dataset
         with _obs_annotate("re/args"):
-            dtype = (prev.coefficients.dtype if prev is not None
-                     else (ds.blocks[0].labels.dtype if ds.blocks
-                           else jnp.float32))
-            coef0 = (prev.coefficients if prev is not None
-                     else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
-            coef0 = self._pad_entity_rows(coef0)
-            lam = self.config.regularization_weight
-            l2 = jnp.asarray(self.config.regularization.l2_weight(lam), dtype)
-            l1 = jnp.asarray(self.config.regularization.l1_weight(lam), dtype)
-            norm_args = ()
-            if self._norm_local is not None:
-                f, s, islot = self._norm_local
-                norm_args = (f,) if s is None else (f, s, islot)
-            if getattr(self, "_chaos_poison_once", False):
-                # fault injection (resilience/chaos.py): NaN residuals
-                # poison every entity's objective, like a corrupt upstream
-                # score pass
-                self._chaos_poison_once = False
-                residual_scores = jnp.full((self.n,), jnp.nan,
-                                           coef0.dtype)
+            dtype, coef0 = self._warm_table(prev)
+            residual_scores, l2, l1, norm_args = self._solve_args(
+                dtype, residual_scores)
         with _obs_annotate("re/solve"):
             coefs, iters, reasons, fails = self._solve_fn(
                 self.dataset, residual_scores, coef0, l2, l1, *norm_args)
@@ -840,24 +967,7 @@ class RandomEffectCoordinate:
         # Keep the DEVICE arrays: a blocking host transfer here would
         # serialize every CD sweep on the solver's completion; the tracker
         # converts lazily when someone actually reads a summary.
-        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
-        e_orig = self._num_entities_orig
-        self.last_tracker = RandomEffectOptimizationTracker(
-            iterations=iters[:e_orig], reasons=reasons[:e_orig],
-            bucket_rows=tuple(blk.entity_rows for blk in ds.blocks))
-        # failure isolation already happened device-side (failed entities
-        # kept their warm start inside solve_all); here only the counts
-        # cross to the host — one scalar at the coordinate boundary
-        with _obs_annotate("re/outcome"):
-            fails_orig = fails[:e_orig]
-            n_failed = int(np.asarray(jnp.sum(fails_orig != 0)))
-        self.last_failed_entities = n_failed
-        self.last_failure = None
-        if n_failed and e_orig and n_failed == e_orig:
-            # EVERY entity failed: the coordinate as a whole is poisoned
-            # (a bad residual pass, not a few degenerate entities)
-            self.last_failure = FailureMode(int(np.asarray(
-                jnp.max(fails_orig))))
+        self._publish(iters, reasons, fails)
         variances = None
         from photon_tpu.types import VarianceComputationType
         if (self.variance_type != VarianceComputationType.NONE
@@ -867,14 +977,7 @@ class RandomEffectCoordinate:
             variances = variances[: self._num_entities_orig]
         # publish the model at the vocabulary's true entity count; mesh
         # padding stays an internal detail of this coordinate
-        coefs = coefs[: self._num_entities_orig]
-        return RandomEffectModel(
-            coefficients=coefs,
-            random_effect_type=self.random_effect_type,
-            feature_shard_id=self.feature_shard_id,
-            task=self.task,
-            variances=variances,
-        )
+        return self._model(coefs[: self._num_entities_orig], variances)
 
     def update_model_swept(
         self,
@@ -911,17 +1014,12 @@ class RandomEffectCoordinate:
         ``last_lane_failures`` and the ``sweep.*`` metrics."""
         from photon_tpu.obs.metrics import registry
         from photon_tpu.optim import batched
-        from photon_tpu.parallel import memory as hbm
 
         lams = batched.validate_lane_weights(weights)
         K = int(lams.size)
         ds = self.dataset
-        dtype = (prev.coefficients.dtype if prev is not None
-                 else (ds.blocks[0].labels.dtype if ds.blocks
-                       else jnp.float32))
-        base = (prev.coefficients if prev is not None
-                else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
-        base = self._pad_entity_rows(jnp.asarray(base, dtype))
+        dtype, base = self._warm_table(prev)
+        base = jnp.asarray(base, dtype)
         if initial_lanes is not None:
             init = jnp.asarray(initial_lanes, dtype)
             if init.ndim != 3 or init.shape[0] != K:
@@ -932,39 +1030,20 @@ class RandomEffectCoordinate:
                 [self._pad_entity_rows(init[k]) for k in range(K)])
         else:
             lanes0 = jnp.broadcast_to(base, (K,) + base.shape)
-        if plan is None:
-            plan = hbm.plan_for_dataset(
-                ds, lanes=K,
-                history=self.config.optimizer.solver_config()
-                .num_corrections,
-                hbm_budget_bytes=hbm_budget_bytes,
-                coordinate=self.random_effect_type)
-        hbm.record_plan(plan)
-        self.last_block_plan = plan
+        plan = self._record_lane_plan(K, plan, hbm_budget_bytes)
         chunk = max(1, min(plan.lane_chunk, K))
-        reg = self.config.regularization
-        norm_args = ()
-        if self._norm_local is not None:
-            f, s, islot = self._norm_local
-            norm_args = (f,) if s is None else (f, s, islot)
-        if getattr(self, "_chaos_poison_once", False):
-            # fault injection (resilience/chaos.py): poisons every lane's
-            # shared residual, like a corrupt upstream score pass
-            self._chaos_poison_once = False
-            residual_scores = jnp.full((self.n,), jnp.nan, dtype)
+        residual_scores, l2_all, l1_all, norm_args = self._solve_args(
+            dtype, residual_scores, lams)
         coefs: list = [None] * K
         iters: list = [None] * K
         reasons: list = [None] * K
         fails: list = [None] * K
         for idx, n_real in batched.pad_lane_grid(lams, chunk):
-            l2c = jnp.asarray([reg.l2_weight(float(lams[i])) for i in idx],
-                              dtype)
-            l1c = jnp.asarray([reg.l1_weight(float(lams[i])) for i in idx],
-                              dtype)
             x0c = jnp.take(lanes0, jnp.asarray(idx), axis=0)
             with _obs_annotate("re/solve_swept"):
                 co, it_c, re_c, fa_c = self._solve_swept_fn(
-                    ds, residual_scores, x0c, l2c, l1c, *norm_args)
+                    ds, residual_scores, x0c, jnp.asarray(l2_all[idx]),
+                    jnp.asarray(l1_all[idx]), *norm_args)
             # padded tail lanes (repeated last λ) are dropped, never
             # published
             for j in range(n_real):
@@ -972,22 +1051,11 @@ class RandomEffectCoordinate:
                 coefs[k], iters[k] = co[j], it_c[j]
                 reasons[k], fails[k] = re_c[j], fa_c[j]
         # host boundary: per-lane scalars for telemetry + failure typing
-        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
         e_orig = self._num_entities_orig
-        self.last_lane_trackers = [
-            RandomEffectOptimizationTracker(iterations=iters[k][:e_orig],
-                                            reasons=reasons[k][:e_orig])
-            for k in range(K)]
-        fails_np = [np.asarray(fails[k][:e_orig]) for k in range(K)]
-        self.last_lane_failed_entities = [
-            int(np.sum(f != 0)) for f in fails_np]
-        self.last_lane_failures = []
+        self._publish_lanes(iters, reasons,
+                            [np.asarray(fails[k][:e_orig]) for k in range(K)])
         lane_medians = []
         for k in range(K):
-            n_failed = self.last_lane_failed_entities[k]
-            self.last_lane_failures.append(
-                FailureMode(int(fails_np[k].max()))
-                if n_failed and e_orig and n_failed == e_orig else None)
             it_np = np.asarray(iters[k][:e_orig])
             trained = it_np[it_np >= 0]
             lane_medians.append(
@@ -1006,173 +1074,39 @@ class RandomEffectCoordinate:
              "failure": 0 if self.last_lane_failures[k] is None
              else int(self.last_lane_failures[k])}
             for k in range(K)])
-        return [
-            RandomEffectModel(
-                coefficients=coefs[k][:e_orig],
-                random_effect_type=self.random_effect_type,
-                feature_shard_id=self.feature_shard_id,
-                task=self.task,
-                variances=None,
-            )
-            for k in range(K)
-        ]
+        return [self._model(coefs[k][:e_orig]) for k in range(K)]
 
-    def _make_block_solver(self, dense: bool):
-        """One size bucket's solve body, UNJITTED — the scalar blocked
-        program (``_block_solve_fn``). The λ-lane blocked program
-        (``_block_solve_swept_fn``) shares the per-entity solvers and
-        the exact vmap structure via ``_make_block_solver_swept``.
+    def _record_lane_plan(self, lanes: int, plan, hbm_budget_bytes):
+        """The HBM plan of a K-lane sweep (computed unless one is passed),
+        recorded for the RunReport ``re_plan`` section and kept as
+        ``last_block_plan``."""
+        from photon_tpu.parallel import memory as hbm
 
-        One compiled program serves every bucket of its flavour, so it
-        cannot carry a ``re/b<index>`` scope: in a trace the bucket is the
-        ``block`` attribute of the host span ``re/solve_block`` around each
-        launch."""
-        solve_sparse, solve_dense = self._make_entity_solvers()
+        if plan is None:
+            plan = hbm.plan_for_dataset(
+                self.dataset, lanes=lanes,
+                history=self.config.optimizer.solver_config()
+                .num_corrections,
+                hbm_budget_bytes=hbm_budget_bytes,
+                coordinate=self.random_effect_type)
+        hbm.record_plan(plan)
+        self.last_block_plan = plan
+        return plan
 
-        def solve_block(blk: EntityBlock, residual_flat: Optional[Array],
-                        x0: Array, l2: Array, l1: Array,
-                        norm_f: Optional[Array] = None,
-                        norm_s: Optional[Array] = None,
-                        norm_islot: Optional[Array] = None):
-            offsets = blk.offsets
-            if residual_flat is not None:
-                with jax.named_scope("re/gather"):
-                    offsets = offsets + residual_flat.at[
-                        blk.sample_rows].get(mode="fill", fill_value=0.0)
-            if dense:
-                fn = solve_dense
-                args = [blk.features.values,
-                        blk.labels, offsets, blk.weights, x0, l2, l1]
-                axes = [0, 0, 0, 0, 0, None, None]
-            else:
-                fn = solve_sparse
-                args = [blk.features.indices, blk.features.values,
-                        blk.labels, offsets, blk.weights, x0, l2, l1]
-                axes = [0, 0, 0, 0, 0, 0, None, None]
-            if norm_f is not None:
-                args.append(norm_f.at[blk.entity_rows].get(
-                    mode="fill", fill_value=1.0))
-                axes.append(0)
-                if norm_s is not None:
-                    args.append(norm_s.at[blk.entity_rows].get(
-                        mode="fill", fill_value=0.0))
-                    args.append(norm_islot.at[blk.entity_rows].get(
-                        mode="fill", fill_value=-1))
-                    axes.extend([0, 0])
-            solved, it_b, reason_b, fail_b = jax.vmap(
-                fn, in_axes=tuple(axes))(*args)
-            solved = jnp.where((fail_b != 0)[:, None], x0, solved)
-            return solved, it_b, reason_b, fail_b
-
-        return solve_block
-
-    def _block_solve_fn(self, dense: bool):
-        """One size bucket's per-entity solves as a standalone program —
-        the streaming unit of ``update_model_blocked``. Two cached
-        programs per coordinate config (dense / sparse block), reused
-        across every block of that flavor."""
-        self._validate_solver()
-        opt = self.config.optimizer
-        has_norm = self._norm_local is not None
-        has_shifts = has_norm and self._norm_local[1] is not None
-
-        def build():
-            return jax.jit(self._make_block_solver(dense))
-
-        key = ("re_solve_block", self.task, solver_cache_key(opt),
-               has_norm, has_shifts, bool(dense))
-        return jitcache.get_or_build(key, build)
-
-    def _make_block_solver_swept(self, dense: bool):
-        """One size bucket's λ-lane solve body, UNJITTED — the c lanes
-        are FLATTENED into the entity axis: the bucket's arrays are
-        tiled c× inside the program (lane-major virtual entities) and
-        the per-entity solver is vmapped over ONE ``c*E``-wide batch
-        axis, exactly the scalar body's vmap structure.
-
-        Flattening — not a nested ``vmap`` over lanes — is the bitwise
-        contract. The entity-vmap is width-insensitive on every backend
-        we pin (solving a tiled ``2E`` batch reproduces the ``E`` batch
-        bit-for-bit), but NESTING a second vmap re-lowers the batched
-        reductions with an extra batch dimension and reassociates their
-        FP order: lane results then drift ~1e-9 from the scalar solve at
-        f64, and a lane sitting at a convergence-threshold knife edge
-        (observed at strong regularization) splits its ITERATION COUNT.
-        With flattening, every lane of every chunk width — padded tails
-        included — is bitwise-equal to its sequential scalar solve.
-
-        The tile costs ``c×`` block data on device; parallel/memory's
-        planner charges each lane ``data + lane_state`` bytes and chunks
-        the grid when the budget can't carry full K. The block still
-        STAGES once — tiling is a device-side op, so storage→device
-        traffic stays one pass per bucket regardless of K."""
-        solve_sparse, solve_dense = self._make_entity_solvers()
-
-        def solve_block_lanes(blk: EntityBlock,
-                              residual_flat: Optional[Array],
-                              x0_lanes: Array, l2_lanes: Array,
-                              l1_lanes: Array,
-                              norm_f: Optional[Array] = None,
-                              norm_s: Optional[Array] = None,
-                              norm_islot: Optional[Array] = None):
-            c, E = x0_lanes.shape[0], x0_lanes.shape[1]
-            tile = ((lambda a: jnp.concatenate([a] * c, axis=0)) if c > 1
-                    else (lambda a: a))
-            offsets = blk.offsets
-            if residual_flat is not None:
-                with jax.named_scope("re/gather"):
-                    offsets = offsets + residual_flat.at[
-                        blk.sample_rows].get(mode="fill", fill_value=0.0)
-            x0 = x0_lanes.reshape((c * E,) + x0_lanes.shape[2:])
-            l2e = jnp.repeat(l2_lanes, E)
-            l1e = jnp.repeat(l1_lanes, E)
-            if dense:
-                fn = solve_dense
-                args = [tile(blk.features.values), tile(blk.labels),
-                        tile(offsets), tile(blk.weights), x0, l2e, l1e]
-            else:
-                fn = solve_sparse
-                args = [tile(blk.features.indices),
-                        tile(blk.features.values), tile(blk.labels),
-                        tile(offsets), tile(blk.weights), x0, l2e, l1e]
-            if norm_f is not None:
-                args.append(tile(norm_f.at[blk.entity_rows].get(
-                    mode="fill", fill_value=1.0)))
-                if norm_s is not None:
-                    args.append(tile(norm_s.at[blk.entity_rows].get(
-                        mode="fill", fill_value=0.0)))
-                    args.append(tile(norm_islot.at[blk.entity_rows].get(
-                        mode="fill", fill_value=-1)))
-            solved, it_b, reason_b, fail_b = jax.vmap(fn)(*args)
-            # per-entity isolation, per lane: a failed virtual entity
-            # keeps its lane's warm start
-            solved = jnp.where((fail_b != 0)[:, None], x0, solved)
-
-            def unflatten(a):
-                return a.reshape((c, E) + a.shape[1:])
-
-            return (unflatten(solved), unflatten(it_b),
-                    unflatten(reason_b), unflatten(fail_b))
-
-        return solve_block_lanes
-
-    def _block_solve_swept_fn(self, dense: bool):
-        """λ-lane variant of ``_block_solve_fn``: one program per
-        (bucket flavor, lane-chunk width) solving c λ points against ONE
-        staging of the bucket (the tile to ``c*E`` virtual entities is a
-        device-side op inside the program). Every lane is bitwise the
-        scalar blocked program (see ``_make_block_solver_swept``)."""
-        self._validate_solver()
-        opt = self.config.optimizer
-        has_norm = self._norm_local is not None
-        has_shifts = has_norm and self._norm_local[1] is not None
-
-        def build():
-            return jax.jit(self._make_block_solver_swept(dense))
-
-        key = ("re_solve_block_swept", self.task, solver_cache_key(opt),
-               has_norm, has_shifts, bool(dense))
-        return jitcache.get_or_build(key, build)
+    def _publish_lanes(self, iters, reasons, fails) -> None:
+        """Per-lane telemetry of a sweep: ``last_lane_trackers`` /
+        ``last_lane_failed_entities`` / ``last_lane_failures`` from each
+        lane's ``[E]`` iterations and reasons and its host ``[E_orig]``
+        failure codes."""
+        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
+        e_orig = self._num_entities_orig
+        self.last_lane_trackers = [
+            RandomEffectOptimizationTracker(iterations=it[:e_orig],
+                                            reasons=re[:e_orig])
+            for it, re in zip(iters, reasons)]
+        outcomes = [self._outcome(f) for f in fails]
+        self.last_lane_failed_entities = [n for n, _ in outcomes]
+        self.last_lane_failures = [failure for _, failure in outcomes]
 
     def update_model_blocked(
         self,
@@ -1197,6 +1131,13 @@ class RandomEffectCoordinate:
         keeps its warm start) but the blocks run sequentially with a host
         round-trip between them, so use it only when [E, K] doesn't fit.
 
+        It is the blocked sweep (``update_model_blocked_swept``: one host
+        loop, one program) at ONE lane, the coordinate's own weight,
+        bitwise ``update_model`` per entity; what it leaves behind is a
+        scalar fit's (``last_tracker`` / ``last_failed_entities`` /
+        ``last_failure``), and neither a sweep run nor an ``re_plan`` is
+        recorded for a fit that swept nothing.
+
         ``warm_start``: ``None`` (zeros), a host/device [E, K] array, or
         an ``io.cold_store.ColdStore`` (requires ``entity_names``: the
         entity id of each dataset row, i.e. the ingest vocabulary order).
@@ -1214,128 +1155,16 @@ class RandomEffectCoordinate:
         contract are unchanged (results stay bitwise with
         ``prefetch=False``); overlap telemetry lands in
         ``last_block_overlap`` / the ``perf.re_block_overlap`` gauge."""
-        ds = self.dataset
-        n_blocks = len(ds.blocks)
-        if not 0 <= start_block <= n_blocks:
-            raise ValueError(
-                f"start_block {start_block} outside [0, {n_blocks}]")
-        E_pad = ds.num_entities
-        K = ds.projected_dim
-        # solve in the dataset's dtype, matching update_model's coef0 —
-        # the per-entity programs must see identical input dtypes for
-        # blocked/all-at-once parity to be bitwise
-        dtype = np.dtype(ds.blocks[0].labels.dtype) if ds.blocks \
-            else np.dtype(np.float32)
-        # host-resident coefficient table: init from the warm-start source
-        if warm_start is None:
-            out = np.zeros((E_pad, K), dtype)
-        elif isinstance(warm_start, np.ndarray) or isinstance(
-                warm_start, jax.Array):
-            out = np.zeros((E_pad, K), dtype)
-            w = np.asarray(warm_start, dtype)
-            out[: min(E_pad, w.shape[0])] = w[:E_pad]
-        else:  # ColdStore
-            if entity_names is None:
-                raise ValueError(
-                    "ColdStore warm_start requires entity_names (entity id "
-                    "per dataset row, vocabulary order)")
-            from photon_tpu.game.random_effect import warm_start_from_cold_store
-            out = warm_start_from_cold_store(
-                warm_start, entity_names, ds.projection).astype(dtype)
-            extra = E_pad - out.shape[0]
-            if extra > 0:
-                out = np.pad(out, [(0, extra), (0, 0)])
-        lam = self.config.regularization_weight
-        l2 = jnp.asarray(self.config.regularization.l2_weight(lam), dtype)
-        l1 = jnp.asarray(self.config.regularization.l1_weight(lam), dtype)
-        norm_args = ()
-        if self._norm_local is not None:
-            f, s, islot = self._norm_local
-            norm_args = (f,) if s is None else (f, s, islot)
-        iters = np.full((E_pad,), -1, np.int32)
-        reasons = np.full((E_pad,), -1, np.int32)
-        fails = np.zeros((E_pad,), np.int32)
-        from photon_tpu.game.block_stream import BlockPrefetcher
-        from photon_tpu.resilience import chaos
-        stream = None
-        if prefetch and n_blocks - start_block > 1:
-            stream = BlockPrefetcher(ds.blocks, start_block=start_block)
-        try:
-            with _obs_span("re/solve_blocked",
-                           blocks=n_blocks - start_block):
-                for bi, (blk, dense) in enumerate(
-                        zip(ds.blocks, self._dense_local_blocks)):
-                    if bi < start_block:
-                        continue
-                    ents = np.asarray(blk.entity_rows)
-                    valid = (ents >= 0) & (ents < E_pad)
-                    x0 = np.zeros((ents.shape[0], K), dtype)
-                    x0[valid] = out[ents[valid]]
-                    # bucket b+1 is already staging on the reader thread
-                    # while this bucket solves; values are identical to
-                    # the unstaged block, so parity stays bitwise
-                    staged = stream.get(bi) if stream is not None else blk
-                    with _obs_span("re/solve_block", block=bi):
-                        with _obs_annotate("re/solve_block"):
-                            solved, it_b, reason_b, fail_b = \
-                                self._block_solve_fn(dense)(
-                                    staged, residual_scores,
-                                    jnp.asarray(x0), l2, l1, *norm_args)
-                        # the per-bucket host round-trip IS the design
-                        # here: device peak memory stays one staged
-                        # bucket (+ one in flight), results land in
-                        # host RAM
-                        out[ents[valid]] = np.asarray(solved)[valid]
-                        iters[ents[valid]] = np.asarray(it_b)[valid]
-                        reasons[ents[valid]] = np.asarray(reason_b)[valid]
-                        fails[ents[valid]] = np.asarray(fail_b)[valid]
-                    if stream is not None:
-                        # results are on the host: the staged buffer is
-                        # consumed — return its token to the reader
-                        stream.release()
-                    if on_block is not None:
-                        on_block(bi + 1, n_blocks)
-                    if chaos.should_kill_re_block(bi):
-                        # after on_block: the cursor is durable, resume
-                        # must be bitwise (the v4 contract)
-                        raise chaos.SimulatedKill(
-                            f"chaos: killed after re block {bi} "
-                            f"checkpoint")
-        finally:
-            if stream is not None:
-                stream.close()
-        self.last_block_overlap = None
-        # storage->device data passes this run (the bench's accounting
-        # unit): one staging per bucket whether prefetched or inline
-        self.last_blocks_staged = (stream.blocks_staged
-                                   if stream is not None
-                                   else n_blocks - start_block)
-        if stream is not None:
-            from photon_tpu.utils import flops
-            self.last_block_overlap = flops.re_block_overlap(
-                stream.reader_busy_s, stream.consumer_stall_s,
-                stream.wall_s, stream.bytes_staged,
-                coordinate=self.random_effect_type)
-        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
-        e_orig = self._num_entities_orig
-        self.last_tracker = RandomEffectOptimizationTracker(
-            iterations=iters[:e_orig], reasons=reasons[:e_orig],
-            bucket_rows=tuple(blk.entity_rows for blk in ds.blocks))
-        n_failed = int(np.sum(fails[:e_orig] != 0))
-        self.last_failed_entities = n_failed
-        self.last_failure = None
-        if n_failed and e_orig and n_failed == e_orig:
-            self.last_failure = FailureMode(int(fails[:e_orig].max()))
+        lams = np.asarray([self.config.regularization_weight], np.float64)
+        out, iters, reasons, fails, _ = self._solve_blocked(
+            residual_scores, lams, None, warm_start=warm_start,
+            entity_names=entity_names, start_block=start_block,
+            on_block=on_block, prefetch=prefetch)
+        self._publish(iters[0], reasons[0], fails[0])
         # coefficients stay a HOST array — materializing [E, K] on device
         # would defeat the mode; downstream jnp ops accept numpy, and
         # io.model_io.save_game_model writes cold stores straight from it
-        return RandomEffectModel(
-            coefficients=out[:e_orig],
-            random_effect_type=self.random_effect_type,
-            feature_shard_id=self.feature_shard_id,
-            task=self.task,
-            variances=None,
-        )
+        return self._model(out[0][: self._num_entities_orig])
 
     def update_model_blocked_swept(
         self,
@@ -1372,13 +1201,50 @@ class RandomEffectCoordinate:
         per-bucket planned-vs-measured footprints land in
         ``last_block_plan`` / ``last_block_measured`` and the
         ``perf.re_peak_hbm_bytes`` gauges."""
+        from photon_tpu.optim import batched
+        from photon_tpu.utils import flops
+
+        lams = batched.validate_lane_weights(weights)
+        K_lanes = int(lams.size)
+        plan = self._record_lane_plan(K_lanes, plan, hbm_budget_bytes)
+        out, iters, reasons, fails, measured = self._solve_blocked(
+            residual_scores, lams, plan, warm_start=warm_start,
+            entity_names=entity_names, start_block=start_block,
+            on_block=on_block, prefetch=prefetch)
+        self.last_block_measured = measured
+        if measured:
+            flops.re_peak_hbm(
+                self.random_effect_type,
+                max(m["planned_peak_bytes"] for m in measured),
+                max(m["measured_peak_bytes"] for m in measured))
+        # host boundary: per-lane telemetry + failure typing
+        e_orig = self._num_entities_orig
+        self._publish_lanes(iters, reasons, [f[:e_orig] for f in fails])
+        batched.record_sweep_run([
+            {"weight": float(lams[k]),
+             "entities_failed": self.last_lane_failed_entities[k],
+             "failure": 0 if self.last_lane_failures[k] is None
+             else int(self.last_lane_failures[k])}
+            for k in range(K_lanes)])
+        return [self._model(out[k][:e_orig]) for k in range(K_lanes)]
+
+    def _solve_blocked(self, residual_scores: Optional[Array],
+                       lams: np.ndarray, plan, *, warm_start, entity_names,
+                       start_block: int, on_block, prefetch: bool):
+        """The one blocked host loop: every bucket from ``start_block`` on
+        staged once (prefetched while its predecessor solves) and solved
+        for all of ``lams``' lanes, in the plan's lane chunks (all lanes at
+        once without a plan), against ``[K, E_pad, d]`` tables in HOST RAM.
+        Returns the host tables ``(coefficients, iterations, reasons,
+        failures)`` and the buckets' planned-vs-measured footprints; the
+        staging counters (``last_blocks_staged`` / ``last_block_overlap``)
+        are set here, what a fit publishes is its public method's."""
         from photon_tpu.game import block_stream
         from photon_tpu.optim import batched
         from photon_tpu.parallel import memory as hbm
         from photon_tpu.resilience import chaos
         from photon_tpu.utils import flops
 
-        lams = batched.validate_lane_weights(weights)
         K_lanes = int(lams.size)
         ds = self.dataset
         n_blocks = len(ds.blocks)
@@ -1387,9 +1253,9 @@ class RandomEffectCoordinate:
                 f"start_block {start_block} outside [0, {n_blocks}]")
         E_pad = ds.num_entities
         D = ds.projected_dim
-        dtype = np.dtype(ds.blocks[0].labels.dtype) if ds.blocks \
-            else np.dtype(np.float32)
-        # K host-resident coefficient tables
+        dtype = np.dtype(self._solve_dtype())
+        # K host-resident coefficient tables: init from the warm-start
+        # source
         if warm_start is None:
             out = np.zeros((K_lanes, E_pad, D), dtype)
         elif isinstance(warm_start, np.ndarray) or isinstance(
@@ -1422,22 +1288,8 @@ class RandomEffectCoordinate:
             if extra > 0:
                 w = np.pad(w, [(0, extra), (0, 0)])
             out = np.repeat(w[None, :E_pad], K_lanes, axis=0)
-        if plan is None:
-            plan = hbm.plan_for_dataset(
-                ds, lanes=K_lanes,
-                history=self.config.optimizer.solver_config()
-                .num_corrections,
-                hbm_budget_bytes=hbm_budget_bytes,
-                coordinate=self.random_effect_type)
-        hbm.record_plan(plan)
-        self.last_block_plan = plan
-        reg = self.config.regularization
-        l2_all = np.asarray([reg.l2_weight(float(w)) for w in lams], dtype)
-        l1_all = np.asarray([reg.l1_weight(float(w)) for w in lams], dtype)
-        norm_args = ()
-        if self._norm_local is not None:
-            f, s, islot = self._norm_local
-            norm_args = (f,) if s is None else (f, s, islot)
+        residual_scores, l2_all, l1_all, norm_args = self._solve_args(
+            dtype, residual_scores, lams)
         iters = np.full((K_lanes, E_pad), -1, np.int32)
         reasons = np.full((K_lanes, E_pad), -1, np.int32)
         fails = np.zeros((K_lanes, E_pad), np.int32)
@@ -1453,13 +1305,17 @@ class RandomEffectCoordinate:
                         zip(ds.blocks, self._dense_local_blocks)):
                     if bi < start_block:
                         continue
-                    bplan = plan.buckets[bi] if bi < len(plan.buckets) \
+                    bplan = plan.buckets[bi] \
+                        if plan is not None and bi < len(plan.buckets) \
                         else None
                     chunk = max(1, min(
                         bplan.lane_chunk if bplan is not None else K_lanes,
                         K_lanes))
                     ents = np.asarray(blk.entity_rows)
                     valid = (ents >= 0) & (ents < E_pad)
+                    # bucket b+1 is already staging on the reader thread
+                    # while this bucket solves; values are identical to
+                    # the unstaged block, so parity stays bitwise
                     staged = stream.get(bi) if stream is not None else blk
                     bucket_peak = 0
                     with _obs_span("re/solve_block", block=bi):
@@ -1477,6 +1333,10 @@ class RandomEffectCoordinate:
                                     self._block_solve_swept_fn(dense)(
                                         staged, residual_scores, x0j,
                                         l2c, l1c, *norm_args)
+                            # the per-bucket host round-trip IS the design
+                            # here: device peak memory stays one staged
+                            # bucket (+ one in flight), results land in
+                            # host RAM
                             solved_np = np.asarray(solved)
                             it_np = np.asarray(it_b)
                             re_np = np.asarray(reason_b)
@@ -1508,26 +1368,25 @@ class RandomEffectCoordinate:
                         "measured_peak_bytes": int(bucket_peak),
                     })
                     if stream is not None:
+                        # results are on the host: the staged buffer is
+                        # consumed — return its token to the reader
                         stream.release()
                     if on_block is not None:
                         # checkpoint hook OUTSIDE the timed solve span
                         on_block(bi + 1, n_blocks)
                     if chaos.should_kill_re_block(bi):
+                        # after on_block: the cursor is durable, resume
+                        # must be bitwise (the v4 contract)
                         raise chaos.SimulatedKill(
                             f"chaos: killed after re block {bi} "
                             f"checkpoint")
         finally:
             if stream is not None:
                 stream.close()
-        self.last_block_measured = measured
-        if measured:
-            flops.re_peak_hbm(
-                self.random_effect_type,
-                max(m["planned_peak_bytes"] for m in measured),
-                max(m["measured_peak_bytes"] for m in measured))
         self.last_block_overlap = None
-        # one staging per bucket serves EVERY lane chunk — this is the
-        # (1/K)-data-passes economics the bench records
+        # storage->device data passes this run (the bench's accounting
+        # unit): one staging per bucket, whether prefetched or inline,
+        # serves EVERY lane chunk — the (1/K)-data-passes economics
         self.last_blocks_staged = (stream.blocks_staged
                                    if stream is not None
                                    else n_blocks - start_block)
@@ -1536,36 +1395,7 @@ class RandomEffectCoordinate:
                 stream.reader_busy_s, stream.consumer_stall_s,
                 stream.wall_s, stream.bytes_staged,
                 coordinate=self.random_effect_type)
-        # host boundary: per-lane telemetry + failure typing
-        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
-        e_orig = self._num_entities_orig
-        self.last_lane_trackers = [
-            RandomEffectOptimizationTracker(iterations=iters[k][:e_orig],
-                                            reasons=reasons[k][:e_orig])
-            for k in range(K_lanes)]
-        self.last_lane_failed_entities = [
-            int(np.sum(fails[k][:e_orig] != 0)) for k in range(K_lanes)]
-        self.last_lane_failures = [
-            FailureMode(int(fails[k][:e_orig].max()))
-            if self.last_lane_failed_entities[k] and e_orig
-            and self.last_lane_failed_entities[k] == e_orig else None
-            for k in range(K_lanes)]
-        batched.record_sweep_run([
-            {"weight": float(lams[k]),
-             "entities_failed": self.last_lane_failed_entities[k],
-             "failure": 0 if self.last_lane_failures[k] is None
-             else int(self.last_lane_failures[k])}
-            for k in range(K_lanes)])
-        return [
-            RandomEffectModel(
-                coefficients=out[k][:e_orig],
-                random_effect_type=self.random_effect_type,
-                feature_shard_id=self.feature_shard_id,
-                task=self.task,
-                variances=None,
-            )
-            for k in range(K_lanes)
-        ]
+        return out, iters, reasons, fails, measured
 
     @functools.cached_property
     def _variance_fn(self):
@@ -1599,17 +1429,12 @@ class RandomEffectCoordinate:
                              coef_block, l2):
                 out = jnp.zeros_like(coef_block)
                 for blk in ds.blocks:
-                    offsets = blk.offsets
-                    if residual_flat is not None:
-                        res = residual_flat.at[blk.sample_rows].get(
-                            mode="fill", fill_value=0.0)
-                        offsets = offsets + res
-                    coefs_b = coef_block.at[blk.entity_rows].get(
-                        mode="fill", fill_value=0.0)
+                    offsets = _residual_offsets(blk, residual_flat)
+                    coefs_b = blk.rows_from_table(coef_block, 0.0)
                     var_b = jax.vmap(one, in_axes=(0, 0, 0, 0, 0, 0, None))(
                         blk.features.indices, blk.features.values,
                         blk.labels, offsets, blk.weights, coefs_b, l2)
-                    out = out.at[blk.entity_rows].set(var_b, mode="drop")
+                    out = blk.set_rows_in_table(out, var_b)
                 return out
 
             return variance_all
@@ -1660,12 +1485,8 @@ class RandomEffectCoordinate:
                           coef_block: Array, l2: Array) -> Array:
                 total = jnp.zeros((), coef_block.dtype)
                 for blk, dense in zip(ds.blocks, dense_flags):
-                    offsets = blk.offsets
-                    if residual_flat is not None:
-                        offsets = offsets + residual_flat.at[
-                            blk.sample_rows].get(mode="fill", fill_value=0.0)
-                    rows = coef_block.at[blk.entity_rows].get(
-                        mode="fill", fill_value=0.0)
+                    offsets = _residual_offsets(blk, residual_flat)
+                    rows = blk.rows_from_table(coef_block, 0.0)
                     if dense:
                         vals = jax.vmap(one_core,
                                         in_axes=(0, 0, 0, 0, 0, None))(
@@ -1718,8 +1539,7 @@ class RandomEffectCoordinate:
             def loss_all(ds: RandomEffectDataset, scores_flat: Array) -> Array:
                 total = jnp.zeros((), scores_flat.dtype)
                 for blk in ds.blocks:
-                    z = blk.offsets + scores_flat.at[blk.sample_rows].get(
-                        mode="fill", fill_value=0.0)
+                    z = _residual_offsets(blk, scores_flat)
                     l, _ = loss.loss_and_dz(z, blk.labels)
                     total = total + jnp.sum(l * blk.weights)
                 return total
@@ -1736,6 +1556,16 @@ class RandomEffectCoordinate:
         return self._data_loss_fn(self.dataset, total_scores)
 
 
+def _residual_offsets(blk: EntityBlock,
+                      residual_flat: Optional[Array]) -> Array:
+    """A bucket's ``[E_b, S_b]`` offsets with a flat score vector injected
+    (Coordinate.scala:60-63: train against residual-injected offsets); pad
+    slots read a zero residual."""
+    if residual_flat is None:
+        return blk.offsets
+    return blk.offsets + blk.rows_from_flat(residual_flat)
+
+
 def _re_score_builder(n: int, dense_flags=()):
     @jax.named_scope("re/score")
     def score(ds: RandomEffectDataset, coef_block: Array) -> Array:
@@ -1744,7 +1574,7 @@ def _re_score_builder(n: int, dense_flags=()):
                  else (False,) * len(ds.blocks))
         # active blocks: per-entity margins, scattered to flat rows
         for blk, dense in zip(ds.blocks, flags):
-            rows = coef_block.at[blk.entity_rows].get(mode="fill", fill_value=0.0)
+            rows = blk.rows_from_table(coef_block, 0.0)
             if dense:
                 # dense-local block: one batched [S, K] x [K] contraction
                 margins = jnp.einsum("esk,ek->es", blk.features.values, rows)
@@ -1754,8 +1584,7 @@ def _re_score_builder(n: int, dense_flags=()):
                     * jax.vmap(lambda c, i: c[i])(rows, blk.features.indices),
                     axis=-1,
                 )
-            flat = flat.at[blk.sample_rows.ravel()].add(
-                margins.ravel(), mode="drop")
+            flat = blk.add_rows_to_flat(flat, margins)
         # passive: gather entity coef rows (out-of-range entity -> 0)
         pcoef = coef_block.at[ds.passive_entity].get(mode="fill", fill_value=0.0)
         pmargin = jnp.sum(ds.passive_features.values

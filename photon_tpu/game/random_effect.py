@@ -78,10 +78,17 @@ class RandomEffectDataConfiguration:
                                 self.projection_seed)
 
 
+def _lane_at(array: Array, index: Array, lanes: bool):
+    """``array.at[index]`` on the first axis, or on the second under a
+    leading lane axis."""
+    return array.at[:, index] if lanes else array.at[index]
+
+
 class EntityBlock(NamedTuple):
     """One size bucket of entities, padded to [E_b, S_b] / [E_b, S_b, K_b].
     All pads carry weight 0; ``entity_rows`` maps block rows to global
-    entity rows (out-of-range = pad row)."""
+    entity rows (out-of-range = pad row). Read ``sample_rows`` and index by
+    ``entity_rows`` through the four mapping methods below, never directly."""
 
     features: F.SparseFeatures        # indices/values [E_b, S_b, K_b] LOCAL slots
     labels: Array                     # [E_b, S_b]
@@ -97,6 +104,48 @@ class EntityBlock(NamedTuple):
     @property
     def max_samples(self) -> int:
         return self.labels.shape[1]
+
+    # -- flat order <-> ladder order ------------------------------------
+    # The four methods below are the ONLY readers of ``sample_rows`` and
+    # the only device-side indexers by ``entity_rows``: the pad
+    # invariants above ("n on pads", "out-of-range = pad row") and the
+    # ``mode="fill"`` / ``mode="drop"`` that pair with them are stated
+    # here once (tests/test_game.py holds coordinate.py and bayes/ to it).
+    # A re-layout of the flat frame (ROADMAP S4: one prepare-time
+    # permutation instead of a gather a bucket) is a change to these and
+    # to ``build_random_effect_dataset``, nowhere else. ``lanes=True``
+    # means a leading lane axis on the flat vector / the table.
+
+    def rows_from_flat(self, flat: Array, lanes: bool = False) -> Array:
+        """Flat ``[n]`` vector -> this bucket's ``[E_b, S_b]`` rows
+        (``[c, n] -> [c, E_b, S_b]`` with lanes); pad slots read 0."""
+        return _lane_at(flat, self.sample_rows, lanes).get(
+            mode="fill", fill_value=0.0)
+
+    def add_rows_to_flat(self, flat: Array, values: Array,
+                         lanes: bool = False) -> Array:
+        """Scatter-add this bucket's ``[E_b, S_b]`` values into a flat
+        ``[n]`` vector (``[c, E_b, S_b]`` into ``[c, n]`` with lanes);
+        pad slots are dropped. With the passive rows the buckets
+        partition the flat frame, so over all of them this is
+        ``rows_from_flat``'s inverse."""
+        return _lane_at(flat, self.sample_rows.ravel(), lanes).add(
+            values.reshape(flat.shape[:-1] + (-1,)), mode="drop")
+
+    def rows_from_table(self, table: Array, fill, lanes: bool = False
+                        ) -> Array:
+        """Entity table ``[E, ...]`` -> this bucket's ``[E_b, ...]`` rows
+        (``[c, E, ...] -> [c, E_b, ...]`` with lanes); a pad row reads
+        ``fill``."""
+        return _lane_at(table, self.entity_rows, lanes).get(
+            mode="fill", fill_value=fill)
+
+    def set_rows_in_table(self, table: Array, rows: Array,
+                          lanes: bool = False) -> Array:
+        """This bucket's ``[E_b, ...]`` rows -> their places in the
+        entity table; pad rows are dropped."""
+        return _lane_at(table, self.entity_rows, lanes).set(
+            rows, mode="drop")
 
 
 class RandomEffectDataset(NamedTuple):

@@ -5,9 +5,10 @@ events.  For each random-effect coordinate the trainer builds a *tiny*
 GAME dataset over just those events, warm-starts each entity's solve
 from the live model's coefficients (the cold store when one backs the
 coordinate, the resident table otherwise), and runs the exact per-entity
-solve programs offline training uses (``RandomEffectCoordinate.
-update_model_blocked`` — size-bucketed, jitted, warm-started, failed
-entities keep their warm start).  The output is a per-coordinate set of
+solve program offline training uses (``RandomEffectCoordinate.
+update_model_blocked``: the blocked sweep's per-bucket program at one lane —
+size-bucketed, jitted, warm-started, failed entities keep their warm
+start).  The output is a per-coordinate set of
 *candidate rows* — ``{entity_id: (coef_row, proj_row)}`` in the delta
 dataset's projected space — which the publisher normalizes into the
 serving layout and pushes behind its gate ladder.

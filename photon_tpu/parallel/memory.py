@@ -43,7 +43,7 @@ where ``m`` is the solver history (``SolverConfig.num_corrections``) and
 the 6 working vectors bound the gradient/direction/line-search temps.
 Each lane is charged ``data + lane_bytes``: the swept program flattens
 its c lanes into the entity axis by tiling the staged block c× on
-device (game/coordinate._make_block_solver_swept — the price of bitwise
+device (game/coordinate._make_bucket_solver, lanes mode — the price of bitwise
 lane-vs-scalar parity), so the tiled batch scales with the chunk, while
 the staging (``copies`` = 2 when double-buffered) does not. All terms
 are deliberate over-estimates of steady state (at c=1 the block is

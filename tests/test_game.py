@@ -252,6 +252,174 @@ def test_entity_bucket_cap_bounds_compiles_and_preserves_results():
                                rtol=1e-7, atol=1e-10)
 
 
+@pytest.fixture(scope="module")
+def skewed_blocks():
+    """A Zipf-skewed random-effect dataset with passive rows (the cap
+    pushes the head entities' overflow to the passive split): (dataset, n,
+    entity count)."""
+    from photon_tpu.game.dataset import EntityVocabulary
+    from photon_tpu.game.random_effect import build_random_effect_dataset
+
+    rng = np.random.default_rng(3)
+    n, d, ents = 1500, 3, 90
+    p = 1.0 / np.arange(1, ents + 1) ** 1.3
+    ent = rng.choice(ents, size=n, p=p / p.sum())
+    idx = np.arange(d, dtype=np.int32)
+    rows = [(idx, rng.normal(size=d)) for _ in range(n)]
+    df = GameDataFrame(num_samples=n, response=rng.random(n),
+                       feature_shards={"u": FeatureShard(rows, d)},
+                       id_tags={"userId": [str(e) for e in ent]})
+    ds = build_random_effect_dataset(
+        df, RandomEffectDataConfiguration(
+            "userId", "u", active_data_upper_bound=40, max_entity_buckets=4),
+        EntityVocabulary(), dtype=np.float64)
+    assert len(ds.blocks) > 2
+    assert int(np.sum(np.asarray(ds.passive_rows) < n)) > 0
+    # the ladder pads: some slot of some bucket is not a sample
+    assert any(np.any(np.asarray(b.sample_rows) == n) for b in ds.blocks)
+    return ds, n, ds.num_entities
+
+
+def _lane_stack(a, lanes):
+    """``a`` itself, or three lanes of it that differ (lane j is
+    ``(j + 1) * a``) so that a lane mix-up shows."""
+    return jnp.stack([(j + 1) * a for j in range(3)]) if lanes else a
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["flat", "lanes"])
+def test_flat_rows_read_zero_on_pads_and_pads_are_dropped(skewed_blocks,
+                                                           lanes):
+    """``EntityBlock.rows_from_flat`` reads every pad slot as 0 and every
+    real slot as its flat row; ``add_rows_to_flat`` drops every pad."""
+    ds, n, _ = skewed_blocks
+    flat = _lane_stack(jnp.arange(1.0, n + 1.0), lanes)  # zero nowhere
+    for blk in ds.blocks:
+        rows_of = np.asarray(blk.sample_rows)
+        pad = rows_of == n
+        got = np.asarray(blk.rows_from_flat(flat, lanes))
+        assert got.shape == flat.shape[:-1] + rows_of.shape
+        assert np.all(got[..., pad] == 0.0)
+        np.testing.assert_array_equal(
+            got[..., ~pad], np.asarray(flat)[..., rows_of[~pad]])
+        # pads carry weight 0: the same slots the solve ignores
+        assert np.all(np.asarray(blk.weights)[pad] == 0.0)
+        ones = _lane_stack(jnp.ones(rows_of.shape), lanes)
+        back = np.asarray(blk.add_rows_to_flat(
+            jnp.zeros(flat.shape), ones, lanes))
+        assert back.shape == flat.shape
+        want = np.zeros(n)
+        want[rows_of[~pad]] = 1.0
+        np.testing.assert_array_equal(
+            back, np.asarray(_lane_stack(jnp.asarray(want), lanes)))
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["flat", "lanes"])
+def test_blocks_and_passive_rows_partition_the_flat_frame(skewed_blocks,
+                                                          lanes):
+    """Gathering a flat vector into every bucket and scatter-adding it
+    back, plus the passive rows, returns the vector: each flat row is
+    reached exactly once (what ``data_loss_at`` relies on)."""
+    ds, n, _ = skewed_blocks
+    rng = np.random.default_rng(0)
+    flat = _lane_stack(jnp.asarray(rng.normal(size=n)), lanes)
+    back = jnp.zeros(flat.shape)
+    reached = jnp.zeros(flat.shape)
+    for blk in ds.blocks:
+        rows = blk.rows_from_flat(flat, lanes)
+        back = blk.add_rows_to_flat(back, rows, lanes)
+        reached = blk.add_rows_to_flat(reached, jnp.ones(rows.shape), lanes)
+    passive = np.asarray(ds.passive_rows)
+    passive = passive[passive < n]
+    back = back.at[..., passive].add(flat[..., passive])
+    reached = reached.at[..., passive].add(1.0)
+    np.testing.assert_array_equal(np.asarray(reached), 1.0)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(flat))
+
+
+@pytest.mark.parametrize("lanes", [False, True], ids=["table", "lanes"])
+def test_table_rows_read_the_fill_on_pad_rows_and_pad_rows_are_dropped(
+        skewed_blocks, lanes):
+    """``rows_from_table`` / ``set_rows_in_table`` against the
+    out-of-range pad row a mesh-padded bucket carries: it reads the fill,
+    it writes nothing, and over the buckets the two are inverse on every
+    entity with active data."""
+    ds, _, E = skewed_blocks
+    rng = np.random.default_rng(1)
+    table = _lane_stack(jnp.asarray(rng.normal(size=(E, 3))), lanes)
+    back = jnp.full(table.shape, -7.0)
+    active = np.zeros(E, bool)
+    for blk in ds.blocks:
+        ents = np.asarray(blk.entity_rows)
+        active[ents] = True
+        # the first block row becomes a pad row, as parallel/mesh pads
+        padded = blk._replace(entity_rows=blk.entity_rows.at[0].set(E))
+        rows = np.asarray(padded.rows_from_table(table, 1.5, lanes))
+        assert rows.shape == table.shape[:-2] + (len(ents), 3)
+        assert np.all(rows[..., 0, :] == 1.5)
+        np.testing.assert_array_equal(
+            rows[..., 1:, :], np.asarray(table)[..., ents[1:], :])
+        wrote = np.asarray(padded.set_rows_in_table(
+            jnp.full(table.shape, -7.0), jnp.asarray(rows), lanes))
+        assert np.all(wrote[..., ents[0], :] == -7.0)
+        back = blk.set_rows_in_table(
+            back, blk.rows_from_table(table, 0.0, lanes), lanes)
+    np.testing.assert_array_equal(
+        np.asarray(back)[..., active, :], np.asarray(table)[..., active, :])
+    assert np.all(np.asarray(back)[..., ~active, :] == -7.0)
+
+
+def _mapping_reads(path):
+    """(line, what) of every direct use of the flat/ladder mapping in a
+    source file: an attribute read of ``sample_rows``, or an
+    ``.at[...]`` whose index mentions ``entity_rows``."""
+    import ast
+
+    found = []
+    for node in ast.walk(ast.parse(open(path).read(), path)):
+        if isinstance(node, ast.Attribute) and node.attr == "sample_rows":
+            found.append((node.lineno, "sample_rows"))
+        if (isinstance(node, ast.Subscript)
+                and isinstance(node.value, ast.Attribute)
+                and node.value.attr == "at"
+                and any(isinstance(sub, ast.Attribute)
+                        and sub.attr == "entity_rows"
+                        for sub in ast.walk(node.slice))):
+            found.append((node.lineno, ".at[entity_rows]"))
+    return found
+
+
+def _mapping_free_sources():
+    import glob
+    import os
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "photon_tpu")
+    return [os.path.join(root, "game", "coordinate.py")] + sorted(
+        glob.glob(os.path.join(root, "bayes", "*.py")))
+
+
+@pytest.mark.parametrize(
+    "path", _mapping_free_sources(),
+    ids=lambda p: "/".join(p.split("/")[-2:]))
+def test_the_flat_ladder_mapping_is_read_only_through_entity_block(path):
+    """How ladder order maps to flat order is ``EntityBlock``'s to know
+    (its four mapping methods): the solve, score, objective, loss and
+    variance programs index neither ``sample_rows`` nor by
+    ``entity_rows`` themselves, so a re-layout is a change to
+    ``game/random_effect.py`` alone."""
+    assert _mapping_reads(path) == []
+
+
+def test_the_mapping_walk_sees_a_direct_read(tmp_path):
+    src = tmp_path / "fork.py"
+    src.write_text(
+        "def f(blk, flat, table):\n"
+        "    a = flat.at[blk.sample_rows].get(mode='fill', fill_value=0.0)\n"
+        "    return a, table.at[:, blk.entity_rows].set(a, mode='drop')\n")
+    assert sorted(what for _, what in _mapping_reads(str(src))) == [
+        ".at[entity_rows]", "sample_rows"]
+
+
 def test_random_effect_tron_matches_lbfgs(glmix):
     """A TRON-solved random effect (explicit per-entity K x K Hessian,
     batched under vmap) must reach the same convex optimum as L-BFGS

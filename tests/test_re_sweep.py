@@ -319,6 +319,38 @@ class TestBlockedSwept:
                 refs_it[k])
         assert coord.last_block_overlap is not None
 
+    def test_one_lane_blocked_fit_leaves_a_scalar_fits_side_effects(self):
+        """``update_model_blocked`` is the blocked sweep at one lane, and
+        what it publishes is a scalar fit's: no sweep run, no ``re_plan``,
+        ``last_tracker`` with the buckets' rows (so
+        ``obs.solver.lane_counts()`` works), host-resident coefficients."""
+        from photon_tpu.optim import batched
+
+        coord, ds, _ = _coordinate(seed=5)
+        hbm.reset_plan_stats()
+        sweeps_before = batched.report_section()
+        try:
+            m = coord.update_model_blocked(None)
+            assert hbm.report_section() is None
+        finally:
+            hbm.reset_plan_stats()
+        assert batched.report_section() == sweeps_before
+        assert not hasattr(coord, "last_lane_trackers")
+        assert isinstance(m.coefficients, np.ndarray)
+        assert m.coefficients.shape == (ds.num_entities, ds.projected_dim)
+        tracker = coord.last_tracker
+        assert len(tracker.bucket_rows) == len(ds.blocks)
+        counts = tracker.lane_counts()
+        assert counts["trips"] > 0
+        assert 0 < counts["sum"] <= counts["capacity"]
+        assert coord.last_failed_entities == 0
+        assert coord.last_failure is None
+        # the resident fit's tracker says the same of the same entities
+        ref = coord.update_model(None, None)
+        np.testing.assert_array_equal(m.coefficients,
+                                      np.asarray(ref.coefficients))
+        assert coord.last_tracker.lane_counts() == counts
+
     def test_blocked_swept_matches_all_at_once(self):
         coord, _ds, _ = _coordinate(seed=3)
         flat = [np.asarray(m.coefficients)

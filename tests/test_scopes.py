@@ -203,18 +203,37 @@ def glmix_fit():
     return est, first, second, placed, after_first == counters()
 
 
-def test_the_ladder_program_names_every_bucket(glmix_fit):
+@pytest.mark.parametrize("program", ["ladder", "swept_ladder", "block"])
+def test_the_ladder_program_names_every_bucket(glmix_fit, program):
+    """Every per-entity solve program is the one bucket body
+    (``RandomEffectCoordinate._make_bucket_solver``) under a wrapper: the
+    scalar ladder and the lane ladder put each bucket under its
+    ``re/b<index>``; the blocked program serves every bucket of its flavour
+    with one executable, so it carries the body's names and no bucket's."""
     est = glmix_fit[0]
     coord = est._coordinates["per-user"]
     ds = coord.dataset
     assert len(ds.blocks) == 4
-    coef0 = jnp.zeros((ds.num_entities, ds.projected_dim), jnp.float64)
-    one = jnp.asarray(1.0)
-    text = coord._solve_fn.lower(
-        ds, jnp.zeros(coord.n), coef0, one, one).as_text(debug_info=True)
-    found = scopes_in(text)
-    assert {f"re/b{i}" for i in range(len(ds.blocks))} <= found
-    assert f"re/b{len(ds.blocks)}" not in found
+    E, K = ds.num_entities, ds.projected_dim
+    one, residual = jnp.asarray(1.0), jnp.zeros(coord.n)
+    if program == "ladder":
+        lowered = coord._solve_fn.lower(
+            ds, residual, jnp.zeros((E, K), jnp.float64), one, one)
+    elif program == "swept_ladder":
+        lowered = coord._solve_swept_fn.lower(
+            ds, residual, jnp.zeros((2, E, K), jnp.float64),
+            jnp.ones(2), jnp.ones(2))
+    else:
+        blk, dense = ds.blocks[1], coord._dense_local_blocks[1]
+        lowered = coord._block_solve_swept_fn(dense).lower(
+            blk, residual, jnp.zeros((1, blk.num_rows, K), jnp.float64),
+            jnp.ones(1), jnp.ones(1))
+    found = scopes_in(lowered.as_text(debug_info=True))
+    buckets = {s for s in found if re.fullmatch(r"re/b\d+", s)}
+    if program == "block":
+        assert not buckets, sorted(buckets)
+    else:
+        assert buckets == {f"re/b{i}" for i in range(len(ds.blocks))}
     assert {"re/gather", "re/scatter", "agg/value_and_gradient",
             "optim/lbfgs/linesearch"} <= found
 
