@@ -13,15 +13,17 @@ flat batch; the random effect trains ALL entities at once with a vmap-ed
 L-BFGS over the entity-blocked dataset (per-entity convergence masking via
 the while_loop batching rule) — the reference's millions of independent
 Breeze solves become one SPMD program on the entity-sharded mesh axis.
-Residual injection is a gather; score emission is a scatter-add.
+Residual injection is a gather a bucket; score emission is ONE gather.
 
 One body solves a size bucket (``RandomEffectCoordinate._make_bucket_
 solver``: the only vmap of the entity solver); the scalar ladder, the λ-lane
 ladder and the blocked program are wrappers around it, and one host loop
 (``_solve_blocked``) streams the blocked fits. How ladder order maps to flat
-sample order and to entity rows is ``EntityBlock``'s (game/random_effect.py:
-``rows_from_flat`` / ``add_rows_to_flat`` / ``rows_from_table`` /
-``set_rows_in_table``); nothing here indexes a block's row maps itself.
+sample order and to entity rows is four mapping methods' business
+(game/random_effect.py: ``EntityBlock.rows_from_flat`` / ``rows_from_table``
+/ ``set_rows_in_table`` a bucket, and ``RandomEffectDataset.rows_to_flat``,
+which takes every bucket's rows back to flat order by one gather through
+the prepare-time inverse map); nothing here indexes a row map itself.
 
 Names (PERF.md §3; they are an interface). Inside the programs, by
 ``jax.named_scope``: ``fe/score``, ``re/score``, and in every per-entity
@@ -454,14 +456,17 @@ class RandomEffectCoordinate:
         from photon_tpu.types import VarianceComputationType
 
         self.variance_type = variance_type or VarianceComputationType.NONE
+        if dataset.num_flat_samples != num_flat_samples:
+            raise ValueError(
+                f"dataset was built over {dataset.num_flat_samples} flat "
+                f"rows, the coordinate is given {num_flat_samples}")
         self._num_entities_orig = dataset.num_entities
         if mesh is not None:
             from photon_tpu.parallel import mesh as M
             # entity-shard once at construction (the co-partitioning
             # replacement); the vmapped solves are independent per entity,
             # so this axis runs collective-free
-            dataset = M.shard_entity_blocks(dataset, mesh,
-                                            num_flat_samples=num_flat_samples)
+            dataset = M.shard_entity_blocks(dataset, mesh)
         self.dataset = dataset
         self.n = num_flat_samples
         self.random_effect_type = random_effect_type
@@ -1453,13 +1458,12 @@ class RandomEffectCoordinate:
 
     @functools.cached_property
     def _score_fn(self):
-        n = self.n
         dense_flags = self._dense_local_blocks
 
         def build():
-            return jax.jit(_re_score_builder(n, dense_flags))
+            return jax.jit(_re_score_builder(dense_flags))
 
-        return jitcache.get_or_build(("re_score", n, dense_flags), build)
+        return jitcache.get_or_build(("re_score", dense_flags), build)
 
     def score(self, model: RandomEffectModel) -> Array:
         with _obs_annotate("re/score"):
@@ -1566,31 +1570,30 @@ def _residual_offsets(blk: EntityBlock,
     return blk.offsets + blk.rows_from_flat(residual_flat)
 
 
-def _re_score_builder(n: int, dense_flags=()):
+def _re_score_builder(dense_flags=()):
     @jax.named_scope("re/score")
     def score(ds: RandomEffectDataset, coef_block: Array) -> Array:
-        flat = jnp.zeros((n,), coef_block.dtype)
         flags = (dense_flags if len(dense_flags) == len(ds.blocks)
                  else (False,) * len(ds.blocks))
-        # active blocks: per-entity margins, scattered to flat rows
+        # active blocks: per-entity margins, in ladder order
+        margins = []
         for blk, dense in zip(ds.blocks, flags):
             rows = blk.rows_from_table(coef_block, 0.0)
             if dense:
                 # dense-local block: one batched [S, K] x [K] contraction
-                margins = jnp.einsum("esk,ek->es", blk.features.values, rows)
+                margins.append(
+                    jnp.einsum("esk,ek->es", blk.features.values, rows))
             else:
-                margins = jnp.sum(
+                margins.append(jnp.sum(
                     blk.features.values
                     * jax.vmap(lambda c, i: c[i])(rows, blk.features.indices),
                     axis=-1,
-                )
-            flat = blk.add_rows_to_flat(flat, margins)
+                ))
         # passive: gather entity coef rows (out-of-range entity -> 0)
         pcoef = coef_block.at[ds.passive_entity].get(mode="fill", fill_value=0.0)
         pmargin = jnp.sum(ds.passive_features.values
                           * jnp.take_along_axis(pcoef, ds.passive_features.indices, axis=1),
                           axis=-1)
-        flat = flat.at[ds.passive_rows].add(pmargin, mode="drop")
-        return flat
+        return ds.rows_to_flat(margins, pmargin).astype(coef_block.dtype)
 
     return score

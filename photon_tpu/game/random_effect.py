@@ -88,7 +88,7 @@ class EntityBlock(NamedTuple):
     """One size bucket of entities, padded to [E_b, S_b] / [E_b, S_b, K_b].
     All pads carry weight 0; ``entity_rows`` maps block rows to global
     entity rows (out-of-range = pad row). Read ``sample_rows`` and index by
-    ``entity_rows`` through the four mapping methods below, never directly."""
+    ``entity_rows`` through the mapping methods below, never directly."""
 
     features: F.SparseFeatures        # indices/values [E_b, S_b, K_b] LOCAL slots
     labels: Array                     # [E_b, S_b]
@@ -106,31 +106,27 @@ class EntityBlock(NamedTuple):
         return self.labels.shape[1]
 
     # -- flat order <-> ladder order ------------------------------------
-    # The four methods below are the ONLY readers of ``sample_rows`` and
-    # the only device-side indexers by ``entity_rows``: the pad
-    # invariants above ("n on pads", "out-of-range = pad row") and the
-    # ``mode="fill"`` / ``mode="drop"`` that pair with them are stated
-    # here once (tests/test_game.py holds coordinate.py and bayes/ to it).
-    # A re-layout of the flat frame (ROADMAP S4: one prepare-time
-    # permutation instead of a gather a bucket) is a change to these and
-    # to ``build_random_effect_dataset``, nowhere else. ``lanes=True``
-    # means a leading lane axis on the flat vector / the table.
+    # Four mapping methods: the three below take a flat vector or an
+    # entity table into this bucket's order and a bucket's rows back to
+    # the table; the fourth, ``RandomEffectDataset.rows_to_flat``, takes
+    # every bucket's rows back to flat order at once (one gather through
+    # ``flat_source``, the inverse map that ``flat_source_map`` derives
+    # from every bucket's ``sample_rows`` at prepare). They are the ONLY
+    # device-side readers of ``sample_rows`` / ``flat_source`` and the
+    # only indexers by ``entity_rows``: the pad invariants above ("n on
+    # pads", "out-of-range = pad row") and the ``mode="fill"`` /
+    # ``mode="drop"`` that pair with them are stated here once
+    # (tests/test_game.py holds coordinate.py and bayes/ to it). A
+    # re-layout of the flat frame (ROADMAP S4: the frame ordered by one
+    # coordinate's ladder) is a change to these and to
+    # ``build_random_effect_dataset``, nowhere else. ``lanes=True`` means
+    # a leading lane axis on the flat vector / the table.
 
     def rows_from_flat(self, flat: Array, lanes: bool = False) -> Array:
         """Flat ``[n]`` vector -> this bucket's ``[E_b, S_b]`` rows
         (``[c, n] -> [c, E_b, S_b]`` with lanes); pad slots read 0."""
         return _lane_at(flat, self.sample_rows, lanes).get(
             mode="fill", fill_value=0.0)
-
-    def add_rows_to_flat(self, flat: Array, values: Array,
-                         lanes: bool = False) -> Array:
-        """Scatter-add this bucket's ``[E_b, S_b]`` values into a flat
-        ``[n]`` vector (``[c, E_b, S_b]`` into ``[c, n]`` with lanes);
-        pad slots are dropped. With the passive rows the buckets
-        partition the flat frame, so over all of them this is
-        ``rows_from_flat``'s inverse."""
-        return _lane_at(flat, self.sample_rows.ravel(), lanes).add(
-            values.reshape(flat.shape[:-1] + (-1,)), mode="drop")
 
     def rows_from_table(self, table: Array, fill, lanes: bool = False
                         ) -> Array:
@@ -158,10 +154,31 @@ class RandomEffectDataset(NamedTuple):
     passive_rows: Array                 # [P] int32 flat row (n on pads)
     # projection table: local slot -> global feature index (-1 unused)
     projection: Array                 # [E, D_loc] int32
+    # inverse of every ``sample_rows`` and ``passive_rows`` at once
+    # (``flat_source_map``); read by ``rows_to_flat`` only
+    flat_source: Array                # [n] int32 slot of each flat row
 
     @property
     def num_entities(self) -> int:
         return self.projection.shape[0]
+
+    @property
+    def num_flat_samples(self) -> int:
+        return self.flat_source.shape[0]
+
+    def rows_to_flat(self, block_values: Sequence[Array],
+                     passive_values: Array) -> Array:
+        """Every bucket's ``[E_b, S_b]`` values and the ``[P]`` passive
+        values -> the flat ``[n]`` vector, by ONE gather: the buckets and
+        the passive rows partition the flat frame, so the way back from
+        ladder order is a permutation, read through its prepare-time
+        inverse. Pad slots are never read; a flat row that no slot holds
+        reads the trailing 0. Over all buckets this is
+        ``EntityBlock.rows_from_flat``'s inverse."""
+        slots = jnp.concatenate(
+            [v.ravel() for v in block_values]
+            + [passive_values, jnp.zeros((1,), passive_values.dtype)])
+        return slots.at[self.flat_source].get(mode="promise_in_bounds")
 
     @property
     def max_samples(self) -> int:
@@ -177,6 +194,23 @@ class RandomEffectDataset(NamedTuple):
         padded = sum(b.labels.size for b in self.blocks)
         real = sum(int(jnp.sum(b.weights > 0)) for b in self.blocks)
         return padded / max(real, 1)
+
+
+def flat_source_map(block_rows: Sequence[np.ndarray],
+                    passive_rows: np.ndarray, n: int) -> np.ndarray:
+    """``RandomEffectDataset.flat_source``: for every flat row its position
+    in ``[bucket 0's slots raveled, bucket 1's, ..., the passive slots,
+    one trailing 0.0]``, from the buckets' ``sample_rows`` and the
+    ``passive_rows`` (host arrays; pads hold ``n`` or more). A row that no
+    slot holds points at the trailing zero."""
+    rows = np.concatenate([np.asarray(r).ravel() for r in block_rows]
+                          + [np.asarray(passive_rows).ravel()])
+    if len(rows) >= np.iinfo(np.int32).max:
+        raise ValueError(f"{len(rows)} slots do not fit an int32 map")
+    source = np.full(n, len(rows), np.int32)
+    held = np.flatnonzero(rows < n)
+    source[rows[held]] = held
+    return source
 
 
 def _splitmix64(x: np.ndarray) -> np.ndarray:
@@ -369,6 +403,7 @@ def build_random_effect_dataset(
                 lut[g] = g[-1]
             bucket_id = np.where(bucket_id >= 0, lut[np.maximum(bucket_id, 0)], -1)
         blocks: List[EntityBlock] = []
+        block_rows: List[np.ndarray] = []     # host sample_rows a bucket
 
         # active samples sorted by (entity, hash) and within cap
         act_idx_sorted = order[active_sorted]             # flat rows, grouped
@@ -399,6 +434,7 @@ def build_random_effect_dataset(
             offsets_b[r_idx, c_idx] = base_offsets[rows_flat]
             weights_b[r_idx, c_idx] = weights[rows_flat]
             rows_b[r_idx, c_idx] = rows_flat
+            block_rows.append(rows_b)
 
             # ELL features: nonzeros of this bucket's active samples
             nz_mask = kept_nz_mask & active[s_nz] & (row_of_entity[e_nz] >= 0)
@@ -445,6 +481,7 @@ def build_random_effect_dataset(
             sel = pas_nz_mask
             p_idx[row_rank[s_nz[sel]], pas_k[sel]] = slot_nz[sel].astype(np.int32)
             p_val[row_rank[s_nz[sel]], pas_k[sel]] = vals[sel]
+        flat_source = flat_source_map(block_rows, p_rows, n)
 
     with phase(h2d):
         ds = RandomEffectDataset(
@@ -453,6 +490,7 @@ def build_random_effect_dataset(
             passive_entity=jnp.asarray(p_entity),
             passive_rows=jnp.asarray(p_rows),
             projection=jnp.asarray(projection),
+            flat_source=jnp.asarray(flat_source),
         )
     count_placed(coordinate, ds)
     # ingest telemetry (VERDICT r2 weak #8): block count == distinct XLA

@@ -37,7 +37,10 @@ Byte model (pinned by tests/test_re_sweep.py — change them together):
   lane_bytes(E, d)     = E*d*itemsize*(2 + 2*m + 6)  x0 + result
                                                      + L-BFGS (S,Y) pairs
                                                      + working vectors
-  peak(c)              = copies*data + c*(data + lane_bytes)
+  resident(n)          = n*4                         the dataset's flat-order
+                                                     map (one int32 a flat
+                                                     row), beside every bucket
+  peak(c)              = resident + copies*data + c*(data + lane_bytes)
 
 where ``m`` is the solver history (``SolverConfig.num_corrections``) and
 the 6 working vectors bound the gradient/direction/line-search temps.
@@ -149,6 +152,7 @@ class BlockPlan:
     history: int
     budget_bytes: int
     budget_source: str       # env | backend | fallback | override
+    resident_bytes: int      # held beside every bucket: the flat-order map
     buckets: Tuple[BucketPlan, ...]
 
     @property
@@ -183,6 +187,7 @@ class BlockPlan:
             "history": self.history,
             "budget_bytes": self.budget_bytes,
             "budget_source": self.budget_source,
+            "resident_bytes": self.resident_bytes,
             "lane_chunk": self.lane_chunk,
             "passes": self.passes,
             "peak_bytes": self.peak_bytes,
@@ -203,9 +208,11 @@ def plan_block_ladder(
     coordinate: str = "re",
     dtype: str = "",
     double_buffer: bool = True,
+    resident_bytes: int = 0,
 ) -> BlockPlan:
     """Plan a K-lane sweep over a bucket ladder of ``(E_b, S_b, K_b)``
-    shapes. Pure byte arithmetic — nothing is staged, nothing traced."""
+    shapes, ``resident_bytes`` of the budget being held beside every
+    bucket. Pure byte arithmetic — nothing is staged, nothing traced."""
     if lanes < 1:
         raise ValueError(f"lanes must be >= 1, got {lanes}")
     if hbm_budget_bytes is None:
@@ -219,7 +226,7 @@ def plan_block_ladder(
     for bi, (e, s, w) in enumerate(bucket_shapes):
         data = block_data_bytes(e, s, w, itemsize)
         lane = lane_state_bytes(e, dim, itemsize, history)
-        base = data_copies * data
+        base = int(resident_bytes) + data_copies * data
         headroom = budget - base
         # each lane costs a tiled copy of the block plus its solver
         # state (the flattened-lane program; module docstring)
@@ -239,6 +246,7 @@ def plan_block_ladder(
     return BlockPlan(coordinate=coordinate, lanes=int(lanes), dim=int(dim),
                      dtype=str(dtype), history=int(history),
                      budget_bytes=int(budget), budget_source=source,
+                     resident_bytes=int(resident_bytes),
                      buckets=tuple(buckets))
 
 
@@ -257,7 +265,8 @@ def plan_for_dataset(dataset, *, lanes: int, history: int = 10,
         shapes, lanes=lanes, dim=dataset.projected_dim,
         itemsize=dt.itemsize, history=history,
         hbm_budget_bytes=hbm_budget_bytes, coordinate=coordinate,
-        dtype=str(dt), double_buffer=double_buffer)
+        dtype=str(dt), double_buffer=double_buffer,
+        resident_bytes=4 * dataset.num_flat_samples)
 
 
 # -- plan accounting for the RunReport `re_plan` section ---------------------

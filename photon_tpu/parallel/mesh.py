@@ -336,26 +336,26 @@ def replicate_from_process_local(x, mesh: Mesh):
 
 # -- entity-block padding + placement (random-effect path) -------------------
 
-def pad_entities(ds, multiple: int, num_flat_samples: Optional[int] = None):
+def pad_entities(ds, multiple: int):
     """Pad each entity block's row dim (and the passive rows) of a
     RandomEffectDataset so all shard evenly; pad rows carry zero weights,
-    out-of-range entity rows, and scatter rows at the drop sentinel
-    ``num_flat_samples`` (the 'n on pads' invariant of sample_rows)."""
-    from photon_tpu.game.random_effect import EntityBlock, RandomEffectDataset
+    out-of-range entity rows, and flat rows at ``n`` (the 'n on pads'
+    invariant of sample_rows). Every slot after a padded bucket moves, so
+    the flat-order map is derived anew."""
+    from photon_tpu.game.random_effect import (
+        EntityBlock,
+        RandomEffectDataset,
+        flat_source_map,
+    )
 
     E = ds.num_entities
+    n = ds.num_flat_samples
     Ppas = ds.passive_entity.shape[0]
     P_pad = pad_to_multiple(Ppas, multiple)
 
     def pad0(a, rows, fill=0):
         widths = [(0, rows)] + [(0, 0)] * (a.ndim - 1)
         return jnp.pad(a, widths, constant_values=fill)
-
-    def sentinel(rows_arr):
-        if num_flat_samples is not None:
-            return num_flat_samples
-        # max is safe only when build-time pads (== n) exist; max+1 always is
-        return int(jnp.max(rows_arr)) + 1 if rows_arr.size else 0
 
     blocks = []
     changed = P_pad != Ppas
@@ -373,21 +373,23 @@ def pad_entities(ds, multiple: int, num_flat_samples: Optional[int] = None):
             labels=pad0(blk.labels, e),
             offsets=pad0(blk.offsets, e),
             weights=pad0(blk.weights, e),
-            sample_rows=pad0(blk.sample_rows, e, fill=sentinel(blk.sample_rows)),
+            sample_rows=pad0(blk.sample_rows, e, fill=n),
             entity_rows=pad0(blk.entity_rows, e, fill=E),  # out of range -> drop
         ))
     if not changed:
         return ds
 
     eP = P_pad - Ppas
+    passive_rows = pad0(ds.passive_rows, eP, fill=n)
     return RandomEffectDataset(
         blocks=tuple(blocks),
         passive_features=F.SparseFeatures(pad0(ds.passive_features.indices, eP),
                                           pad0(ds.passive_features.values, eP)),
         passive_entity=pad0(ds.passive_entity, eP, fill=E),
-        passive_rows=pad0(ds.passive_rows, eP,
-                          fill=sentinel(ds.passive_rows)),
+        passive_rows=passive_rows,
         projection=ds.projection,
+        flat_source=jnp.asarray(flat_source_map(
+            [b.sample_rows for b in blocks], passive_rows, n)),
     )
 
 
@@ -408,8 +410,7 @@ def entity_axis_assignment(entity_ids: Sequence, mesh: Mesh,
     return entity_shards(entity_ids, axis_size(mesh, axis))
 
 
-def shard_entity_blocks(ds, mesh: Mesh, axis: Optional[str] = None,
-                        num_flat_samples: Optional[int] = None):
+def shard_entity_blocks(ds, mesh: Mesh, axis: Optional[str] = None):
     """Pad + place a RandomEffectDataset with entities (and passive rows)
     sharded over ``axis`` — the static replacement for the reference's
     entity co-partitioning (RandomEffectDatasetPartitioner.scala:44).
@@ -422,7 +423,7 @@ def shard_entity_blocks(ds, mesh: Mesh, axis: Optional[str] = None,
     before calling this."""
     if axis is None:
         axis = ENTITY_AXIS if ENTITY_AXIS in mesh.axis_names else DATA_AXIS
-    ds = pad_entities(ds, axis_size(mesh, axis), num_flat_samples)
+    ds = pad_entities(ds, axis_size(mesh, axis))
 
     def put(a):
         spec = P(axis, *([None] * (a.ndim - 1)))
@@ -437,6 +438,8 @@ def shard_entity_blocks(ds, mesh: Mesh, axis: Optional[str] = None,
         # the projection's entity dim is not padded — replicate it (it is
         # only consulted on the host and for scoring-frame projection)
         projection=jax.device_put(ds.projection, replicated(mesh)),
+        # every flat row's slot: the score's one gather reads the map whole
+        flat_source=jax.device_put(ds.flat_source, replicated(mesh)),
     )
 
 

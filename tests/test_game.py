@@ -2,6 +2,7 @@
 coordinate descent on synthetic data — the role of GameEstimatorIntegTest /
 GameTrainingDriverIntegTest's fixed-and-random-effect cases."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -286,14 +287,33 @@ def _lane_stack(a, lanes):
     return jnp.stack([(j + 1) * a for j in range(3)]) if lanes else a
 
 
+def _held_slots(ds, n):
+    """(flat row, slot) of every non-pad slot, slots numbered through the
+    buckets in order, then the passive rows."""
+    rows = np.concatenate([np.asarray(b.sample_rows).ravel()
+                           for b in ds.blocks]
+                          + [np.asarray(ds.passive_rows)])
+    slots = np.flatnonzero(rows < n)
+    return rows[slots], slots, len(rows)
+
+
+def _rows_to_flat(ds, block_values, passive_values, lanes):
+    """``ds.rows_to_flat``, a lane at a time under a leading lane axis."""
+    back = jax.vmap(ds.rows_to_flat) if lanes else ds.rows_to_flat
+    return back(list(block_values), passive_values)
+
+
 @pytest.mark.parametrize("lanes", [False, True], ids=["flat", "lanes"])
 def test_flat_rows_read_zero_on_pads_and_pads_are_dropped(skewed_blocks,
                                                            lanes):
     """``EntityBlock.rows_from_flat`` reads every pad slot as 0 and every
-    real slot as its flat row; ``add_rows_to_flat`` drops every pad."""
+    real slot as its flat row; ``rows_to_flat`` reads no pad."""
     ds, n, _ = skewed_blocks
     flat = _lane_stack(jnp.arange(1.0, n + 1.0), lanes)  # zero nowhere
-    for blk in ds.blocks:
+    nowhere = [_lane_stack(jnp.zeros(b.sample_rows.shape), lanes)
+               for b in ds.blocks]
+    no_passive = _lane_stack(jnp.zeros(ds.passive_rows.shape), lanes)
+    for at, blk in enumerate(ds.blocks):
         rows_of = np.asarray(blk.sample_rows)
         pad = rows_of == n
         got = np.asarray(blk.rows_from_flat(flat, lanes))
@@ -303,9 +323,11 @@ def test_flat_rows_read_zero_on_pads_and_pads_are_dropped(skewed_blocks,
             got[..., ~pad], np.asarray(flat)[..., rows_of[~pad]])
         # pads carry weight 0: the same slots the solve ignores
         assert np.all(np.asarray(blk.weights)[pad] == 0.0)
+        # ones in this bucket's every slot, pads too: only its samples
+        # come back, and a pad's value lands on no row
         ones = _lane_stack(jnp.ones(rows_of.shape), lanes)
-        back = np.asarray(blk.add_rows_to_flat(
-            jnp.zeros(flat.shape), ones, lanes))
+        back = np.asarray(_rows_to_flat(
+            ds, nowhere[:at] + [ones] + nowhere[at + 1:], no_passive, lanes))
         assert back.shape == flat.shape
         want = np.zeros(n)
         want[rows_of[~pad]] = 1.0
@@ -316,24 +338,19 @@ def test_flat_rows_read_zero_on_pads_and_pads_are_dropped(skewed_blocks,
 @pytest.mark.parametrize("lanes", [False, True], ids=["flat", "lanes"])
 def test_blocks_and_passive_rows_partition_the_flat_frame(skewed_blocks,
                                                           lanes):
-    """Gathering a flat vector into every bucket and scatter-adding it
-    back, plus the passive rows, returns the vector: each flat row is
-    reached exactly once (what ``data_loss_at`` relies on)."""
+    """Gathering a flat vector into every bucket and the passive slots
+    and taking it back to flat order returns the vector: each flat row is
+    held by exactly one slot (what ``data_loss_at`` and the one-gather
+    score rely on)."""
     ds, n, _ = skewed_blocks
     rng = np.random.default_rng(0)
     flat = _lane_stack(jnp.asarray(rng.normal(size=n)), lanes)
-    back = jnp.zeros(flat.shape)
-    reached = jnp.zeros(flat.shape)
-    for blk in ds.blocks:
-        rows = blk.rows_from_flat(flat, lanes)
-        back = blk.add_rows_to_flat(back, rows, lanes)
-        reached = blk.add_rows_to_flat(reached, jnp.ones(rows.shape), lanes)
-    passive = np.asarray(ds.passive_rows)
-    passive = passive[passive < n]
-    back = back.at[..., passive].add(flat[..., passive])
-    reached = reached.at[..., passive].add(1.0)
-    np.testing.assert_array_equal(np.asarray(reached), 1.0)
+    rows = [blk.rows_from_flat(flat, lanes) for blk in ds.blocks]
+    passive = flat.at[..., ds.passive_rows].get(mode="fill", fill_value=0.0)
+    back = _rows_to_flat(ds, rows, passive, lanes)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(flat))
+    np.testing.assert_array_equal(
+        np.bincount(_held_slots(ds, n)[0], minlength=n), 1)
 
 
 @pytest.mark.parametrize("lanes", [False, True], ids=["table", "lanes"])
@@ -368,16 +385,16 @@ def test_table_rows_read_the_fill_on_pad_rows_and_pad_rows_are_dropped(
     assert np.all(np.asarray(back)[..., ~active, :] == -7.0)
 
 
-def _mapping_reads(path):
+def _mapping_reads(path, fields=("sample_rows", "flat_source")):
     """(line, what) of every direct use of the flat/ladder mapping in a
-    source file: an attribute read of ``sample_rows``, or an
+    source file: an attribute read of one of ``fields``, or an
     ``.at[...]`` whose index mentions ``entity_rows``."""
     import ast
 
     found = []
     for node in ast.walk(ast.parse(open(path).read(), path)):
-        if isinstance(node, ast.Attribute) and node.attr == "sample_rows":
-            found.append((node.lineno, "sample_rows"))
+        if isinstance(node, ast.Attribute) and node.attr in fields:
+            found.append((node.lineno, node.attr))
         if (isinstance(node, ast.Subscript)
                 and isinstance(node.value, ast.Attribute)
                 and node.value.attr == "at"
@@ -388,12 +405,18 @@ def _mapping_reads(path):
     return found
 
 
+def _package_root():
+    import os
+
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "photon_tpu")
+
+
 def _mapping_free_sources():
     import glob
     import os
 
-    root = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "photon_tpu")
+    root = _package_root()
     return [os.path.join(root, "game", "coordinate.py")] + sorted(
         glob.glob(os.path.join(root, "bayes", "*.py")))
 
@@ -402,22 +425,182 @@ def _mapping_free_sources():
     "path", _mapping_free_sources(),
     ids=lambda p: "/".join(p.split("/")[-2:]))
 def test_the_flat_ladder_mapping_is_read_only_through_entity_block(path):
-    """How ladder order maps to flat order is ``EntityBlock``'s to know
-    (its four mapping methods): the solve, score, objective, loss and
-    variance programs index neither ``sample_rows`` nor by
-    ``entity_rows`` themselves, so a re-layout is a change to
-    ``game/random_effect.py`` alone."""
+    """How ladder order maps to flat order is ``game/random_effect.py``'s
+    to know (its four mapping methods): the solve, score, objective, loss
+    and variance programs index neither ``sample_rows`` nor
+    ``flat_source`` nor by ``entity_rows`` themselves, so a re-layout is
+    a change to ``game/random_effect.py`` alone."""
     assert _mapping_reads(path) == []
+
+
+def test_flat_source_is_read_in_random_effect_and_mesh_only():
+    """The inverse map has two homes: ``game/random_effect.py`` (built,
+    read by ``rows_to_flat``) and ``parallel/mesh.py`` (re-derived when
+    entity padding moves the slots, placed). Nothing else in the package
+    reads it."""
+    import glob
+    import os
+
+    root = _package_root()
+    homes = {os.path.join(root, "game", "random_effect.py"),
+             os.path.join(root, "parallel", "mesh.py")}
+    sources = set(glob.glob(os.path.join(root, "**", "*.py"),
+                            recursive=True))
+    assert homes <= sources
+    reads = {os.path.relpath(path, root): _mapping_reads(
+        path, fields=("flat_source",)) for path in sources - homes}
+    assert {p: r for p, r in reads.items() if r} == {}
+    for path in homes:
+        assert _mapping_reads(path, fields=("flat_source",))
 
 
 def test_the_mapping_walk_sees_a_direct_read(tmp_path):
     src = tmp_path / "fork.py"
     src.write_text(
-        "def f(blk, flat, table):\n"
+        "def f(blk, ds, flat, table):\n"
         "    a = flat.at[blk.sample_rows].get(mode='fill', fill_value=0.0)\n"
-        "    return a, table.at[:, blk.entity_rows].set(a, mode='drop')\n")
+        "    b = flat[ds.flat_source]\n"
+        "    return a, b, table.at[:, blk.entity_rows].set(a, mode='drop')\n")
     assert sorted(what for _, what in _mapping_reads(str(src))) == [
-        ".at[entity_rows]", "sample_rows"]
+        ".at[entity_rows]", "flat_source", "sample_rows"]
+
+
+_SCORE_LAYOUTS = ["dense", "sparse", "no-passive", "dropped", "mesh-padded"]
+
+
+def _score_layout(layout, dtype):
+    """(dataset, n) of one shape the builder can make: dense-local or
+    sparse buckets, passive rows or none, capped entities whose overflow
+    is DROPPED (``keep_passive_data=False``: flat rows no slot holds), or
+    entity-padded as ``parallel/mesh`` pads for a mesh."""
+    from photon_tpu.game.dataset import EntityVocabulary
+    from photon_tpu.game.random_effect import build_random_effect_dataset
+    from photon_tpu.parallel.mesh import pad_entities
+
+    rng = np.random.default_rng(5)
+    n, d, ents = 1200, 5, 70
+    p = 1.0 / np.arange(1, ents + 1) ** 1.3
+    ent = rng.choice(ents, size=n, p=p / p.sum())
+
+    def whole(size):
+        # small whole numbers: a margin is exact in any order of summation
+        return rng.choice([-3.0, -2.0, -1.0, 1.0, 2.0, 3.0], size=size)
+
+    if layout == "sparse":
+        rows = []
+        for _ in range(n):
+            idx = np.sort(rng.choice(d, size=rng.integers(1, d),
+                                     replace=False)).astype(np.int32)
+            rows.append((idx, whole(len(idx))))
+    else:
+        rows = [(np.arange(d, dtype=np.int32), whole(d)) for _ in range(n)]
+    df = GameDataFrame(num_samples=n, response=rng.random(n),
+                       feature_shards={"u": FeatureShard(rows, d)},
+                       id_tags={"userId": [str(e) for e in ent]})
+    ds = build_random_effect_dataset(
+        df, RandomEffectDataConfiguration(
+            "userId", "u", max_entity_buckets=4,
+            active_data_upper_bound=None if layout == "no-passive" else 30,
+            keep_passive_data=layout != "dropped"),
+        EntityVocabulary(), dtype=dtype)
+    if layout == "mesh-padded":
+        padded = pad_entities(ds, 8)
+        assert padded is not ds
+        assert [b.num_rows for b in padded.blocks] != [
+            b.num_rows for b in ds.blocks]
+        ds = padded
+    return ds, n
+
+
+@pytest.mark.parametrize("layout", _SCORE_LAYOUTS)
+def test_flat_source_inverts_the_ladder(layout):
+    """``flat_source`` restricted to the rows some slot holds is a
+    bijection onto the non-pad slots; every other row points at the
+    trailing zero; no index is out of bounds."""
+    ds, n = _score_layout(layout, np.float64)
+    source = np.asarray(ds.flat_source)
+    rows, slots, total = _held_slots(ds, n)
+    assert source.shape == (n,) and source.dtype == np.int32
+    assert source.min() >= 0 and source.max() <= total
+    held = source < total
+    np.testing.assert_array_equal(np.sort(source[held]), slots)
+    np.testing.assert_array_equal(source[rows], slots)
+    n_passive = int(np.sum(np.asarray(ds.passive_rows) < n))
+    assert (n_passive > 0) == (layout not in ("no-passive", "dropped"))
+    assert (int(np.sum(~held)) > 0) == (layout == "dropped")
+
+
+def _score_program(ds, n, dtype):
+    """(the coordinate's jitted score program, a random coefficient
+    table, its dense flags)."""
+    from photon_tpu.game.coordinate import RandomEffectCoordinate
+
+    coord = RandomEffectCoordinate(ds, n, "userId", "u",
+                                   TaskType.LOGISTIC_REGRESSION)
+    table = jnp.asarray(np.random.default_rng(9).integers(
+        -4, 5, size=(ds.num_entities, ds.projected_dim)).astype(dtype))
+    return coord._score_fn, table, coord._dense_local_blocks
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64],
+                         ids=["float32", "float64"])
+@pytest.mark.parametrize("layout", _SCORE_LAYOUTS)
+def test_one_gather_score_is_the_reference_bit_for_bit(layout, dtype):
+    """The score program against numpy's placement of each slot's margin
+    at its ``sample_rows`` / ``passive_rows`` row (whole-number features
+    and coefficients, so a margin has one value however it is summed):
+    the same bits, 0 on rows no slot holds (``want`` is 0 there)."""
+    ds, n = _score_layout(layout, dtype)
+    score, table, dense = _score_program(ds, n, dtype)
+    if layout != "mesh-padded":   # the ELL width of padding is not local
+        assert all(dense) == (layout != "sparse")
+    got = np.asarray(score(ds, table))
+    assert got.shape == (n,) and got.dtype == dtype
+
+    coef = np.asarray(table)
+    want = np.zeros(n, dtype)
+    for blk in ds.blocks:
+        rows_of = np.asarray(blk.sample_rows)
+        ents = np.asarray(blk.entity_rows)
+        c = np.zeros((len(ents), coef.shape[1]), dtype)   # a pad row: 0
+        c[ents < ds.num_entities] = coef[ents[ents < ds.num_entities]]
+        margins = np.sum(
+            np.asarray(blk.features.values) * np.take_along_axis(
+                c[:, None, :], np.asarray(blk.features.indices), axis=2),
+            axis=-1)
+        want[rows_of[rows_of < n]] = margins[rows_of < n]
+    live = np.asarray(ds.passive_rows) < n
+    want[np.asarray(ds.passive_rows)[live]] = np.sum(
+        np.asarray(ds.passive_features.values)[live] * np.take_along_axis(
+            coef[np.asarray(ds.passive_entity)[live]],
+            np.asarray(ds.passive_features.indices)[live], axis=1), axis=-1)
+    assert np.any(want != 0.0)
+    np.testing.assert_array_equal(got, want)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("layout", _SCORE_LAYOUTS)
+def test_score_program_holds_one_gather_onto_the_flat_rows(layout):
+    """The way back to flat order is ONE gather with an ``[n]`` output
+    and no scatter of any kind, however many buckets the ladder has."""
+    ds, n = _score_layout(layout, np.float32)
+    score, table, _ = _score_program(ds, n, np.float32)
+    assert len(ds.blocks) > 1
+    eqns = list(_equations(jax.make_jaxpr(score)(ds, table).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert not [m for m in names if m.startswith("scatter")], names
+    onto_flat = [e for e in eqns if e.primitive.name == "gather"
+                 and e.outvars[0].aval.shape == (n,)]
+    assert len(onto_flat) == 1
+    slots = sum(b.sample_rows.size for b in ds.blocks) + len(ds.passive_rows)
+    assert onto_flat[0].invars[0].aval.shape == (slots + 1,)
 
 
 def test_random_effect_tron_matches_lbfgs(glmix):
@@ -605,8 +788,8 @@ def test_dense_local_score_matches_sparse_path(glmix):
     flags = coord._dense_local_blocks
     assert any(flags)   # user_feats rows are observed in full
     coefs = coord._pad_entity_rows(result.model["per-user"].coefficients)
-    s_dense = _re_score_builder(coord.n, flags)(coord.dataset, coefs)
-    s_sparse = _re_score_builder(coord.n, (False,) * len(flags))(
+    s_dense = _re_score_builder(flags)(coord.dataset, coefs)
+    s_sparse = _re_score_builder((False,) * len(flags))(
         coord.dataset, coefs)
     np.testing.assert_allclose(np.asarray(s_dense), np.asarray(s_sparse),
                                rtol=1e-6, atol=1e-8)

@@ -198,12 +198,25 @@ class TestPlannerBytes:
                                     hbm_budget_bytes=1 << 30)
         shapes = [(b.num_rows, b.max_samples, b.features.values.shape[-1])
                   for b in ds.blocks]
+        # the flat-order map (one int32 a flat row) is held beside every
+        # bucket: n = 300 rows
+        assert plan.resident_bytes == 4 * 300
         manual = hbm.plan_block_ladder(
             shapes, lanes=4, dim=ds.projected_dim, itemsize=8, history=10,
-            hbm_budget_bytes=1 << 30)
+            hbm_budget_bytes=1 << 30, resident_bytes=4 * 300)
         assert [b.to_dict() for b in plan.buckets] == \
             [b.to_dict() for b in manual.buckets]
         assert plan.dtype == "float64"
+
+    def test_resident_bytes_come_off_every_buckets_headroom(self):
+        base, per_lane = 2 * 2064, 2064 + 2688
+        kw = dict(lanes=4, dim=3, itemsize=8, history=10,
+                  hbm_budget_bytes=base + 3 * per_lane + 100)
+        assert hbm.plan_block_ladder([(4, 8, 3)], **kw).lane_chunk == 3
+        plan = hbm.plan_block_ladder([(4, 8, 3)], resident_bytes=101, **kw)
+        (b,) = plan.buckets
+        assert b.lane_chunk == 2 and plan.to_dict()["resident_bytes"] == 101
+        assert b.peak_bytes == 101 + base + 2 * per_lane
 
     def test_record_plan_feeds_run_report(self):
         from photon_tpu.obs.report import build_run_report, \
@@ -265,10 +278,11 @@ class TestSweptParity:
         # padded tail (repeated last λ) that must never be published
         coord, ds, _ = _coordinate()
         K = len(GRID)
-        budget = max(2 * b.data_bytes + 3 * (b.data_bytes + b.lane_bytes)
-                     for b in hbm.plan_for_dataset(
-                         ds, lanes=K, history=10,
-                         hbm_budget_bytes=1 << 30).buckets)
+        roomy = hbm.plan_for_dataset(ds, lanes=K, history=10,
+                                     hbm_budget_bytes=1 << 30)
+        budget = roomy.resident_bytes + max(
+            2 * b.data_bytes + 3 * (b.data_bytes + b.lane_bytes)
+            for b in roomy.buckets)
         plan = hbm.plan_for_dataset(ds, lanes=K, history=10,
                                     hbm_budget_bytes=budget)
         assert plan.lane_chunk == 3 and plan.degraded

@@ -126,7 +126,7 @@ def test_entity_sharded_blocks_cover_all_devices(glmix, devices8):  # noqa: F811
     mesh = M.create_mesh()
     est = glmix_estimator(num_iterations=1)
     est.mesh = mesh
-    est.fit(train)
+    model = est.fit(train)[0].model["per-user"]
     from photon_tpu.game.coordinate import RandomEffectCoordinate
     # rebuild a coordinate directly to inspect placement
     ds = est._re_datasets["per-user"]
@@ -137,6 +137,18 @@ def test_entity_sharded_blocks_cover_all_devices(glmix, devices8):  # noqa: F811
     for blk in coord.dataset.blocks:
         sharding = blk.labels.sharding
         assert len(sharding.device_set) == 8, "entity block not spread over mesh"
+    # the flat-order map, re-derived for the padded buckets, is whole on
+    # every device, and the score's one gather over the sharded margins
+    # gives the one-device score
+    assert coord.dataset.flat_source.sharding.is_fully_replicated
+    assert [b.num_rows for b in coord.dataset.blocks] != [
+        b.num_rows for b in ds.blocks]
+    single = RandomEffectCoordinate(ds, train.num_samples, "userId",
+                                    "user_feats", TaskType.LOGISTIC_REGRESSION)
+    # (to the last bits: a margin's K-sum is ordered by the batch width)
+    np.testing.assert_allclose(np.asarray(coord.score(model)),
+                               np.asarray(single.score(model)),
+                               rtol=1e-12, atol=1e-13)
 
 
 def test_model_parallel_margins_allreduce(rng, devices8):
