@@ -47,6 +47,7 @@ from photon_tpu.game.random_effect import (
     build_random_effect_dataset,
 )
 from photon_tpu.game.scoring import GameScorer
+from photon_tpu.obs import solver as _obs_solver
 from photon_tpu.optim.problem import GLMOptimizationConfiguration
 from photon_tpu.types import TaskType
 from photon_tpu.utils.timing import Timed
@@ -419,6 +420,9 @@ class GameEstimator:
         cid = cids[0]
         shard_id = self.coordinate_configs[cid].data.feature_shard_id
         swept = only.update_model_swept(None, None, lams)
+        # the lanes' tracker by reference, like a sweep's update
+        # (game/descent.py): obs.solver.lane_counts() sums it when asked
+        _obs_solver.record(cid, only.last_tracker, sweep=0)
         evaluations: List[Optional[Dict[str, float]]] = [None] * len(lams)
         if validation_df is not None:
             from photon_tpu.game.coordinate import _fixed_score_lanes
@@ -434,8 +438,9 @@ class GameEstimator:
                                         jnp.asarray(swept.coefs))
             evaluations = [suite.evaluate(scores[i]).evaluations
                            for i in range(len(lams))]
-        iters = np.asarray(swept.stacked.iterations)
-        reasons = np.asarray(swept.stacked.reason)
+        # per-lane scalars the update already brought to the host
+        iters = only.last_lane_result.iterations
+        reasons = only.last_lane_result.reason
         results = []
         for i, w in enumerate(lams):
             gm = GameModel({cid: FixedEffectModel(swept.models[i], shard_id)})

@@ -37,7 +37,9 @@ and names none (the bucket is the ``block`` attribute of the host span
 when telemetry is on, nothing otherwise): ``fe/args`` / ``re/args`` (the
 eager programs that build a solve's arguments), ``fe/solve`` / ``re/solve``
 (the dispatch), ``fe/outcome`` / ``re/outcome`` (the blocking scalar read of
-the failure code), ``fe/score`` / ``re/score``.
+the failure code), ``fe/score`` / ``re/score``; a fixed effect's lambda-lane
+update is ``fe/args``, ``fe/solve_swept``, ``fe/outcome`` (its ONE read of
+the lanes' scalars) and ``fe/score_lanes``.
 """
 
 from __future__ import annotations
@@ -297,8 +299,12 @@ class FixedEffectCoordinate:
         best); otherwise every lane starts from ``prev``'s coefficients.
         Returns the :class:`~photon_tpu.optim.problem.SweptSolve`;
         per-lane failures stay per-lane (a poisoned lane freezes typed
-        without sinking its siblings). Sweep telemetry: ``sweep.*``
-        metrics + the RunReport ``sweep`` section.
+        without sinking its siblings). The update crosses to the host
+        once, under ``fe/outcome``, and leaves ``last_lane_result`` (the
+        stacked result, per-lane scalars as host arrays),
+        ``last_tracker`` (the lanes' ``lane_counts()``) and
+        ``last_lane_failures``. Sweep telemetry: ``sweep.*`` metrics +
+        the RunReport ``sweep`` section.
         """
         if self._model_sharded:
             raise ValueError(
@@ -319,12 +325,25 @@ class FixedEffectCoordinate:
             swept = self.problem.solve_swept(
                 batch, weights, initial=init, initial_lanes=initial_lanes,
                 dim=self.dim, dtype=batch.labels.dtype)
-        # host boundary: per-lane scalars for telemetry + failure typing
-        iters = np.asarray(swept.stacked.iterations)
-        reasons = np.asarray(swept.stacked.reason)
-        fails = (np.zeros_like(iters) if swept.stacked.failure is None
-                 else np.asarray(swept.stacked.failure))
-        losses = np.asarray(swept.stacked.value)
+        # host boundary: every per-lane scalar the host needs (telemetry,
+        # failure typing, the lane counts) in ONE blocking transfer
+        stacked = swept.stacked
+        with _obs_annotate("fe/outcome"):
+            iters, reasons, evals, losses, fails = jax.device_get(  # host-sync-ok: the coordinate boundary
+                (stacked.iterations, stacked.reason, stacked.num_fun_evals,
+                 stacked.value, stacked.failure))
+        if fails is None:
+            fails = np.zeros_like(iters)
+        # the swept counterpart of ``last_result`` / ``last_tracker``: the
+        # stacked result with its per-lane scalars already on the host,
+        # and the K lanes as ONE vmapped loop's bucket (``lane_counts()``)
+        from photon_tpu.optim.tracking import RandomEffectOptimizationTracker
+        self.last_lane_result = stacked._replace(
+            iterations=iters, reason=reasons, num_fun_evals=evals,
+            value=losses, failure=fails)
+        self.last_tracker = RandomEffectOptimizationTracker(
+            iterations=iters, reasons=reasons,
+            bucket_rows=(np.arange(len(iters)),))
         self.last_lane_failures = [
             None if code == FailureMode.NONE else FailureMode(int(code))
             for code in fails]

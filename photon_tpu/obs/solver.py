@@ -50,11 +50,12 @@ def record(coordinate: str, tracker, **meta: Any) -> None:
 
 def lane_counts() -> Dict[str, Dict[str, int]]:
     """``{coordinate: {"sum", "capacity", "trips"}}``: the buffered
-    random-effect updates' ``lane_counts()`` added up, which is ONE fit's
-    (a later fit's updates replace an earlier fit's): what its vmapped
-    per-entity loops ran against what its entities needed. Pays the host
-    transfers and leaves the buffer as it is — ask after a fit, not inside
-    a sweep. Empty with telemetry off."""
+    updates' ``lane_counts()`` added up, which is ONE fit's (a later fit's
+    updates replace an earlier fit's): what its vmapped loops ran against
+    what their lanes needed — a random effect's per-entity loops, a bucket
+    each, and a fixed effect's lambda lanes (``GameEstimator.fit_swept``),
+    one loop. Pays the host transfers and leaves the buffer as it is — ask
+    after a fit, not inside a sweep. Empty with telemetry off."""
     with _LOCK:
         entries = [e for e in _BUFFER.values()
                    if e["kind"] == "random_effect"]

@@ -158,7 +158,9 @@ def matvec_lanes(x: FeatureMatrix, thetas: Array) -> Array:
     gather over the shared ``x.indices`` plan — the batch is read once
     regardless of K. Model-sharded layouts train one model per mesh and
     are refused typed (sweep lanes would multiply the sharded theta
-    footprint K-fold).
+    footprint K-fold). The precision is stated: two matrix operands make
+    this a real dot, which a TPU would otherwise run as ONE bfloat16 pass
+    (optim/batched.LANE_MATMUL_PRECISION says the same of the lane solvers).
     """
     if isinstance(x, ModelShardedSparse):
         raise NotImplementedError(
@@ -168,7 +170,8 @@ def matvec_lanes(x: FeatureMatrix, thetas: Array) -> Array:
     if isinstance(x, SparseFeatures):
         gathered = jnp.take(thetas, x.indices, axis=1)   # [K, n, k]
         return jnp.sum(x.values[None, :, :] * gathered, axis=-1)
-    return jnp.einsum("kd,nd->kn", thetas, x)
+    return jnp.einsum("kd,nd->kn", thetas, x,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _ms_scatter(x: ModelShardedSparse, w: Array, square: bool) -> Array:

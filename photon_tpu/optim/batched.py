@@ -28,6 +28,14 @@ we want:
   singleton-lane program takes exactly the scalar solver's iteration
   count.
 
+Precision is stated, not defaulted: with two matrix operands the lane
+contractions are real dots to XLA, and on a TPU a float32 dot at default
+precision is ONE bfloat16 pass on the MXU (the scalar solver's
+matrix-vector products are not: the compiler runs them on the vector unit
+in float32). The lane solvers are therefore traced under
+``LANE_MATMUL_PRECISION``, so every ``dot_general`` of a lane program
+carries it and a lane reads what its scalar fit reads.
+
 On a mesh the whole vmapped solve runs inside ONE outer shard_map over
 the sample axes; the per-evaluation reduction is a single staged
 ICI→DCN psum of the packed ``[K, d+1]`` value/gradient block (the
@@ -49,6 +57,16 @@ from photon_tpu.optim import lbfgs, owlqn
 from photon_tpu.optim.base import SolverConfig, SolverResult
 
 Array = jax.Array
+
+# what every dot of a lane program is traced at (module docstring)
+LANE_MATMUL_PRECISION = "highest"
+
+
+def _vmap_lanes(one_lane: Callable, *lane_args: Array):
+    """``vmap(one_lane)`` over the leading lane axis, traced so that every
+    ``dot_general`` in it states ``LANE_MATMUL_PRECISION``."""
+    with jax.default_matmul_precision(LANE_MATMUL_PRECISION):
+        return jax.vmap(one_lane)(*lane_args)
 
 # value_and_gradient(coef [d], hyper) -> (value, grad [d]) for ONE lane;
 # the data batch is closed over so every lane shares it.
@@ -137,13 +155,13 @@ def minimize_lanes(value_and_gradient: LaneValueAndGradient,
             vg = lambda c: value_and_gradient(c, Hyper(l2_weight=l2k))
             return owlqn.minimize(vg, x0, l1_weight=l1k, config=config)
 
-        return jax.vmap(one_lane)(x0_lanes, l2, l1_lanes)
+        return _vmap_lanes(one_lane, x0_lanes, l2, l1_lanes)
 
     def one_lane(x0, l2k):
         vg = lambda c: value_and_gradient(c, Hyper(l2_weight=l2k))
         return lbfgs.minimize(vg, x0, config=config)
 
-    return jax.vmap(one_lane)(x0_lanes, l2)
+    return _vmap_lanes(one_lane, x0_lanes, l2)
 
 
 def minimize_lanes_meshed(objective: GLMObjective,
@@ -185,12 +203,12 @@ def minimize_lanes_meshed(objective: GLMObjective,
             def one_lane(x0, l2k, l1k):
                 vg = lambda c: lane_vg(c, Hyper(l2_weight=l2k))
                 return owlqn.minimize(vg, x0, l1_weight=l1k, config=config)
-            return jax.vmap(one_lane)(x0_l, l2_l, l1_l)
+            return _vmap_lanes(one_lane, x0_l, l2_l, l1_l)
 
         def one_lane(x0, l2k):
             vg = lambda c: lane_vg(c, Hyper(l2_weight=l2k))
             return lbfgs.minimize(vg, x0, config=config)
-        return jax.vmap(one_lane)(x0_l, l2_l)
+        return _vmap_lanes(one_lane, x0_l, l2_l)
 
     specs = hier._batch_specs(sharded_batch, sample_axes)
     l1_lanes = l1 if l1 is not None else jnp.zeros_like(l2)

@@ -41,13 +41,19 @@ Array = jax.Array
 
 
 class SweptSolve(NamedTuple):
-    """Output of :meth:`GlmOptimizationProblem.solve_swept`: one model /
-    solver result per grid lane, plus the stacked device views."""
+    """Output of :meth:`GlmOptimizationProblem.solve_swept`: one model per
+    grid lane, plus the stacked device views."""
 
     models: List[GeneralizedLinearModel]   # per-lane, original space
-    results: List[SolverResult]            # per-lane views of ``stacked``
     stacked: SolverResult                  # every field has a [K] lane axis
     coefs: Array                           # [K, d] original-space stack
+
+    @property
+    def results(self) -> List[SolverResult]:
+        """Per-lane views of ``stacked``, sliced when asked: K x 7 eager
+        device programs, which no caller inside a fit pays for."""
+        from photon_tpu.optim import batched
+        return batched.split_lanes(self.stacked)
 
 
 def _validate_direct(task, opt: "OptimizerConfig", regularization) -> None:
@@ -494,11 +500,11 @@ class GlmOptimizationProblem:
         if not norm.is_identity:
             coefs = jax.vmap(lambda c: norm.transformed_space_to_model(
                 c, self.intercept_index))(coefs)
-        models = [GeneralizedLinearModel(Coefficients(coefs[i]), self.task)
-                  for i in range(k)]
-        return SweptSolve(models=models,
-                          results=batched.split_lanes(stacked),
-                          stacked=stacked, coefs=coefs)
+        # iterating a device array unstacks it in ONE program (an index a
+        # lane is two eager programs a lane)
+        models = [GeneralizedLinearModel(Coefficients(c), self.task)
+                  for c in coefs]
+        return SweptSolve(models=models, stacked=stacked, coefs=coefs)
 
     def run_streamed(
         self,

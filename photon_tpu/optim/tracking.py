@@ -76,7 +76,9 @@ class OptimizationStatesTracker:
 
 @dataclasses.dataclass
 class RandomEffectOptimizationTracker:
-    """Aggregate of per-entity solver outcomes for one coordinate update.
+    """Aggregate of per-entity solver outcomes for one coordinate update
+    (or of per-lambda-lane outcomes: a fixed effect's swept update is ONE
+    vmapped loop whose K lanes are its only bucket).
 
     ``iterations``/``reasons`` may be DEVICE arrays — the producing solve
     hands them over without a host sync, and the first summary accessor

@@ -418,7 +418,7 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              "serve/gather", "serve/score", "ingest/prepare/", "ingest/h2d/",
              "ingest/stats", "ingest.h2d_bytes", "fe/args", "re/args",
              "fe/outcome", "re/outcome", "cd/score", "cd/commit",
-             "cd/record"} | LINESEARCH
+             "cd/record", "fe/solve_swept", "fe/score_lanes"} | LINESEARCH
     for _, _, steps, dense, sparse in SOLVERS.values():
         names |= steps | dense | (sparse or set())
     missing = sorted(n for n in names if n not in text)
@@ -431,5 +431,6 @@ def test_perf_md_lists_the_lane_counts_and_their_readers():
     with open(os.path.join(REPO, "PERF.md")) as f:
         text = f.read()
     for name in ("obs.solver.lane_counts", "`sum`", "`capacity`", "`trips`",
-                 "re_lane_occupancy", "re_solver_trips"):
+                 "re_lane_occupancy", "re_solver_trips",
+                 "sweep_lane_occupancy", "last_lane_result"):
         assert name in text, name
