@@ -85,6 +85,9 @@ class Sizes:
     n_cli: int = 5_000           # rows written as Avro for cli.train
     kernel_rows: int = 8_192
     kernel_dense_dims: Tuple[int, ...] = (256, 1024)
+    # epsilon's width: no multiple of 128, and rows that are no multiple
+    # of the 256-row tile the kernel picks there (<= kernel_rows)
+    kernel_ragged_shape: Tuple[int, int] = (8_100, 2_000)
     kernel_sparse_dim: int = 4_096
     kernel_ell_width: int = 16
     kernel_serving_rows: int = 128
@@ -576,10 +579,12 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
     out = {}
 
     def logistic_oracle(margins64, x_t_times):
-        """float64 value and gradient from float64 margins."""
-        z = margins64 + off
-        value = float(np.sum(w * (np.logaddexp(0.0, z) - y * z)))
-        dz = w * (1.0 / (1.0 + np.exp(-z)) - y)
+        """float64 value and gradient from float64 margins (of the
+        first ``len(margins64)`` rows)."""
+        m = len(margins64)
+        z = margins64 + off[:m]
+        value = float(np.sum(w[:m] * (np.logaddexp(0.0, z) - y[:m] * z)))
+        dz = w[:m] * (1.0 / (1.0 + np.exp(-z)) - y[:m])
         return value, x_t_times(dz)
 
     def compare(name, got, xla, want):
@@ -599,20 +604,23 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
               f"2x the XLA path + 1e-4")
         out[name] = {"pallas_rel_err": k_err, "xla_rel_err": x_err}
 
-    for d in sizes.kernel_dense_dims:
-        x = rng.normal(size=(n, d)).astype(np.float32) / np.sqrt(d)
+    for m, d in ([(n, d) for d in sizes.kernel_dense_dims]
+                 + [sizes.kernel_ragged_shape]):
+        x = rng.normal(size=(m, d)).astype(np.float32) / np.sqrt(d)
         coef = (rng.normal(size=d) * 0.4).astype(np.float32)
-        args = (LogisticLoss, jnp.asarray(x), jnp.asarray(y),
-                jnp.asarray(off), jnp.asarray(w), jnp.asarray(coef))
+        args = (LogisticLoss, jnp.asarray(x), jnp.asarray(y[:m]),
+                jnp.asarray(off[:m]), jnp.asarray(w[:m]), jnp.asarray(coef))
         v1, g1 = pallas_glm.fused_dense_value_grad(*args,
                                                    interpret=interpret)
-        v0, g0 = aggregators.value_and_gradient(*args, no_normalization())
+        with pallas_glm.disabled():     # XLA's two passes, at any width
+            v0, g0 = aggregators.value_and_gradient(*args,
+                                                    no_normalization())
         x64 = x.astype(np.float64)
         v, g = logistic_oracle(x64 @ coef.astype(np.float64),
                                lambda dz: x64.T @ dz)
-        compare(f"fused_dense_value_grad[{n}x{d}] value",
+        compare(f"fused_dense_value_grad[{m}x{d}] value",
                 np.asarray([v1]), np.asarray([v0]), np.asarray([v]))
-        compare(f"fused_dense_value_grad[{n}x{d}] grad", g1, g0, g)
+        compare(f"fused_dense_value_grad[{m}x{d}] grad", g1, g0, g)
 
     d, k = sizes.kernel_sparse_dim, sizes.kernel_ell_width
     idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
@@ -650,8 +658,9 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
               if key.startswith(("kernels.pallas_hits",
                                  "kernels.xla_fallbacks"))}
     say("kernels.pallas_hits / kernels.xla_fallbacks on the main path: "
-        + (str(routed) if routed else "none (the kernels are opt-in; the "
-           "fit and the server above ran the XLA path)"))
+        + (str(routed) if routed else "none (no dense fixed effect of an "
+           "admitted width was solved above, and the serving kernel is "
+           "opt-in)"))
     out["interpret"] = interpret
     return out
 
@@ -678,9 +687,11 @@ def mesh_phase(trained: dict) -> dict:
     theta0 = M.shard_coef_model_parallel(
         jnp.zeros((coord.dim,), jnp.float32), mesh,
         padded_dim=coord._dim_padded)
-    hlo = coord.problem._solve_fn.lower(
-        theta0, coord.batch, jnp.asarray(L2, jnp.float32),
-        jnp.asarray(0.0, jnp.float32)).compile().as_text()
+    from photon_tpu.ops import pallas_glm
+    with pallas_glm.disabled():      # as ``problem.run`` traces a mesh solve
+        hlo = coord.problem._solve_fn_for(False).lower(
+            theta0, coord.batch, jnp.asarray(L2, jnp.float32),
+            jnp.asarray(0.0, jnp.float32)).compile().as_text()
     check("all-reduce" in hlo, "the meshed fixed-effect solve's HLO has "
           "an all-reduce")
 

@@ -47,32 +47,14 @@ from photon_tpu.ops.normalization import NormalizationContext
 
 Array = jax.Array
 
-_WARNED_REFUSED: set = set()
 
-
-def _kernel_counter(name: str, path: str) -> None:
+def _kernel_counter(name: str, path: str, **labels: str) -> None:
     """Tick a kernel-activation counter. Runs at TRACE time (the routing
     decision is a Python branch), so the count is per compiled program,
     not per execution — exactly what "did this solve use the fused
     kernel" needs, with zero on-device cost."""
     from photon_tpu.obs.metrics import registry
-    registry.counter(f"kernels.{name}", path=path).inc()
-
-
-def _warn_kernel_refused(path: str, stacklevel: int = 3) -> None:
-    """Warn ONCE per path when PHOTON_TPU_PALLAS_GLM=1 asked for the
-    fused kernel but ``_supported`` refused the operands — a silent
-    performance downgrade the counters record and this makes audible."""
-    if path in _WARNED_REFUSED:
-        return
-    _WARNED_REFUSED.add(path)
-    import warnings
-    warnings.warn(
-        f"PHOTON_TPU_PALLAS_GLM=1 requested the fused Pallas kernel but "
-        f"the {path} operands were refused (dtype/normalization/vmap/"
-        f"mesh or dimension gate); falling back to the two-pass XLA "
-        f"path. kernels.xla_fallbacks{{path={path}}} counts these.",
-        RuntimeWarning, stacklevel=stacklevel)
+    registry.counter(f"kernels.{name}", path=path, **labels).inc()
 
 
 def effective_coefficients(coef: Array, norm: NormalizationContext) -> Tuple[Array, Array]:
@@ -128,34 +110,25 @@ def value_and_gradient(
     Reference: ValueAndGradientAggregator.calculateValueAndGradient
     (:240-255 RDD path, :266-279 local path) — here one fused kernel.
 
-    With ``PHOTON_TPU_PALLAS_GLM=1`` the dense / identity-normalization /
-    f32 case runs the Pallas single-HBM-pass kernel
-    (ops/pallas_glm.py) instead of XLA's two contractions over X, and
-    the ELL-sparse case runs its one-nnz-pass analogue. The flag is
-    read at trace time: toggling it mid-process does not affect
-    already-compiled solves. Routing decisions are counted into
-    ``kernels.pallas_hits`` / ``kernels.xla_fallbacks`` (trace-time
-    counters with a ``path`` label — one tick per compiled program, so
-    a silent fallback to the unfused path shows up in every RunReport).
+    On a TPU a dense, identity-normalised, float32, unbatched design
+    matrix of a width the kernel won at runs the Pallas single-HBM-pass
+    kernel (``ops/pallas_glm.py``) instead of XLA's two contractions over
+    X: ``pallas_glm.dense_route`` decides from what it can observe while
+    tracing, and no flag or option has a say. ``kernels.pallas_hits
+    {path=dense}`` ticks once a traced program that took the kernel,
+    ``kernels.xla_fallbacks{path=dense, reason}`` once a traced program
+    whose dense / identity / float32 evaluation on a TPU was turned away
+    (``vmap``, ``mesh``, ``shape``). The ELL-sparse kernel is routed by
+    nothing: no cell runs sparse features and nothing has timed it.
     """
-    import os
-    if os.environ.get("PHOTON_TPU_PALLAS_GLM") == "1":
-        from photon_tpu.ops import pallas_glm
-        if pallas_glm._supported(x, norm, coef):
-            _kernel_counter("pallas_hits", "dense")
-            return pallas_glm.fused_dense_value_grad(
-                loss, x, labels, offsets, weights, coef)
-        if pallas_glm._supported_sparse(x, norm, coef):
-            _kernel_counter("pallas_hits", "sparse")
-            return pallas_glm.fused_sparse_value_grad(
-                loss, x, labels, offsets, weights, coef)
-        path = "sparse" if isinstance(x, SparseFeatures) else "dense"
-        _kernel_counter("xla_fallbacks", path)
-        if not pallas_glm._TRACE_DISABLED.get():
-            # a disabled() region is a deliberate routing decision (mesh
-            # solves); only an unexpected refusal warrants the warning
-            # (one level further up: the scope decorator is a frame)
-            _warn_kernel_refused(path, stacklevel=4)
+    from photon_tpu.ops import pallas_glm
+    route = pallas_glm.dense_route(x, norm, coef)
+    if route == pallas_glm.KERNEL:
+        _kernel_counter("pallas_hits", "dense")
+        return pallas_glm.fused_dense_value_grad(
+            loss, x, labels, offsets, weights, coef)
+    if route is not None:
+        _kernel_counter("xla_fallbacks", "dense", reason=route)
     dim = coef.shape[0]
     margins = compute_margins(x, coef, offsets, norm)
     l, dz = loss.loss_and_dz(margins, labels)

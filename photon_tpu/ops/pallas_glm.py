@@ -8,40 +8,49 @@ bound, which makes the second pass pure waste: dz depends only on each
 row's own margin, so the gradient contraction can consume the SAME
 VMEM-resident tile of X that just produced the margins.
 
-Layout, the same in all three kernels: SAMPLES RUN ALONG THE LANES.
-Per-sample vectors (labels, offsets, weights, margins) are lane-dense
-``[1, T]`` rows, the coefficient vector and the gradient are ``[8, D]``
-blocks of identical rows (a matmul is fed no fewer than one sublane tile
-of LHS rows; row 0 is the answer), and the scalar loss accumulates in
-SMEM. No block has a lane dimension of 1 and no contraction runs over a
-transposed left operand.
+``fused_dense_value_grad`` tiles X over rows and runs on the VECTOR
+unit, as XLA's own matrix-vector fusions do (a matrix-vector product
+uses 8 of the MXU's 128 rows; at full float32 precision the two
+contractions a tile cost more than the read they ride on — PERF.md §5's
+gate table): rows on the sublanes, features on the lanes, as X lies in
+HBM, so a block spans X's full width whatever it is and nothing is
+padded or copied; the kernel takes the whole row tiles and the rows left
+over (fewer than a tile) are summed beside it as plain array operations. Per grid step and 128-row block: ``m = sum_lanes(X *
+theta)``, the pointwise loss/dz on lane-dense ``[tile / 128, 128]``
+vectors, then ``g += sum_sublane_groups(X * (w dz))`` from the same
+VMEM tile; the value's and the gradient's partial sums accumulate in
+revisited output blocks (the grid is sequential) and are summed outside.
+X is read from HBM exactly once: 5.7 ms an evaluation at 530,000 x
+2,000 against XLA's 11.5 (93% of the HBM peak). ``dense_route`` says
+where ``aggregators.value_and_gradient`` takes it: on a TPU, dense
+float32, identity normalization, not under vmap or a mesh, a width the
+kernel won at on the chip.
 
-``fused_dense_value_grad`` tiles X over rows; per grid step it computes
-``m = coef . X_tile^T`` (MXU), the pointwise loss/dz (VPU), and
-accumulates ``value += sum(w*l)`` and ``grad += (w*dz) . X_tile`` (MXU)
-into carried output blocks — X is read from HBM exactly once.
-Theoretical ceiling vs the XLA path on a bandwidth-bound solve: 2x.
+The other two kernels keep SAMPLES ALONG THE LANES: per-sample vectors
+(labels, offsets, weights, margins) are lane-dense ``[1, T]`` rows, the
+coefficient vector and the gradient are ``[8, D]`` blocks of identical
+rows (a matmul is fed no fewer than one sublane tile of LHS rows; row 0
+is the answer), and the scalar loss accumulates in SMEM.
 
-``fused_sparse_value_grad`` extends the same structure to padded-ELL
+``fused_sparse_value_grad`` extends the single pass to padded-ELL
 sparse rows: each grid step reads one slot-major ``[K, T]`` tile of the
 nnz stream (indices + values), expands it into a VMEM-resident dense
 ``[D, T]`` tile via a static-K unrolled one-hot accumulation (a row
 iota compared against one sublane-broadcast slot row at a time — never
-touches HBM), then runs the identical margins/loss/grad flow on that
-tile. The XLA sparse arm instead gathers theta for margins and
-scatter-adds the gradient — two passes over the nnz stream plus a
-serialized scatter. The slot-major view is one XLA transpose of the nnz
-stream outside the kernel. The VMEM tile bounds the supported
-coefficient dimension (``_MAX_SPARSE_DIM``); larger models stay on the
-CSC segment-sum path.
+touches HBM), then runs the margins/loss/grad flow on that tile with
+full-float32 MXU contractions. The XLA sparse arm instead gathers theta
+for margins and scatter-adds the gradient — two passes over the nnz
+stream plus a serialized scatter. The slot-major view is one XLA
+transpose of the nnz stream outside the kernel. The VMEM tile bounds
+the supported coefficient dimension (``_MAX_SPARSE_DIM``); larger models
+stay on the CSC segment-sum path. Nothing routes it (no cell runs sparse
+features and the chip has not timed it): its callers call it.
 
-Scope: identity normalization, f32 coefficients, dense f32/bf16 or
-ELL-sparse features. Callers opt in via ``PHOTON_TPU_PALLAS_GLM=1``
-(see ops/aggregators.py). On a TPU the kernels are compiled by Mosaic
-and a kernel that cannot compile raises; on every other backend they
-run in interpret mode, which is what pins them to the XLA path in
-tests/test_pallas_glm.py. ``chip_smoke.py`` compiles each of them on
-the chip and holds it to a float64 oracle.
+On a TPU the kernels are compiled by Mosaic and a kernel that cannot
+compile raises; on every other backend they run in interpret mode,
+which is what pins them to the XLA path in tests/test_pallas_glm.py.
+``chip_smoke.py`` compiles each of them on the chip and holds it to a
+float64 oracle.
 
 Reference semantics: ValueAndGradientAggregator.scala:36-80 (the same
 fused margin/loss/grad algebra, minus the normalization prefactors).
@@ -61,17 +70,32 @@ Array = jax.Array
 
 _LANES = 128                 # last-dim tile of every TPU vector layout
 _MXU_ROWS = 8                # fewest LHS rows a matmul is fed (one f32 sublane tile)
-_TILE_N = 1024
 _TILE_N_SPARSE = 128
 _TILE_B_SERVING = 128
-# A v5e core's scoped VMEM is 16 MiB. The pipeline double-buffers the X
-# tile and the two full-f32 contractions split it into bf16 pieces: the
-# compiler's scoped allocation measured 6.3x the tile (25.1 MiB at a
-# 4 MiB tile, refused), so one buffer is capped at 2 MiB. The row tile
-# shrinks as d grows; past _MAX_DENSE_DIM even a 128-row tile overflows
-# the cap.
+# The dense kernel's pipeline double-buffers its tile of X and holds
+# nothing else of size, so the tile is set in bytes and the row count
+# follows from d: 256 rows at epsilon's 2,000 (2,048 lanes a row in VMEM).
+# The step overhead does not show: 256, 512, 1,024 and 2,048 rows a step
+# cost 5.752 / 5.745 / 5.753 / 5.745 ms an evaluation at 530,000 x 2,000
+# (my chip run, PR 32), so the tile stays inside the 16 MiB of scoped VMEM
+# a v5e core hands a kernel by default and no limit is asked for.
 _X_TILE_BYTES = 2 << 20
-_MAX_DENSE_DIM = _X_TILE_BYTES // (_LANES * 4)        # 4096
+# past this width a 128-row block's per-feature partial sums (d / 128
+# registers of the 64) no longer ride in registers through a tile
+_MAX_DENSE_DIM = 4096
+# The least width the routing admits: a 128-row block costs the kernel
+# some 240 ns whatever its width (its per-row numbers change layout twice)
+# and XLA's two passes nothing of the kind, so the one read of X wins only
+# where a row is wide enough. Seconds an evaluation inside a solve-like
+# loop, XLA's two passes / the kernel (my chip run, PR 32, TPU v5e):
+#   5,000,000 x   128   6.897 / 9.282 ms   1.35x  (loses)
+#   4,000,000 x   128   5.516 / 7.438 ms   1.35x  (loses)
+#   4,000,000 x   256  10.929 / 7.469 ms   0.68x
+#   2,000,000 x   512  10.883 / 5.482 ms   0.50x
+#   1,000,000 x 1,024  10.856 / 5.434 ms   0.50x
+#     530,000 x 2,000  11.226 / 5.745 ms   0.51x  (the floor: 5.30 ms)
+# Nothing between 128 and 256 was measured, so 256 it is.
+_DENSE_MIN_WIDTH = 256
 # the sparse/serving kernels expand a [D, T] f32 tile in VMEM: at
 # T = 128 that is 4096 x 128 x 4B = 2 MiB of scratch, built and consumed
 # in _D_CHUNK-row pieces so the compare/select and contraction
@@ -87,8 +111,8 @@ _F32 = jax.lax.Precision.HIGHEST
 # trace-time kill switch: pallas_call carries no sharding annotations, so
 # a mesh-sharded SPMD solve must never pick the kernel up (it would force
 # replication of X or fail at lowering). GlmOptimizationProblem wraps
-# mesh solves in ``disabled()``; the flag is a ContextVar so it binds at
-# TRACE time, exactly like the env flag it refines.
+# mesh and lambda-lane solves in ``disabled()``; the flag is a ContextVar
+# so it binds at TRACE time, like the routing it refines.
 _TRACE_DISABLED = contextvars.ContextVar("pallas_glm_disabled",
                                          default=False)
 
@@ -100,6 +124,30 @@ def disabled():
         yield
     finally:
         _TRACE_DISABLED.reset(token)
+
+
+_PREFETCH = None
+
+
+def prefetch_toolchain() -> None:
+    """Start importing Pallas on a daemon thread (idempotent). The import
+    is 1.2 s of Python on a chip's host, paid inside the first solve's
+    trace unless it is already under way: a driver's entry hook
+    (``utils.compile_cache.maybe_enable``) calls this before the data is
+    read or generated, which takes longer and mostly runs outside the
+    interpreter's lock. A kernel traced meanwhile waits on the import
+    lock for the rest of it."""
+    global _PREFETCH
+    if _PREFETCH is None:
+        import threading
+
+        def load():
+            from jax.experimental import pallas  # noqa: F401
+            from jax.experimental.pallas import tpu  # noqa: F401
+
+        _PREFETCH = threading.Thread(target=load, name="pallas-import",
+                                     daemon=True)
+        _PREFETCH.start()
 
 
 def _round_up(n: int, m: int) -> int:
@@ -144,84 +192,160 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _supported(x, norm, coef) -> bool:
-    """Dense 2D f32/bf16 features AND f32 coefficients, identity
-    normalization, a feature dimension whose 128-row tile fits the VMEM
-    cap, NOT under vmap, NOT inside a ``disabled()`` (mesh) region. The
-    vmap exclusion: the kernel's sequential-grid accumulation (init on
-    program_id 0, += into a revisited output block) assumes it owns the
-    whole grid, which a batching transform breaks (the random-effect
-    path vmaps the objective over dense-local entity blocks). The
-    coef-dtype exclusion: an f64 solve over f32 features promotes in the
-    XLA path, while the kernel would silently return f32 and break the
-    while_loop carry dtype at trace time."""
-    if _TRACE_DISABLED.get() or _batched(x, coef):
-        return False
-    return (isinstance(x, jax.Array) and x.ndim == 2
-            and x.dtype in (jnp.float32, jnp.bfloat16)
-            and x.shape[1] <= _MAX_DENSE_DIM
-            and coef.dtype == jnp.float32
-            and norm.is_identity)
+def _on_tpu() -> bool:
+    """The routing's one look at the backend (a test that drives the
+    routing on the CPU, in interpret mode, patches this and nothing
+    else)."""
+    return jax.default_backend() == "tpu"
+
+
+KERNEL = "kernel"
+
+
+def dense_route(x, norm, coef) -> Optional[str]:
+    """Where ``aggregators.value_and_gradient`` sends an evaluation, from
+    what it can observe while tracing. ``None``: not the kernel's case —
+    not a TPU, not a dense rank-2 float32 matrix, a normalised objective,
+    coefficients that are not float32 (a float64 solve over float32
+    features promotes on the XLA path; the kernel would hand back float32
+    and break the ``while_loop`` carry) — so XLA's two passes, uncounted.
+    ``KERNEL``: the one fused pass. Otherwise the reason such an
+    evaluation was turned away, which ``kernels.xla_fallbacks`` carries
+    as a label: ``"vmap"`` (the sequential grid's accumulation into a
+    revisited block assumes it owns the grid; the per-entity ladders and
+    the lambda lanes batch the objective), ``"mesh"`` (a ``disabled()``
+    region: ``pallas_call`` carries no sharding), ``"shape"`` (the
+    width the kernel did not win at: ``_DENSE_MIN_WIDTH``)."""
+    if not (_on_tpu() and isinstance(x, jax.Array) and x.ndim == 2
+            and x.dtype == jnp.float32 and coef.dtype == jnp.float32
+            and norm.is_identity):
+        return None
+    if _batched(x, coef):
+        return "vmap"
+    if _TRACE_DISABLED.get():
+        return "mesh"
+    if not _DENSE_MIN_WIDTH <= x.shape[1] <= _MAX_DENSE_DIM:
+        return "shape"
+    return KERNEL
+
+
+def _lane_chunks(d: int):
+    """Static (start, size) pieces of the feature dimension as it lies on
+    the lanes: whole 128-lane tiles, then the ragged tail (80 lanes at
+    d = 2,000)."""
+    return [(c0, min(_LANES, d - c0)) for c0 in range(0, d, _LANES)]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 5, 6))
 def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
            interpret: bool, coef):
-    """x [n, d]; labels/offsets/weights [1, n] rows; coef [8, d] (equal
-    rows); n % tile_n == 0, tile_n % 128 == 0, d % 128 == 0."""
+    """x [n, d] AS PLACED (no pad, no copy: any n >= tile_n, any d), of
+    which the kernel reads the ``n // tile_n`` whole tiles; labels/
+    offsets/weights [steps, tile_n / 128, 128] (row t of step i at
+    [i, t // 128, t % 128]); coef [1, d]; tile_n % 128 == 0. Returns the
+    value's per-lane partial sums [tile_n / 128, 128] and the gradient's
+    per-sublane partial sums [8, d]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, d = x.shape
+    steps = n // tile_n
+    blocks = tile_n // _LANES
+    chunks = _lane_chunks(d)
+    f32 = jnp.float32
 
-    def kernel(x_ref, y_ref, off_ref, w_ref, coef_ref, val_ref, grad_ref):
+    def kernel(x_ref, y_ref, off_ref, w_ref, coef_ref, val_ref, grad_ref,
+               m_ref, wdz_ref):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _():
-            val_ref[0, 0] = jnp.float32(0.0)
+            val_ref[...] = jnp.zeros_like(val_ref)
             grad_ref[...] = jnp.zeros_like(grad_ref)
 
-        # the tile of X stays in VMEM for both contractions — HBM reads
-        # X exactly once. bf16 storage composes: the tile is read at
-        # half the bytes and upcast once in VMEM. Samples run along the
-        # LANES in everything below: margins are coef . X_tile^T (an
-        # A.B^T contraction, like q.k^T), so every per-sample vector is
-        # a lane-dense [1, T] row and no block has a lane dimension of 1.
-        x_t = x_ref[...].astype(jnp.float32)
-        m = jax.lax.dot_general(
-            coef_ref[...], x_t,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_F32)   # [8, T]
-        z = m[0:1, :] + off_ref[...]
-        l, dz = loss_and_dz(z, y_ref[...])
-        w = w_ref[...]
-        val_ref[0, 0] += jnp.sum(l * w)
-        grad_ref[...] += jnp.dot(
-            _lhs_rows(w * dz), x_t,
-            preferred_element_type=jnp.float32, precision=_F32)   # [8, D]
+        # A 128-row block's per-row numbers change hands between two
+        # layouts: one a SUBLANE ([128, 1], what a sum over the lanes of
+        # X . theta leaves, and what a row of X is scaled by) and one a
+        # LANE ([1, 128], what the loss is computed on and what comes from
+        # HBM densely). The diagonal of a [128, 128] broadcast carries one
+        # into the other exactly, on the vector unit alone.
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1))
+        zero = f32(0.0)
 
-    row = pl.BlockSpec((1, tile_n), lambda i: (0, i))
-    value, grad = pl.pallas_call(
-        kernel,
-        grid=(n // tile_n,),
-        in_specs=[
-            pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
-            row, row, row,
-            pl.BlockSpec((_MXU_ROWS, d), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            # the scalar accumulator lives in scalar memory
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((_MXU_ROWS, d), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((_MXU_ROWS, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, labels, offsets, weights, coef)
-    return value[0, 0], grad[0]
+        def x_block(b, c0, size):
+            r0 = pl.multiple_of(b * _LANES, _LANES)
+            return x_ref[pl.ds(r0, _LANES), c0:c0 + size].astype(f32)
+
+        def margins(b, carry):
+            whole = tail = None
+            for c0, size in chunks:
+                p = x_block(b, c0, size) * coef_ref[:, c0:c0 + size]
+                if size == _LANES:
+                    whole = p if whole is None else whole + p
+                else:
+                    tail = p
+            col = sum(jnp.sum(p, axis=1, keepdims=True)
+                      for p in (whole, tail) if p is not None)
+            m_ref[pl.ds(b, 1), :] = jnp.sum(
+                jnp.where(eye, col, zero), axis=0, keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, blocks, margins, 0)
+        # the whole tile's loss on dense [tile_n / 128, 128] vectors
+        l, dz = loss_and_dz(m_ref[...] + off_ref[...], y_ref[...])
+        w = w_ref[...]
+        val_ref[...] += l * w
+        wdz_ref[...] = w * dz
+
+        def gradient(b, acc):
+            col = jnp.sum(jnp.where(eye, wdz_ref[pl.ds(b, 1), :], zero),
+                          axis=1, keepdims=True)                # [128, 1]
+            return tuple(
+                a + jnp.sum((x_block(b, c0, size) * col).reshape(
+                    _LANES // _MXU_ROWS, _MXU_ROWS, size), axis=0)
+                for (c0, size), a in zip(chunks, acc))
+
+        # the same tile of X, still in VMEM, a second time: HBM was read
+        # once. Eight partial sums a feature (one a sublane) are carried
+        # in registers through the tile.
+        acc = jax.lax.fori_loop(
+            0, blocks, gradient,
+            tuple(jnp.zeros((_MXU_ROWS, size), f32) for _, size in chunks))
+        for (c0, size), a in zip(chunks, acc):
+            grad_ref[:, c0:c0 + size] += a
+
+    rows = pl.BlockSpec((None, blocks, _LANES), lambda i: (i, 0, 0))
+    # Mosaic lowers no 64-bit type, and under x64 a ``fori_loop`` counts
+    # in int64 whatever its bounds: the kernel is traced with x64 off
+    # (its operands and results are float32 either way)
+    with jax.enable_x64(False):
+        value, grad = pl.pallas_call(
+            kernel,
+            grid=(steps,),
+            in_specs=[
+                # the block spans X's FULL last dimension, whatever it is
+                pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
+                rows, rows, rows,
+                pl.BlockSpec((1, d), lambda i: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((blocks, _LANES), lambda i: (0, 0)),
+                pl.BlockSpec((_MXU_ROWS, d), lambda i: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((blocks, _LANES), f32),
+                jax.ShapeDtypeStruct((_MXU_ROWS, d), f32),
+            ],
+            scratch_shapes=[pltpu.VMEM((blocks, _LANES), f32),
+                            pltpu.VMEM((blocks, _LANES), f32)],
+            compiler_params=pltpu.CompilerParams(
+                # every step adds into the revisited output blocks: in
+                # order
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(x, labels, offsets, weights, coef)
+    return value, grad
 
 
 def _sample_rows(n: int, labels, offsets, weights, n_pad: int):
@@ -251,36 +375,52 @@ def fused_dense_value_grad(
     weights: Optional[Array],
     coef: Array,
     *,
-    tile_n: int = _TILE_N,
+    tile_n: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[Array, Array]:
     """Weighted loss value and gradient, X streamed from HBM once.
 
     Drop-in for the un-normalized dense case of
     ``aggregators.value_and_gradient`` (no L2 term — the objective adds
-    it, as with the XLA path). Rows are padded to the tile size with
-    zero-weight samples, which contribute nothing to either output; a
-    feature dimension that is not a multiple of 128 is zero-padded too
-    (a copy of X — callers that care keep d lane-aligned).
+    it, as with the XLA path). X goes to the kernel as it is placed, for
+    any ``n`` and ``d``: a block spans its full width, the kernel takes
+    the whole tiles, and the rows left over (fewer than a tile: 80 of
+    epsilon's 530,000) are the same sums as plain float32 array
+    operations over a slice. Nothing is padded and no copy of X is made.
+    The kernel is never shown a row past ``n``: a block past the end of
+    X holds unspecified bits that zero weights would not silence, and
+    where XLA has placed a small X in VMEM the block IS the operand, so
+    it cannot be cleaned in place either (PERF.md §6, PR 32).
     """
     if interpret is None:
         interpret = _default_interpret()
     n, d = x.shape
-    if n == 0:
-        # grid=(0,) would skip the kernel entirely and return
-        # uninitialized buffers; match the XLA path's empty-sum contract
-        zero = jnp.zeros((), jnp.float32)
-        return zero, jnp.zeros((d,), jnp.float32)
-    coef = _coef_lhs(coef, _MAX_DENSE_DIM, "dense")
-    d_pad = coef.shape[1]
-    tile = _row_tile(tile_n, n, cap=_X_TILE_BYTES // (d_pad * 4))
-    n_pad = _round_up(n, tile)
-    if (n_pad, d_pad) != (n, d):
-        x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-    value, grad = _fused(loss.loss_and_dz, x,
-                         *_sample_rows(n, labels, offsets, weights, n_pad),
-                         tile, bool(interpret), coef)
-    return value, grad[:d]
+    if d > _MAX_DENSE_DIM:
+        raise ValueError(
+            f"fused dense kernel holds a 128-row tile of {d} feature "
+            f"columns in VMEM and supports d <= {_MAX_DENSE_DIM}")
+    f32 = jnp.float32
+    coef = jnp.asarray(coef, f32)
+    y = jnp.asarray(labels, f32)
+    off = jnp.zeros((n,), f32) if offsets is None else jnp.asarray(offsets, f32)
+    w = jnp.ones((n,), f32) if weights is None else jnp.asarray(weights, f32)
+    cap = max(_LANES, _X_TILE_BYTES // (_round_up(d, _LANES) * 4))
+    tile = min(cap if tile_n is None else tile_n, cap, n) // _LANES * _LANES
+    whole = n // tile * tile if tile else 0
+    # the rows past the last whole tile (all of them below 128 rows; an
+    # empty sum at n = 0)
+    xt = x[whole:].astype(f32)
+    lt, dzt = loss.loss_and_dz(jnp.sum(xt * coef, axis=1) + off[whole:],
+                               y[whole:])
+    value = jnp.sum(lt * w[whole:])
+    grad = jnp.sum(xt * (dzt * w[whole:])[:, None], axis=0)
+    if whole:
+        shape = (whole // tile, tile // _LANES, _LANES)
+        v, g = _fused(loss.loss_and_dz, x,
+                      *(r[:whole].reshape(shape) for r in (y, off, w)),
+                      tile, bool(interpret), coef.reshape(1, d))
+        value, grad = value + jnp.sum(v), grad + jnp.sum(g, axis=0)
+    return value, grad
 
 
 def _supported_sparse(x, norm, coef) -> bool:

@@ -188,11 +188,9 @@ class GlmOptimizationProblem:
     @property
     def _solve_fn(self):
         """Default solve (non-mesh callers / HLO inspection in tests)."""
-        import os
-        return self._solve_fn_for(
-            os.environ.get("PHOTON_TPU_PALLAS_GLM") == "1")
+        return self._solve_fn_for(True)
 
-    def _solve_fn_for(self, use_pallas: bool):
+    def _solve_fn_for(self, kernel_ok: bool):
         opt = self.config.optimizer
         solver_cfg = opt.solver_config()
         obj = self.objective
@@ -297,11 +295,12 @@ class GlmOptimizationProblem:
 
         # share the compiled solve across problem instances with identical
         # trace-shaping state (re-fits, sweep candidates, fresh
-        # estimators). use_pallas is trace-shaping too: a mesh solve and
-        # a single-device solve with the flag set must not share a trace
-        # (the kernel carries no sharding annotations).
+        # estimators). kernel_ok is trace-shaping too: a mesh solve is
+        # traced inside ``pallas_glm.disabled()`` and a single-device
+        # solve is not, and the two must not share a trace (the fused
+        # kernel carries no sharding annotations).
         key = ("glm_solve", self.task, solver_cache_key(opt),
-               norm_cache_key(self.objective.norm), use_pallas)
+               norm_cache_key(self.objective.norm), kernel_ok)
         return jitcache.get_or_build(key, build)
 
     def run(
@@ -367,21 +366,19 @@ class GlmOptimizationProblem:
                if regularization_weight is None else regularization_weight)
         l2 = jnp.asarray(self.config.regularization.l2_weight(lam), initial.dtype)
         l1 = jnp.asarray(self.config.regularization.l1_weight(lam), initial.dtype)
-        import os
-        flag = os.environ.get("PHOTON_TPU_PALLAS_GLM") == "1"
         # mesh here OR a caller-declared sharded batch (FixedEffect
         # Coordinate pre-shards at construction and passes pallas_ok=False)
-        use_pallas = flag and mesh is None and pallas_ok is not False
-        solve = self._solve_fn_for(use_pallas)
-        if flag and not use_pallas:
+        kernel_ok = mesh is None and pallas_ok is not False
+        solve = self._solve_fn_for(kernel_ok)
+        if kernel_ok:
+            result = solve(initial, batch, l2, l1)
+        else:
             # the fused kernel has no sharding annotations: under a mesh
             # it would force replication of X or fail at lowering, so the
             # SPMD solve traces with the kernel hard-disabled
             from photon_tpu.ops import pallas_glm
             with pallas_glm.disabled():
                 result = solve(initial, batch, l2, l1)
-        else:
-            result = solve(initial, batch, l2, l1)
         coef = result.coef
         if not norm.is_identity:
             coef = norm.transformed_space_to_model(coef, self.intercept_index)
@@ -487,14 +484,10 @@ class GlmOptimizationProblem:
         l2 = jnp.asarray([reg.l2_weight(l) for l in lams], dtype)
         l1 = jnp.asarray([reg.l1_weight(l) for l in lams], dtype)
         solve = self._swept_solve_fn(mesh)
-        import os
-        if os.environ.get("PHOTON_TPU_PALLAS_GLM") == "1":
-            # the fused kernel has no batching rule for the lane stack;
-            # the swept program always traces with it hard-disabled
-            from photon_tpu.ops import pallas_glm
-            with pallas_glm.disabled():
-                stacked = solve(x0, batch, l2, l1)
-        else:
+        # the fused kernel has no batching rule for the lane stack;
+        # the swept program always traces with it hard-disabled
+        from photon_tpu.ops import pallas_glm
+        with pallas_glm.disabled():
             stacked = solve(x0, batch, l2, l1)
         coefs = stacked.coef
         if not norm.is_identity:

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_tpu.function.objective import GLMObjective, Hyper
+from photon_tpu.ops import pallas_glm
 from photon_tpu.optim.base import (
     ConvergenceReason,
     FailureMode,
@@ -119,7 +120,10 @@ class StreamedProblem:
 
         def partial_body(cv, cg, coef, batch):
             # shard-local accumulate: cv [1], cg [1, d] — NO collectives
-            v, g = obj.chunk_value_and_gradient((cv[0], cg[0]), coef, batch)
+            # (a mesh trace: the fused kernel carries no sharding)
+            with pallas_glm.disabled():
+                v, g = obj.chunk_value_and_gradient((cv[0], cg[0]), coef,
+                                                    batch)
             return v[None], g[None]
 
         def finalize_body(cv, cg, coef, l2):

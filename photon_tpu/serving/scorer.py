@@ -94,6 +94,26 @@ def serving_modes(model: DeviceResidentModel) -> Tuple[str, ...]:
     return modes
 
 
+_WARNED_REFUSED = False
+
+
+def _warn_kernel_refused() -> None:
+    """Warn ONCE when PHOTON_TPU_PALLAS_SERVING=1 asked for the fused
+    gather+margin kernel and the scorer's shapes were refused — a silent
+    downgrade the counters record and this makes audible."""
+    global _WARNED_REFUSED
+    if _WARNED_REFUSED:
+        return
+    _WARNED_REFUSED = True
+    import warnings
+    warnings.warn(
+        "PHOTON_TPU_PALLAS_SERVING=1 requested the fused Pallas "
+        "gather+margin kernel but the scorer's operands were refused "
+        "(dtype/mesh/dimension gate); falling back to the XLA "
+        "expressions. kernels.xla_fallbacks{path=serving} counts these.",
+        RuntimeWarning, stacklevel=3)
+
+
 def _fused_fixed_margin(mesh_local: bool, dtype, theta_dims, theta_dtypes,
                         fixed_pos, k_total: int):
     """Build-time routing for the fixed-effect term: returns a
@@ -111,8 +131,7 @@ def _fused_fixed_margin(mesh_local: bool, dtype, theta_dims, theta_dtypes,
     import jax.numpy as jnp
 
     from photon_tpu.ops import pallas_glm
-    from photon_tpu.ops.aggregators import (_kernel_counter,
-                                            _warn_kernel_refused)
+    from photon_tpu.ops.aggregators import _kernel_counter
 
     ok = (mesh_local and dtype == jnp.float32
           and len(theta_dims) > 0
@@ -123,7 +142,7 @@ def _fused_fixed_margin(mesh_local: bool, dtype, theta_dims, theta_dtypes,
     if not ok:
         _kernel_counter("xla_fallbacks", "serving")
         if not pallas_glm._TRACE_DISABLED.get():
-            _warn_kernel_refused("serving")
+            _warn_kernel_refused()
         return None
     _kernel_counter("pallas_hits", "serving")
     col_off = [0]

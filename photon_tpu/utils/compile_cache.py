@@ -71,12 +71,28 @@ def enable_persistent_cache() -> str:
     return path
 
 
+def _held_to_cpu() -> bool:
+    """``JAX_PLATFORMS=cpu`` (or the config's equal): read without
+    starting a backend."""
+    import jax
+
+    return jax.config.jax_platforms == "cpu"
+
+
 def maybe_enable() -> str | None:
     """Entry-point hook every driver goes through: enable the cache
     unless the user opted out via ``PHOTON_TPU_NO_XLA_CACHE``. On a CPU
     run a cache that cannot be enabled (unwritable directory) is a
     warning; on any other backend it is an error — a chip run that
-    silently recompiles everything pays minutes per process."""
+    silently recompiles everything pays minutes per process. Where the
+    process is not held to the CPU it also starts the kernels' toolchain
+    importing in the background (``pallas_glm.prefetch_toolchain``): a
+    driver calls this first and reads its data next."""
+    import jax
+
+    if not _held_to_cpu():
+        from photon_tpu.ops import pallas_glm
+        pallas_glm.prefetch_toolchain()
     if os.environ.get(ENV_OPT_OUT):
         _metrics.counter("compile_cache.disabled", reason="env_opt_out").inc()
         _metrics.gauge("compile_cache.enabled").set(0)
@@ -85,8 +101,6 @@ def maybe_enable() -> str | None:
     try:
         return enable_persistent_cache()
     except OSError as e:
-        import jax
-
         if jax.default_backend() != "cpu":
             raise RuntimeError(
                 f"persistent XLA cache at {cache_dir()!r} cannot be enabled "

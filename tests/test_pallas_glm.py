@@ -55,23 +55,78 @@ def test_fused_none_offsets_weights(problem):
                                rtol=5e-5, atol=5e-5)
 
 
-def test_env_flag_routes_objective(problem, monkeypatch):
-    """PHOTON_TPU_PALLAS_GLM=1 routes the dense f32 objective through the
-    fused kernel with unchanged results at the solver boundary."""
+def _ticks():
+    """The routing's trace-time counters, by (name, reason)."""
+    from photon_tpu.obs.metrics import registry
+
+    out = {}
+    for key, v in registry.snapshot()["counters"].items():
+        if key.startswith("kernels.pallas_hits{path=\"dense\""):
+            out["hit"] = out.get("hit", 0) + int(v)
+        elif key.startswith("kernels.xla_fallbacks{path=\"dense\""):
+            reason = key.split('reason="')[1].split('"')[0]
+            out[reason] = out.get(reason, 0) + int(v)
+    return out
+
+
+def _ticked(before):
+    now = _ticks()
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if v != before.get(k, 0)}
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Drive the routing on the CPU: the ONE backend check answers "a
+    TPU", and the kernel runs in interpret mode (``_default_interpret``
+    still sees the CPU). Compiled programs traced under it are dropped
+    on the way in and out."""
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.utils import jitcache
+
+    jitcache.clear()
+    monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: True)
+    yield pallas_glm
+    jitcache.clear()
+
+
+@pytest.fixture
+def wide_problem():
+    """epsilon's width, ragged rows: d = 2,000 is no multiple of 128 and
+    n = 700 no multiple of the 256-row tile the kernel picks there."""
+    rng = np.random.default_rng(11)
+    n, d = 700, 2000
+    X = jnp.asarray(rng.normal(size=(n, d)) / np.sqrt(d), jnp.float32)
+    y = jnp.asarray((rng.random(n) > 0.4), jnp.float32)
+    off = jnp.asarray(rng.normal(size=n) * 0.2, jnp.float32)
+    w = jnp.asarray(rng.random(n) + 0.1, jnp.float32)
+    coef = jnp.asarray(rng.normal(size=d) * 4.0, jnp.float32)
+    return X, y, off, w, coef
+
+
+def test_routing_takes_the_objective_through_the_kernel(wide_problem,
+                                                         on_tpu):
+    """On a TPU the dense f32 identity objective of an admitted width
+    runs the fused kernel, by what the function observes and nothing
+    else, with unchanged results at the solver boundary."""
     from photon_tpu.function.objective import GLMObjective, Hyper
 
-    X, y, off, w, coef = problem
+    X, y, off, w, coef = wide_problem
     batch = DataBatch(X, y, off, w)
     obj = GLMObjective(LogisticLoss)
     hyper = Hyper(l2_weight=jnp.float32(0.3))
-    v0, g0 = obj.value_and_gradient(coef, batch, hyper)
-    monkeypatch.setenv("PHOTON_TPU_PALLAS_GLM", "1")
+    with on_tpu.disabled():
+        v0, g0 = obj.value_and_gradient(coef, batch, hyper)
+    before = _ticks()
     v1, g1 = obj.value_and_gradient(coef, batch, hyper)
-    np.testing.assert_allclose(float(v1), float(v0), rtol=5e-6)
+    assert _ticked(before) == {"hit": 1}
+    np.testing.assert_allclose(float(v1), float(v0), rtol=2e-6)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g0),
-                               rtol=5e-5, atol=5e-5)
-    # sparse features fall back to the XLA path untouched (flag still set)
+                               rtol=2e-6, atol=2e-6)
+    # sparse features go to the XLA path untouched and uncounted: the
+    # ELL kernel is routed by nothing
     from photon_tpu.ops import features as F
+    before = _ticks()
     idx = jnp.tile(jnp.arange(8, dtype=jnp.int32), (X.shape[0], 1))
     sb = DataBatch(F.SparseFeatures(idx, X[:, :8]), y, off, w)
     vs, gs = obj.value_and_gradient(coef[:8], sb, hyper)
@@ -80,6 +135,82 @@ def test_env_flag_routes_objective(problem, monkeypatch):
     np.testing.assert_allclose(
         float(vs), float(vr) + 0.15 * float(coef[:8] @ coef[:8]), rtol=1e-6)
     assert np.isfinite(float(vs)) and bool(jnp.all(jnp.isfinite(gs)))
+    assert _ticked(before) == {}
+
+
+@pytest.mark.parametrize("n,d,tile", [
+    (700, 2000, None),      # epsilon's width, the tile the kernel picks
+    (997, 2000, 256),       # three whole tiles, 229 rows over
+    (130, 2000, 128),       # one tile, two rows over
+    (513, 1500, 512),       # 11 whole lane tiles + 92 lanes; one row over
+    (1000, 37, 256),        # narrower than one lane tile
+    (100, 300, None),       # fewer rows than one 128-row block: no kernel
+    (1024, 256, 512),       # nothing left over
+], ids=lambda v: str(v))
+def test_fused_ragged_shapes_against_xla(n, d, tile):
+    """d not a multiple of 128 AND n not a multiple of the tile: X goes
+    in as placed; the kernel takes the whole tiles and is never shown a
+    block past the end of X (interpret mode would fill it with NaN, a
+    stale buffer on the chip with anything); the rows left over are
+    summed beside it."""
+    rng = np.random.default_rng(n + d)
+    X = jnp.asarray(rng.normal(size=(n, d)) / np.sqrt(d), jnp.float32)
+    y = jnp.asarray((rng.random(n) > 0.4), jnp.float32)
+    off = jnp.asarray(rng.normal(size=n) * 0.2, jnp.float32)
+    w = jnp.asarray(rng.random(n) + 0.1, jnp.float32)
+    coef = jnp.asarray(rng.normal(size=d) * 4.0, jnp.float32)
+    v0, g0 = aggregators.value_and_gradient(
+        LogisticLoss, X, y, off, w, coef, no_normalization())
+    v1, g1 = fused_dense_value_grad(LogisticLoss, X, y, off, w, coef,
+                                    tile_n=tile)
+    assert np.isfinite(float(v1)) and np.isfinite(np.asarray(g1)).all()
+    np.testing.assert_allclose(float(v1), float(v0), rtol=2e-6)
+    scale = float(jnp.abs(g0).max())
+    np.testing.assert_allclose(np.asarray(g1) / scale,
+                               np.asarray(g0) / scale, atol=2e-6)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (``while`` bodies, ``pjit`` calls), a ``pallas_call``'s kernel body
+    left out: what the kernel does in VMEM is not a copy of X."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_routed_solve_pads_no_matrix(wide_problem, on_tpu):
+    """The routed L-BFGS solve holds the kernel inside its loops and no
+    ``pad`` at all: X goes in as placed and the per-row vectors are cut
+    to the whole tiles."""
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import TaskType
+
+    X, y, off, w, _ = wide_problem
+    prob = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(max_iterations=5),
+            regularization=L2Regularization, regularization_weight=1.0))
+    one = jnp.float32(1.0)
+    jaxpr = jax.make_jaxpr(prob._solve_fn)(
+        jnp.zeros(X.shape[1], jnp.float32), DataBatch(X, y, off, w), one, one)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) >= 2
+    assert not [e for e in eqns if e.primitive.name == "pad"]
+    assert not any(e.primitive.name in ("dot_general", "concatenate")
+                   and any(getattr(v.aval, "shape", ()) == X.shape
+                           for v in e.invars) for e in eqns)
 
 
 def test_fused_empty_batch():
@@ -93,9 +224,10 @@ def test_fused_empty_batch():
     np.testing.assert_array_equal(np.asarray(g), np.zeros(5))
 
 
-def test_flag_solve_parity(problem, monkeypatch):
-    """A full L-BFGS solve with the kernel enabled lands on the same
-    coefficients as the XLA path (f32 tolerance)."""
+def test_routed_solve_parity(wide_problem, on_tpu):
+    """A full L-BFGS solve through the routed kernel lands on the same
+    coefficients as the XLA path (f32 tolerance), and says so in the
+    counters: one tick a traced program."""
     from photon_tpu.function.objective import L2Regularization
     from photon_tpu.optim.problem import (
         GLMOptimizationConfiguration,
@@ -105,32 +237,47 @@ def test_flag_solve_parity(problem, monkeypatch):
     from photon_tpu.types import TaskType
     from photon_tpu.utils import jitcache
 
-    X, y, off, w, coef = problem
+    X, y, off, w, _ = wide_problem
     batch = DataBatch(X, y, off, w)
     cfg = GLMOptimizationConfiguration(
         optimizer=OptimizerConfig(max_iterations=80, tolerance=1e-8),
         regularization=L2Regularization, regularization_weight=1.0)
 
-    def solve():
-        # fresh compilation per run: the env flag is a trace-time constant
-        # the jitcache key knows nothing about
-        jitcache.clear()
+    def solve(**kw):
         prob = GlmOptimizationProblem(TaskType.LOGISTIC_REGRESSION, cfg)
-        m, _ = prob.run(batch, dim=X.shape[1], dtype=jnp.float32)
+        m, _ = prob.run(batch, dim=X.shape[1], dtype=jnp.float32, **kw)
         return np.asarray(m.coefficients.means)
 
-    c0 = solve()
-    monkeypatch.setenv("PHOTON_TPU_PALLAS_GLM", "1")
+    before = _ticks()
     c1 = solve()
+    took = _ticked(before)
+    # the objective is traced at the solver's init and in its line search
+    assert set(took) == {"hit"} and took["hit"] >= 2, took
+    before = _ticks()
+    c0 = solve(pallas_ok=False)     # a caller-declared sharded batch
+    assert set(_ticked(before)) == {"mesh"}
+    # 2,000 coefficients from 700 rows in float32: the objective (485)
+    # resolves 3e-5, which leaves each solve's end point 1e-2 of room in
+    # norm under a curvature of one; the objective itself agrees closely
+    assert np.linalg.norm(c1 - c0) < 1e-2 * np.linalg.norm(c0)
+    from photon_tpu.function.objective import GLMObjective, Hyper
+    with on_tpu.disabled():
+        f1, f0 = (float(GLMObjective(LogisticLoss).value(
+            jnp.asarray(c), batch, Hyper(l2_weight=jnp.float32(1.0))))
+            for c in (c1, c0))
+    assert abs(f1 - f0) <= 2e-6 * f0
+    # the same program again: compiled once, counted once
+    before = _ticks()
+    solve()
+    assert _ticked(before) == {}
     jitcache.clear()
-    np.testing.assert_allclose(c1, c0, rtol=5e-4, atol=5e-5)
 
 
-def test_flag_does_not_break_vmapped_re_solves(monkeypatch):
-    """PHOTON_TPU_PALLAS_GLM=1 must NOT route vmapped per-entity
-    objectives (dense-local random-effect blocks) through the kernel —
-    its sequential-grid accumulation is not vmap-safe. The solve must
-    produce identical results with the flag on and off."""
+def test_routing_turns_vmapped_re_solves_away(on_tpu, monkeypatch):
+    """The vmapped per-entity objectives (dense-local random-effect
+    blocks) must NOT reach the kernel — its sequential-grid accumulation
+    is not vmap-safe — whatever their width: the fit is the CPU's fit and
+    the refusals are counted under ``vmap``."""
     from photon_tpu.estimators.game_estimator import (
         CoordinateConfiguration,
         GameEstimator,
@@ -171,20 +318,28 @@ def test_flag_does_not_break_vmapped_re_solves(monkeypatch):
         assert all(est._coordinates["per_user"]._dense_local_blocks)
         return np.asarray(res[-1].model["per_user"].coefficients)
 
-    c_off = fit()
-    monkeypatch.setenv("PHOTON_TPU_PALLAS_GLM", "1")
+    # even with every width admitted, vmap refuses first
+    monkeypatch.setattr(on_tpu, "_DENSE_MIN_WIDTH", 1)
+    before = _ticks()
     c_on = fit()
+    took = _ticked(before)
+    assert set(took) == {"vmap"}, took
+    monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
+    before = _ticks()
+    c_off = fit()
+    assert _ticked(before) == {}          # off a TPU nothing is counted
     jitcache.clear()
     np.testing.assert_allclose(c_on, c_off, rtol=1e-6, atol=1e-7)
     assert np.all(np.isfinite(c_on))
 
 
-def test_flag_mesh_solve_gated_off(monkeypatch, devices8):
-    """ADVICE r4: with PHOTON_TPU_PALLAS_GLM=1, a mesh-sharded SPMD solve
-    must NOT trace the kernel (pallas_call has no sharding annotations) —
-    the solve runs the XLA path, matches the flag-off result, and the
-    single-device solve with the flag still uses its own (separate) cache
-    entry."""
+def test_routing_mesh_solve_gated_off(wide_problem, on_tpu, monkeypatch,
+                                      devices8):
+    """ADVICE r4: a mesh-sharded SPMD solve must NOT trace the kernel
+    (pallas_call has no sharding annotations) — with no flag to have been
+    set or forgotten: ``run(mesh=...)`` and the lambda lanes trace inside
+    ``disabled()`` unconditionally, run the XLA path and match the
+    off-TPU result bit for bit."""
     import jax
 
     from photon_tpu.function.objective import L2Regularization
@@ -197,45 +352,128 @@ def test_flag_mesh_solve_gated_off(monkeypatch, devices8):
     from photon_tpu.types import TaskType
     from photon_tpu.utils import jitcache
 
-    rng = np.random.default_rng(5)
-    n, d = 256, 16
-    X = rng.normal(size=(n, d)).astype(np.float32)
-    y = (rng.random(n) < 0.5).astype(np.float32)
-    batch = DataBatch(jnp.asarray(X), jnp.asarray(y))
+    X, y, _, _, _ = wide_problem
+    X, y = X[:256], y[:256]
+    batch = DataBatch(X, y)
     cfg = GLMOptimizationConfiguration(
-        optimizer=OptimizerConfig(max_iterations=40, tolerance=1e-8),
+        optimizer=OptimizerConfig(max_iterations=10, tolerance=1e-8),
         regularization=L2Regularization, regularization_weight=1.0)
     mesh = M.create_mesh(8, (M.DATA_AXIS,), (8,))
 
     def run_mesh():
+        jitcache.clear()
         prob = GlmOptimizationProblem(TaskType.LOGISTIC_REGRESSION, cfg)
-        m, _ = prob.run(batch, dim=d, dtype=jnp.float32, mesh=mesh)
+        m, _ = prob.run(batch, dim=X.shape[1], dtype=jnp.float32, mesh=mesh)
         return np.asarray(m.coefficients.means)
 
-    jitcache.clear()
-    c_off = run_mesh()
-    monkeypatch.setenv("PHOTON_TPU_PALLAS_GLM", "1")
-    jitcache.clear()
-    c_on = run_mesh()
+    def run_swept():
+        jitcache.clear()
+        prob = GlmOptimizationProblem(TaskType.LOGISTIC_REGRESSION, cfg)
+        return np.asarray(prob.solve_swept(
+            batch, [0.5, 2.0], dim=X.shape[1], dtype=jnp.float32).coefs)
+
+    before = _ticks()
+    c_on, s_on = run_mesh(), run_swept()
+    took = _ticked(before)
+    # the mesh solve under "mesh"; the lanes are a vmap inside disabled()
+    assert set(took) == {"mesh", "vmap"}, took
+    monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
+    c_off, s_off = run_mesh(), run_swept()
     # bitwise: the same (XLA) trace must have been used
     np.testing.assert_array_equal(c_on, c_off)
-    # and the kernel is hard-disabled at trace time inside disabled()
-    from photon_tpu.ops import pallas_glm
-    with pallas_glm.disabled():
-        assert not pallas_glm._supported(
-            jnp.zeros((8, 4), jnp.float32), _IDN, jnp.zeros(4, jnp.float32))
+    np.testing.assert_array_equal(s_on, s_off)
     jitcache.clear()
 
 
-def test_supported_rejects_f64_coef():
-    """ADVICE r4: an f64 solve over f32 features must not take the fused
-    path (it would silently return f32 and break the while_loop carry
-    dtype); the XLA path promotes instead."""
+@pytest.mark.parametrize("case", ["float64_coef", "normalised", "bfloat16_x",
+                                  "sparse", "not_a_tpu", "disabled", "vmap",
+                                  "narrow", "too_wide", "admitted"])
+def test_dense_route(case, monkeypatch):
+    """What the predicate sees, case by case: ``None`` (not the kernel's
+    case: XLA's two passes, uncounted), the kernel, or the reason a TPU's
+    dense / identity / float32 evaluation was turned away. ADVICE r4: an
+    f64 solve over f32 features must not take the fused path (it would
+    silently return f32 and break the while_loop carry dtype); the XLA
+    path promotes instead."""
+    from photon_tpu.ops import features as F
     from photon_tpu.ops import pallas_glm
+    from photon_tpu.ops.normalization import NormalizationContext
 
-    x = jnp.zeros((8, 4), jnp.float32)
-    assert pallas_glm._supported(x, _IDN, jnp.zeros(4, jnp.float32))
-    assert not pallas_glm._supported(x, _IDN, jnp.zeros(4, jnp.float64))
+    monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: case != "not_a_tpu")
+    d = pallas_glm._DENSE_MIN_WIDTH
+    x = jnp.zeros((8, d), jnp.float32)
+    coef = jnp.zeros(d, jnp.float32)
+    route = lambda x=x, norm=_IDN, coef=coef: pallas_glm.dense_route(
+        x, norm, coef)
+    if case == "float64_coef":
+        assert route(coef=jnp.zeros(d, jnp.float64)) is None
+    elif case == "normalised":
+        assert route(norm=NormalizationContext(
+            factors=jnp.ones(d, jnp.float32), shifts=None)) is None
+    elif case == "bfloat16_x":
+        # the kernel takes bfloat16 rows when called (below); nothing has
+        # timed it, so nothing routes it
+        assert route(x=x.astype(jnp.bfloat16)) is None
+    elif case == "sparse":
+        assert route(x=F.SparseFeatures(jnp.zeros((8, 2), jnp.int32),
+                                        jnp.zeros((8, 2), jnp.float32))) is None
+    elif case == "not_a_tpu":
+        assert route() is None
+    elif case == "disabled":
+        with pallas_glm.disabled():
+            assert route() == "mesh"
+        assert route() == pallas_glm.KERNEL
+    elif case == "vmap":
+        seen = []
+        jax.vmap(lambda c: seen.append(route(coef=c)) or c)(
+            jnp.zeros((3, d), jnp.float32))
+        assert seen == ["vmap"]
+    elif case == "narrow":
+        assert d > 1, "the gate admits every width: drop this case"
+        assert route(x=x[:, :d - 1], coef=coef[:d - 1]) == "shape"
+    elif case == "too_wide":
+        wide = pallas_glm._MAX_DENSE_DIM + 1
+        assert route(x=jnp.zeros((8, wide), jnp.float32),
+                     coef=jnp.zeros(wide, jnp.float32)) == "shape"
+    else:
+        assert route() == pallas_glm.KERNEL
+        assert route(x=jnp.zeros((8, 2000), jnp.float32),
+                     coef=jnp.zeros(2000, jnp.float32)) == pallas_glm.KERNEL
+
+
+def test_refusals_tick_only_where_a_tpu_turned_the_kernel_away(
+        wide_problem, on_tpu, monkeypatch):
+    """``kernels.xla_fallbacks{path=dense, reason}`` ticks once a traced
+    program for ``vmap``, ``mesh`` and ``shape``; float64 coefficients, a
+    normalised objective and a non-TPU backend are not the kernel's case
+    and tick nothing."""
+    from photon_tpu.ops.normalization import NormalizationContext
+
+    X, y, off, w, coef = wide_problem
+    vg = lambda x, c, norm=_IDN: aggregators.value_and_gradient(
+        LogisticLoss, x, y, off, w, c, norm)
+    d = X.shape[1]
+
+    before = _ticks()
+    vg(X, coef.astype(jnp.float64))
+    vg(X, coef, NormalizationContext(factors=jnp.ones(d, jnp.float32),
+                                     shifts=None))
+    assert _ticked(before) == {}
+    before = _ticks()
+    narrow = on_tpu._DENSE_MIN_WIDTH - 1
+    vg(X[:, :narrow], coef[:narrow])
+    assert _ticked(before) == {"shape": 1}
+    before = _ticks()
+    jax.vmap(lambda c: vg(X, c))(jnp.stack([coef, coef]))
+    assert _ticked(before) == {"vmap": 1}
+    before = _ticks()
+    with on_tpu.disabled():
+        vg(X, coef)
+    assert _ticked(before) == {"mesh": 1}
+    monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
+    before = _ticks()
+    vg(X, coef)
+    assert _ticked(before) == {}
 
 
 def test_fused_bf16_feature_storage():
@@ -247,9 +485,6 @@ def test_fused_bf16_feature_storage():
     X16 = jnp.asarray(rng.normal(size=(n, d)), jnp.bfloat16)
     y = jnp.asarray((rng.random(n) > 0.4), jnp.float32)
     coef = jnp.asarray(rng.normal(size=d) * 0.3, jnp.float32)
-
-    from photon_tpu.ops import pallas_glm
-    assert pallas_glm._supported(X16, _IDN, coef)
 
     v_f, g_f = fused_dense_value_grad(LogisticLoss, X16, y, None, None, coef)
     v_x, g_x = aggregators.value_and_gradient(
@@ -405,3 +640,181 @@ def test_serving_supported_gate():
         jnp.zeros(pallas_glm._MAX_SPARSE_DIM + 1, jnp.float32), 4)
     with pallas_glm.disabled():
         assert not pallas_glm._supported_serving(theta, 4)
+
+
+def test_entry_hook_prefetches_the_toolchain_only_off_the_cpu(monkeypatch,
+                                                              tmp_path):
+    """``compile_cache.maybe_enable`` (every driver's first call) starts
+    the Pallas import on a daemon thread where the process is not held to
+    the CPU, once; a CPU run (this suite) starts none."""
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.utils import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV_OPT_OUT, "1")   # touch no cache
+    monkeypatch.setattr(pallas_glm, "_PREFETCH", None)
+    assert compile_cache._held_to_cpu()
+    compile_cache.maybe_enable()
+    assert pallas_glm._PREFETCH is None
+    monkeypatch.setattr(compile_cache, "_held_to_cpu", lambda: False)
+    compile_cache.maybe_enable()
+    thread = pallas_glm._PREFETCH
+    assert thread is not None and thread.daemon
+    compile_cache.maybe_enable()
+    assert pallas_glm._PREFETCH is thread
+    thread.join(60)
+    assert not thread.is_alive()
+    import sys
+    assert "jax.experimental.pallas.tpu" in sys.modules
+
+
+# ---------------------------------------------------------------------------
+# compiled FOR the chip, from here: what interpret mode cannot show (the
+# TPU's compiler is installed; the chip is described, not attached)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device's sharding; the persistent compile cache
+    is off around the compiles (an entry written for a described chip
+    cannot be read back without one)."""
+    os = __import__("os")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shaped(v5e, *shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+
+@pytest.mark.parametrize("n,d,dtype", [
+    (530_000, 2_000, jnp.float32),      # fe-epsilon: ragged both ways
+    (4_000_000, 256, jnp.float32),      # the least width the gate admits
+    (500_000, 4_096, jnp.float32),      # the widest
+    (8_101, 2_000, jnp.bfloat16),       # packed rows, an odd ragged edge
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_dense_kernel_compiles_for_a_v5e(v5e, n, d, dtype):
+    """Mosaic takes the kernel at the real shapes, inside the scoped VMEM
+    a core hands out by default, and the program around it holds no copy
+    of X (the matrix goes in as placed: here row-major, as the caller
+    states it)."""
+    f = jax.jit(lambda x, y, off, w, c: fused_dense_value_grad(
+        LogisticLoss, x, y, off, w, c, interpret=False))
+    row = _shaped(v5e, n)
+    compiled = f.lower(_shaped(v5e, n, d, dtype=dtype), row, row, row,
+                       _shaped(v5e, d)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    x_bytes = n * d * jnp.dtype(dtype).itemsize
+    # the argument's default layout is column-major where the width is no
+    # multiple of 128: ONE re-layout then, the compiler's; never two
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (1.1 if d % 128 else 0.1) * x_bytes, temp
+
+
+def test_routed_solve_compiles_for_a_v5e_without_a_copy_of_its_own(
+        v5e, monkeypatch):
+    """The fe-epsilon solve as the chip's compiler leaves it: the kernel
+    twice (the solver's first evaluation, the line search's), under
+    ``agg/value_and_gradient``; ONE X-sized temporary, the re-layout copy
+    the parent's program holds too (S10), so the same temporary bytes as
+    the XLA program's; no ``pad`` that makes a matrix."""
+    import re
+
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import TaskType
+    from photon_tpu.utils import jitcache
+
+    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
+    n, d = 530_000, 2_000
+    row, one = _shaped(v5e, n), _shaped(v5e)
+    batch = DataBatch(_shaped(v5e, n, d), row, row, row)
+
+    def compiled(routed):
+        monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: routed)
+        jitcache.clear()
+        prob = GlmOptimizationProblem(
+            TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+                optimizer=OptimizerConfig(max_iterations=100, tolerance=1e-6),
+                regularization=L2Regularization, regularization_weight=1.0))
+        try:
+            return prob._solve_fn.lower(_shaped(v5e, d), batch, one,
+                                        one).compile()
+        finally:
+            jitcache.clear()
+
+    fused, xla = compiled(True), compiled(False)
+    text = fused.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]+)"', text)
+    assert len(names) == 2, names
+    assert all("agg/value_and_gradient/jit(_fused)/pallas_call" in name
+               for name in names), names
+    assert "tpu_custom_call" not in xla.as_text()
+    temp = [c.memory_analysis().temp_size_in_bytes for c in (fused, xla)]
+    x_tiled = n * 2_048 * 4
+    assert x_tiled <= temp[0] < 1.01 * x_tiled, temp
+    assert abs(temp[0] - temp[1]) < 0.001 * x_tiled, temp
+    padded = re.findall(r"= f32\[(\d+),(\d+)\][^=\n]* pad\(", text)
+    assert not [shape for shape in padded if int(shape[0]) >= n], padded
+
+
+def _stores_to(jaxpr, refs):
+    """The equations of ``jaxpr`` (and of the jaxprs its ``cond`` /
+    ``scan`` / ``pjit`` equations hold, their operands matched by
+    position) that store to one of the variables ``refs``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name in ("swap", "masked_swap", "addupdate")
+                and not hasattr(eqn.invars[0], "val")
+                and eqn.invars[0] in refs):
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    skip = len(eqn.invars) - len(inner.invars)
+                    found += _stores_to(inner, {
+                        inner.invars[k - skip]
+                        for k, var in enumerate(eqn.invars)
+                        if k >= skip and not hasattr(var, "val")
+                        and var in refs})
+    return found
+
+
+def test_dense_kernel_stores_to_no_input():
+    """The kernel writes its two outputs and its two scratch vectors and
+    nothing else: where XLA has placed a small X in VMEM a block of an
+    input IS the operand, and a store past its end lands in whatever lies
+    behind it (on the chip 8,100 x 2,000 read 0.0 for it; interpret mode
+    cannot show it, so the kernel's jaxpr is held to it)."""
+    n, d = 700, 2000
+    args = (jnp.zeros((n, d), jnp.float32),) + (jnp.zeros(n, jnp.float32),) * 3
+    jaxpr = jax.make_jaxpr(lambda *a: fused_dense_value_grad(
+        LogisticLoss, *a, jnp.zeros(d, jnp.float32)))(*args)
+    (call,) = [e for e in _eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    kernel = call.params["jaxpr"]
+    inputs, rest = set(kernel.invars[:5]), set(kernel.invars[5:])
+    assert not _stores_to(kernel, inputs)
+    assert _stores_to(kernel, rest)       # the walk does see a store

@@ -107,6 +107,73 @@ def test_a_solve_lowers_with_every_step_and_aggregator_named(solver, sparse):
     assert not found & others, sorted(found & others)
 
 
+@pytest.mark.parametrize("solver", ["LBFGS", "OWLQN", "NEWTON", "TRON"])
+def test_the_fused_kernel_lowers_under_value_and_gradient(solver,
+                                                          monkeypatch):
+    """On a TPU a dense solve of an admitted width holds the ONE fused
+    kernel wherever the objective is evaluated, and every call of it is
+    located under ``agg/value_and_gradient`` (so ``benchmark/
+    scope_reader.py`` keeps charging its seconds to ``aggregators``). The
+    program is lowered FOR a TPU from here (Mosaic serialises the kernel
+    into the custom call; nothing is compiled or run)."""
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.utils import jitcache
+
+    task, reg, steps, _, _ = SOLVERS[solver]
+    monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
+    jitcache.clear()
+    n, d = 300, 2000
+    batch = DataBatch(jnp.zeros((n, d), jnp.float32),
+                      jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32),
+                      jnp.ones(n, jnp.float32))
+    problem = GlmOptimizationProblem(task, GLMOptimizationConfiguration(
+        optimizer=OptimizerConfig(optimizer_type=OptimizerType[solver],
+                                  max_iterations=5),
+        regularization=reg, regularization_weight=1.0))
+    one = jnp.float32(1.0)
+    try:
+        text = problem._solve_fn.trace(
+            jnp.zeros(d, jnp.float32), batch, one, one).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+    finally:
+        jitcache.clear()
+    assert "tpu_custom_call" in text
+    locs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", text, flags=re.M))
+    calls = [locs[ref] for ref in re.findall(
+        r"call @_fused\w*\(.*loc\((#loc\d+)\)\s*$", text, flags=re.M)]
+    assert calls
+    for loc in calls:
+        assert re.search(r"agg/value_and_gradient/jit\(_fused\)", loc), loc
+        # the innermost scope of the call is the aggregator's
+        assert _SCOPE.findall(loc)[-1] == "agg/value_and_gradient", loc
+    assert steps <= scopes_in(text), sorted(steps - scopes_in(text))
+    if solver in ("LBFGS", "OWLQN"):
+        # X theta is inside the kernel: no first pass of its own
+        assert "agg/margins" not in scopes_in(text)
+
+
+def test_the_kernel_flag_is_gone():
+    """An environment flag selected the fused kernel by hand until PR 32;
+    the kernel is chosen by backend and shape now, and no file a user or
+    a driver runs reads the flag's name."""
+    paths = [os.path.join(REPO, "bench.py"), os.path.join(REPO, "README.md"),
+             os.path.join(REPO, "chip_smoke.py")]
+    for top in ("photon_tpu", "scripts", "benchmark"):
+        paths += glob.glob(os.path.join(REPO, top, "**", "*.*"),
+                           recursive=True)
+    paths = [p for p in paths if os.path.isfile(p)
+             and not p.endswith((".pyc", ".so", ".pb", ".gz", ".avro"))]
+    assert len(paths) > 100
+    flag = "PHOTON_TPU_" + "PALLAS_GLM"
+    holders = []
+    for path in paths:
+        with open(path, errors="ignore") as f:
+            if flag in f.read():
+                holders.append(os.path.relpath(path, REPO))
+    assert not holders, holders
+
+
 def _vmapped_solve_names(solver, k):
     """The named locations of a vmapped NEWTON or DIRECT solve of ``k``
     coefficients, as lowered."""
