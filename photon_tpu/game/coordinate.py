@@ -270,6 +270,27 @@ class FixedEffectCoordinate:
                              else c.variances[: self.dim]), model.task)
         return FixedEffectModel(model, self.feature_shard_id)
 
+    def tron_counts(self) -> Optional[dict]:
+        """The last update's curvature work under TRON: ``{"cg_steps",
+        "hessian_builds", "rejected_steps"}``, None under any other solver
+        or before an update. ``last_result`` carries them as device
+        scalars; they cross to the host HERE, when asked (an update reads
+        the failure code and nothing else), and feed the counters
+        ``solver.tron.cg_steps`` / ``solver.tron.rejected_steps`` once a
+        result."""
+        result = getattr(self, "last_result", None)
+        if result is None or result.cg_steps is None:
+            return None
+        cg, builds, rejected = (int(v) for v in jax.device_get(  # host-sync-ok: read when asked, after the fit
+            (result.cg_steps, result.hessian_builds, result.rejected_steps)))
+        if getattr(self, "_tron_counted", None) is not result:
+            from photon_tpu.obs.metrics import registry
+            self._tron_counted = result
+            registry.counter("solver.tron.cg_steps").inc(cg)
+            registry.counter("solver.tron.rejected_steps").inc(rejected)
+        return {"cg_steps": cg, "hessian_builds": builds,
+                "rejected_steps": rejected}
+
     def score(self, model: FixedEffectModel) -> Array:
         """Training-data scores WITHOUT offsets — coordinate-descent score
         algebra sums raw model scores (reference: scoreForCoordinateDescent).

@@ -224,7 +224,10 @@ def hessian_vector_from_weights(
     norm: NormalizationContext,
     dim: int,
 ) -> Array:
-    """Hv given precomputed curvature weights: two passes over X."""
+    """Hv given precomputed curvature weights: TWO passes over X (``X v``,
+    then ``X^T (d2 * Xv)``; 11.2 ms at 530,000 x 2,000 float32 on a TPU
+    v5e, what a value-and-gradient evaluation cost before it was fused;
+    PERF.md §5, PR 33)."""
     v_eff = vector * norm.factors if norm.factors is not None else vector
     t = matvec(x, v_eff)
     if norm.shifts is not None:
@@ -243,8 +246,12 @@ def hessian_matrix_from_weights(
 ) -> Array:
     """Full H from precomputed curvature weights: one GEMM (MXU).
 
-    For small feature dims this turns a whole CG solve's data passes into a
-    single ``X^T diag(d2) X`` contraction plus O(d^2) matvecs."""
+    This turns a whole CG solve's data passes into a single
+    ``X^T diag(d2) X`` contraction plus O(d^2) matvecs. On a TPU v5e the
+    contraction costs what ONE matrix-free product costs up to some 1,000
+    features (it is bound by its two reads of X) and 2.3 products at 2,000
+    (PERF.md §5, PR 33): ``optim/problem.tron_explicit_hessian`` gates
+    TRON's use of it by that."""
     h = weighted_gram(x, d2, dim)
     if norm.shifts is not None:
         lin = rmatvec(x, d2, dim)

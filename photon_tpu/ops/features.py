@@ -293,7 +293,13 @@ def weighted_gram(x: FeatureMatrix, w: Array, dim: int) -> Array:
             return h
         dense = to_dense(x, dim)
         return dense.T @ (dense * w[:, None])
-    return x.T @ (x * w[:, None])
+    # DEFAULT, stated: on a TPU ONE bfloat16 pass of the MXU. The Hessian's
+    # callers (NEWTON, TRON) take their gradient exactly, so an inexact
+    # Hessian moves iteration counts, not the optimum, and at DEFAULT it
+    # moved none: epsilon's TRON fit is 5 iterations / 8 CG steps at
+    # DEFAULT, HIGH and HIGHEST alike, for 25.9 / 69.0 / 141.1 ms a build
+    # at 530,000 x 2,000 (PERF.md §5, my chip runs, PR 33)
+    return jnp.matmul(x.T, x * w[:, None], precision=jax.lax.Precision.DEFAULT)
 
 
 def to_dense(x: FeatureMatrix, dim: int) -> Array:

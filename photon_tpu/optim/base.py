@@ -99,6 +99,14 @@ class SolverResult(NamedTuple):
     # int32 FailureMode; None only for legacy constructions that predate
     # the non-finite guards (treated as NONE by consumers)
     failure: Optional[Array] = None
+    # TRON's curvature work, int32 scalars; None (no leaves: no other
+    # solver's program carries them) everywhere else. ``cg_steps``: one
+    # operator product each; ``hessian_builds``: ``hess_setup`` calls that
+    # ran (one at the start, one after each accepted step that is not the
+    # last); ``rejected_steps``: trial points the trust region refused
+    cg_steps: Optional[Array] = None
+    hessian_builds: Optional[Array] = None
+    rejected_steps: Optional[Array] = None
 
 
 class StateTracking(NamedTuple):

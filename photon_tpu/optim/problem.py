@@ -118,7 +118,7 @@ class OptimizerConfig:
     # TRON Hessian strategy: True = build the d x d Gauss-Newton matrix once
     # per outer iteration (one MXU GEMM; CG steps become O(d^2)); False =
     # matrix-free Hv with per-iteration curvature weights; None = auto
-    # (explicit for dense features with dim <= 2048)
+    # (``tron_explicit_hessian``: dense features and, on a TPU, dim <= 1024)
     explicit_hessian: Optional[bool] = None
 
     def solver_config(self) -> SolverConfig:
@@ -241,26 +241,26 @@ class GlmOptimizationProblem:
                     return owlqn.minimize(vg, x0, l1_weight=l1, config=solver_cfg)
                 if opt.optimizer_type == OptimizerType.TRON:
                     # Hessian operator split: curvature weights once per
-                    # outer iteration; explicit d x d Gauss-Newton matrix
-                    # (single GEMM -> MXU) when the dim is small and the
-                    # features dense, matrix-free Hv otherwise.
+                    # operator build; the explicit d x d Gauss-Newton
+                    # matrix (one MXU contraction a build, no pass over X
+                    # a CG step) or matrix-free products (two passes a
+                    # step), by ``tron_explicit_hessian``
                     from photon_tpu.ops.features import (
                         ModelShardedSparse,
                         SparseFeatures,
                     )
                     dim = x0.shape[0]
-                    dense = not isinstance(
-                        batch.features, (SparseFeatures, ModelShardedSparse))
                     explicit = opt.explicit_hessian
                     if explicit is None:
-                        # auto: the d x d GEMM rebuild per outer iteration
-                        # is an MXU bargain at any moderate dim (measured
-                        # 20x faster on TPU v5e at d=512); on host CPU the
-                        # crossover vs matrix-free Hv sits between d=256
-                        # (1.5x faster) and d=512 (1.3x slower)
-                        on_tpu = jax.default_backend() not in ("cpu",)
-                        explicit = dense and (dim <= 2048 if on_tpu
-                                              else dim <= 256)
+                        explicit = tron_explicit_hessian(
+                            not isinstance(batch.features, (
+                                SparseFeatures, ModelShardedSparse)), dim)
+                    # ticked at TRACE time: once a traced solve, with the
+                    # operator the solve was traced with
+                    from photon_tpu.obs.metrics import registry
+                    registry.counter(
+                        "kernels.tron_hessian",
+                        path="explicit" if explicit else "matrix_free").inc()
                     if explicit:
                         hs = lambda c: obj.hessian_matrix_from_weights(
                             obj.hessian_weights(c, batch), dim, batch, hyper)
@@ -712,3 +712,35 @@ class GlmOptimizationProblem:
         if variance_type == VarianceComputationType.SIMPLE:
             return simple(coef, batch, l2)
         return full(coef, batch, l2)
+
+
+# (At the end of the module: the fused kernel's serialised body carries its
+# traceback's line numbers, so a line added above ``solve`` re-keys the
+# compile cache of every kernel-bearing program; PERF.md §6, PR 33.)
+#
+# TRON's explicit-or-matrix-free gate, by what a solve can observe: dense
+# features, the coefficient dimension, the backend. On a TPU v5e (PERF.md §5,
+# my chip runs, PR 33; ms inside one program, float32 rows): one explicit
+# ``X^T D X`` build at DEFAULT precision / one matrix-free product is 5.4 /
+# 5.4 at 4,000,000 x 128, 10.9 / 10.8 at 2,000,000 x 512 (the build is bound
+# by its two reads of X, as the product is, so explicit pays from the first
+# CG step: whole fits 0.100 against 0.133 s and 0.118 against 0.205 s) and
+# 25.9 / 11.2 at 530,000 x 2,000 (the build is bound by the MXU and costs
+# 2.3 products, epsilon's fit takes 1.6 CG steps a build: 0.207 against
+# 0.173 s, matrix-free wins). 1,024 is the last power of two at which a
+# build stays within a fifth of one product. Past it a build pays only on a
+# problem that needs three CG steps a build or more, which a solve cannot
+# know before it runs; ``explicit_hessian=True`` is there for one that does.
+# On a host CPU the crossover sits between d = 256 (explicit 1.5x faster)
+# and d = 512 (1.3x slower).
+TRON_EXPLICIT_MAX_DIM_TPU = 1024
+TRON_EXPLICIT_MAX_DIM_CPU = 256
+
+
+def tron_explicit_hessian(dense: bool, dim: int) -> bool:
+    """Whether TRON builds ``X^T D X`` once an accepted step (True) or
+    applies it matrix-free, two passes over X a CG step (False), where the
+    configuration leaves ``explicit_hessian`` at None."""
+    return dense and dim <= (TRON_EXPLICIT_MAX_DIM_CPU
+                             if jax.default_backend() == "cpu"
+                             else TRON_EXPLICIT_MAX_DIM_TPU)

@@ -8,8 +8,16 @@ truncatedConjugateGradientMethod :278): outer trust-region loop with
 non-improvement capped at ``max_improvement_failures`` (5). Defaults
 maxIter=15, tol=1e-5, CG cap 20 (TRON.scala:256-262).
 
-Each Hv product is one fused aggregator pass (ops/aggregators.py) — the
-reference's extra treeAggregate per CG step becomes an extra XLA matvec.
+What a Hessian-vector product costs depends on the operator the caller
+hands in (``optim/problem.py`` chooses it): matrix-free, TWO passes over X
+a product (``agg/hessian_vector``: ``X v``, then ``X^T (d2 * Xv)``) where
+the reference pays a treeAggregate; explicit, NO pass over X (one
+``[d, d] @ [d]`` product under ``optim/tron/direction``) after one
+``X^T D X`` contraction an operator build (``agg/hessian_matrix``). The
+operator belongs to a POINT: it is built once at the start and again only
+after an accepted step, never after a rejected one (the point did not
+move), and ``SolverResult`` counts the builds, the CG steps and the
+rejected steps of a solve.
 
 Each step of an iteration runs under a ``jax.named_scope``
 ``optim/tron/<step>``: ``init``, ``hessian`` (the once-an-iteration
@@ -57,7 +65,8 @@ class _CGCarry(NamedTuple):
 def _trcg(hess_vec, g, delta, max_cg, cg_tol_factor, *args):
     """Steihaug truncated CG: approximately solve H s = -g within ||s||<=delta.
 
-    Returns (s, r) with r the final residual -g - Hs (used in prered).
+    Returns (s, r, steps) with r the final residual -g - Hs (used in
+    prered) and steps the CG steps taken (one operator product each).
     """
     dtype = g.dtype
     r0 = -g
@@ -101,7 +110,7 @@ def _trcg(hess_vec, g, delta, max_cg, cg_tol_factor, *args):
         it=jnp.asarray(0, jnp.int32), done=jnp.asarray(False),
     )
     out = lax.while_loop(cond, body, init)
-    return out.s, out.r
+    return out.s, out.r, out.it
 
 
 class _Carry(NamedTuple):
@@ -117,6 +126,11 @@ class _Carry(NamedTuple):
     nf_count: Array   # consecutive non-finite trial steps
     failure: Array    # int32 FailureMode (non-zero terminates the loop)
     trk: "Optional[StateTracking]"  # per-iteration ring buffer (None = off)
+    hstate: object    # hess_setup's operator at x (None without hess_setup)
+    stale: Array      # bool: x has moved since hstate was built
+    cg_steps: Array   # int32, summed over the outer iterations
+    builds: Array     # int32 hess_setup calls that ran
+    rejected: Array   # int32 trial steps refused
 
 
 def minimize(
@@ -138,7 +152,9 @@ def minimize(
     small dims) and a cheap per-CG-step ``hess_apply(hstate, v, *args)``.
     The GLM Hessian at fixed x is fully determined by per-sample curvature
     weights, so this removes one full data pass from every CG step
-    (reference pays it: HessianVectorAggregator.scala:37)."""
+    (reference pays it: HessianVectorAggregator.scala:37). ``hess_setup``
+    runs under a ``lax.cond`` on "x moved since the last build": a rejected
+    step keeps the operator it was computed with."""
     with jax.named_scope("optim/tron/init"):
         f0, g0 = value_and_grad(x0, *args)
         tols = absolute_tolerances(f0, g0, config.tolerance)
@@ -151,13 +167,16 @@ def minimize(
     def body(c: _Carry) -> _Carry:
         if hess_setup is not None:
             with jax.named_scope("optim/tron/hessian"):
-                hstate = hess_setup(c.x, *args)
+                hstate = lax.cond(c.stale,
+                                  lambda: hess_setup(c.x, *args),
+                                  lambda: c.hstate)
             hv = lambda v: hess_apply(hstate, v, *args)
         else:
+            hstate = None
             hv = lambda v: hess_vec(c.x, v, *args)
         with jax.named_scope("optim/tron/direction"):
-            s, r = _trcg(lambda v, *_: hv(v), c.g, c.delta,
-                         config.max_cg_iterations, cg_tol_factor)
+            s, r, cg = _trcg(lambda v, *_: hv(v), c.g, c.delta,
+                             config.max_cg_iterations, cg_tol_factor)
 
         with jax.named_scope("optim/tron/trial"):
             gs = jnp.dot(c.g, s)
@@ -168,21 +187,25 @@ def minimize(
             snorm = jnp.linalg.norm(s)
 
         with jax.named_scope("optim/tron/update"):
-            # trust-radius update (LIBLINEAR/TRON.scala constants)
+            # trust-radius update (LIBLINEAR/TRON.scala constants); the
+            # first step's length caps the initial radius ||g0||
+            # (tron.cpp: ``if (iter == 1) delta = min(delta, snorm)``)
+            delta_in = jnp.where((c.it == 0) & jnp.isfinite(snorm),
+                                 jnp.minimum(c.delta, snorm), c.delta)
             denom = f_try - c.f - gs
             alpha = jnp.where(denom <= 0, _SIGMA3,
                               jnp.maximum(_SIGMA1, -0.5 * (gs / jnp.where(denom != 0, denom, 1.0))))
             asn = alpha * snorm
             delta = jnp.where(
                 actred < _ETA0 * prered,
-                jnp.minimum(jnp.maximum(asn, _SIGMA1 * snorm), _SIGMA2 * c.delta),
+                jnp.minimum(jnp.maximum(asn, _SIGMA1 * snorm), _SIGMA2 * delta_in),
                 jnp.where(
                     actred < _ETA1 * prered,
-                    jnp.maximum(_SIGMA1 * c.delta, jnp.minimum(asn, _SIGMA2 * c.delta)),
+                    jnp.maximum(_SIGMA1 * delta_in, jnp.minimum(asn, _SIGMA2 * delta_in)),
                     jnp.where(
                         actred < _ETA2 * prered,
-                        jnp.maximum(_SIGMA1 * c.delta, jnp.minimum(asn, _SIGMA3 * c.delta)),
-                        jnp.maximum(c.delta, jnp.minimum(asn, _SIGMA3 * c.delta)),
+                        jnp.maximum(_SIGMA1 * delta_in, jnp.minimum(asn, _SIGMA3 * delta_in)),
+                        jnp.maximum(delta_in, jnp.minimum(asn, _SIGMA3 * delta_in)),
                     ),
                 ),
             )
@@ -195,7 +218,7 @@ def minimize(
             g_fin = jnp.all(jnp.isfinite(g_try))
             fin = jnp.isfinite(f_try) & g_fin
             accept = fin & (actred > _ETA0 * prered)
-            delta = jnp.where(jnp.isfinite(delta), delta, 0.5 * c.delta)
+            delta = jnp.where(jnp.isfinite(delta), delta, 0.5 * delta_in)
             x_new = jnp.where(accept, x_try, c.x)
             f_new = jnp.where(accept, f_try, c.f)
             g_new = jnp.where(accept, g_try, c.g)
@@ -227,9 +250,13 @@ def minimize(
         return _Carry(x=x_new, f=f_new, g=g_new, f_prev=c.f, delta=delta,
                       it=it, failures=failures, reason=reason,
                       n_evals=c.n_evals + 1, nf_count=nf_count,
-                      failure=failure, trk=trk)
+                      failure=failure, trk=trk, hstate=hstate, stale=accept,
+                      cg_steps=c.cg_steps + cg,
+                      builds=c.builds + c.stale.astype(jnp.int32),
+                      rejected=c.rejected + (~accept).astype(jnp.int32))
 
     with jax.named_scope("optim/tron/init"):
+        zero = jnp.asarray(0, jnp.int32)
         init = _Carry(
             x=x0, f=f0, g=g0, f_prev=f0,
             delta=jnp.linalg.norm(g0).astype(dtype),
@@ -244,6 +271,12 @@ def minimize(
             nf_count=jnp.asarray(0, jnp.int32),
             failure=nonfinite_code(f0, jnp.all(jnp.isfinite(g0))),
             trk=StateTracking.init(config.track_states, dtype),
+            # a placeholder of the operator's shape: the first trip builds
+            hstate=None if hess_setup is None else jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(hess_setup, x0, *args)),
+            stale=jnp.asarray(hess_setup is not None),
+            cg_steps=zero, builds=zero, rejected=zero,
         )
 
     with jax.named_scope("optim/tron/loop"):
@@ -255,4 +288,6 @@ def minimize(
         gnorm_history=None if out.trk is None else out.trk.gnorm,
         step_history=None if out.trk is None else out.trk.step,
         failure=out.failure,
+        cg_steps=out.cg_steps, hessian_builds=out.builds,
+        rejected_steps=out.rejected,
     )

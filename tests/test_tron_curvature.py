@@ -1,0 +1,385 @@
+"""TRON as a deployment runs it (PERF.md §4, ``fe-epsilon-tron``): the fit
+through ``GameEstimator.fit`` against a plain NumPy float64 trust-region
+Newton written from LIBLINEAR's description (Lin, Weng, Keerthi, JMLR 9,
+2008; tron.cpp), the explicit and the matrix-free Hessian against each
+other, the gate between them, the curvature counts ``SolverResult`` carries
+for TRON and for no other solver, and no operator build after a refused
+step."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from photon_tpu.data.dataset import DataBatch
+from photon_tpu.estimators.game_estimator import (
+    CoordinateConfiguration,
+    FixedEffectDataConfiguration,
+    GameEstimator,
+)
+from photon_tpu.function.objective import L1Regularization, L2Regularization
+from photon_tpu.game.dataset import FeatureShard, GameDataFrame
+from photon_tpu.obs.metrics import registry
+from photon_tpu.ops import features as F
+from photon_tpu.optim import problem as P
+from photon_tpu.optim import tron
+from photon_tpu.optim.base import SolverConfig
+from photon_tpu.optim.problem import (
+    GLMOptimizationConfiguration,
+    GlmOptimizationProblem,
+    OptimizerConfig,
+)
+from photon_tpu.types import OptimizerType, TaskType
+from photon_tpu.utils import jitcache
+
+N, D, L2 = 3000, 40, 1.0
+
+
+# --------------------------------------------------------------------------
+# the plain reference: nothing of photon_tpu below this line
+# --------------------------------------------------------------------------
+
+def numpy_tron(x, y, offsets, l2, max_iterations=15, tolerance=1e-5,
+               max_cg=20, max_failures=5):
+    """LIBLINEAR's trust-region Newton for L2-regularised logistic
+    regression, float64: the outer loop of tron.cpp (eta0/1/2 = 1e-4, 0.25,
+    0.75; sigma1/2/3 = 0.25, 0.5, 4; the first step caps the radius),
+    Steihaug's truncated CG (trcg: stop at ||r|| <= 0.1 ||g||, step to the
+    boundary when the iterate leaves the region), with Photon ML's caps and
+    stopping rule around it (TRON.scala:256-262, Optimizer.scala:135-149:
+    iterations, then the objective's change, then the gradient, each
+    relative to the start; five refused steps in a row end the solve).
+    Returns (w, accepted flags, CG steps)."""
+    x = x.astype(np.float64)
+    y = y.astype(np.float64)
+
+    def fun(w):
+        z = x @ w + offsets
+        return np.sum(np.logaddexp(0.0, z) - y * z) + 0.5 * l2 * w @ w
+
+    def grad(w):
+        z = x @ w + offsets
+        return x.T @ (1.0 / (1.0 + np.exp(-z)) - y) + l2 * w
+
+    def curvature(w):
+        p = 1.0 / (1.0 + np.exp(-(x @ w + offsets)))
+        return p * (1.0 - p)
+
+    def trcg(d2, g, delta):
+        s, r = np.zeros_like(g), -g
+        d, rtr, steps = r.copy(), g @ g, 0
+        while np.sqrt(rtr) > 0.1 * np.linalg.norm(g) and steps < max_cg:
+            steps += 1
+            hd = x.T @ (d2 * (x @ d)) + l2 * d
+            alpha = rtr / (d @ hd)
+            s = s + alpha * d
+            if np.linalg.norm(s) > delta:
+                s = s - alpha * d
+                std, sts, dtd = s @ d, s @ s, d @ d
+                rad = np.sqrt(std * std + dtd * (delta * delta - sts))
+                alpha = ((delta * delta - sts) / (std + rad) if std >= 0
+                         else (rad - std) / dtd)
+                return s + alpha * d, r - alpha * hd, steps
+            r = r - alpha * hd
+            rnew = r @ r
+            d = r + (rnew / rtr) * d
+            rtr = rnew
+        return s, r, steps
+
+    w = np.zeros(x.shape[1])
+    f, g = fun(w), grad(w)
+    delta = np.linalg.norm(g)
+    value_tol, gradient_tol = tolerance * abs(f), tolerance * delta
+    accepted, cg_steps, failures = [], 0, 0
+    while True:
+        s, r, steps = trcg(curvature(w), g, delta)
+        cg_steps += steps
+        gs = g @ s
+        prered = -0.5 * (gs - s @ r)
+        f_new = fun(w + s)
+        actred = f - f_new
+        snorm = np.linalg.norm(s)
+        if not accepted:
+            delta = min(delta, snorm)
+        denom = f_new - f - gs
+        alpha = 4.0 if denom <= 0 else max(0.25, -0.5 * (gs / denom))
+        if actred < 1e-4 * prered:
+            delta = min(max(alpha, 0.25) * snorm, 0.5 * delta)
+        elif actred < 0.25 * prered:
+            delta = max(0.25 * delta, min(alpha * snorm, 0.5 * delta))
+        elif actred < 0.75 * prered:
+            delta = max(0.25 * delta, min(alpha * snorm, 4.0 * delta))
+        else:
+            delta = max(delta, min(alpha * snorm, 4.0 * delta))
+        ok = actred > 1e-4 * prered
+        accepted.append(bool(ok))
+        f_prev = f
+        if ok:
+            w, f, g, failures = w + s, f_new, grad(w + s), 0
+        else:
+            failures += 1
+        if (len(accepted) >= max_iterations
+                or (ok and abs(f_prev - f) <= value_tol)
+                or np.linalg.norm(g) <= gradient_tol
+                or failures >= max_failures):
+            return w, accepted, cg_steps
+
+
+# --------------------------------------------------------------------------
+# the problem: 3,000 x 40, seeded; the offsets put the start where the model
+# is confidently wrong, so the first Newton steps overshoot and one is
+# refused
+# --------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def rows():
+    rng = np.random.default_rng(33)
+    x = rng.normal(size=(N, D)).astype(np.float32)
+    theta = rng.normal(size=D) * 0.8
+    y = (rng.random(N) < 1.0 / (1.0 + np.exp(-(x @ theta)))).astype(
+        np.float32)
+    offsets = np.where(y > 0, -3.0, 3.0).astype(np.float32)
+    return x, y, offsets
+
+
+def _fit(rows, explicit=None, optimizer=OptimizerType.TRON, **opt):
+    """One fixed-effect coordinate through GameEstimator.fit, float32."""
+    x, y, offsets = rows
+    jitcache.clear()
+    config = GLMOptimizationConfiguration(
+        optimizer=OptimizerConfig(
+            optimizer_type=optimizer, track_states=16,
+            explicit_hessian=explicit,
+            **{"max_iterations": 15, "tolerance": 1e-5, **opt}),
+        regularization=L2Regularization, regularization_weight=L2)
+    est = GameEstimator(
+        TaskType.LOGISTIC_REGRESSION,
+        {"fixed": CoordinateConfiguration(
+            FixedEffectDataConfiguration("features"), config)},
+        update_sequence=["fixed"], num_iterations=1)
+    frame = GameDataFrame(num_samples=N, response=y, offsets=offsets,
+                          feature_shards={"features": FeatureShard(x, D)})
+    model = est.fit(frame)[-1].model
+    coord = est._coordinates["fixed"]
+    return (np.asarray(model["fixed"].model.coefficients.means, np.float64),
+            coord)
+
+
+def _accepted(losses):
+    """The accepted / refused sequence from the tracked objective: a
+    refused step leaves it where it was."""
+    losses = np.asarray(losses)
+    return [bool(v) for v in losses != np.concatenate([[np.nan], losses[:-1]])]
+
+
+def test_the_fit_takes_the_references_steps(rows):
+    """Same accepted / refused sequence (one refused), outer iterations and
+    CG steps as the float64 reference; coefficients within 2e-6 absolute
+    (float32 against float64 at the same stopping point; read 3.1e-7)."""
+    x, y, offsets = rows
+    want, accepted, cg_steps = numpy_tron(x, y, offsets.astype(np.float64),
+                                          L2)
+    assert accepted.count(False) == 1 and accepted[-1]
+    got, coord = _fit(rows)
+    counts = coord.tron_counts()
+    assert _accepted(coord.last_tracker.losses) == accepted
+    assert int(coord.last_result.iterations) == len(accepted)
+    assert counts == {"cg_steps": cg_steps,
+                      "rejected_steps": accepted.count(False),
+                      # one at the start, one after each accepted step but
+                      # the last: none after the refused one
+                      "hessian_builds": accepted[:-1].count(True) + 1}
+    assert int(coord.last_result.num_fun_evals) == len(accepted) + 1
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+def test_explicit_and_matrix_free_agree(rows):
+    """The two operators are one Hessian: the same counts, and coefficients
+    within 2e-6 absolute of each other in float32 (read 1.5e-7)."""
+    fits = {explicit: _fit(rows, explicit=explicit)
+            for explicit in (True, False)}
+    (c_exp, k_exp), (c_free, k_free) = fits[True], fits[False]
+    assert k_exp.tron_counts() == k_free.tron_counts()
+    assert (_accepted(k_exp.last_tracker.losses)
+            == _accepted(k_free.last_tracker.losses))
+    assert (int(k_exp.last_result.iterations)
+            == int(k_free.last_result.iterations))
+    np.testing.assert_allclose(c_exp, c_free, rtol=0, atol=2e-6)
+
+
+def test_the_counts_feed_the_counters_once_a_result(rows):
+    _, coord = _fit(rows)
+    before = registry.snapshot()["counters"]
+    counts = coord.tron_counts()
+    assert coord.tron_counts() == counts          # asked twice, fed once
+    after = registry.snapshot()["counters"]
+    assert (after["solver.tron.cg_steps"]
+            - before.get("solver.tron.cg_steps", 0)) == counts["cg_steps"]
+    assert (after["solver.tron.rejected_steps"]
+            - before.get("solver.tron.rejected_steps", 0)) == 1
+
+
+# --------------------------------------------------------------------------
+# the gate, and the counter that says which side a solve was traced on
+# --------------------------------------------------------------------------
+
+def _traced_path(features, d, explicit=None):
+    """Which label of kernels.tron_hessian ONE traced solve ticks."""
+    jitcache.clear()
+    n = features.shape[0] if hasattr(features, "shape") else \
+        features.indices.shape[0]
+    batch = DataBatch(features, jnp.zeros(n), jnp.zeros(n), jnp.ones(n))
+    problem = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(optimizer_type=OptimizerType.TRON,
+                                      explicit_hessian=explicit),
+            regularization=L2Regularization, regularization_weight=1.0))
+    before = registry.snapshot()["counters"]
+    one = jnp.asarray(1.0)
+    problem._solve_fn.lower(jnp.zeros(d), batch, one, one)
+    after = registry.snapshot()["counters"]
+    jitcache.clear()
+    ticked = {k: after[k] - before.get(k, 0) for k in after
+              if k.startswith("kernels.tron_hessian") and
+              after[k] != before.get(k, 0)}
+    assert len(ticked) == 1 and set(ticked.values()) == {1.0}, ticked
+    return next(iter(ticked)).split('"')[1]
+
+
+GATE_CASES = [
+    # (backend, dense, dim) -> path, on either side of each backend's gate
+    ("cpu", True, P.TRON_EXPLICIT_MAX_DIM_CPU, "explicit"),
+    ("cpu", True, P.TRON_EXPLICIT_MAX_DIM_CPU + 1, "matrix_free"),
+    ("tpu", True, P.TRON_EXPLICIT_MAX_DIM_TPU, "explicit"),
+    ("tpu", True, P.TRON_EXPLICIT_MAX_DIM_TPU + 1, "matrix_free"),
+    ("cpu", False, 8, "matrix_free"),
+    ("tpu", False, 8, "matrix_free"),
+]
+
+
+@pytest.mark.parametrize("backend,dense,dim,path", GATE_CASES)
+def test_the_gate_is_pinned(backend, dense, dim, path, monkeypatch):
+    """Dense d on either side of the gate, and sparse features at any d,
+    on the backend a solve would observe (the TPU's side is steered from
+    here: the gate reads ``jax.default_backend()`` and nothing else)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert P.tron_explicit_hessian(dense, dim) is (path == "explicit")
+    n = 16
+    feats = (jnp.zeros((n, dim)) if dense else F.SparseFeatures(
+        jnp.zeros((n, 2), jnp.int32), jnp.zeros((n, 2))))
+    assert _traced_path(feats, dim) == path
+
+
+def test_the_gate_holds_the_measured_widths():
+    """PERF.md §5's table (my chip runs, PR 33): on a TPU the GLMix cells'
+    128 features and 512 sit on the explicit side (a build costs what one
+    product costs), epsilon's 2,000 on the matrix-free side (a build costs
+    2.3 products and its fit takes 1.6 CG steps a build)."""
+    assert P.TRON_EXPLICIT_MAX_DIM_TPU == 1024
+    assert P.TRON_EXPLICIT_MAX_DIM_CPU == 256
+
+
+def test_a_configured_operator_overrides_the_gate():
+    x = jnp.zeros((16, 8))
+    assert _traced_path(x, 8, explicit=False) == "matrix_free"
+    assert _traced_path(x, 8, explicit=True) == "explicit"
+
+
+# --------------------------------------------------------------------------
+# the counts are TRON's alone: no other solver's program carries them
+# --------------------------------------------------------------------------
+
+def _small_problem(optimizer, regularization=L2Regularization):
+    rng = np.random.default_rng(5)
+    n, d = 64, 6
+    x = jnp.asarray(rng.normal(size=(n, d)))
+    y = jnp.asarray((rng.random(n) < 0.5).astype(float))
+    batch = DataBatch(x, y, jnp.zeros(n), jnp.ones(n))
+    problem = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(optimizer_type=optimizer,
+                                      max_iterations=20),
+            regularization=regularization, regularization_weight=1.0))
+    return problem, batch, d
+
+
+@pytest.mark.parametrize("optimizer,regularization", [
+    (OptimizerType.LBFGS, L2Regularization),
+    (OptimizerType.NEWTON, L2Regularization),
+    (OptimizerType.OWLQN, L1Regularization)], ids=["LBFGS", "NEWTON", "OWLQN"])
+def test_no_other_solver_carries_the_counts(optimizer, regularization):
+    jitcache.clear()
+    problem, batch, d = _small_problem(optimizer, regularization)
+    _, result = problem.run(batch, dim=d, dtype=batch.labels.dtype)
+    assert result.cg_steps is None
+    assert result.hessian_builds is None
+    assert result.rejected_steps is None
+    # seven outputs, as before TRON counted anything
+    assert len(jax.tree_util.tree_leaves(result)) == 7
+    jitcache.clear()
+    _, tron_result = _small_problem(OptimizerType.TRON)[0].run(
+        batch, dim=d, dtype=batch.labels.dtype)
+    assert len(jax.tree_util.tree_leaves(tron_result)) == 10
+
+
+# sha256 of the lowered (StableHLO) text of one small float64 solve on the
+# CPU, taken from the PARENT commit of PR 33 (4753a8d) with this very
+# function: the fields TRON gained are None elsewhere, so the text is the
+# parent's to the byte. A PR that means to change either solver re-pins it.
+PARENT_LOWERED = {
+    "LBFGS": "fe0ecb671dc1afec39c40f9ccefd24b5ede4c57e93b1d19ca4c28c4f3104cc0e",
+    "NEWTON": "f91bb243aa0213f80dc8dc2271e9e3cbc013346f1f6a0680e0daea30f9382bc1",
+}
+
+
+def lowered_digest(optimizer):
+    jitcache.clear()
+    problem, batch, d = _small_problem(OptimizerType[optimizer])
+    one = jnp.asarray(1.0)
+    text = problem._solve_fn.lower(jnp.zeros(d), batch, one, one).as_text()
+    jitcache.clear()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("optimizer", sorted(PARENT_LOWERED))
+def test_the_lowered_solve_is_the_parents(optimizer):
+    assert lowered_digest(optimizer) == PARENT_LOWERED[optimizer]
+
+
+# --------------------------------------------------------------------------
+# a refused step keeps its operator
+# --------------------------------------------------------------------------
+
+def test_no_operator_build_after_a_refused_step():
+    """``hess_setup`` RUNS (a host callback counts it, under the solver's
+    ``lax.cond``) once at the start and once after each accepted step that
+    another iteration follows, on a problem made to refuse steps: f(x) =
+    sum(log(cosh(x))) from a far start, where the curvature is next to
+    nothing, the gradient is not, and the quadratic model overshoots."""
+    ran = []
+
+    def value_and_grad(x):
+        return jnp.sum(jnp.log(jnp.cosh(x))), jnp.tanh(x)
+
+    def hess_setup(x):
+        jax.debug.callback(lambda: ran.append(1))
+        return 1.0 / jnp.cosh(x) ** 2
+
+    result = jax.jit(lambda x0: tron.minimize(
+        value_and_grad, None, x0,
+        config=SolverConfig(max_iterations=40, tolerance=1e-9,
+                            track_states=64),
+        hess_setup=hess_setup, hess_apply=lambda h, v: h * v))(
+            jnp.asarray([10.0, -8.0, 6.0]))
+    jax.block_until_ready(result)
+    jax.effects_barrier()
+    iterations, rejected = int(result.iterations), int(result.rejected_steps)
+    assert rejected == 2, "the problem was made to refuse two steps"
+    accepted = _accepted(np.asarray(result.loss_history)[:iterations])
+    assert accepted.count(False) == rejected
+    assert len(ran) == int(result.hessian_builds)
+    assert len(ran) == accepted[:-1].count(True) + 1
+    assert len(ran) < iterations
+    np.testing.assert_allclose(np.asarray(result.coef), 0.0, atol=1e-9)
