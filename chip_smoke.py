@@ -88,6 +88,11 @@ class Sizes:
     # epsilon's width: no multiple of 128, and rows that are no multiple
     # of the 256-row tile the kernel picks there (<= kernel_rows)
     kernel_ragged_shape: Tuple[int, int] = (8_100, 2_000)
+    # the Hessian-vector product's further shapes: a small X that XLA places
+    # in VMEM (PR 32's wrong answer showed only at such shapes, only on the
+    # chip) and fe-epsilon-tron's own
+    kernel_product_shapes: Tuple[Tuple[int, int], ...] = (
+        (8_016, 2_000), (530_000, 2_000))
     kernel_sparse_dim: int = 4_096
     kernel_ell_width: int = 16
     kernel_serving_rows: int = 128
@@ -604,23 +609,59 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
               f"2x the XLA path + 1e-4")
         out[name] = {"pallas_rel_err": k_err, "xla_rel_err": x_err}
 
+    def dense_oracle(x, coef, v, ym, offm, wm, chunk=65_536):
+        """float64 value, gradient, curvature weights and product
+        ``X^T (d2 * Xv)`` at ``coef``, the rows a chunk at a time (530,000
+        x 2,000 in float64 is 8.5 GB whole)."""
+        value, g, hv = 0.0, np.zeros(x.shape[1]), np.zeros(x.shape[1])
+        d2 = np.empty(x.shape[0])
+        coef64, v64 = coef.astype(np.float64), v.astype(np.float64)
+        for s in range(0, x.shape[0], chunk):
+            rows = slice(s, s + chunk)
+            x64 = x[rows].astype(np.float64)
+            z = x64 @ coef64 + offm[rows]
+            p = 1.0 / (1.0 + np.exp(-z))
+            value += float(np.sum(wm[rows] * (np.logaddexp(0.0, z)
+                                              - ym[rows] * z)))
+            g += x64.T @ (wm[rows] * (p - ym[rows]))
+            d2[rows] = wm[rows] * p * (1.0 - p)
+            hv += x64.T @ (d2[rows] * (x64 @ v64))
+        return value, g, d2, hv
+
+    # every dense shape takes the evaluation AND the Hessian-vector product
+    # (the same kernel at another per-row function); the product's shapes
+    # add the small ones where XLA places X in VMEM and the cell's own
     for m, d in ([(n, d) for d in sizes.kernel_dense_dims]
-                 + [sizes.kernel_ragged_shape]):
-        x = rng.normal(size=(m, d)).astype(np.float32) / np.sqrt(d)
+                 + [sizes.kernel_ragged_shape]
+                 + list(sizes.kernel_product_shapes)):
+        x = rng.standard_normal(size=(m, d), dtype=np.float32)
+        x /= np.float32(np.sqrt(d))
         coef = (rng.normal(size=d) * 0.4).astype(np.float32)
-        args = (LogisticLoss, jnp.asarray(x), jnp.asarray(y[:m]),
-                jnp.asarray(off[:m]), jnp.asarray(w[:m]), jnp.asarray(coef))
+        v = rng.normal(size=d).astype(np.float32)
+        ym = (rng.random(m) > 0.4).astype(np.float32)
+        offm = (rng.normal(size=m) * 0.2).astype(np.float32)
+        wm = (rng.random(m) + 0.1).astype(np.float32)
+        value, g, d2, hv = dense_oracle(x, coef, v, ym, offm, wm)
+        xd, d2d, vd = jnp.asarray(x), jnp.asarray(d2, jnp.float32), jnp.asarray(v)
+        args = (LogisticLoss, xd, jnp.asarray(ym), jnp.asarray(offm),
+                jnp.asarray(wm), jnp.asarray(coef))
         v1, g1 = pallas_glm.fused_dense_value_grad(*args,
                                                    interpret=interpret)
+        q1, hv1 = pallas_glm.fused_dense_hessian_vector(
+            xd, d2d, vd, interpret=interpret)
         with pallas_glm.disabled():     # XLA's two passes, at any width
             v0, g0 = aggregators.value_and_gradient(*args,
                                                     no_normalization())
-        x64 = x.astype(np.float64)
-        v, g = logistic_oracle(x64 @ coef.astype(np.float64),
-                               lambda dz: x64.T @ dz)
+            hv0 = aggregators.hessian_vector_from_weights(
+                xd, d2d, vd, no_normalization(), d)
         compare(f"fused_dense_value_grad[{m}x{d}] value",
-                np.asarray([v1]), np.asarray([v0]), np.asarray([v]))
+                np.asarray([v1]), np.asarray([v0]), np.asarray([value]))
         compare(f"fused_dense_value_grad[{m}x{d}] grad", g1, g0, g)
+        compare(f"fused_dense_hessian_vector[{m}x{d}] product", hv1, hv0, hv)
+        compare(f"fused_dense_hessian_vector[{m}x{d}] quadratic form",
+                np.asarray([q1]), np.asarray([0.5 * jnp.dot(vd, hv0)]),
+                np.asarray([0.5 * float(v.astype(np.float64) @ hv)]))
+        del x, xd, args
 
     d, k = sizes.kernel_sparse_dim, sizes.kernel_ell_width
     idx = rng.integers(0, d, size=(n, k)).astype(np.int32)

@@ -224,10 +224,24 @@ def hessian_vector_from_weights(
     norm: NormalizationContext,
     dim: int,
 ) -> Array:
-    """Hv given precomputed curvature weights: TWO passes over X (``X v``,
-    then ``X^T (d2 * Xv)``; 11.2 ms at 530,000 x 2,000 float32 on a TPU
-    v5e, what a value-and-gradient evaluation cost before it was fused;
-    PERF.md §5, PR 33)."""
+    """Hv given precomputed curvature weights.
+
+    Where ``pallas_glm.dense_route`` admits the matrix (the gate of
+    ``value_and_gradient``: a TPU, dense float32, identity normalisation,
+    unbatched, a width the kernel won at) ONE read of X through the same
+    fused kernel (``pallas_glm.fused_dense_hessian_vector``); elsewhere
+    XLA's TWO passes (``X v``, then ``X^T (d2 * Xv)``: 11.5 ms a product
+    at 530,000 x 2,000 float32 on a TPU v5e; PERF.md §5). ``kernels.
+    pallas_hits{path=dense_hv}`` ticks once a traced program that took the
+    kernel for a product, ``kernels.xla_fallbacks{path=dense_hv, reason}``
+    once a traced program turned away (``vmap``, ``mesh``, ``shape``)."""
+    from photon_tpu.ops import pallas_glm
+    route = pallas_glm.dense_route(x, norm, vector)
+    if route == pallas_glm.KERNEL:
+        _kernel_counter("pallas_hits", "dense_hv")
+        return pallas_glm.fused_dense_hessian_vector(x, d2, vector)[1]
+    if route is not None:
+        _kernel_counter("xla_fallbacks", "dense_hv", reason=route)
     v_eff = vector * norm.factors if norm.factors is not None else vector
     t = matvec(x, v_eff)
     if norm.shifts is not None:
@@ -248,10 +262,12 @@ def hessian_matrix_from_weights(
 
     This turns a whole CG solve's data passes into a single
     ``X^T diag(d2) X`` contraction plus O(d^2) matvecs. On a TPU v5e the
-    contraction costs what ONE matrix-free product costs up to some 1,000
-    features (it is bound by its two reads of X) and 2.3 products at 2,000
-    (PERF.md §5, PR 33): ``optim/problem.tron_explicit_hessian`` gates
-    TRON's use of it by that."""
+    contraction is bound by its two reads of X up to some 1,000 features
+    and by the MXU above: what ONE matrix-free product costs where the
+    product is XLA's two passes (under 256 features), 1.5-2.3 one-read
+    products at 256-1,024 and 4.5 at 2,000 (PERF.md §5, PR 34):
+    ``optim/problem.tron_explicit_hessian`` gates TRON's use of it by
+    that."""
     h = weighted_gram(x, d2, dim)
     if norm.shifts is not None:
         lin = rmatvec(x, d2, dim)

@@ -118,7 +118,7 @@ class OptimizerConfig:
     # TRON Hessian strategy: True = build the d x d Gauss-Newton matrix once
     # per outer iteration (one MXU GEMM; CG steps become O(d^2)); False =
     # matrix-free Hv with per-iteration curvature weights; None = auto
-    # (``tron_explicit_hessian``: dense features and, on a TPU, dim <= 1024)
+    # (``tron_explicit_hessian``: dense features and, on a TPU, dim < 256)
     explicit_hessian: Optional[bool] = None
 
     def solver_config(self) -> SolverConfig:
@@ -243,8 +243,8 @@ class GlmOptimizationProblem:
                     # Hessian operator split: curvature weights once per
                     # operator build; the explicit d x d Gauss-Newton
                     # matrix (one MXU contraction a build, no pass over X
-                    # a CG step) or matrix-free products (two passes a
-                    # step), by ``tron_explicit_hessian``
+                    # a CG step) or matrix-free products (a read of X a
+                    # step, two off the kernel), by ``tron_explicit_hessian``
                     from photon_tpu.ops.features import (
                         ModelShardedSparse,
                         SparseFeatures,
@@ -720,27 +720,33 @@ class GlmOptimizationProblem:
 #
 # TRON's explicit-or-matrix-free gate, by what a solve can observe: dense
 # features, the coefficient dimension, the backend. On a TPU v5e (PERF.md §5,
-# my chip runs, PR 33; ms inside one program, float32 rows): one explicit
+# my chip runs, PR 34; ms inside one program, float32 rows): one explicit
 # ``X^T D X`` build at DEFAULT precision / one matrix-free product is 5.4 /
-# 5.4 at 4,000,000 x 128, 10.9 / 10.8 at 2,000,000 x 512 (the build is bound
-# by its two reads of X, as the product is, so explicit pays from the first
-# CG step: whole fits 0.100 against 0.133 s and 0.118 against 0.205 s) and
-# 25.9 / 11.2 at 530,000 x 2,000 (the build is bound by the MXU and costs
-# 2.3 products, epsilon's fit takes 1.6 CG steps a build: 0.207 against
-# 0.173 s, matrix-free wins). 1,024 is the last power of two at which a
-# build stays within a fifth of one product. Past it a build pays only on a
-# problem that needs three CG steps a build or more, which a solve cannot
+# 5.4 at 4,000,000 x 128, where ``pallas_glm.dense_route`` leaves the product
+# to XLA's two passes (the build is bound by its two reads of X, as the
+# product is, so explicit pays from the first CG step: whole fits 0.100
+# against 0.133 s). From ``pallas_glm._DENSE_MIN_WIDTH`` = 256 features up
+# the product is ONE read of X through the fused kernel and a build costs
+# 1.5 products at 256 (10.8 / 7.3), 2.0 at 512 (10.9 / 5.4), 2.3 at 1,024
+# (12.3 / 5.4) and 4.5 at 2,000 (25.9 / 5.8); the table's fits take 1.3-1.6
+# CG steps a build and matrix-free wins them: 0.099 against 0.106 s at 256,
+# 0.107 against 0.126 at 1,024, 0.126 against 0.207 at epsilon's 2,000.
+# So the gate is the last width whose product still reads X twice (until
+# PR 34 it was 1,024: a product read X twice at every width and a build
+# stayed within a fifth of one up to there). Past it a build pays only on a
+# problem that needs two CG steps a build or more, which a solve cannot
 # know before it runs; ``explicit_hessian=True`` is there for one that does.
 # On a host CPU the crossover sits between d = 256 (explicit 1.5x faster)
 # and d = 512 (1.3x slower).
-TRON_EXPLICIT_MAX_DIM_TPU = 1024
+TRON_EXPLICIT_MAX_DIM_TPU = 255
 TRON_EXPLICIT_MAX_DIM_CPU = 256
 
 
 def tron_explicit_hessian(dense: bool, dim: int) -> bool:
     """Whether TRON builds ``X^T D X`` once an accepted step (True) or
-    applies it matrix-free, two passes over X a CG step (False), where the
-    configuration leaves ``explicit_hessian`` at None."""
+    applies it matrix-free, a read of X a CG step through the fused kernel
+    and two off it (False), where the configuration leaves
+    ``explicit_hessian`` at None."""
     return dense and dim <= (TRON_EXPLICIT_MAX_DIM_CPU
                              if jax.default_backend() == "cpu"
                              else TRON_EXPLICIT_MAX_DIM_TPU)

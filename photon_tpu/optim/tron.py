@@ -9,15 +9,16 @@ non-improvement capped at ``max_improvement_failures`` (5). Defaults
 maxIter=15, tol=1e-5, CG cap 20 (TRON.scala:256-262).
 
 What a Hessian-vector product costs depends on the operator the caller
-hands in (``optim/problem.py`` chooses it): matrix-free, TWO passes over X
-a product (``agg/hessian_vector``: ``X v``, then ``X^T (d2 * Xv)``) where
-the reference pays a treeAggregate; explicit, NO pass over X (one
-``[d, d] @ [d]`` product under ``optim/tron/direction``) after one
-``X^T D X`` contraction an operator build (``agg/hessian_matrix``). The
-operator belongs to a POINT: it is built once at the start and again only
-after an accepted step, never after a rejected one (the point did not
-move), and ``SolverResult`` counts the builds, the CG steps and the
-rejected steps of a solve.
+hands in (``optim/problem.py`` chooses it): matrix-free, one product
+``X^T (d2 * Xv)`` under ``agg/hessian_vector`` where the reference pays a
+treeAggregate (ONE read of X through the fused kernel where
+``pallas_glm.dense_route`` admits the matrix, XLA's two passes elsewhere);
+explicit, NO pass over X (one ``[d, d] @ [d]`` product under
+``optim/tron/direction``) after one ``X^T D X`` contraction an operator
+build (``agg/hessian_matrix``). The operator belongs to a POINT: it is
+built once at the start and again only after an accepted step, never after
+a rejected one (the point did not move), and ``SolverResult`` counts the
+builds, the CG steps and the rejected steps of a solve.
 
 Each step of an iteration runs under a ``jax.named_scope``
 ``optim/tron/<step>``: ``init``, ``hessian`` (the once-an-iteration

@@ -39,6 +39,7 @@ def test_chip_smoke_body_at_toy_size(monkeypatch):
         n_train=4000, n_val=1000, d_global=16, n_users=40, d_user=4,
         n_requests=40, n_unknown=3, n_cli=600, kernel_rows=300,
         kernel_dense_dims=(16, 128), kernel_ragged_shape=(290, 200),
+        kernel_product_shapes=((272, 200),),
         kernel_sparse_dim=200,
         kernel_ell_width=5, kernel_serving_rows=24)
     jax.config.update("jax_enable_x64", False)

@@ -16,7 +16,10 @@ from photon_tpu.data.dataset import DataBatch
 from photon_tpu.ops import aggregators
 from photon_tpu.ops.losses import LogisticLoss, PoissonLoss, SquaredLoss
 from photon_tpu.ops.normalization import no_normalization
-from photon_tpu.ops.pallas_glm import fused_dense_value_grad
+from photon_tpu.ops.pallas_glm import (
+    fused_dense_hessian_vector,
+    fused_dense_value_grad,
+)
 
 _IDN = no_normalization()
 
@@ -55,22 +58,24 @@ def test_fused_none_offsets_weights(problem):
                                rtol=5e-5, atol=5e-5)
 
 
-def _ticks():
-    """The routing's trace-time counters, by (name, reason)."""
+def _ticks(path="dense"):
+    """The routing's trace-time counters of one path (``dense``: the
+    evaluations; ``dense_hv``: the Hessian-vector products), by (name,
+    reason)."""
     from photon_tpu.obs.metrics import registry
 
     out = {}
     for key, v in registry.snapshot()["counters"].items():
-        if key.startswith("kernels.pallas_hits{path=\"dense\""):
+        if key.startswith(f"kernels.pallas_hits{{path=\"{path}\""):
             out["hit"] = out.get("hit", 0) + int(v)
-        elif key.startswith("kernels.xla_fallbacks{path=\"dense\""):
+        elif key.startswith(f"kernels.xla_fallbacks{{path=\"{path}\""):
             reason = key.split('reason="')[1].split('"')[0]
             out[reason] = out.get(reason, 0) + int(v)
     return out
 
 
-def _ticked(before):
-    now = _ticks()
+def _ticked(before, path="dense"):
+    now = _ticks(path)
     return {k: v - before.get(k, 0) for k, v in now.items()
             if v != before.get(k, 0)}
 
@@ -168,6 +173,105 @@ def test_fused_ragged_shapes_against_xla(n, d, tile):
     scale = float(jnp.abs(g0).max())
     np.testing.assert_allclose(np.asarray(g1) / scale,
                                np.asarray(g0) / scale, atol=2e-6)
+
+
+@pytest.mark.parametrize("sample_weights", [False, True],
+                         ids=["unweighted", "weighted"])
+@pytest.mark.parametrize("n,d", [
+    (300, 2000),            # one tile, 44 rows over
+    (4000, 2000),           # 15 tiles, 160 rows over
+    (8016, 2000),           # the chip's small-X-in-VMEM shapes (PR 32)
+    (8100, 2000),
+    (20001, 2000),
+    (8016, 1000),
+    (1000, 256),            # the least width the gate admits
+], ids=lambda v: str(v))
+def test_product_through_the_kernel_against_xla(n, d, sample_weights):
+    """A Hessian-vector product IS the kernel's evaluation of the squared
+    loss at labels 0 and offsets 0 with the curvature weights for sample
+    weights: ``fused_dense_hessian_vector`` against XLA's two passes, at
+    ragged and aligned shapes; the value it returns beside the product is
+    the quadratic form ``v . Hv / 2``."""
+    from photon_tpu.ops import pallas_glm
+
+    rng = np.random.default_rng(n + d)
+    X = jnp.asarray(rng.normal(size=(n, d)) / np.sqrt(d), jnp.float32)
+    p = 1.0 / (1.0 + np.exp(-rng.normal(size=n) * 2.0))
+    d2 = p * (1.0 - p)
+    if sample_weights:      # folded into d2, as ``hessian_weights`` does
+        d2 = d2 * (rng.random(n) + 0.1)
+    d2 = jnp.asarray(d2, jnp.float32)
+    v = jnp.asarray(rng.normal(size=d), jnp.float32)
+    with pallas_glm.disabled():
+        hv0 = aggregators.hessian_vector_from_weights(X, d2, v, _IDN, d)
+    q, hv1 = fused_dense_hessian_vector(X, d2, v)
+    assert hv1.shape == (d,) and hv1.dtype == jnp.float32
+    assert np.isfinite(np.asarray(hv1)).all()
+    # two float32 summation orders over up to 20,001 rows (read 4.8e-6)
+    scale = float(jnp.abs(hv0).max())
+    np.testing.assert_allclose(np.asarray(hv1) / scale,
+                               np.asarray(hv0) / scale, atol=1e-5)
+    np.testing.assert_allclose(float(q), 0.5 * float(v @ hv1), rtol=5e-6)
+
+
+@pytest.mark.parametrize("case", ["admitted", "narrow", "vmap", "disabled",
+                                  "float64_vector", "normalised",
+                                  "not_a_tpu"])
+def test_product_route(case, wide_problem, on_tpu, monkeypatch):
+    """``hessian_vector_from_weights`` goes where ``dense_route`` sends
+    it, the gate of ``value_and_gradient``, and says so under its own
+    label: ``kernels.pallas_hits{path=dense_hv}`` once a traced program
+    that took the kernel, ``kernels.xla_fallbacks{path=dense_hv, reason}``
+    once a traced program turned away; a float64 vector, a normalised
+    objective and a backend that is no TPU are not the kernel's case and
+    tick nothing. Either way the product is XLA's."""
+    from photon_tpu.ops.normalization import NormalizationContext
+
+    X, _, _, w, v = wide_problem
+    d2, d = 0.25 * w, X.shape[1]
+    hv = lambda x=X, v=v, norm=_IDN: aggregators.hessian_vector_from_weights(
+        x, d2, v, norm, v.shape[-1])
+    with on_tpu.disabled():
+        want = hv()
+    before = {path: _ticks(path) for path in ("dense", "dense_hv")}
+    ticked = lambda: _ticked(before["dense_hv"], "dense_hv")
+    if case == "admitted":
+        got = hv()
+        assert ticked() == {"hit": 1}
+        jaxpr = jax.make_jaxpr(hv)()
+        eqns = list(_eqns(jaxpr.jaxpr))
+        assert sum(e.primitive.name == "pallas_call" for e in eqns) == 1
+    elif case == "narrow":
+        narrow = on_tpu._DENSE_MIN_WIDTH // 2
+        with on_tpu.disabled():
+            want = hv(X[:, :narrow], v[:narrow])
+        before["dense_hv"] = _ticks("dense_hv")
+        got = hv(X[:, :narrow], v[:narrow])
+        assert ticked() == {"shape": 1}
+    elif case == "vmap":
+        got = jax.vmap(lambda u: hv(v=u))(jnp.stack([v, v]))[1]
+        assert ticked() == {"vmap": 1}
+    elif case == "disabled":
+        with on_tpu.disabled():
+            got = hv()
+        assert ticked() == {"mesh": 1}
+    elif case == "float64_vector":
+        got = hv(v=v.astype(jnp.float64))
+        assert got.dtype == jnp.float64 and ticked() == {}
+    elif case == "normalised":
+        ones = NormalizationContext(factors=jnp.ones(d, jnp.float32),
+                                    shifts=None)
+        got = hv(norm=ones)
+        assert ticked() == {}
+    else:
+        monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
+        got = hv()
+        assert ticked() == {}
+    # a product ticks nothing under the evaluations' label
+    assert _ticked(before["dense"]) == {}
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(np.asarray(got) / scale,
+                               np.asarray(want) / scale, atol=2e-6)
 
 
 def _eqns(jaxpr):
@@ -701,22 +805,30 @@ def _shaped(v5e, *shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
 
-@pytest.mark.parametrize("n,d,dtype", [
-    (530_000, 2_000, jnp.float32),      # fe-epsilon: ragged both ways
-    (4_000_000, 256, jnp.float32),      # the least width the gate admits
-    (500_000, 4_096, jnp.float32),      # the widest
-    (8_101, 2_000, jnp.bfloat16),       # packed rows, an odd ragged edge
+@pytest.mark.parametrize("n,d,dtype,call", [
+    (530_000, 2_000, jnp.float32, "evaluation"),    # fe-epsilon: ragged both ways
+    (4_000_000, 256, jnp.float32, "evaluation"),    # the least width the gate admits
+    (500_000, 4_096, jnp.float32, "evaluation"),    # the widest
+    (8_101, 2_000, jnp.bfloat16, "evaluation"),     # packed rows, an odd ragged edge
+    (530_000, 2_000, jnp.float32, "product"),       # fe-epsilon-tron's CG step
+    (4_000_000, 256, jnp.float32, "product"),
 ], ids=lambda v: getattr(v, "__name__", str(v)))
-def test_dense_kernel_compiles_for_a_v5e(v5e, n, d, dtype):
+def test_dense_kernel_compiles_for_a_v5e(v5e, n, d, dtype, call):
     """Mosaic takes the kernel at the real shapes, inside the scoped VMEM
     a core hands out by default, and the program around it holds no copy
     of X (the matrix goes in as placed: here row-major, as the caller
-    states it)."""
-    f = jax.jit(lambda x, y, off, w, c: fused_dense_value_grad(
-        LogisticLoss, x, y, off, w, c, interpret=False))
+    states it). A Hessian-vector product is the same ONE custom call."""
     row = _shaped(v5e, n)
-    compiled = f.lower(_shaped(v5e, n, d, dtype=dtype), row, row, row,
-                       _shaped(v5e, d)).compile()
+    if call == "product":
+        f = jax.jit(lambda x, d2, v: fused_dense_hessian_vector(
+            x, d2, v, interpret=False))
+        args = (_shaped(v5e, n, d, dtype=dtype), row, _shaped(v5e, d))
+    else:
+        f = jax.jit(lambda x, y, off, w, c: fused_dense_value_grad(
+            LogisticLoss, x, y, off, w, c, interpret=False))
+        args = (_shaped(v5e, n, d, dtype=dtype), row, row, row,
+                _shaped(v5e, d))
+    compiled = f.lower(*args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     x_bytes = n * d * jnp.dtype(dtype).itemsize
@@ -779,6 +891,61 @@ def test_routed_solve_compiles_for_a_v5e_without_a_copy_of_its_own(
     assert not [shape for shape in padded if int(shape[0]) >= n], padded
 
 
+def test_routed_tron_solve_compiles_for_a_v5e_with_one_kernel_a_product(
+        v5e, monkeypatch):
+    """The fe-epsilon-tron solve as the chip's compiler leaves it: the
+    kernel three times (the first evaluation, the trial point's, and ONE
+    under ``agg/hessian_vector`` inside the CG ``while``), and the XLA
+    program's temporaries: the one re-layout copy of X, none of the
+    product's own."""
+    import re
+
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import OptimizerType, TaskType
+    from photon_tpu.utils import jitcache
+
+    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
+    n, d = 530_000, 2_000
+    row, one = _shaped(v5e, n), _shaped(v5e)
+    batch = DataBatch(_shaped(v5e, n, d), row, row, row)
+
+    def compiled(routed):
+        monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: routed)
+        jitcache.clear()
+        prob = GlmOptimizationProblem(
+            TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+                optimizer=OptimizerConfig(
+                    optimizer_type=OptimizerType.TRON, max_iterations=15,
+                    tolerance=1e-5, explicit_hessian=False),
+                regularization=L2Regularization, regularization_weight=1.0))
+        try:
+            return prob._solve_fn.lower(_shaped(v5e, d), batch, one,
+                                        one).compile()
+        finally:
+            jitcache.clear()
+
+    fused, xla = compiled(True), compiled(False)
+    text = fused.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]+)"', text)
+    assert len(names) == 3, names
+    assert sum("optim/tron/direction/while/body/agg/hessian_vector/"
+               "jit(_fused)/pallas_call" in name for name in names) == 1, names
+    assert sum("agg/value_and_gradient/jit(_fused)/pallas_call" in name
+               for name in names) == 2, names
+    assert "tpu_custom_call" not in xla.as_text()
+    temp = [c.memory_analysis().temp_size_in_bytes for c in (fused, xla)]
+    x_tiled = n * 2_048 * 4
+    assert x_tiled <= temp[0] < 1.01 * x_tiled, temp
+    assert abs(temp[0] - temp[1]) < 0.001 * x_tiled, temp
+
+
 def _stores_to(jaxpr, refs):
     """The equations of ``jaxpr`` (and of the jaxprs its ``cond`` /
     ``scan`` / ``pjit`` equations hold, their operands matched by
@@ -802,18 +969,27 @@ def _stores_to(jaxpr, refs):
     return found
 
 
-def test_dense_kernel_stores_to_no_input():
+@pytest.mark.parametrize("call", ["evaluation", "product"])
+def test_dense_kernel_stores_to_no_input(call):
     """The kernel writes its two outputs and its two scratch vectors and
     nothing else: where XLA has placed a small X in VMEM a block of an
     input IS the operand, and a store past its end lands in whatever lies
     behind it (on the chip 8,100 x 2,000 read 0.0 for it; interpret mode
-    cannot show it, so the kernel's jaxpr is held to it)."""
+    cannot show it, so the kernel's jaxpr is held to it). A product is
+    the same ONE ``pallas_call`` with no contraction over X beside it."""
     n, d = 700, 2000
-    args = (jnp.zeros((n, d), jnp.float32),) + (jnp.zeros(n, jnp.float32),) * 3
-    jaxpr = jax.make_jaxpr(lambda *a: fused_dense_value_grad(
-        LogisticLoss, *a, jnp.zeros(d, jnp.float32)))(*args)
-    (call,) = [e for e in _eqns(jaxpr.jaxpr)
-               if e.primitive.name == "pallas_call"]
+    x, row = jnp.zeros((n, d), jnp.float32), jnp.zeros(n, jnp.float32)
+    if call == "product":
+        jaxpr = jax.make_jaxpr(fused_dense_hessian_vector)(
+            x, row, jnp.zeros(d, jnp.float32))
+    else:
+        jaxpr = jax.make_jaxpr(lambda *a: fused_dense_value_grad(
+            LogisticLoss, *a, jnp.zeros(d, jnp.float32)))(x, row, row, row)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    (call,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert not any(e.primitive.name == "dot_general"
+                   and any(getattr(v.aval, "shape", ()) == x.shape
+                           for v in e.invars) for e in eqns)
     kernel = call.params["jaxpr"]
     inputs, rest = set(kernel.invars[:5]), set(kernel.invars[5:])
     assert not _stores_to(kernel, inputs)

@@ -113,9 +113,11 @@ def test_the_fused_kernel_lowers_under_value_and_gradient(solver,
     """On a TPU a dense solve of an admitted width holds the ONE fused
     kernel wherever the objective is evaluated, and every call of it is
     located under ``agg/value_and_gradient`` (so ``benchmark/
-    scope_reader.py`` keeps charging its seconds to ``aggregators``). The
-    program is lowered FOR a TPU from here (Mosaic serialises the kernel
-    into the custom call; nothing is compiled or run)."""
+    scope_reader.py`` keeps charging its seconds to ``aggregators``);
+    TRON's matrix-free program holds it once more, for the CG step's
+    product, under ``agg/hessian_vector`` (PR 34). The program is lowered
+    FOR a TPU from here (Mosaic serialises the kernel into the custom
+    call; nothing is compiled or run)."""
     from photon_tpu.ops import pallas_glm
     from photon_tpu.utils import jitcache
 
@@ -143,10 +145,14 @@ def test_the_fused_kernel_lowers_under_value_and_gradient(solver,
     calls = [locs[ref] for ref in re.findall(
         r"call @_fused\w*\(.*loc\((#loc\d+)\)\s*$", text, flags=re.M)]
     assert calls
-    for loc in calls:
-        assert re.search(r"agg/value_and_gradient/jit\(_fused\)", loc), loc
-        # the innermost scope of the call is the aggregator's
-        assert _SCOPE.findall(loc)[-1] == "agg/value_and_gradient", loc
+    # the innermost scope of each call is the aggregator's
+    under = [_SCOPE.findall(loc)[-1] for loc in calls]
+    for loc, scope in zip(calls, under):
+        assert re.search(scope + r"/jit\(_fused\)", loc), loc
+    products = ["agg/hessian_vector"] if solver == "TRON" else []
+    assert sorted(set(under)) == sorted(
+        {"agg/value_and_gradient", *products}), under
+    assert under.count("agg/hessian_vector") == len(products), under
     assert steps <= scopes_in(text), sorted(steps - scopes_in(text))
     if solver in ("LBFGS", "OWLQN"):
         # X theta is inside the kernel: no first pass of its own

@@ -33,6 +33,7 @@ from photon_tpu.optim.problem import (
 )
 from photon_tpu.types import OptimizerType, TaskType
 from photon_tpu.utils import jitcache
+from test_pallas_glm import _ticked, _ticks     # the routing's counters
 
 N, D, L2 = 3000, 40, 1.0
 
@@ -273,11 +274,16 @@ def test_the_gate_is_pinned(backend, dense, dim, path, monkeypatch):
 
 
 def test_the_gate_holds_the_measured_widths():
-    """PERF.md §5's table (my chip runs, PR 33): on a TPU the GLMix cells'
-    128 features and 512 sit on the explicit side (a build costs what one
-    product costs), epsilon's 2,000 on the matrix-free side (a build costs
-    2.3 products and its fit takes 1.6 CG steps a build)."""
-    assert P.TRON_EXPLICIT_MAX_DIM_TPU == 1024
+    """PERF.md §5's table (my chip runs, PR 34): on a TPU the GLMix cells'
+    128 features sit on the explicit side (the product is XLA's two passes
+    there and a build costs what one costs); from the least width whose
+    product is ONE read of X through the fused kernel a build costs 1.5-4.5
+    products and the table's fits (1.3-1.6 CG steps a build) are faster
+    matrix-free: the gate is the last width under
+    ``pallas_glm._DENSE_MIN_WIDTH``."""
+    from photon_tpu.ops import pallas_glm
+
+    assert P.TRON_EXPLICIT_MAX_DIM_TPU == pallas_glm._DENSE_MIN_WIDTH - 1 == 255
     assert P.TRON_EXPLICIT_MAX_DIM_CPU == 256
 
 
@@ -383,3 +389,133 @@ def test_no_operator_build_after_a_refused_step():
     assert len(ran) == accepted[:-1].count(True) + 1
     assert len(ran) < iterations
     np.testing.assert_allclose(np.asarray(result.coef), 0.0, atol=1e-9)
+
+
+# --------------------------------------------------------------------------
+# the matrix-free product through the fused kernel (PR 34): one read of X a
+# CG step where ``pallas_glm.dense_route`` admits the matrix
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The routing driven on the CPU (the kernel in interpret mode), every
+    width admitted: the file's problem is 40 features wide."""
+    from photon_tpu.ops import pallas_glm
+
+    jitcache.clear()
+    monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_glm, "_DENSE_MIN_WIDTH", 1)
+    yield pallas_glm
+    jitcache.clear()
+
+
+def test_a_solve_through_the_kernel_takes_the_xla_paths_steps(rows, on_tpu):
+    """A whole matrix-free TRON fit whose products (and evaluations) run
+    the fused kernel ends with XLA's counts, accepted / refused sequence
+    and reason, and its coefficients within 2e-6 absolute (read 4.8e-7).
+    At tolerance 1e-4: at the file's 1e-5 this problem's LAST step lowers
+    the objective (2,440.88) by one unit in its last place, so whether it
+    is accepted or refused five times over turns on a summation order
+    (PERF.md §7, float32 and the trust region), not on the operator."""
+    with on_tpu.disabled():
+        want, k_xla = _fit(rows, explicit=False, tolerance=1e-4)
+        xla = (k_xla.tron_counts(), _accepted(k_xla.last_tracker.losses),
+               int(k_xla.last_result.iterations),
+               int(k_xla.last_result.reason),
+               int(k_xla.last_result.num_fun_evals))
+    before = _ticks("dense_hv")
+    got, k_fused = _fit(rows, explicit=False, tolerance=1e-4)
+    # one traced solve: one CG body, one product call site
+    assert _ticked(before, "dense_hv") == {"hit": 1}
+    assert (k_fused.tron_counts(), _accepted(k_fused.last_tracker.losses),
+            int(k_fused.last_result.iterations),
+            int(k_fused.last_result.reason),
+            int(k_fused.last_result.num_fun_evals)) == xla
+    assert xla[0]["rejected_steps"] == 1 and xla[0]["cg_steps"] > 0
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+def test_the_routed_cg_step_reads_x_once(rows, on_tpu):
+    """The traced solve holds ONE kernel call in the CG step and no
+    contraction over X there."""
+    x, y, offsets = rows
+    batch = DataBatch(jnp.asarray(x), jnp.asarray(y), jnp.asarray(offsets),
+                      jnp.ones(N, jnp.float32))
+    problem = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(optimizer_type=OptimizerType.TRON,
+                                      explicit_hessian=False),
+            regularization=L2Regularization, regularization_weight=L2))
+    one = jnp.float32(1.0)
+    jaxpr = jax.make_jaxpr(problem._solve_fn)(
+        jnp.zeros(D, jnp.float32), batch, one, one)
+
+    def walk(jaxpr, inside=()):
+        for eqn in jaxpr.eqns:
+            yield eqn, inside
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from walk(inner, inside + (eqn.primitive.name,))
+
+    eqns = list(walk(jaxpr.jaxpr))
+    over_x = lambda e: any(getattr(v.aval, "shape", ()) == x.shape
+                           for v in e.invars)
+    # the kernel: the first evaluation, the trial point's, the product's
+    # (the only one two ``while``s deep: the CG loop inside the outer loop)
+    calls = [inside for e, inside in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3, calls
+    assert sum(inside.count("while") == 2 for inside in calls) == 1, calls
+    # the one contraction over X left is the weights' pass (X theta under
+    # the operator build's ``cond``); none in the CG loop
+    dots = [inside for e, inside in eqns
+            if e.primitive.name == "dot_general" and over_x(e)]
+    assert len(dots) == 1 and "cond" in dots[0], dots
+
+
+def test_per_entity_tron_under_vmap_keeps_xlas_products(on_tpu, monkeypatch):
+    """Per-entity TRON (``game/coordinate.py``) batches its objective: the
+    kernel's sequential grid is not vmap-safe, so every product there is
+    turned away under ``vmap`` and the fit is the CPU's fit."""
+    from photon_tpu.game.dataset import CsrRows
+    from photon_tpu.game.random_effect import RandomEffectDataConfiguration
+
+    rng = np.random.default_rng(2)
+    n, d_u, users = 300, 4, 6
+    xu = rng.normal(size=(n, d_u)).astype(np.float32)
+    uid = rng.integers(0, users, size=n)
+    y = (rng.random(n) < 0.5).astype(np.float32)
+    frame = GameDataFrame(
+        num_samples=n, response=y,
+        feature_shards={"u": FeatureShard(CsrRows.from_dense(xu), d_u)},
+        id_tags={"userId": [f"u{v}" for v in uid]})
+
+    def fit():
+        jitcache.clear()
+        config = GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(
+                optimizer_type=OptimizerType.TRON, max_iterations=15,
+                tolerance=1e-6, explicit_hessian=False),
+            regularization=L2Regularization, regularization_weight=0.5)
+        est = GameEstimator(
+            TaskType.LOGISTIC_REGRESSION,
+            {"per_user": CoordinateConfiguration(
+                RandomEffectDataConfiguration("userId", "u"), config)},
+            update_sequence=["per_user"], num_iterations=1,
+            dtype=jnp.float32)
+        model = est.fit(frame)[-1].model
+        assert all(est._coordinates["per_user"]._dense_local_blocks)
+        return np.asarray(model["per_user"].coefficients)
+
+    before = _ticks("dense_hv")
+    routed = fit()
+    ticked = _ticked(before, "dense_hv")
+    assert set(ticked) == {"vmap"}, ticked
+    monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
+    before = _ticks("dense_hv")
+    plain = fit()
+    assert _ticked(before, "dense_hv") == {}
+    np.testing.assert_array_equal(routed, plain)
