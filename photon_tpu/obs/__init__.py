@@ -70,6 +70,11 @@ def reset() -> None:
     spans.clear()
     memory.clear()
     _solver_mod.clear()
+    # the compile account's counters went with the registry; its buffer of
+    # programs goes too (the jax.monitoring listeners stay: once a process)
+    mod = _sys.modules.get("photon_tpu.utils.compile_cache")
+    if mod is not None:
+        mod.clear_programs()
     # windowed series + SLO verdicts: lazy (sys.modules) so offline
     # drivers that never touched them pay nothing here either
     for name in ("photon_tpu.obs.timeseries", "photon_tpu.obs.slo"):

@@ -198,6 +198,14 @@ class MetricsRegistry:
                 out["histograms"][key] = h
         return out
 
+    def series(self, name: str) -> List[Tuple[Dict[str, str], float]]:
+        """``[(labels, value)]`` of every counter or gauge called ``name``:
+        a family by its labels, without parsing snapshot keys."""
+        with self._lock:
+            return [(dict(labels), metric.value)
+                    for (n, labels), metric in self._metrics.items()
+                    if n == name and isinstance(metric, (Counter, Gauge))]
+
     def to_json(self, indent: Optional[int] = None) -> str:
         return json.dumps(self.snapshot(), indent=indent, sort_keys=True)
 

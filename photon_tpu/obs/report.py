@@ -106,9 +106,29 @@ def build_run_report(driver: str,
     slo = _slo_section()
     if slo is not None:
         report["slo"] = slo
+    compiled = _compile_section()
+    if compiled is not None:
+        report["compile"] = compiled
     if extra:
         report["extra"] = extra
     return report
+
+
+def _compile_section() -> Optional[Dict[str, Any]]:
+    """The job's compile account (utils/compile_cache.py): seconds and
+    programs by stage (trace / lower / cache_load / backend) and phase
+    (``during``), the persistent cache's outcomes and the ten slowest
+    programs by name. Same ``sys.modules`` pattern as
+    :func:`_serving_section`: a process that never built a program has no
+    section."""
+    mod = sys.modules.get("photon_tpu.utils.compile_cache")
+    if mod is None:
+        return None
+    try:
+        section = mod.report_section()
+        return section if section["programs"] else None
+    except Exception:  # noqa: BLE001 — reporting must not kill a run
+        return None
 
 
 def _serving_section() -> Optional[Dict[str, Any]]:
@@ -325,6 +345,18 @@ def validate_run_report(report: Dict[str, Any]) -> List[str]:
     if (not isinstance(proc, dict) or "index" not in proc
             or "count" not in proc):
         errors.append("process must be {'index', 'count'}")
+    if "compile" in report:  # optional: only processes that built a program
+        compiled = report["compile"]
+        if not isinstance(compiled, dict):
+            errors.append("compile must be a dict")
+        else:
+            for k in ("seconds", "programs", "cache", "slowest"):
+                if k not in compiled:
+                    errors.append(f"compile missing {k!r}")
+            for i, r in enumerate(compiled.get("slowest", [])):
+                for k in ("fun", "stage", "seconds", "start_unix", "during"):
+                    if k not in r:
+                        errors.append(f"compile.slowest[{i}] missing {k!r}")
     if "serving" in report:  # optional: only serving processes emit it
         serving = report["serving"]
         if not isinstance(serving, dict):

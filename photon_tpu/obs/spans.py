@@ -63,6 +63,27 @@ def _jax_annotation(name: str, attrs: Dict[str, Any]):
         return None
 
 
+def _append(name: str, ts_us: float, seconds: float, start_unix: float,
+            parent, depth: int, attrs: Dict[str, Any],
+            error: bool = False) -> None:
+    rec = {
+        "name": name,
+        "ts_us": ts_us,
+        "dur_us": seconds * 1e6,
+        "start_unix": start_unix,
+        "end_unix": start_unix + seconds,
+        "tid": threading.get_ident(),
+        "parent": parent,
+        "depth": depth,
+    }
+    if attrs:
+        rec["args"] = dict(attrs)
+    if error:
+        rec["error"] = True
+    with _LOCK:
+        _RECORDS.append(rec)
+
+
 class span:
     """``with span("phase", key=value): ...`` — records one trace event.
 
@@ -104,23 +125,25 @@ class span:
         st = _stack()
         if st and st[-1] is self:
             st.pop()
-        seconds = t1 - self._t0
-        rec = {
-            "name": self.name,
-            "ts_us": (self._t0 - _EPOCH_PERF) * 1e6,
-            "dur_us": seconds * 1e6,
-            "start_unix": self._wall0,
-            "end_unix": self._wall0 + seconds,
-            "tid": threading.get_ident(),
-            "parent": self._parent,
-            "depth": self._depth,
-        }
-        if self.attrs:
-            rec["args"] = dict(self.attrs)
-        if exc_type is not None:
-            rec["error"] = True
-        with _LOCK:
-            _RECORDS.append(rec)
+        _append(self.name, (self._t0 - _EPOCH_PERF) * 1e6, t1 - self._t0,
+                self._wall0, self._parent, self._depth, self.attrs,
+                error=exc_type is not None)
+
+
+def record(name: str, start_unix: float, seconds: float,
+           **attrs: Any) -> None:
+    """One span that something else timed, put on this module's clock:
+    machinery that reports itself only through a callback when it is DONE
+    (JAX's trace / lower / compile stages, ``utils/compile_cache``) hands
+    in its start on ``time.time`` and its seconds, and the record lands
+    where a ``span`` closed at that moment would have (``ts_us`` from
+    ``_EPOCH_UNIX``; parent = the innermost span open on the thread). A
+    no-op with telemetry off."""
+    if not _config.enabled():
+        return
+    st = _stack()
+    _append(name, (start_unix - _EPOCH_UNIX) * 1e6, seconds, start_unix,
+            st[-1].name if st else None, len(st), attrs)
 
 
 def annotate(name: str, **attrs: Any):

@@ -9,14 +9,27 @@ disk.
 
 The reference has no analog (JVM/Spark JITs incrementally); on TPU this is
 the standard deployment answer: ``jax.config.jax_compilation_cache_dir``.
+
+It also keeps the job's COMPILE ACCOUNT (PERF.md §3, layer ``compile``):
+what JAX spent tracing, lowering, loading from the cache and compiling,
+counted where JAX reports it (``jax.monitoring``) and booked under the
+``Timed`` phase the host stood in. Always on, like ``ingest.h2d_bytes``;
+the listeners run only when JAX traces, lowers or compiles, never on jit's
+fast path. Read it with ``obs.metrics.snapshot()["counters"]``,
+:func:`programs` or :func:`report_section` (a RunReport's ``compile``).
 """
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
+import threading
+from typing import Any, Deque, Dict, List, Tuple
 
+from photon_tpu.obs import spans as _spans
 from photon_tpu.obs.metrics import registry as _metrics
+from photon_tpu.utils import timing as _timing
 
 _logger = logging.getLogger("photon_tpu.compile_cache")
 
@@ -90,6 +103,7 @@ def maybe_enable() -> str | None:
     driver calls this first and reads its data next."""
     import jax
 
+    account_compiles()
     if not _held_to_cpu():
         from photon_tpu.ops import pallas_glm
         pallas_glm.prefetch_toolchain()
@@ -132,6 +146,7 @@ def record_compile(what: str = "program") -> None:
     """Count one program build under the current phase. Called by the
     jitcache on every build; serving asserts
     ``compiles{phase="steady_state"}`` stays zero after warmup."""
+    account_compiles()      # a library user never calls maybe_enable()
     phase = "warmup" if in_warmup() else "steady_state"
     _metrics.counter("compile_cache.compiles", phase=phase, what=what).inc()
 
@@ -168,3 +183,159 @@ def warmup(buckets, compile_fn) -> int:
         return n
     finally:
         _warmup_depth -= 1
+
+
+# ---------------------------------------------------------------------------
+# the compile account: JAX's own stages, booked under the job's phases
+# ---------------------------------------------------------------------------
+
+# what JAX 0.9.0 sends (jax/_src/dispatch.py:60-62, 184-215;
+# compiler.py:435-452). A stage event sends a scalar (its start) when the
+# stage BEGINS and, when it ends, a duration and a time span (start and end
+# on ``time.time``); the span carries everything the duration does, so the
+# span listener books it and no duration listener is registered.
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_OUTCOMES = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+MAX_PROGRAMS = 256
+# (fun_name, stage, seconds, start_unix, during), newest last
+_PROGRAMS: Deque[Tuple[str, str, float, float, str]] = collections.deque(
+    maxlen=MAX_PROGRAMS)
+_ACCOUNT_LOCK = threading.Lock()
+_accounting = False
+
+
+# per thread. ``open``: one entry a stage event that has begun and not
+# ended, innermost last, holding the seconds of the events that ended
+# inside it. Stage events NEST: tracing a solve traces every jitted
+# function it calls (5,921 stage events for the 86 programs of
+# ``glmix-ml20m``, 12,077 for ``glmix-ml20m-lbfgs``: PR 35's count), and
+# each reports its whole span, so a sum of durations counts an inner trace
+# once for itself and once for every trace around it. What is booked is an
+# event's OWN seconds, its span less the spans that ended inside it: the
+# stages then sum to the wall time the pipeline took. ``own``: the own
+# seconds of the nested events, by stage, until the outermost event around
+# them closes and books them with its own, so a nested event touches no
+# lock, no counter and no phase. ``hit``: the persistent cache served the
+# backend event that is open.
+class _OpenStages(threading.local):
+    def __init__(self):
+        self.open: List[float] = []
+        self.own: Dict[str, float] = {}
+        self.hit = False
+
+
+_THREAD = _OpenStages()
+
+
+def _on_scalar(event: str, value: float, **_: Any) -> None:
+    if event in _STAGES:
+        _THREAD.open.append(0.0)
+
+
+def _on_event(event: str, **_: Any) -> None:
+    outcome = _CACHE_OUTCOMES.get(event)
+    if outcome is None:
+        return
+    _metrics.counter("compile.cache", outcome=outcome).inc()
+    # JAX reports the outcome INSIDE the backend-compile event of the same
+    # program on the same thread (pxla.py: compile_or_get_cached under
+    # BACKEND_COMPILE_EVENT); remember it for the span that follows
+    _THREAD.hit = outcome == "hit"
+
+
+def _on_time_span(event: str, start: float, end: float, fun_name: str = "",
+                  **_: Any) -> None:
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    thread = _THREAD
+    stack, own = thread.open, thread.own
+    seconds = end - start
+    # empty where the event began before the listeners did
+    inside = stack.pop() if stack else 0.0
+    if stage == "backend" and thread.hit:
+        # the persistent cache served this program: the event held the
+        # key's hashing, the read and the deserialisation, and no compile
+        thread.hit = False
+        stage = "cache_load"
+    own[stage] = own.get(stage, 0.0) + seconds - inside
+    if stack:                       # nested: its span is the outer's too
+        stack[-1] += seconds
+        return
+    # an outermost event is a program's: book what ended with it, count
+    # it and keep its whole span
+    during = _timing.current_phase()
+    for ended, ended_seconds in own.items():
+        # ``time.time`` may step back, and a counter refuses a negative
+        _metrics.counter("compile.seconds", stage=ended,
+                         during=during).inc(max(ended_seconds, 0.0))
+    own.clear()
+    _metrics.counter("compile.programs", stage=stage, during=during).inc()
+    _PROGRAMS.append((fun_name, stage, seconds, start, during))
+    _spans.record(f"compile/{stage}", start, seconds, fun=fun_name,
+                  during=during)
+
+
+def account_compiles() -> None:
+    """Register the three ``jax.monitoring`` listeners, once a process
+    however often it is called (``maybe_enable`` and the jitcache's
+    builds call it). They feed ``compile.seconds{stage, during}``, an
+    event's own seconds, and ``compile.programs{stage, during}``, the
+    outermost events, with ``stage`` one of ``trace`` (to a jaxpr),
+    ``lower`` (jaxpr to MLIR), ``cache_load`` (a backend event the
+    persistent cache served) and ``backend`` (one that compiled) and
+    ``during`` = ``utils/timing.current_phase()``; ``compile.cache
+    {outcome=hit|miss}``; and the newest ``MAX_PROGRAMS`` outermost events
+    in :func:`programs`. With telemetry on each of those is also a span
+    ``compile/<stage>`` (attributes ``fun``, ``during``). JAX calls a
+    listener only where it traces, lowers or compiles: a call on jit's
+    fast path reaches none."""
+    global _accounting
+    if _accounting:
+        return
+    with _ACCOUNT_LOCK:
+        if _accounting:
+            return
+        import jax.monitoring as monitoring
+
+        monitoring.register_scalar_listener(_on_scalar)
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_time_span_listener(_on_time_span)
+        _accounting = True
+
+
+def programs() -> List[Dict[str, Any]]:
+    """The newest ``MAX_PROGRAMS`` outermost stage events, oldest first;
+    ``seconds`` is the event's whole span and ``start_unix`` its start on
+    ``time.time`` (``obs/spans``' epoch pair moves it onto
+    ``time.perf_counter``)."""
+    return [{"fun": fun, "stage": stage, "seconds": seconds,
+             "start_unix": start, "during": during}
+            for fun, stage, seconds, start, during in list(_PROGRAMS)]
+
+
+def clear_programs() -> None:
+    _PROGRAMS.clear()
+
+
+def report_section() -> Dict[str, Any]:
+    """A RunReport's ``compile``: seconds and programs by stage and, under
+    each, by the phase they were booked to (``during``), the cache's
+    outcomes, and the ten slowest stage records of the buffer by name."""
+    section: Dict[str, Any] = {"seconds": {}, "programs": {}}
+    for what, by_stage in section.items():
+        for labels, value in _metrics.series(f"compile.{what}"):
+            by_stage.setdefault(labels["stage"], {})[labels["during"]] = value
+    section["cache"] = {labels["outcome"]: value for labels, value
+                        in _metrics.series("compile.cache")}
+    section["slowest"] = sorted(
+        programs(), key=lambda r: -r["seconds"])[:10]
+    return section

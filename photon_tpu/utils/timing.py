@@ -25,6 +25,16 @@ with telemetry off. A phase times what the HOST spends in the block on
 ``time.perf_counter``; nothing in it waits for the device. The registry
 keeps the newest ``_MAX_TIMINGS`` records, so a long-lived process that
 prepares datasets round after round (nearline) cannot grow it.
+
+A phase also says WHERE host work beneath the spans was done: while a
+``Timed`` whose label is a scoped name (``ingest/prepare/per_user/pad``: it
+has a ``/`` and no space; a driver's free-text log line has not) is open
+on a thread, :func:`current_phase` returns the label's first two segments
+(``ingest/prepare``), and ``none`` outside every such phase.
+``utils/compile_cache`` books JAX's trace / lower / compile seconds under
+it (``compile.seconds{stage, during}``). A ``Timed`` wraps phases of a job,
+never an update or an evaluation, so the stack costs a split a phase and
+nothing a fit.
 """
 
 from __future__ import annotations
@@ -45,6 +55,24 @@ _default_logger = logging.getLogger("photon_tpu.timing")
 _MAX_TIMINGS = 4096
 _TIMINGS: Deque[Tuple[str, float]] = collections.deque(maxlen=_MAX_TIMINGS)
 _TIMINGS_LOCK = threading.Lock()
+
+
+# per thread: the open scoped phases, innermost last
+_PHASES = threading.local()
+
+
+def _phase_of(label: str) -> Optional[str]:
+    """The first two segments of a scoped label; ``None`` for free text."""
+    if "/" not in label or " " in label:
+        return None
+    return "/".join(label.split("/", 2)[:2])
+
+
+def current_phase() -> str:
+    """Where this thread stands: the innermost open scoped ``Timed``
+    (its first two segments), else ``none``."""
+    stack = getattr(_PHASES, "stack", None)
+    return stack[-1] if stack else "none"
 
 
 def timing_records() -> List[Tuple[str, float]]:
@@ -72,16 +100,24 @@ class Timed(contextlib.AbstractContextManager):
         self.logger = logger or _default_logger
         self.level = level
         self.seconds: Optional[float] = None
+        self._phase = _phase_of(label)
 
     def __enter__(self) -> "Timed":
         # span shim: no-op (two attribute writes) when telemetry is off
         self._span = _obs_span(self.label)
         self._span.__enter__()
+        if self._phase is not None:
+            stack = getattr(_PHASES, "stack", None)
+            if stack is None:
+                stack = _PHASES.stack = []
+            stack.append(self._phase)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.seconds = time.perf_counter() - self._t0
+        if self._phase is not None:
+            _PHASES.stack.pop()
         self._span.__exit__(exc_type, exc, tb)
         with _TIMINGS_LOCK:
             _TIMINGS.append((self.label, self.seconds))

@@ -491,7 +491,12 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              "serve/gather", "serve/score", "ingest/prepare/", "ingest/h2d/",
              "ingest/stats", "ingest.h2d_bytes", "fe/args", "re/args",
              "fe/outcome", "re/outcome", "cd/score", "cd/commit",
-             "cd/record", "fe/solve_swept", "fe/score_lanes"} | LINESEARCH
+             "cd/record", "fe/solve_swept", "fe/score_lanes",
+             # the compile account (PR 36): its spans, counters and readers
+             "compile/<stage>", "compile.seconds", "compile.programs",
+             "compile.cache", "account_compiles", "current_phase",
+             "retrace_s", "lower_s", "cache_load_s", "repeat_fit_traces",
+             "start_s"} | LINESEARCH
     for _, _, steps, dense, sparse in SOLVERS.values():
         names |= steps | dense | (sparse or set())
     missing = sorted(n for n in names if n not in text)
