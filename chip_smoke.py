@@ -16,8 +16,14 @@ the full width of the supported GLMix headline shape (bench.py
      the same rows written as Avro, and serves its output;
   4. compiles each Pallas kernel with ``interpret=False`` and compares it
      with the XLA path;
-  5. when the host has four devices, repeats one sweep on a (2, 2) mesh
-     and checks that all four took part and agree with one device.
+  5. stores dense fixed-effect matrices as ``GameEstimator._prepare`` does
+     (``store_rows_major``: rows-major where the chip's own layout is not),
+     checks L-BFGS, TRON and four-lane swept fits on them against the same
+     fits on a plain ``jnp.asarray``, and fits, validates and transforms
+     one estimator on a ragged-width frame;
+  6. when the host has four devices, repeats one sweep on a (2, 2) mesh,
+     checks that all four took part and agree with one device, and runs
+     the ragged-width job on the mesh.
 
 Any failed check raises: no phase is wrapped in try/except, and the exit
 code is non-zero. ``main()`` refuses to run unless ``jax.devices()`` is a
@@ -96,6 +102,11 @@ class Sizes:
     kernel_sparse_dim: int = 4_096
     kernel_ell_width: int = 16
     kernel_serving_rows: int = 128
+    # dense fixed effects whose placement the chip re-lays rows-major (a
+    # small X and fe-epsilon's own), and one whose default is rows-major
+    layout_relaid_shapes: Tuple[Tuple[int, int], ...] = (
+        (8_100, 2_000), (530_000, 2_000))
+    layout_plain_shape: Tuple[int, int] = (8_192, 128)
 
 
 class SmokeFailure(AssertionError):
@@ -707,11 +718,186 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
 
 
 # --------------------------------------------------------------------------
-# phase 5: four devices
+# phase 5: where a dense fixed effect's matrix lies
 # --------------------------------------------------------------------------
 
-def mesh_phase(trained: dict) -> dict:
-    """One sweep on a (data=2, model=2) mesh in this same process."""
+def _logistic_rows(rng, m: int, d: int):
+    x = rng.standard_normal(size=(m, d), dtype=np.float32)
+    x /= np.float32(np.sqrt(d))
+    beta = (rng.normal(size=d) * 4.0).astype(np.float32)
+    y = (rng.random(m) < 1.0 / (1.0 + np.exp(-(x @ beta)))).astype(
+        np.float32)
+    return x, y
+
+
+def ragged_width_job(sizes: Sizes, mesh=None) -> str:
+    """One estimator on a dense fixed effect of ragged width (the smallest
+    relaid shape), with or without a mesh: ``fit`` with a validation frame,
+    then ``GameTransformer.transform``. The training X is the estimator's
+    to store (rows-major on one chip, the mesh's own on four); the
+    validation and transform X are placed plainly, uncommitted, and must
+    score against coefficients that lie wherever the solve left them.
+    Returns the training X's ``ingest.row_major`` outcome."""
+    import jax.numpy as jnp
+
+    from photon_tpu.estimators.game_estimator import (
+        CoordinateConfiguration,
+        FixedEffectDataConfiguration,
+        GameEstimator,
+        GameTransformer,
+    )
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.game.dataset import FeatureShard, GameDataFrame
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import TaskType
+
+    m, d = sizes.layout_relaid_shapes[0]
+    tag = f"ragged-{m}x{d}" + ("" if mesh is None else "-mesh")
+    rng = np.random.default_rng(29)
+    x, y = _logistic_rows(rng, m + m // 4, d)
+    frames = [GameDataFrame(num_samples=len(y[rows]), response=y[rows],
+                            feature_shards={"g": FeatureShard(x[rows], d)})
+              for rows in (slice(0, m), slice(m, None))]
+    est = GameEstimator(
+        TaskType.LOGISTIC_REGRESSION,
+        {tag: CoordinateConfiguration(
+            FixedEffectDataConfiguration("g"), GLMOptimizationConfiguration(
+                optimizer=OptimizerConfig(max_iterations=100,
+                                          tolerance=1e-6),
+                regularization=L2Regularization, regularization_weight=L2))},
+        update_sequence=[tag], num_iterations=1, dtype=jnp.float32,
+        mesh=mesh, validation_evaluators=["AUC"])
+    (result,) = est.fit(frames[0], validation_df=frames[1])
+    (outcome,) = [key.split('outcome="')[1].split('"')[0]
+                  for key in registry.snapshot()["counters"]
+                  if key.startswith(f'ingest.row_major{{coordinate="{tag}"')]
+    auc = result.evaluation["AUC"]
+    scores = np.asarray(GameTransformer(result.model, est).transform(
+        frames[1]))
+    coef = np.asarray(result.model[tag].model.coefficients.means)
+    want = x[m:] @ coef
+    gap = float(np.abs(scores - want).max() / np.abs(want).max())
+    say(f"{tag}: training X {outcome}, validation AUC {auc:.4f}, "
+        f"transform within {gap:.2e} of the largest X @ theta on the host")
+    check(0.6 < auc <= 1.0, f"{tag}: the validation frame was scored")
+    # a dot at the chip's default precision is a bfloat16 pass
+    check(gap <= 2e-2, f"{tag}: transform scores the plain X against the "
+          f"fitted coefficients")
+    return outcome
+
+
+def layout_phase(sizes: Sizes) -> dict:
+    """``store_rows_major`` (``GameEstimator._prepare``'s step after
+    ``fixed_effect_batch``) stores a dense X rows-major wherever the
+    device's own layout of the shape is not (on the chip: the relaid
+    shapes; on a CPU: none), and an L-BFGS fit, a TRON fit and a
+    four-lane swept fit on the placed matrix are the fits on a plain
+    ``jnp.asarray`` of the same rows: counts exactly, coefficients to 2e-6
+    of the largest. Then ``ragged_width_job`` on this one device. Returns
+    each shape's ``ingest.row_major`` outcome, the job's under ``job``."""
+    import jax.numpy as jnp
+
+    from photon_tpu.data.dataset import DataBatch
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.game.dataset import (
+        ROW_MAJOR,
+        FeatureShard,
+        GameDataFrame,
+        store_rows_major,
+    )
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import OptimizerType, TaskType
+
+    def problem(**optimizer):
+        return GlmOptimizationProblem(
+            TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+                optimizer=OptimizerConfig(**optimizer),
+                regularization=L2Regularization, regularization_weight=L2))
+
+    lbfgs = problem(max_iterations=100, tolerance=1e-6)
+    tron = problem(optimizer_type=OptimizerType.TRON, max_iterations=15,
+                   tolerance=1e-5)
+
+    def fits(batch, d):
+        """{fit: (counts, coefficients)} on the host, nothing kept on the
+        device."""
+        out = {}
+        for name, prob in (("lbfgs", lbfgs), ("tron", tron)):
+            model, res = prob.run(batch, dim=d, dtype=jnp.float32)
+            counts = [int(res.iterations), int(res.num_fun_evals)]
+            if res.cg_steps is not None:
+                counts.append(int(res.cg_steps))
+            out[name] = (counts, np.asarray(model.coefficients.means))
+        swept = lbfgs.solve_swept(batch, [0.1, 1.0, 10.0, 100.0], dim=d,
+                                  dtype=jnp.float32)
+        out["swept"] = (
+            np.asarray(swept.stacked.iterations).tolist()
+            + np.asarray(swept.stacked.num_fun_evals).tolist(),
+            np.asarray(swept.coefs))
+        return out
+
+    def outcomes(tag):
+        return {key.split('outcome="')[1].split('"')[0]: int(v)
+                for key, v in registry.snapshot()["counters"].items()
+                if key.startswith(f'ingest.row_major{{coordinate="{tag}"')}
+
+    rng = np.random.default_rng(17)
+    out = {}
+    for m, d in sizes.layout_relaid_shapes + (sizes.layout_plain_shape,):
+        tag = f"layout-{m}x{d}"
+        x, y = _logistic_rows(rng, m, d)
+        df = GameDataFrame(num_samples=m, response=y,
+                           feature_shards={"g": FeatureShard(x, d)})
+        t0 = time.perf_counter()
+        placed = df.fixed_effect_batch("g", coordinate=tag)
+        check(not placed.features.committed,
+              f"{tag}: the frame's own placement is uncommitted")
+        placed = placed._replace(
+            features=store_rows_major(placed.features, tag))
+        placed.features.block_until_ready()
+        seconds = time.perf_counter() - t0
+        (outcome,) = outcomes(tag)
+        layout = placed.features.format.layout
+        say(f"{tag}: placed in {seconds:.2f} s, outcome {outcome}, "
+            f"{layout}, committed {placed.features.committed}")
+        check(layout is None or layout.major_to_minor == ROW_MAJOR,
+              f"{tag}: the placed matrix lies rows-major")
+        check(placed.features.committed == (outcome == "relaid"),
+              f"{tag}: committed exactly where it was relaid")
+        got = fits(placed, d)
+        del placed
+        want = fits(DataBatch(jnp.asarray(x), jnp.asarray(y)), d)
+        for name, (counts, coef) in got.items():
+            gap = float(np.abs(coef - want[name][1]).max()
+                        / max(np.abs(want[name][1]).max(), 1e-30))
+            say(f"{tag} {name}: counts {counts}, coefficients {gap:.2e} "
+                f"of the largest from the default layout's")
+            check(counts == want[name][0],
+                  f"{tag} {name}: the default layout's counts "
+                  f"{want[name][0]}")
+            check(gap <= 2e-6, f"{tag} {name}: coefficients within 2e-6")
+        out[tag] = outcome
+        del x, df
+    out["job"] = ragged_width_job(sizes)
+    return out
+
+
+# --------------------------------------------------------------------------
+# phase 6: four devices
+# --------------------------------------------------------------------------
+
+def mesh_phase(sizes: Sizes, trained: dict) -> dict:
+    """One sweep on a (data=2, model=2) mesh in this same process, then
+    the ragged-width job on the mesh."""
     import jax
     import jax.numpy as jnp
 
@@ -772,7 +958,8 @@ def mesh_phase(trained: dict) -> dict:
     check(max(gaps.values()) <= atol,
           f"mesh agrees with one device inside the solver's stopping "
           f"radius {atol:.2e}")
-    return {"gaps": gaps, "mem_checked": mem_checked}
+    return {"gaps": gaps, "mem_checked": mem_checked,
+            "job": ragged_width_job(sizes, mesh)}
 
 
 # --------------------------------------------------------------------------
@@ -796,8 +983,9 @@ def run(sizes: Sizes, kernel_interpret: bool) -> dict:
         out["cli"] = cli_phase(sizes, trained, tmp)
         clock.lap("after serve + cli")
         out["kernels"] = kernel_phase(sizes, interpret=kernel_interpret)
+        out["layout"] = layout_phase(sizes)
         if jax.device_count() >= 4:
-            out["mesh"] = mesh_phase(trained)
+            out["mesh"] = mesh_phase(sizes, trained)
         else:
             say(f"{jax.device_count()} device(s): the four-device mesh "
                 f"phase does not apply")
@@ -832,7 +1020,14 @@ def main() -> int:
     check(out["train"]["budget_source"] == "backend",
           "the random-effect planner's budget comes from the backend's "
           "bytes_limit")
+    plain = "layout-%dx%d" % Sizes().layout_plain_shape
+    check(all(outcome == ("default" if tag == plain else "relaid")
+              for tag, outcome in out["layout"].items()),
+          "the chip re-lays the ragged-width matrices rows-major and "
+          "leaves the 128-wide one as placed")
     if "mesh" in out:
+        check(out["mesh"]["job"] == "mesh",
+              "a meshed estimator leaves its ragged-width X to the mesh")
         check(out["mesh"]["mem_checked"],
               "per-device memory growth was checked")
     say("summary: " + json.dumps(out, sort_keys=True))

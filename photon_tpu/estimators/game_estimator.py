@@ -34,7 +34,11 @@ from photon_tpu.evaluation.multi import (
     parse_evaluator,
 )
 from photon_tpu.game.coordinate import FixedEffectCoordinate, RandomEffectCoordinate
-from photon_tpu.game.dataset import EntityVocabulary, GameDataFrame
+from photon_tpu.game.dataset import (
+    EntityVocabulary,
+    GameDataFrame,
+    store_rows_major,
+)
 from photon_tpu.game.descent import (
     CoordinateDescentConfig,
     CoordinateDescentResult,
@@ -231,6 +235,11 @@ class GameEstimator:
                 batch = df.fixed_effect_batch(
                     shard_id, dtype=np.dtype(self.dtype).type,
                     feature_dtype=self.feature_dtype, coordinate=cid)
+                # this X is solved on again and again: on one device it is
+                # stored in the layout the solves read (a mesh re-places it)
+                with Timed(f"ingest/h2d/{cid}", level=logging.DEBUG):
+                    batch = batch._replace(features=store_rows_major(
+                        batch.features, cid, on_mesh=self.mesh is not None))
                 with Timed(f"ingest/prepare/{cid}/coordinate",
                            level=logging.DEBUG):
                     key = jax.random.PRNGKey(sampling_seed + i)

@@ -22,10 +22,12 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from photon_tpu.data.dataset import DataBatch
 from photon_tpu.obs.metrics import registry
 from photon_tpu.ops import features as F
+from photon_tpu.utils import compile_cache
 from photon_tpu.utils.timing import Timed
 
 SparseRows = List[Tuple[np.ndarray, np.ndarray]]  # per-row (indices, values)
@@ -36,6 +38,89 @@ def count_placed(coordinate: str, tree) -> None:
     ``coordinate``, to the always-on counter ``ingest.h2d_bytes``."""
     registry.counter("ingest.h2d_bytes", coordinate=coordinate).inc(
         sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)))
+
+
+ROW_MAJOR = (0, 1)      # ``Layout.major_to_minor`` with the rows outermost
+_LANES = 128            # a TPU tiles the minor dimension in 128 lanes
+# Stored rows-major, a row is padded to whole lane tiles and the excess is
+# read on EVERY pass over X, 14-20 a solve; left as the compiler lays it, a
+# solve begins with a re-layout copy, one read and one write of X, some 2.3
+# passes. So the stored layout wins while the excess is under 2.3 / 16 =
+# 0.14 of a row: an eighth (2,000 -> 2,048 is 2.4%; 130 -> 256 is not
+# admitted). Arithmetic, not a setting. Read once on the chip (PERF.md §6,
+# PR 37): at 1,000,000 x 1,000 the stored layout wins a fit by 13.6%; at
+# 130 wide the two are within 3% either way (the compiler's own copy pads
+# the rows the same), so past the bound the rule saves no time and spares
+# the device a stored matrix of twice its values.
+_MAX_ROW_PADDING = 1.125
+
+
+def row_major_outcome(width: int, default_major_to_minor,
+                      on_mesh: bool = False) -> str:
+    """What ``store_rows_major`` does with a dense ``[rows, width]`` matrix
+    whose device lays it ``default_major_to_minor`` when nothing is stated
+    (``None``: the backend reports no layout), and the label it counts
+    under ``ingest.row_major``: ``default`` (the rows are outermost
+    already: every CPU array, a TPU's where the width is a multiple of
+    128), ``mesh`` (a mesh re-places the batch), ``padding`` (whole lane
+    tiles would cost over an eighth more bytes), else ``relaid``."""
+    if default_major_to_minor in (None, ROW_MAJOR):
+        return "default"
+    if on_mesh:
+        return "mesh"
+    if -(-width // _LANES) * _LANES > _MAX_ROW_PADDING * width:
+        return "padding"
+    return "relaid"
+
+
+def _major_to_minor(x: jax.Array):
+    """The layout ``x`` lies in on its device (``None``: not reported)."""
+    layout = x.format.layout
+    return None if layout is None else layout.major_to_minor
+
+
+def _rows_major(x: jax.Array) -> jax.Array:
+    """The relayout program's body. A function of this module's own, not
+    ``jax.device_put(x, Format(...))``: that one compiles JAX's
+    ``_identity_fn``, under whose name a persistent cache may already hold
+    an entry that mislabels its output (``compiled_in_this_process``)."""
+    return x
+
+
+def store_rows_major(x, coordinate: str, on_mesh: bool = False):
+    """A placed dense feature matrix, ONCE in the layout every solve reads.
+    For the layer that holds X for MANY solves and knows its mesh
+    (``GameEstimator._prepare``); a matrix that is read once (a validation
+    or transform X, statistics) stays as ``shard_features`` placed it.
+
+    Where ``row_major_outcome`` says ``relaid`` the array is stored
+    rows-major (one identity program; the device holds X and the copy for
+    its duration, what each solve program held until now) and comes back
+    COMMITTED: a jitted function that states no layout is compiled for a
+    committed argument's own, so no solve, score or lane program begins
+    with the compiler's re-layout copy of X. Everywhere else ``x`` comes
+    back as it is (every CPU array; a TPU's 128-wide matrix; anything but
+    a dense rank-2 array, uncounted). A relaid array whose label does not
+    read rows-major (a stale executable: ``compiled_in_this_process``) is
+    dropped for ``x``, outcome ``mislabelled``: a fit is then slower, not
+    refused. One tick of ``ingest.row_major{coordinate, outcome}`` a dense
+    matrix."""
+    if not isinstance(x, jax.Array) or x.ndim != 2:
+        return x
+    outcome = row_major_outcome(x.shape[1], _major_to_minor(x), on_mesh)
+    if outcome == "relaid":
+        # compiled here, never served from the persistent cache: a served
+        # program mislabels a stated output layout (compile_cache)
+        with compile_cache.compiled_in_this_process():
+            relaid = jax.jit(_rows_major, out_shardings=Format(
+                Layout(ROW_MAJOR), x.sharding))(x)
+        if _major_to_minor(relaid) in (None, ROW_MAJOR):
+            x = relaid
+        else:
+            outcome = "mislabelled"
+    registry.counter("ingest.row_major", coordinate=coordinate,
+                     outcome=outcome).inc()
+    return x
 
 
 class CsrRows:
@@ -125,6 +210,13 @@ class GameDataFrame:
             assert len(vals) == n, f"id tag {tag} length mismatch"
 
     def shard_features(self, shard_id: str, dtype=np.float32) -> F.FeatureMatrix:
+        """One shard's features on the device. A dense matrix is the plain
+        uncommitted ``jnp.asarray``, in the device's DEFAULT layout for
+        its shape (on a TPU column-major where the width is no multiple of
+        128): right for a matrix that is read once (a validation or
+        transform X, statistics) and free to move to any device or mesh.
+        The layer that solves on it again and again stores it rows-major
+        (``store_rows_major``, ``GameEstimator._prepare``)."""
         shard = self.feature_shards[shard_id]
         if shard.is_dense:
             return jnp.asarray(shard.rows, dtype)
@@ -148,7 +240,14 @@ class GameDataFrame:
         ``ingest/h2d/<coordinate>`` (the shard id when the caller gives no
         coordinate; a sparse shard's padded fill, which ``ops/features``
         does in the same call, is inside it), and the placed bytes go to
-        the counter ``ingest.h2d_bytes{coordinate}``."""
+        the counter ``ingest.h2d_bytes{coordinate}``.
+
+        The layout contract is ``shard_features``': X is placed plainly, in
+        the device's default layout, uncommitted. ``GameEstimator._prepare``
+        passes a dense X it will solve on many times, on one device,
+        through ``store_rows_major``: it is then a COMMITTED rows-major
+        array whose layout the solves compile for, and
+        ``ingest.row_major{coordinate, outcome}`` says which way it went."""
         coordinate = coordinate or shard_id
         with Timed(f"ingest/h2d/{coordinate}", level=logging.DEBUG):
             batch = DataBatch(

@@ -22,6 +22,7 @@ fast path. Read it with ``obs.metrics.snapshot()["counters"]``,
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import os
 import threading
@@ -82,6 +83,39 @@ def enable_persistent_cache() -> str:
     _metrics.counter("compile_cache.activations").inc()
     _logger.info("persistent XLA compilation cache enabled at %s", path)
     return path
+
+
+@contextlib.contextmanager
+def compiled_in_this_process():
+    """Programs compiled inside are never written to the persistent cache,
+    so no later process is served them from it: each compiles its own.
+
+    For a program whose OUTPUT layout is stated. On this stack (JAX 0.9.0,
+    the TPU's PJRT plugin) an executable the persistent cache serves hands
+    out such an output in the stated layout and LABELS it with the
+    device's default one (``x.format`` reads column-major over rows-major
+    bytes), and the next program, compiled for the label, is refused its
+    argument (``INVALID_ARGUMENT: expected parameter 1 of size 4240384000
+    ... but got buffer with incompatible size 4341760000``; PERF.md §6,
+    PR 37). A program compiled in the process labels its output rightly.
+
+    The switch is JAX's process-wide write threshold
+    (``jax_persistent_cache_min_compile_time_secs``), not a per-thread
+    one: a program another thread compiles meanwhile is not written either
+    (it is compiled again by the next process, nothing worse), and reads of
+    the cache go on. Keep the body to the one small program; what it
+    returns is checked by its caller (``game/dataset.store_rows_major``
+    reads the label back and drops a mislabelled array).
+    """
+    import jax
+
+    name = "jax_persistent_cache_min_compile_time_secs"
+    was = getattr(jax.config, name)
+    jax.config.update(name, float("inf"))
+    try:
+        yield
+    finally:
+        jax.config.update(name, was)
 
 
 def _held_to_cpu() -> bool:

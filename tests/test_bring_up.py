@@ -41,7 +41,8 @@ def test_chip_smoke_body_at_toy_size(monkeypatch):
         kernel_dense_dims=(16, 128), kernel_ragged_shape=(290, 200),
         kernel_product_shapes=((272, 200),),
         kernel_sparse_dim=200,
-        kernel_ell_width=5, kernel_serving_rows=24)
+        kernel_ell_width=5, kernel_serving_rows=24,
+        layout_relaid_shapes=((290, 200),), layout_plain_shape=(256, 128))
     jax.config.update("jax_enable_x64", False)
     try:
         out = chip_smoke.run(toy, kernel_interpret=True)
@@ -50,6 +51,8 @@ def test_chip_smoke_body_at_toy_size(monkeypatch):
     assert abs(out["train"]["auc"] - out["train"]["oracle_auc"]) <= 2e-3
     assert out["train"]["budget_source"] == "fallback"      # a CPU
     assert out["kernels"]["interpret"] is True
+    assert set(out["layout"].values()) == {"default"}       # a CPU
+    assert out["mesh"]["job"] == "default"
     assert "mesh" in out                    # 8 virtual devices >= 4
 
 

@@ -6,11 +6,14 @@ to ValueAndGradientAggregator semantics the same way the aggregator
 tests pin the XLA path to jax.grad.
 """
 
+import re
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from photon_tpu.data.dataset import DataBatch
 from photon_tpu.ops import aggregators
@@ -20,6 +23,10 @@ from photon_tpu.ops.pallas_glm import (
     fused_dense_hessian_vector,
     fused_dense_value_grad,
 )
+
+# tests/ is on sys.path (pytest's prepend import mode): the shapes and what
+# a v5e lays them as, on record beside the rule's CPU tests
+from test_dataset_layout import V5E_DEFAULTS  # noqa: E402
 
 _IDN = no_normalization()
 
@@ -838,15 +845,156 @@ def test_dense_kernel_compiles_for_a_v5e(v5e, n, d, dtype, call):
     assert temp < (1.1 if d % 128 else 0.1) * x_bytes, temp
 
 
+@pytest.mark.parametrize("shape,dtype,default,outcome", V5E_DEFAULTS,
+                         ids=lambda v: str(v))
+def test_row_major_admission_against_a_v5e(v5e, shape, dtype, default,
+                                           outcome):
+    """The chip's own default for the argument (``compiled.input_formats``
+    of a program that states none) is what ``tests/test_dataset_layout.py``
+    has on record, and ``store_rows_major``'s rule admits the matrix exactly
+    where that default is column-major AND whole lane tiles cost at most
+    an eighth."""
+    from photon_tpu.game.dataset import ROW_MAJOR, row_major_outcome
+
+    compiled = jax.jit(lambda x: x[0]).lower(
+        _shaped(v5e, *shape, dtype=jnp.dtype(dtype))).compile()
+    (fmt,), _ = compiled.input_formats
+    assert fmt.layout.major_to_minor == default
+    got = row_major_outcome(shape[1], fmt.layout.major_to_minor)
+    assert got == outcome
+    assert (got == "relaid") == (
+        default != ROW_MAJOR
+        and -(-shape[1] // 128) * 128 <= 1.125 * shape[1])
+
+
+_EPSILON = (530_000, 2_000)        # fe-epsilon's rows: 2,000 is no multiple of 128
+_X_COPY = re.compile(r"= f32\[530000,2000\]\S* copy\(")
+
+
+def _epsilon_batch(v5e, placed):
+    """fe-epsilon's batch as shapes on the described chip: X ``default``
+    (no layout stated: the compiler's, column-major at this width) or
+    ``row_major`` (as ``GameEstimator._prepare`` stores it there: a
+    committed array whose ``Format`` the solve is compiled for)."""
+    from photon_tpu.game.dataset import ROW_MAJOR
+
+    n, d = _EPSILON
+    x = _shaped(v5e, n, d) if placed == "default" else jax.ShapeDtypeStruct(
+        (n, d), jnp.float32, sharding=Format(Layout(ROW_MAJOR), v5e))
+    row = _shaped(v5e, n)
+    return DataBatch(x, row, row, row)
+
+
+def _solve_compiled(v5e, monkeypatch, optimizer, placed, routed):
+    """``GlmOptimizationProblem._solve_fn`` at fe-epsilon's shape as the
+    chip's compiler leaves it, the kernel routed or XLA's two passes."""
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+    )
+    from photon_tpu.types import TaskType
+    from photon_tpu.utils import jitcache
+
+    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
+    monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: routed)
+    jitcache.clear()
+    prob = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=optimizer, regularization=L2Regularization,
+            regularization_weight=1.0))
+    one = _shaped(v5e)
+    try:
+        return prob._solve_fn.lower(_shaped(v5e, _EPSILON[1]),
+                                    _epsilon_batch(v5e, placed), one,
+                                    one).compile()
+    finally:
+        jitcache.clear()
+
+
+def _held_to_the_layout(placed, compiled):
+    """What X's layout costs a solve program: as the compiler lays the
+    argument, ONE X-sized temporary (its re-layout ``copy``, S10: kept as
+    the record of what the default costs); as ``store_rows_major`` stores
+    it, temporaries under 1% of X and no ``copy`` of X in the text."""
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    x_tiled = _EPSILON[0] * 2_048 * 4
+    copies = _X_COPY.findall(compiled.as_text())
+    if placed == "default":
+        assert x_tiled <= temp < 1.01 * x_tiled, temp
+        assert copies
+    else:
+        assert temp < 0.01 * x_tiled, temp
+        assert not copies, copies
+    return temp
+
+
+_PLACED = pytest.mark.parametrize("placed", ["default", "row_major"])
+
+
+@_PLACED
 def test_routed_solve_compiles_for_a_v5e_without_a_copy_of_its_own(
-        v5e, monkeypatch):
+        v5e, monkeypatch, placed):
     """The fe-epsilon solve as the chip's compiler leaves it: the kernel
     twice (the solver's first evaluation, the line search's), under
-    ``agg/value_and_gradient``; ONE X-sized temporary, the re-layout copy
-    the parent's program holds too (S10), so the same temporary bytes as
-    the XLA program's; no ``pad`` that makes a matrix."""
-    import re
+    ``agg/value_and_gradient``; no X-sized temporary but the re-layout
+    copy of a default-layout argument, which the XLA program holds too,
+    and none at all for X as it is placed; no ``pad`` that makes a
+    matrix."""
+    from photon_tpu.optim.problem import OptimizerConfig
 
+    opt = OptimizerConfig(max_iterations=100, tolerance=1e-6)
+    fused, xla = (_solve_compiled(v5e, monkeypatch, opt, placed, routed)
+                  for routed in (True, False))
+    text = fused.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]+)"', text)
+    assert len(names) == 2, names
+    assert all("agg/value_and_gradient/jit(_fused)/pallas_call" in name
+               for name in names), names
+    assert "tpu_custom_call" not in xla.as_text()
+    temp = [_held_to_the_layout(placed, c) for c in (fused, xla)]
+    assert abs(temp[0] - temp[1]) < 0.001 * _EPSILON[0] * 2_048 * 4, temp
+    padded = re.findall(r"= f32\[(\d+),(\d+)\][^=\n]* pad\(", text)
+    assert not [shape for shape in padded
+                if int(shape[0]) >= _EPSILON[0]], padded
+
+
+@_PLACED
+def test_routed_tron_solve_compiles_for_a_v5e_with_one_kernel_a_product(
+        v5e, monkeypatch, placed):
+    """The fe-epsilon-tron solve as the chip's compiler leaves it: the
+    kernel three times (the first evaluation, the trial point's, and ONE
+    under ``agg/hessian_vector`` inside the CG ``while``), and the XLA
+    program's temporaries: the one re-layout copy of a default-layout X
+    (none for X as it is placed), none of the product's own."""
+    from photon_tpu.optim.problem import OptimizerConfig
+    from photon_tpu.types import OptimizerType
+
+    opt = OptimizerConfig(optimizer_type=OptimizerType.TRON,
+                          max_iterations=15, tolerance=1e-5,
+                          explicit_hessian=False)
+    fused, xla = (_solve_compiled(v5e, monkeypatch, opt, placed, routed)
+                  for routed in (True, False))
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]+)"', fused.as_text())
+    assert len(names) == 3, names
+    assert sum("optim/tron/direction/while/body/agg/hessian_vector/"
+               "jit(_fused)/pallas_call" in name for name in names) == 1, names
+    assert sum("agg/value_and_gradient/jit(_fused)/pallas_call" in name
+               for name in names) == 2, names
+    assert "tpu_custom_call" not in xla.as_text()
+    temp = [_held_to_the_layout(placed, c) for c in (fused, xla)]
+    assert abs(temp[0] - temp[1]) < 0.001 * _EPSILON[0] * 2_048 * 4, temp
+
+
+@_PLACED
+def test_swept_lane_solve_compiles_for_a_v5e(v5e, placed):
+    """The fe-epsilon-l2grid solve (``_swept_solve_fn(None)``, four lambda
+    lanes, traced inside ``disabled()`` as ``solve_swept`` traces it): XLA's
+    MXU contractions and no kernel; the re-layout copy of a default-layout
+    X survives the GEMM consumer, X as it is placed has none."""
     from photon_tpu.function.objective import L2Regularization
     from photon_tpu.ops import pallas_glm
     from photon_tpu.optim.problem import (
@@ -857,93 +1005,21 @@ def test_routed_solve_compiles_for_a_v5e_without_a_copy_of_its_own(
     from photon_tpu.types import TaskType
     from photon_tpu.utils import jitcache
 
-    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
-    n, d = 530_000, 2_000
-    row, one = _shaped(v5e, n), _shaped(v5e)
-    batch = DataBatch(_shaped(v5e, n, d), row, row, row)
-
-    def compiled(routed):
-        monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: routed)
+    jitcache.clear()
+    prob = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(max_iterations=100, tolerance=1e-6),
+            regularization=L2Regularization, regularization_weight=1.0))
+    lanes = _shaped(v5e, 4)
+    try:
+        with pallas_glm.disabled():
+            compiled = prob._swept_solve_fn(None).lower(
+                _shaped(v5e, 4, _EPSILON[1]), _epsilon_batch(v5e, placed),
+                lanes, lanes).compile()
+    finally:
         jitcache.clear()
-        prob = GlmOptimizationProblem(
-            TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
-                optimizer=OptimizerConfig(max_iterations=100, tolerance=1e-6),
-                regularization=L2Regularization, regularization_weight=1.0))
-        try:
-            return prob._solve_fn.lower(_shaped(v5e, d), batch, one,
-                                        one).compile()
-        finally:
-            jitcache.clear()
-
-    fused, xla = compiled(True), compiled(False)
-    text = fused.as_text()
-    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
-                       r'op_name="([^"]+)"', text)
-    assert len(names) == 2, names
-    assert all("agg/value_and_gradient/jit(_fused)/pallas_call" in name
-               for name in names), names
-    assert "tpu_custom_call" not in xla.as_text()
-    temp = [c.memory_analysis().temp_size_in_bytes for c in (fused, xla)]
-    x_tiled = n * 2_048 * 4
-    assert x_tiled <= temp[0] < 1.01 * x_tiled, temp
-    assert abs(temp[0] - temp[1]) < 0.001 * x_tiled, temp
-    padded = re.findall(r"= f32\[(\d+),(\d+)\][^=\n]* pad\(", text)
-    assert not [shape for shape in padded if int(shape[0]) >= n], padded
-
-
-def test_routed_tron_solve_compiles_for_a_v5e_with_one_kernel_a_product(
-        v5e, monkeypatch):
-    """The fe-epsilon-tron solve as the chip's compiler leaves it: the
-    kernel three times (the first evaluation, the trial point's, and ONE
-    under ``agg/hessian_vector`` inside the CG ``while``), and the XLA
-    program's temporaries: the one re-layout copy of X, none of the
-    product's own."""
-    import re
-
-    from photon_tpu.function.objective import L2Regularization
-    from photon_tpu.ops import pallas_glm
-    from photon_tpu.optim.problem import (
-        GLMOptimizationConfiguration,
-        GlmOptimizationProblem,
-        OptimizerConfig,
-    )
-    from photon_tpu.types import OptimizerType, TaskType
-    from photon_tpu.utils import jitcache
-
-    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
-    n, d = 530_000, 2_000
-    row, one = _shaped(v5e, n), _shaped(v5e)
-    batch = DataBatch(_shaped(v5e, n, d), row, row, row)
-
-    def compiled(routed):
-        monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: routed)
-        jitcache.clear()
-        prob = GlmOptimizationProblem(
-            TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
-                optimizer=OptimizerConfig(
-                    optimizer_type=OptimizerType.TRON, max_iterations=15,
-                    tolerance=1e-5, explicit_hessian=False),
-                regularization=L2Regularization, regularization_weight=1.0))
-        try:
-            return prob._solve_fn.lower(_shaped(v5e, d), batch, one,
-                                        one).compile()
-        finally:
-            jitcache.clear()
-
-    fused, xla = compiled(True), compiled(False)
-    text = fused.as_text()
-    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
-                       r'op_name="([^"]+)"', text)
-    assert len(names) == 3, names
-    assert sum("optim/tron/direction/while/body/agg/hessian_vector/"
-               "jit(_fused)/pallas_call" in name for name in names) == 1, names
-    assert sum("agg/value_and_gradient/jit(_fused)/pallas_call" in name
-               for name in names) == 2, names
-    assert "tpu_custom_call" not in xla.as_text()
-    temp = [c.memory_analysis().temp_size_in_bytes for c in (fused, xla)]
-    x_tiled = n * 2_048 * 4
-    assert x_tiled <= temp[0] < 1.01 * x_tiled, temp
-    assert abs(temp[0] - temp[1]) < 0.001 * x_tiled, temp
+    assert "tpu_custom_call" not in compiled.as_text()
+    _held_to_the_layout(placed, compiled)
 
 
 def _stores_to(jaxpr, refs):
