@@ -621,10 +621,11 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
         out[name] = {"pallas_rel_err": k_err, "xla_rel_err": x_err}
 
     def dense_oracle(x, coef, v, ym, offm, wm, chunk=65_536):
-        """float64 value, gradient, curvature weights and product
-        ``X^T (d2 * Xv)`` at ``coef``, the rows a chunk at a time (530,000
-        x 2,000 in float64 is 8.5 GB whole)."""
+        """float64 value, gradient, curvature weights, product
+        ``X^T (d2 * Xv)`` and ``sum(w dz)`` at ``coef``, the rows a chunk at
+        a time (530,000 x 2,000 in float64 is 8.5 GB whole)."""
         value, g, hv = 0.0, np.zeros(x.shape[1]), np.zeros(x.shape[1])
+        dz_sum = 0.0
         d2 = np.empty(x.shape[0])
         coef64, v64 = coef.astype(np.float64), v.astype(np.float64)
         for s in range(0, x.shape[0], chunk):
@@ -635,9 +636,10 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
             value += float(np.sum(wm[rows] * (np.logaddexp(0.0, z)
                                               - ym[rows] * z)))
             g += x64.T @ (wm[rows] * (p - ym[rows]))
+            dz_sum += float(np.sum(wm[rows] * (p - ym[rows])))
             d2[rows] = wm[rows] * p * (1.0 - p)
             hv += x64.T @ (d2[rows] * (x64 @ v64))
-        return value, g, d2, hv
+        return value, g, d2, hv, dz_sum
 
     # every dense shape takes the evaluation AND the Hessian-vector product
     # (the same kernel at another per-row function); the product's shapes
@@ -652,7 +654,7 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
         ym = (rng.random(m) > 0.4).astype(np.float32)
         offm = (rng.normal(size=m) * 0.2).astype(np.float32)
         wm = (rng.random(m) + 0.1).astype(np.float32)
-        value, g, d2, hv = dense_oracle(x, coef, v, ym, offm, wm)
+        value, g, d2, hv, dz_sum = dense_oracle(x, coef, v, ym, offm, wm)
         xd, d2d, vd = jnp.asarray(x), jnp.asarray(d2, jnp.float32), jnp.asarray(v)
         args = (LogisticLoss, xd, jnp.asarray(ym), jnp.asarray(offm),
                 jnp.asarray(wm), jnp.asarray(coef))
@@ -672,6 +674,18 @@ def kernel_phase(sizes: Sizes, interpret: bool) -> dict:
         compare(f"fused_dense_hessian_vector[{m}x{d}] quadratic form",
                 np.asarray([q1]), np.asarray([0.5 * jnp.dot(vd, hv0)]),
                 np.asarray([0.5 * float(v.astype(np.float64) @ hv)]))
+        # the three-result program a normalised objective with shifts runs
+        # (PR 38): the same value and gradient, and sum(w dz) beside them
+        v2, g2, s2 = pallas_glm.fused_dense_value_grad(
+            *args, interpret=interpret, with_dz_sum=True)
+        _, dz0 = LogisticLoss.loss_and_dz(
+            aggregators.compute_margins(xd, args[5], args[3],
+                                        no_normalization()), args[2])
+        compare(f"fused_dense_value_grad[{m}x{d}] grad beside sum(w dz)",
+                g2, g0, g)
+        compare(f"fused_dense_value_grad[{m}x{d}] sum(w dz)",
+                np.asarray([s2]), np.asarray([jnp.sum(args[4] * dz0)]),
+                np.asarray([dz_sum]))
         del x, xd, args
 
     d, k = sizes.kernel_sparse_dim, sizes.kernel_ell_width

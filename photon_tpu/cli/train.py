@@ -187,13 +187,29 @@ def _emit_optimization_logs(estimator, results) -> None:
 
 def compute_shard_statistics(df, shard_ids):
     """Per-shard FeatureDataStatistics over the training frame
-    (reference: GameTrainingDriver.prepareFeatureMapsAndStats)."""
+    (reference: GameTrainingDriver.prepareFeatureMapsAndStats).
+
+    A shard's pass is the ``Timed`` phase ``ingest/feature_stats/<shard>``
+    (beside ``ingest/stats``, which is ``padding_waste()``'s) and its
+    placement counts in ``ingest.h2d_bytes{coordinate=<shard>}``. Unlike
+    the other ingest phases this one WAITS for the device: the pass holds
+    its own copy of the shard's matrix (``shard_features``: plain,
+    uncommitted), and it has to be gone before the estimator places the
+    training matrix and its rows-major copy, or a design matrix of a
+    quarter of the chip is held three times."""
+    import jax
+
     from photon_tpu.data.stats import compute_feature_stats
+    from photon_tpu.game.dataset import count_placed
 
     out = {}
     for sid in shard_ids:
-        feats = df.shard_features(sid)
-        out[sid] = compute_feature_stats(feats, df.feature_shards[sid].dim)
+        with Timed(f"ingest/feature_stats/{sid}", level=logging.DEBUG):
+            feats = df.shard_features(sid)
+            count_placed(sid, feats)
+            out[sid] = jax.block_until_ready(
+                compute_feature_stats(feats, df.feature_shards[sid].dim))
+            del feats
     return out
 
 

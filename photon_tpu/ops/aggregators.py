@@ -110,25 +110,32 @@ def value_and_gradient(
     Reference: ValueAndGradientAggregator.calculateValueAndGradient
     (:240-255 RDD path, :266-279 local path) — here one fused kernel.
 
-    On a TPU a dense, identity-normalised, float32, unbatched design
-    matrix of a width the kernel won at runs the Pallas single-HBM-pass
-    kernel (``ops/pallas_glm.py``) instead of XLA's two contractions over
-    X: ``pallas_glm.dense_route`` decides from what it can observe while
-    tracing, and no flag or option has a say. ``kernels.pallas_hits
-    {path=dense}`` ticks once a traced program that took the kernel,
-    ``kernels.xla_fallbacks{path=dense, reason}`` once a traced program
-    whose dense / identity / float32 evaluation on a TPU was turned away
-    (``vmap``, ``mesh``, ``shape``). The ELL-sparse kernel is routed by
-    nothing: no cell runs sparse features and nothing has timed it.
+    On a TPU a dense, float32, unbatched design matrix of a width the
+    kernel won at runs the Pallas single-HBM-pass kernel
+    (``ops/pallas_glm.py``) instead of XLA's two contractions over X,
+    under an identity context and under a normalisation alike
+    (``_through_kernel``): ``pallas_glm.dense_route`` decides from what it
+    can observe while tracing, and no flag or option has a say.
+    ``kernels.pallas_hits{path}`` ticks once a traced program that took
+    the kernel, ``kernels.xla_fallbacks{path, reason}`` once a traced
+    program whose dense float32 evaluation on a TPU was turned away
+    (``vmap``, ``mesh``, ``shape``); ``path`` is ``dense`` under an
+    identity context and ``dense_norm`` under factors or shifts. The
+    ELL-sparse kernel is routed by nothing: no cell runs sparse features
+    and nothing has timed it.
     """
     from photon_tpu.ops import pallas_glm
-    route = pallas_glm.dense_route(x, norm, coef)
+    route = pallas_glm.dense_route(x, coef, labels, offsets, weights,
+                                   norm.factors, norm.shifts)
+    path = "dense" if norm.is_identity else "dense_norm"
     if route == pallas_glm.KERNEL:
-        _kernel_counter("pallas_hits", "dense")
-        return pallas_glm.fused_dense_value_grad(
-            loss, x, labels, offsets, weights, coef)
+        _kernel_counter("pallas_hits", path)
+        return _through_kernel(
+            lambda off, c, s: pallas_glm.fused_dense_value_grad(
+                loss, x, labels, off, weights, c, with_dz_sum=s),
+            x.shape[0], offsets, coef, norm)
     if route is not None:
-        _kernel_counter("xla_fallbacks", "dense", reason=route)
+        _kernel_counter("xla_fallbacks", path, reason=route)
     dim = coef.shape[0]
     margins = compute_margins(x, coef, offsets, norm)
     l, dz = loss.loss_and_dz(margins, labels)
@@ -139,6 +146,33 @@ def value_and_gradient(
     vector_sum = rmatvec(x, dz, dim)
     grad = _apply_factor_and_shift(vector_sum, jnp.sum(dz), norm)
     return value, grad
+
+
+def _through_kernel(fused, rows: int, offsets: Optional[Array], coef: Array,
+                    norm: NormalizationContext) -> Tuple[Array, Array]:
+    """(value, gradient) in transformed space through the one-read kernel,
+    ``fused(offsets, coefficients, with_dz_sum)`` being the kernel bound to
+    its rows. The fold this module's header writes out: the kernel runs at
+    the EFFECTIVE coefficients ``coef * factor`` with the margin shift
+    ``-e . shift`` added to the offsets (one ``[rows]`` vector), and what
+    comes out takes ``_apply_factor_and_shift``. The prefactor
+    ``sum_i w_i dz_i`` is the kernel's third result, asked for only where
+    there are shifts: under an identity context or factors alone the
+    program is the two-result one, and under an identity context the
+    trace is the call ``fused(offsets, coef, False)`` and nothing else.
+    The third result and not the gradient's intercept slot (a column of
+    ones makes ``(X^T w dz)[intercept]`` the same sum): the context
+    carries no intercept index, the XLA path asks for none, and the kernel
+    holds ``w dz`` in scratch anyway, two vector adds a tile."""
+    e = coef if norm.factors is None else coef * norm.factors
+    if norm.shifts is None:
+        value, vector_sum = fused(offsets, e, False)
+        return value, _apply_factor_and_shift(vector_sum, None, norm)
+    margin_shift = -jnp.dot(e, norm.shifts)
+    off = (jnp.broadcast_to(margin_shift, (rows,)) if offsets is None
+           else offsets + margin_shift)
+    value, vector_sum, prefactor = fused(off, e, True)
+    return value, _apply_factor_and_shift(vector_sum, prefactor, norm)
 
 
 def _weighted_loss_and_dz(
@@ -227,21 +261,30 @@ def hessian_vector_from_weights(
     """Hv given precomputed curvature weights.
 
     Where ``pallas_glm.dense_route`` admits the matrix (the gate of
-    ``value_and_gradient``: a TPU, dense float32, identity normalisation,
-    unbatched, a width the kernel won at) ONE read of X through the same
-    fused kernel (``pallas_glm.fused_dense_hessian_vector``); elsewhere
-    XLA's TWO passes (``X v``, then ``X^T (d2 * Xv)``: 11.5 ms a product
-    at 530,000 x 2,000 float32 on a TPU v5e; PERF.md §5). ``kernels.
-    pallas_hits{path=dense_hv}`` ticks once a traced program that took the
-    kernel for a product, ``kernels.xla_fallbacks{path=dense_hv, reason}``
-    once a traced program turned away (``vmap``, ``mesh``, ``shape``)."""
+    ``value_and_gradient``: a TPU, dense float32, unbatched, a width the
+    kernel won at) ONE read of X through the same fused kernel
+    (``pallas_glm.fused_dense_hessian_vector``), a normalisation folded in
+    around it as in ``value_and_gradient`` (``_through_kernel``: the
+    product is linear in the vector as the margins are in the
+    coefficients); elsewhere XLA's TWO passes (``X v``, then
+    ``X^T (d2 * Xv)``: 11.5 ms a product at 530,000 x 2,000 float32 on a
+    TPU v5e; PERF.md §5). ``kernels.pallas_hits{path}`` ticks once a
+    traced program that took the kernel for a product,
+    ``kernels.xla_fallbacks{path, reason}`` once a traced program turned
+    away (``vmap``, ``mesh``, ``shape``); ``path`` is ``dense_hv`` under
+    an identity context, ``dense_hv_norm`` under factors or shifts."""
     from photon_tpu.ops import pallas_glm
-    route = pallas_glm.dense_route(x, norm, vector)
+    route = pallas_glm.dense_route(x, vector, d2, norm.factors,
+                                   norm.shifts)
+    path = "dense_hv" if norm.is_identity else "dense_hv_norm"
     if route == pallas_glm.KERNEL:
-        _kernel_counter("pallas_hits", "dense_hv")
-        return pallas_glm.fused_dense_hessian_vector(x, d2, vector)[1]
+        _kernel_counter("pallas_hits", path)
+        return _through_kernel(
+            lambda off, v, s: pallas_glm.fused_dense_hessian_vector(
+                x, d2, v, offsets=off, with_dz_sum=s),
+            x.shape[0], None, vector, norm)[1]
     if route is not None:
-        _kernel_counter("xla_fallbacks", "dense_hv", reason=route)
+        _kernel_counter("xla_fallbacks", path, reason=route)
     v_eff = vector * norm.factors if norm.factors is not None else vector
     t = matvec(x, v_eff)
     if norm.shifts is not None:

@@ -23,8 +23,9 @@ revisited output blocks (the grid is sequential) and are summed outside.
 X is read from HBM exactly once: 5.7 ms an evaluation at 530,000 x
 2,000 against XLA's 11.5 (93% of the HBM peak). ``dense_route`` says
 where ``aggregators.value_and_gradient`` takes it: on a TPU, dense
-float32, identity normalization, not under vmap or a mesh, a width the
-kernel won at on the chip.
+float32, not under vmap or a mesh, a width the kernel won at on the
+chip; a normalised objective takes it too, at effective coefficients,
+with the margin shift on the offsets and a third result ``sum(w dz)``.
 
 The other two kernels keep SAMPLES ALONG THE LANES: per-sample vectors
 (labels, offsets, weights, margins) are lane-dense ``[1, T]`` rows, the
@@ -53,7 +54,9 @@ which is what pins them to the XLA path in tests/test_pallas_glm.py.
 float64 oracle.
 
 Reference semantics: ValueAndGradientAggregator.scala:36-80 (the same
-fused margin/loss/grad algebra, minus the normalization prefactors).
+fused margin/loss/grad algebra; the normalization prefactors are applied
+by ``ops/aggregators.py`` around the kernel, which hands it effective
+coefficients and takes ``sum(w dz)`` back).
 """
 
 from __future__ import annotations
@@ -202,25 +205,29 @@ def _on_tpu() -> bool:
 KERNEL = "kernel"
 
 
-def dense_route(x, norm, coef) -> Optional[str]:
+def dense_route(x, coef, *rest) -> Optional[str]:
     """Where ``aggregators`` sends an evaluation or a Hessian-vector product
-    (``coef`` its vector), by what it observes. ``None``: not the kernel's —
-    not a TPU, not a dense rank-2 float32 matrix, a normalised objective,
-    coefficients that are not float32 (a float64 solve over float32
-    features promotes on the XLA path; the kernel would hand back float32
-    and break the ``while_loop`` carry) — so XLA's two passes, uncounted.
-    ``KERNEL``: the one fused pass. Otherwise the reason such an
-    evaluation was turned away, which ``kernels.xla_fallbacks`` carries
-    as a label: ``"vmap"`` (the sequential grid's accumulation into a
-    revisited block assumes it owns the grid; the per-entity ladders and
-    the lambda lanes batch the objective), ``"mesh"`` (a ``disabled()``
-    region: ``pallas_call`` carries no sharding), ``"shape"`` (the
-    width the kernel did not win at: ``_DENSE_MIN_WIDTH``)."""
+    (``coef`` its vector; ``rest`` whatever else would reach the kernel:
+    labels, offsets, weights, a context's factors and shifts, ``None``
+    where there is none), by what it observes. ``None``: not the kernel's —
+    not a TPU, not a dense rank-2 float32 matrix, coefficients that are
+    not float32 (a float64 solve over float32 features promotes on the XLA
+    path; the kernel would hand back float32 and break the ``while_loop``
+    carry) — so XLA's two passes, uncounted. ``KERNEL``: the one fused
+    pass, under an identity context and under a normalisation alike (the
+    aggregator folds factors and shifts in around the kernel). Otherwise
+    the reason such an evaluation was turned away, which
+    ``kernels.xla_fallbacks`` carries as a label: ``"vmap"`` (the
+    sequential grid's accumulation into a revisited block assumes it owns
+    the grid; the per-entity ladders and the lambda lanes batch the
+    objective, and a batch over offsets or contexts alone would reach the
+    kernel through ``rest``), ``"mesh"`` (a ``disabled()`` region:
+    ``pallas_call`` carries no sharding), ``"shape"`` (the width the
+    kernel did not win at: ``_DENSE_MIN_WIDTH``)."""
     if not (_on_tpu() and isinstance(x, jax.Array) and x.ndim == 2
-            and x.dtype == jnp.float32 and coef.dtype == jnp.float32
-            and norm.is_identity):
+            and x.dtype == jnp.float32 and coef.dtype == jnp.float32):
         return None
-    if _batched(x, coef):
+    if _batched(x, coef, *rest):
         return "vmap"
     if _TRACE_DISABLED.get():
         return "mesh"
@@ -236,15 +243,18 @@ def _lane_chunks(d: int):
     return [(c0, min(_LANES, d - c0)) for c0 in range(0, d, _LANES)]
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5, 6))
+@functools.partial(jax.jit, static_argnums=(0, 5, 6, 8))
 def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
-           interpret: bool, coef):
+           interpret: bool, coef, dz_sum: bool = False):
     """x [n, d] AS PLACED (no pad, no copy: any n >= tile_n, any d), of
     which the kernel reads the ``n // tile_n`` whole tiles; labels/
     offsets/weights [steps, tile_n / 128, 128] (row t of step i at
     [i, t // 128, t % 128]); coef [1, d]; tile_n % 128 == 0. Returns the
     value's per-lane partial sums [tile_n / 128, 128] and the gradient's
-    per-sublane partial sums [8, d]."""
+    per-sublane partial sums [8, d]; with ``dz_sum`` a third output, the
+    per-lane partial sums [tile_n / 128, 128] of ``w * dz`` (what a
+    normalisation's shifts multiply: the scratch the gradient is fed from,
+    added up on its way). Without it the program is the two-output one."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -255,13 +265,17 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
     f32 = jnp.float32
 
     def kernel(x_ref, y_ref, off_ref, w_ref, coef_ref, val_ref, grad_ref,
-               m_ref, wdz_ref):
+               *rest):
+        # rest: the third output where asked for, then the two scratches
+        m_ref, wdz_ref = rest[-2:]
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _():
             val_ref[...] = jnp.zeros_like(val_ref)
             grad_ref[...] = jnp.zeros_like(grad_ref)
+            if dz_sum:
+                rest[0][...] = jnp.zeros_like(rest[0])
 
         # A 128-row block's per-row numbers change hands between two
         # layouts: one a SUBLANE ([128, 1], what a sum over the lanes of
@@ -297,6 +311,8 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
         w = w_ref[...]
         val_ref[...] += l * w
         wdz_ref[...] = w * dz
+        if dz_sum:
+            rest[0][...] += wdz_ref[...]
 
         def gradient(b, acc):
             col = jnp.sum(jnp.where(eye, wdz_ref[pl.ds(b, 1), :], zero),
@@ -316,11 +332,13 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
             grad_ref[:, c0:c0 + size] += a
 
     rows = pl.BlockSpec((None, blocks, _LANES), lambda i: (i, 0, 0))
+    lanes = pl.BlockSpec((blocks, _LANES), lambda i: (0, 0))
+    lanes_shape = jax.ShapeDtypeStruct((blocks, _LANES), f32)
     # Mosaic lowers no 64-bit type, and under x64 a ``fori_loop`` counts
     # in int64 whatever its bounds: the kernel is traced with x64 off
     # (its operands and results are float32 either way)
     with jax.enable_x64(False):
-        value, grad = pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid=(steps,),
             in_specs=[
@@ -330,13 +348,13 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
                 pl.BlockSpec((1, d), lambda i: (0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((blocks, _LANES), lambda i: (0, 0)),
+                lanes,
                 pl.BlockSpec((_MXU_ROWS, d), lambda i: (0, 0)),
-            ],
+            ] + [lanes] * dz_sum,
             out_shape=[
-                jax.ShapeDtypeStruct((blocks, _LANES), f32),
+                lanes_shape,
                 jax.ShapeDtypeStruct((_MXU_ROWS, d), f32),
-            ],
+            ] + [lanes_shape] * dz_sum,
             scratch_shapes=[pltpu.VMEM((blocks, _LANES), f32),
                             pltpu.VMEM((blocks, _LANES), f32)],
             compiler_params=pltpu.CompilerParams(
@@ -345,7 +363,7 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(x, labels, offsets, weights, coef)
-    return value, grad
+    return tuple(out)
 
 
 def _sample_rows(n: int, labels, offsets, weights, n_pad: int):
@@ -377,12 +395,17 @@ def fused_dense_value_grad(
     *,
     tile_n: Optional[int] = None,
     interpret: Optional[bool] = None,
-) -> Tuple[Array, Array]:
+    with_dz_sum: bool = False,
+) -> Tuple[Array, ...]:
     """Weighted loss value and gradient, X streamed from HBM once.
 
-    Drop-in for the un-normalized dense case of
-    ``aggregators.value_and_gradient`` (no L2 term — the objective adds
-    it, as with the XLA path). X goes to the kernel as it is placed, for
+    The dense case of ``aggregators.value_and_gradient`` on raw rows (no
+    L2 term — the objective adds it, as with the XLA path). A normalised
+    objective is this evaluation too: the aggregator hands in the
+    EFFECTIVE coefficients and the margin shift on the offsets, asks
+    ``with_dz_sum`` for a third result, ``sum_i w_i dz_i`` (what the
+    shifts multiply in the gradient), and applies factors and shifts to
+    what comes out. X goes to the kernel as it is placed, for
     any ``n`` and ``d``: a block spans its full width, the kernel takes
     the whole tiles, and the rows left over (fewer than a tile: 80 of
     epsilon's 530,000) are the same sums as plain float32 array
@@ -414,13 +437,16 @@ def fused_dense_value_grad(
                                y[whole:])
     value = jnp.sum(lt * w[whole:])
     grad = jnp.sum(xt * (dzt * w[whole:])[:, None], axis=0)
+    sums = (jnp.sum(dzt * w[whole:]),) if with_dz_sum else ()
     if whole:
         shape = (whole // tile, tile // _LANES, _LANES)
-        v, g = _fused(loss.loss_and_dz, x,
-                      *(r[:whole].reshape(shape) for r in (y, off, w)),
-                      tile, bool(interpret), coef.reshape(1, d))
+        v, g, *rest = _fused(loss.loss_and_dz, x,
+                          *(r[:whole].reshape(shape) for r in (y, off, w)),
+                          tile, bool(interpret), coef.reshape(1, d),
+                          bool(with_dz_sum))
         value, grad = value + jnp.sum(v), grad + jnp.sum(g, axis=0)
-    return value, grad
+        sums = tuple(a + jnp.sum(b) for a, b in zip(sums, rest))
+    return (value, grad) + sums
 
 
 def fused_dense_hessian_vector(
@@ -428,9 +454,11 @@ def fused_dense_hessian_vector(
     d2: Array,
     vector: Array,
     *,
+    offsets: Optional[Array] = None,
     tile_n: Optional[int] = None,
     interpret: Optional[bool] = None,
-) -> Tuple[Array, Array]:
+    with_dz_sum: bool = False,
+) -> Tuple[Array, ...]:
     """``(v . Hv / 2, Hv)`` for ``H = X^T diag(d2) X``, X streamed from HBM
     once: TRON's matrix-free CG step (``aggregators.
     hessian_vector_from_weights`` where ``dense_route`` admits the matrix).
@@ -443,11 +471,15 @@ def fused_dense_hessian_vector(
     So this is ``fused_dense_value_grad``: the same kernel body, tile and
     left-over rows; XLA's path reads X twice (``X v``, then ``X^T (d2 *
     Xv)``). The labels and the offsets are zeros the compiler makes once a
-    solve, outside its loops: 2 MB each at 530,000 rows, 0.02 ms a fit."""
+    solve, outside its loops: 2 MB each at 530,000 rows, 0.02 ms a fit.
+    Under a normalisation the aggregator hands in the effective vector,
+    ``offsets`` = the margin shift, and asks ``with_dz_sum`` for
+    ``sum_i d2_i t_i`` (``fused_dense_value_grad``)."""
     from photon_tpu.ops.losses import SquaredLoss
     zeros = jnp.zeros((x.shape[0],), jnp.float32)
-    return fused_dense_value_grad(SquaredLoss, x, zeros, zeros, d2, vector,
-                                  tile_n=tile_n, interpret=interpret)
+    return fused_dense_value_grad(
+        SquaredLoss, x, zeros, zeros if offsets is None else offsets, d2,
+        vector, tile_n=tile_n, interpret=interpret, with_dz_sum=with_dz_sum)
 
 
 def _supported_sparse(x, norm, coef) -> bool:

@@ -19,10 +19,13 @@ can't corrupt or interleave the summary.
 
 It is the one primitive that records with telemetry OFF, which is why
 the set-up phases of a fit are ``Timed`` (``ingest/prepare/<coordinate>
-/<step>``, ``ingest/h2d/<coordinate>``, ``ingest/stats``: PERF.md §3):
-a job's set-up seconds have to be readable from a run that was measured
-with telemetry off. A phase times what the HOST spends in the block on
-``time.perf_counter``; nothing in it waits for the device. The registry
+/<step>``, ``ingest/h2d/<coordinate>``, ``ingest/stats``,
+``ingest/feature_stats/<shard>``: PERF.md §3): a job's set-up seconds
+have to be readable from a run that was measured with telemetry off. A
+phase times what the HOST spends in the block on ``time.perf_counter``;
+nothing in it waits for the device, but for ``ingest/feature_stats``,
+whose statistics have to be done (and its copy of the matrix gone) before
+the estimator places its own (``cli/train.py``). The registry
 keeps the newest ``_MAX_TIMINGS`` records, so a long-lived process that
 prepares datasets round after round (nearline) cannot grow it.
 

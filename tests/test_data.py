@@ -6,6 +6,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 import scipy.sparse as sp
 
 from photon_tpu.data import ingest, sampling
@@ -38,6 +39,51 @@ def test_feature_stats_sparse_accounts_for_implicit_zeros(rng):
     np.testing.assert_allclose(s.max, X.max(0), rtol=1e-12)
     np.testing.assert_allclose(s.num_nonzeros, (X != 0).sum(0))
     np.testing.assert_allclose(s.abs_max, np.abs(X).max(0), rtol=1e-12)
+
+
+@pytest.mark.parametrize("mean_over_std", [3.0, 300.0])
+def test_feature_stats_float32_variance_of_a_feature_off_zero(mean_over_std):
+    """The statistics a STANDARDIZATION is built from, in float32, at a
+    cell's row count, of features whose mean lies ``mean_over_std``
+    deviations from zero, against float64 numpy. The one-pass ``sum(x^2) -
+    n mean^2`` this replaces (PR 38) read 2.4e-6 off at 3 deviations and
+    3% off at 300 (a factor off by as much); about the mean it is 1.3e-7
+    and 2e-7 (CPU readings)."""
+    rng = np.random.default_rng(3)
+    n, d = 530_000, 16
+    scale = np.exp(rng.uniform(np.log(1e-2), np.log(1e2), d))
+    sign = np.array([-1.0, 1.0] * (d // 2))
+    x = (mean_over_std * sign * scale
+         + scale * rng.standard_normal((n, d))).astype(np.float32)
+    want = x.astype(np.float64)
+    s = compute_feature_stats(jnp.asarray(x), d)
+    assert s.variance.dtype == jnp.float32
+    variance = np.asarray(s.variance, np.float64)
+    np.testing.assert_allclose(variance, want.var(0, ddof=1), rtol=6e-7)
+    np.testing.assert_allclose(
+        np.asarray(s.mean, np.float64), want.mean(0), rtol=0,
+        atol=1e-6 * mean_over_std * np.sqrt(variance).max())
+    # an error in the mean moves x' by mean_err / std: under 1e-3 of a
+    # deviation even 300 deviations out
+    assert (np.abs(np.asarray(s.mean, np.float64) - want.mean(0))
+            / want.std(0)).max() < 1e-6 * mean_over_std
+
+
+def test_feature_stats_sparse_float32_variance_off_zero():
+    """The sparse rows' variance is taken about the mean too: stored values
+    by scatter, every other cell of a column as ``-mean``."""
+    rng = np.random.default_rng(4)
+    n, d = 20_000, 6
+    X = 50.0 + rng.standard_normal((n, d))
+    X[rng.random((n, d)) < 0.02] = 0.0          # a few implicit zeros
+    X[:, 1] = 0.0                                # an empty column
+    sparse = F.from_scipy_csr(sp.csr_matrix(X), dtype=np.float32)
+    s = compute_feature_stats(sparse, d)
+    want = X.astype(np.float32).astype(np.float64)
+    np.testing.assert_allclose(np.asarray(s.variance, np.float64),
+                               want.var(0, ddof=1), rtol=2e-5, atol=1e-12)
+    np.testing.assert_allclose(np.asarray(s.mean, np.float64), want.mean(0),
+                               rtol=1e-5, atol=1e-12)
 
 
 def test_binary_downsampler_preserves_expectation(rng):

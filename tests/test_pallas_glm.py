@@ -6,6 +6,7 @@ to ValueAndGradientAggregator semantics the same way the aggregator
 tests pin the XLA path to jax.grad.
 """
 
+import inspect
 import re
 
 import numpy as np
@@ -229,9 +230,10 @@ def test_product_route(case, wide_problem, on_tpu, monkeypatch):
     it, the gate of ``value_and_gradient``, and says so under its own
     label: ``kernels.pallas_hits{path=dense_hv}`` once a traced program
     that took the kernel, ``kernels.xla_fallbacks{path=dense_hv, reason}``
-    once a traced program turned away; a float64 vector, a normalised
-    objective and a backend that is no TPU are not the kernel's case and
-    tick nothing. Either way the product is XLA's."""
+    once a traced program turned away; a float64 vector and a backend
+    that is no TPU are not the kernel's case and tick nothing; a
+    normalised product takes the kernel and ticks ``path=dense_hv_norm``,
+    nothing under ``dense_hv``. Either way the product is XLA's."""
     from photon_tpu.ops.normalization import NormalizationContext
 
     X, _, _, w, v = wide_problem
@@ -268,8 +270,10 @@ def test_product_route(case, wide_problem, on_tpu, monkeypatch):
     elif case == "normalised":
         ones = NormalizationContext(factors=jnp.ones(d, jnp.float32),
                                     shifts=None)
+        normed = _ticks("dense_hv_norm")
         got = hv(norm=ones)
         assert ticked() == {}
+        assert _ticked(normed, "dense_hv_norm") == {"hit": 1}
     else:
         monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
         got = hv()
@@ -502,25 +506,39 @@ def test_routing_mesh_solve_gated_off(wide_problem, on_tpu, monkeypatch,
 def test_dense_route(case, monkeypatch):
     """What the predicate sees, case by case: ``None`` (not the kernel's
     case: XLA's two passes, uncounted), the kernel, or the reason a TPU's
-    dense / identity / float32 evaluation was turned away. ADVICE r4: an
+    dense float32 evaluation was turned away; the normalization context
+    is not its business (PR 38: the aggregator folds it in around the
+    kernel, so the predicate takes its arrays only to see whether they
+    are batched). ADVICE r4: an
     f64 solve over f32 features must not take the fused path (it would
     silently return f32 and break the while_loop carry dtype); the XLA
     path promotes instead."""
     from photon_tpu.ops import features as F
     from photon_tpu.ops import pallas_glm
-    from photon_tpu.ops.normalization import NormalizationContext
 
     monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: case != "not_a_tpu")
     d = pallas_glm._DENSE_MIN_WIDTH
     x = jnp.zeros((8, d), jnp.float32)
     coef = jnp.zeros(d, jnp.float32)
-    route = lambda x=x, norm=_IDN, coef=coef: pallas_glm.dense_route(
-        x, norm, coef)
+    route = lambda x=x, coef=coef: pallas_glm.dense_route(x, coef)
     if case == "float64_coef":
         assert route(coef=jnp.zeros(d, jnp.float64)) is None
     elif case == "normalised":
-        assert route(norm=NormalizationContext(
-            factors=jnp.ones(d, jnp.float32), shifts=None)) is None
+        # the predicate is shown a context only as arrays that would
+        # reach the kernel (``rest``: batched or not, nothing else of
+        # them counts); the per-entity ladders, which gather a context a
+        # lane, come with a batched X: "vmap"
+        assert list(inspect.signature(pallas_glm.dense_route).parameters) \
+            == ["x", "coef", "rest"]
+        assert pallas_glm.dense_route(x, coef, None, coef + 2.0) == \
+            pallas_glm.KERNEL
+        seen = []
+        jax.vmap(lambda xs, c: seen.append(route(x=xs, coef=c)) or c)(
+            jnp.zeros((3, 8, d), jnp.float32), jnp.zeros((3, d), jnp.float32))
+        jax.vmap(lambda f: seen.append(
+            pallas_glm.dense_route(x, coef, None, f)) or f)(
+            jnp.ones((3, d), jnp.float32))
+        assert seen == ["vmap", "vmap"]
     elif case == "bfloat16_x":
         # the kernel takes bfloat16 rows when called (below); nothing has
         # timed it, so nothing routes it
@@ -555,9 +573,10 @@ def test_dense_route(case, monkeypatch):
 def test_refusals_tick_only_where_a_tpu_turned_the_kernel_away(
         wide_problem, on_tpu, monkeypatch):
     """``kernels.xla_fallbacks{path=dense, reason}`` ticks once a traced
-    program for ``vmap``, ``mesh`` and ``shape``; float64 coefficients, a
-    normalised objective and a non-TPU backend are not the kernel's case
-    and tick nothing."""
+    program for ``vmap``, ``mesh`` and ``shape``; float64 coefficients
+    and a non-TPU backend are not the kernel's case and tick nothing; a
+    normalised objective ticks nothing under ``path=dense`` (it has a
+    label of its own: ``test_a_normalised_evaluation_is_counted``)."""
     from photon_tpu.ops.normalization import NormalizationContext
 
     X, y, off, w, coef = wide_problem
@@ -585,6 +604,219 @@ def test_refusals_tick_only_where_a_tpu_turned_the_kernel_away(
     before = _ticks()
     vg(X, coef)
     assert _ticked(before) == {}
+
+
+# ---------------------------------------------------------------------------
+# a normalised objective through the same kernel (PR 38): effective
+# coefficients in, the margin shift on the offsets, sum(w dz) as a third
+# result, factors and shifts applied to what comes out
+# ---------------------------------------------------------------------------
+
+
+def _raw_problem(n=700, d=300, seed=5):
+    """Features in raw units (scales four decades apart, means up to three
+    deviations from zero) and an intercept column, last."""
+    rng = np.random.default_rng(seed)
+    s = np.exp(rng.uniform(np.log(1e-2), np.log(1e2), d - 1))
+    m = rng.uniform(-3, 3, d - 1) * s
+    x = np.concatenate([m + s * rng.normal(size=(n, d - 1)),
+                        np.ones((n, 1))], axis=1)
+    X = jnp.asarray(x, jnp.float32)
+    y = jnp.asarray(rng.random(n) > 0.4, jnp.float32)
+    off = jnp.asarray(rng.normal(size=n) * 0.2, jnp.float32)
+    w = jnp.asarray(rng.random(n) + 0.1, jnp.float32)
+    coef = jnp.asarray(rng.normal(size=d) * 0.1, jnp.float32)
+    return X, y, off, w, coef
+
+
+def _context(kind, X):
+    """A context of each ``NormalizationType`` from X's own statistics, as
+    ``cli/train.py::build_normalization`` builds it (intercept last), and
+    ``shifts_only``: shifts without factors, which no type builds and the
+    algebra allows."""
+    from photon_tpu.data.stats import compute_feature_stats
+    from photon_tpu.ops.normalization import (
+        NormalizationContext,
+        NormalizationType,
+        build_normalization_context,
+    )
+
+    stats = compute_feature_stats(X, X.shape[1])
+    if kind == "shifts_only":
+        return NormalizationContext(None, stats.mean.at[-1].set(0.0))
+    return build_normalization_context(
+        NormalizationType(kind), stats.mean, stats.variance, stats.abs_max,
+        intercept_index=X.shape[1] - 1)
+
+
+_CONTEXTS = ["STANDARDIZATION", "SCALE_WITH_STANDARD_DEVIATION",
+             "SCALE_WITH_MAX_MAGNITUDE", "shifts_only"]
+
+
+@pytest.mark.parametrize("sample_vectors", [True, False],
+                         ids=["offsets_weights", "bare"])
+@pytest.mark.parametrize("kind", _CONTEXTS)
+def test_normalised_evaluation_through_the_kernel_against_xla(
+        kind, sample_vectors, on_tpu):
+    """Value, gradient and Hessian-vector product of a normalised objective
+    through the kernel (interpret mode) against XLA's two passes, under
+    each ``NormalizationType``, with and without shifts, with and without
+    offsets and weights; counted under ``dense_norm`` / ``dense_hv_norm``
+    and nothing under the identity labels."""
+    X, y, off, w, coef = _raw_problem()
+    norm = _context(kind, X)
+    off, w = (off, w) if sample_vectors else (None, None)
+    d2 = 0.25 * (w if w is not None else jnp.ones_like(y))
+    vg = lambda: aggregators.value_and_gradient(
+        LogisticLoss, X, y, off, w, coef, norm)
+    hv = lambda: aggregators.hessian_vector_from_weights(
+        X, d2, coef, norm, coef.shape[0])
+    with on_tpu.disabled():
+        (v0, g0), h0 = vg(), hv()
+    before = {p: _ticks(p) for p in ("dense", "dense_hv", "dense_norm",
+                                     "dense_hv_norm")}
+    (v1, g1), h1 = vg(), hv()
+    assert _ticked(before["dense_norm"], "dense_norm") == {"hit": 1}
+    assert _ticked(before["dense_hv_norm"], "dense_hv_norm") == {"hit": 1}
+    assert _ticked(before["dense"], "dense") == {}
+    assert _ticked(before["dense_hv"], "dense_hv") == {}
+    np.testing.assert_allclose(float(v1), float(v0), rtol=2e-6)
+    for got, want in ((g1, g0), (h1, h0)):
+        scale = float(jnp.abs(want).max())
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(want) / scale, atol=5e-6)
+    # the third result is asked for only where there are shifts
+    outs = [len(e.outvars) for e in _eqns(jax.make_jaxpr(vg)().jaxpr)
+            if e.primitive.name == "pallas_call"]
+    assert outs == [3 if norm.shifts is not None else 2]
+
+
+@pytest.mark.parametrize("reason", ["vmap", "vmap_contexts", "vmap_rows",
+                                    "mesh", "shape"])
+def test_a_normalised_evaluation_is_counted(reason, on_tpu):
+    """A normalised evaluation or product that the gate turns away ticks
+    ``kernels.xla_fallbacks{path=dense_norm | dense_hv_norm, reason}``,
+    once a traced program, and nothing under the identity labels: until
+    PR 38 it left the kernel's path uncounted. A batch over the contexts
+    or over the offsets and curvature weights ALONE (X and the vector
+    shared) is a ``vmap`` too: the effective coefficients and the shifted
+    offsets would reach the kernel batched."""
+    X, y, off, w, coef = _raw_problem()
+    norm = _context("STANDARDIZATION", X)
+    if reason.startswith("vmap_"):
+        two = lambda a: jnp.stack([a, a])
+        before = {p: _ticks(p) for p in ("dense_norm", "dense_hv_norm")}
+        if reason == "vmap_contexts":
+            ctx = type(norm)(two(norm.factors), two(norm.shifts))
+            axes = type(norm)(0, 0)
+            jax.vmap(lambda n: aggregators.value_and_gradient(
+                LogisticLoss, X, y, off, w, coef, n), in_axes=(axes,))(ctx)
+            jax.vmap(lambda n: aggregators.hessian_vector_from_weights(
+                X, 0.25 * w, coef, n, coef.shape[0]), in_axes=(axes,))(ctx)
+        else:
+            jax.vmap(lambda o: aggregators.value_and_gradient(
+                LogisticLoss, X, y, o, w, coef, norm))(two(off))
+            jax.vmap(lambda d2: aggregators.hessian_vector_from_weights(
+                X, d2, coef, norm, coef.shape[0]))(two(0.25 * w))
+        for p in before:
+            assert _ticked(before[p], p) == {"vmap": 1}
+        return
+    if reason == "shape":
+        narrow = on_tpu._DENSE_MIN_WIDTH - 1
+        X, coef = X[:, :narrow], coef[:narrow]
+        norm = type(norm)(norm.factors[:narrow], norm.shifts[:narrow])
+    d = coef.shape[0]
+    vg = lambda c: aggregators.value_and_gradient(
+        LogisticLoss, X, y, off, w, c, norm)
+    hv = lambda c: aggregators.hessian_vector_from_weights(
+        X, 0.25 * w, c, norm, d)
+    before = {p: _ticks(p) for p in ("dense", "dense_hv", "dense_norm",
+                                     "dense_hv_norm")}
+    if reason == "vmap":
+        jax.vmap(vg)(jnp.stack([coef, coef]))
+        jax.vmap(hv)(jnp.stack([coef, coef]))
+    elif reason == "mesh":
+        with on_tpu.disabled():
+            vg(coef), hv(coef)
+    else:
+        vg(coef), hv(coef)
+    assert _ticked(before["dense_norm"], "dense_norm") == {reason: 1}
+    assert _ticked(before["dense_hv_norm"], "dense_hv_norm") == {reason: 1}
+    assert _ticked(before["dense"], "dense") == {}
+    assert _ticked(before["dense_hv"], "dense_hv") == {}
+
+
+@pytest.mark.parametrize("call", ["evaluation", "product"])
+def test_an_identity_context_traces_the_program_it_traced(
+        call, wide_problem, on_tpu):
+    """Under an identity context the aggregator's trace is the kernel's own
+    call and nothing else, equation for equation (the five accepted cells
+    run it): two results a ``pallas_call``, no margin shift, no third
+    sum."""
+    from photon_tpu.ops import pallas_glm
+
+    X, y, off, w, coef = wide_problem
+    d = X.shape[1]
+    if call == "evaluation":
+        through = lambda x, c: aggregators.value_and_gradient(
+            LogisticLoss, x, y, off, w, c, _IDN)
+        direct = lambda x, c: pallas_glm.fused_dense_value_grad(
+            LogisticLoss, x, y, off, w, c)
+    else:
+        through = lambda x, c: aggregators.hessian_vector_from_weights(
+            x, w, c, _IDN, d)
+        direct = lambda x, c: pallas_glm.fused_dense_hessian_vector(
+            x, w, c)[1]
+    got, want = (jax.make_jaxpr(f)(X, coef) for f in (through, direct))
+    names = lambda j: [e.primitive.name for e in _eqns(j.jaxpr)]
+    assert names(got) == names(want)
+    calls = [e for e in _eqns(got.jaxpr) if e.primitive.name == "pallas_call"]
+    assert [len(e.outvars) for e in calls] == [2]
+
+
+def test_normalised_solves_repeat_xlas_counts(on_tpu):
+    """L-BFGS and TRON on raw rows under STANDARDIZATION, routed through the
+    kernel and on XLA's two passes: the same iteration counts, models in
+    ORIGINAL space within float32 of each other."""
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import OptimizerType, TaskType
+    from photon_tpu.utils import jitcache
+
+    X, y, _, _, _ = _raw_problem(n=1500, d=300, seed=8)
+    norm = _context("STANDARDIZATION", X)
+    batch = DataBatch(X, y, None, None)
+    for kind, tol in ((OptimizerType.LBFGS, 1e-6), (OptimizerType.TRON, 1e-4)):
+        cfg = GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(optimizer_type=kind, tolerance=tol,
+                                      max_iterations=60,
+                                      explicit_hessian=False
+                                      if kind == OptimizerType.TRON else None),
+            regularization=L2Regularization, regularization_weight=1.0)
+
+        def run():
+            jitcache.clear()
+            prob = GlmOptimizationProblem(
+                TaskType.LOGISTIC_REGRESSION, cfg, norm=norm,
+                intercept_index=X.shape[1] - 1)
+            model, result = prob.run(batch, dim=X.shape[1],
+                                     dtype=jnp.float32)
+            return (np.asarray(model.coefficients.means),
+                    int(result.iterations))
+
+        before = _ticks("dense_norm")
+        routed, its = run()
+        assert _ticked(before, "dense_norm").get("hit", 0) >= 1
+        with on_tpu.disabled():
+            xla, its_xla = run()
+        assert its == its_xla, (kind, its, its_xla)
+        margins = lambda theta: np.asarray(X, np.float64) @ theta
+        np.testing.assert_allclose(margins(routed), margins(xla), atol=2e-3)
+    jitcache.clear()
 
 
 def test_fused_bf16_feature_storage():
@@ -987,6 +1219,68 @@ def test_routed_tron_solve_compiles_for_a_v5e_with_one_kernel_a_product(
     assert "tpu_custom_call" not in xla.as_text()
     temp = [_held_to_the_layout(placed, c) for c in (fused, xla)]
     assert abs(temp[0] - temp[1]) < 0.001 * _EPSILON[0] * 2_048 * 4, temp
+
+
+@pytest.mark.parametrize("solver", ["LBFGS", "TRON"])
+def test_normalised_solve_compiles_for_a_v5e(v5e, monkeypatch, solver):
+    """fe-epsilon-standardized's solve (530,000 x 2,001 raw rows stored
+    rows-major, a STANDARDIZATION context) as the chip's compiler leaves
+    it: the SAME kernel under the same names, each call with its third
+    result (``sum(w dz)``: 81 ragged lanes and three outputs are Mosaic's
+    to take or refuse), no copy of X and no X-sized temporary; and under
+    TRON one more call under ``agg/hessian_vector``."""
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.game.dataset import ROW_MAJOR
+    from photon_tpu.ops import pallas_glm
+    from photon_tpu.ops.normalization import NormalizationContext
+    from photon_tpu.optim.problem import (
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import OptimizerType, TaskType
+    from photon_tpu.utils import jitcache
+
+    n, d = 530_000, 2_001
+    monkeypatch.setattr(pallas_glm, "_default_interpret", lambda: False)
+    monkeypatch.setattr(pallas_glm, "_on_tpu", lambda: True)
+    jitcache.clear()
+    opt = (OptimizerConfig(max_iterations=100, tolerance=1e-6)
+           if solver == "LBFGS" else OptimizerConfig(
+               optimizer_type=OptimizerType.TRON, max_iterations=15,
+               tolerance=1e-5, explicit_hessian=False))
+    prob = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=opt, regularization=L2Regularization,
+            regularization_weight=1.0),
+        norm=NormalizationContext(jnp.ones(d, jnp.float32),
+                                  jnp.zeros(d, jnp.float32)),
+        intercept_index=d - 1)
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32,
+                             sharding=Format(Layout(ROW_MAJOR), v5e))
+    one = _shaped(v5e)
+    try:
+        compiled = prob._solve_fn.lower(
+            _shaped(v5e, d), DataBatch(x, _shaped(v5e, n), None, None), one,
+            one).compile()
+    finally:
+        jitcache.clear()
+    text = compiled.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]+)"', text)
+    evaluations = [m for m in names
+                   if "agg/value_and_gradient/jit(_fused)/pallas_call" in m]
+    products = [m for m in names
+                if "agg/hessian_vector/jit(_fused)/pallas_call" in m]
+    assert len(evaluations) == 2, names
+    assert len(products) == (1 if solver == "TRON" else 0), names
+    assert len(names) == len(evaluations) + len(products)
+    # value's lanes, the gradient's sublanes, sum(w dz)'s lanes
+    results = re.findall(r"= \((f32\[2,128\]\S*, f32\[8,2001\]\S*, "
+                         r"f32\[2,128\]\S*)\) custom-call\(", text)
+    assert len(results) == len(names), results
+    assert not re.findall(r"= f32\[530000,2001\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * n * 2_048 * 4
 
 
 @_PLACED

@@ -496,7 +496,12 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              "compile/<stage>", "compile.seconds", "compile.programs",
              "compile.cache", "account_compiles", "current_phase",
              "retrace_s", "lower_s", "cache_load_s", "repeat_fit_traces",
-             "start_s"} | LINESEARCH
+             "start_s",
+             # a normalised objective (PR 38): the statistics pass's phase,
+             # the kernel's labels under a context, the two readers
+             "ingest/feature_stats/", "dense_norm", "dense_hv_norm",
+             "feature_stats_s", "standardized_value_gradient_roofline",
+             } | LINESEARCH
     for _, _, steps, dense, sparse in SOLVERS.values():
         names |= steps | dense | (sparse or set())
     missing = sorted(n for n in names if n not in text)
