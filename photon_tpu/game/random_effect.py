@@ -38,6 +38,7 @@ from photon_tpu.game.dataset import (
     GameDataFrame,
     count_placed,
 )
+from photon_tpu.obs.metrics import registry
 from photon_tpu.ops import features as F
 from photon_tpu.utils.timing import Timed
 
@@ -270,8 +271,14 @@ def build_random_effect_dataset(
     named for ``coordinate`` (the random-effect type when the caller gives
     none): ``ingest/prepare/<coordinate>/group`` (vocabulary, ordering,
     active/passive split, projection table, local slots),
-    ``.../bucket`` (the size ladder), ``.../pad`` (one record a bucket: the
-    padded fill, which rescans every nonzero) and ``.../passive``;
+    ``.../bucket`` (the size ladder, and ONE stable sort of the active
+    samples into bucket-major order), ``.../pad`` (one record a bucket: the
+    padded fill of that bucket's block from a contiguous slice of its
+    samples and, through their ``indptr`` ranges, their nonzeros; it reads
+    no other bucket's, and ticks the counter
+    ``ingest.pad_nonzeros{coordinate}`` by the nonzero positions it reads:
+    over the buckets, the nonzeros of the active samples, once) and
+    ``.../passive``;
     ``ingest/h2d/<coordinate>`` around each placement (what the host
     spends in ``jnp.asarray``: nothing waits for the copy), the placed
     bytes going to the counter ``ingest.h2d_bytes{coordinate}``;
@@ -412,45 +419,75 @@ def build_random_effect_dataset(
 
         k_nz_pos_all = _slot_positions(kept_nz_mask & active[s_nz])
 
-    for b in np.unique(bucket_id[bucket_id >= 0]):
+        # bucket-major order, ONCE for all buckets: a bucket's entities in
+        # ascending global row are its block rows, and a stable sort of the
+        # active samples by their entity's bucket keeps them in (entity,
+        # hash) order inside it, so each bucket below is a contiguous slice
+        live = np.flatnonzero(bucket_id >= 0)             # entities in a bucket
+        buckets = np.unique(bucket_id[live])
+        ents_sorted = live[np.argsort(bucket_id[live], kind="stable")]
+        ent_bounds = np.append(
+            np.searchsorted(bucket_id[ents_sorted], buckets), len(live))
+        row_of_entity = np.full(E, -1, np.int64)          # block row, any bucket
+        row_of_entity[ents_sorted] = (
+            np.arange(len(ents_sorted))
+            - np.repeat(ent_bounds[:-1], np.diff(ent_bounds)))
+        # pow-2 bucket ids are under 64: an int8 key sorts by counting
+        act_bucket = bucket_id[act_entity].astype(np.int8)
+        by_bucket = np.argsort(act_bucket, kind="stable")
+        act_bounds = np.append(
+            np.searchsorted(act_bucket[by_bucket], buckets.astype(np.int8)),
+            len(by_bucket))
+        rows_sorted = act_idx_sorted[by_bucket]           # flat sample rows
+        r_sorted = row_of_entity[act_entity[by_bucket]]   # block row
+        c_sorted = act_pos[by_bucket]                     # block column
+        pad_nonzeros = registry.counter("ingest.pad_nonzeros",
+                                        coordinate=coordinate)
+
+    for i in range(len(buckets)):
         with phase(f"{prepare}/pad"):
-            ents = np.flatnonzero(bucket_id == b)         # global entity rows
+            # everything below is as long as THIS bucket's entities, samples
+            # or nonzeros: nothing of the other buckets is read or built
+            ents = ents_sorted[ent_bounds[i]:ent_bounds[i + 1]]
             E_b = len(ents)
             S_b = int(act_counts[ents].max())
-            # block row per global entity
-            row_of_entity = np.full(E, -1, np.int64)
-            row_of_entity[ents] = np.arange(E_b)
-
-            in_b = row_of_entity[act_entity] >= 0
-            rows_flat = act_idx_sorted[in_b]              # flat sample rows
-            r_idx = row_of_entity[act_entity[in_b]]
-            c_idx = act_pos[in_b]
+            in_b = slice(act_bounds[i], act_bounds[i + 1])
+            rows_flat = rows_sorted[in_b]
+            cell = r_sorted[in_b] * S_b + c_sorted[in_b]  # slot in [E_b * S_b]
 
             labels_b = np.zeros((E_b, S_b), dtype)
             offsets_b = np.zeros((E_b, S_b), dtype)
             weights_b = np.zeros((E_b, S_b), dtype)
             rows_b = np.full((E_b, S_b), n, np.int32)
-            labels_b[r_idx, c_idx] = resp[rows_flat]
-            offsets_b[r_idx, c_idx] = base_offsets[rows_flat]
-            weights_b[r_idx, c_idx] = weights[rows_flat]
-            rows_b[r_idx, c_idx] = rows_flat
+            labels_b.reshape(-1)[cell] = resp[rows_flat]
+            offsets_b.reshape(-1)[cell] = base_offsets[rows_flat]
+            weights_b.reshape(-1)[cell] = weights[rows_flat]
+            rows_b.reshape(-1)[cell] = rows_flat
             block_rows.append(rows_b)
 
-            # ELL features: nonzeros of this bucket's active samples
-            nz_mask = kept_nz_mask & active[s_nz] & (row_of_entity[e_nz] >= 0)
-            nz_sample = s_nz[nz_mask]
-            nz_r = row_of_entity[e_nz[nz_mask]]
-            # column of the sample within the block
-            pos_of_sample = np.full(n, -1, np.int64)
-            pos_of_sample[act_idx_sorted[in_b]] = c_idx
-            nz_c = pos_of_sample[nz_sample]
-            nz_k = k_nz_pos_all[nz_mask]
+            # ELL features: the nonzeros of this bucket's samples, gathered
+            # through their ``indptr`` ranges (each is visited by one bucket)
+            lens = nnz[rows_flat]
+            ends = np.cumsum(lens)
+            total = int(ends[-1])
+            pad_nonzeros.inc(total)
+            nz = np.repeat(indptr[rows_flat] - (ends - lens), lens)
+            nz += np.arange(total)                        # place in cols/vals
+            kept = kept_nz_mask[nz]
+            some_dropped = not kept.all()
+            if some_dropped:
+                nz = nz[kept]
+            nz_k = k_nz_pos_all[nz]
             K_b = max(int(nz_k.max()) + 1 if len(nz_k) else 1, 1)
 
             f_idx = np.zeros((E_b, S_b, K_b), np.int32)
             f_val = np.zeros((E_b, S_b, K_b), dtype)
-            f_idx[nz_r, nz_c, nz_k] = slot_nz[nz_mask].astype(np.int32)
-            f_val[nz_r, nz_c, nz_k] = vals[nz_mask]
+            slot = np.repeat(cell * K_b, lens)            # in [E_b * S_b * K_b]
+            if some_dropped:
+                slot = slot[kept]
+            slot += nz_k
+            f_idx.reshape(-1)[slot] = slot_nz[nz]
+            f_val.reshape(-1)[slot] = vals[nz]
 
         with phase(h2d):
             blocks.append(EntityBlock(

@@ -253,6 +253,388 @@ def test_entity_bucket_cap_bounds_compiles_and_preserves_results():
                                rtol=1e-7, atol=1e-10)
 
 
+# -- the padded fill against the loop it replaced (PR 39) ----------------------
+
+def _reference_blocks(df, config, vocab, dtype=np.float32,
+                      scores_offsets=None):
+    """``build_random_effect_dataset`` as it stood before PR 39, on the
+    host: its loop over buckets VERBATIM (a ``row_of_entity``, a
+    ``pos_of_sample`` and an ``nz_mask`` over EVERY nonzero of the shard,
+    rebuilt a bucket), behind the grouping it read its arrays from, less
+    the ``Timed`` phases and the placement. What the one-visit fill is held
+    to, array for array."""
+    from photon_tpu.game.random_effect import (
+        _bucket_of,
+        _csr_of,
+        _maybe_random_project,
+        _pearson_scores_vectorized,
+        _splitmix64,
+        flat_source_map,
+    )
+
+    re_type = config.random_effect_type
+    shard = df.feature_shards[config.feature_shard_id]
+    # sparse row lists, columnar CsrRows, and dense [n, d] matrices all
+    # funnel through _csr_of into the same columnar pipeline
+    shard = _maybe_random_project(shard, config)
+    n = df.num_samples
+    D = shard.dim
+
+    entity_idx = vocab.build(re_type, df.id_tags[re_type]).astype(np.int64)
+    E = vocab.size(re_type)
+    base_offsets = np.zeros(n) if df.offsets is None else np.asarray(df.offsets, np.float64)
+    if scores_offsets is not None:
+        base_offsets = base_offsets + np.asarray(scores_offsets, np.float64)
+    weights = np.ones(n) if df.weights is None else np.asarray(df.weights, np.float64)
+    resp = np.asarray(df.response, np.float64)
+
+    indptr, cols, vals = _csr_of(shard.rows)
+    nnz = np.diff(indptr)
+
+    # -- deterministic ordering within entities + active/passive split -------
+    counts = np.bincount(entity_idx, minlength=E)
+    keys = _splitmix64(np.arange(n, dtype=np.uint64))
+    order = np.lexsort((keys, entity_idx))           # by (entity, hash)
+    starts = np.concatenate([[0], np.cumsum(counts)])
+    pos = np.arange(n) - np.repeat(starts[:-1], counts)  # rank within entity
+
+    e_sorted = entity_idx[order]
+    active_sorted = np.ones(n, bool)
+    if config.active_data_lower_bound is not None:
+        active_sorted &= counts[e_sorted] >= config.active_data_lower_bound
+    if config.active_data_upper_bound is not None:
+        active_sorted &= pos < config.active_data_upper_bound
+    passive_sorted = ~active_sorted
+    if config.active_data_upper_bound is not None and not config.keep_passive_data:
+        # over-cap samples are dropped entirely; below-lower-bound samples
+        # stay passive (they are scored, just never trained on)
+        over_cap = pos >= config.active_data_upper_bound
+        if config.active_data_lower_bound is not None:
+            over_cap &= counts[e_sorted] >= config.active_data_lower_bound
+        passive_sorted &= ~over_cap
+
+    active = np.zeros(n, bool)
+    active[order] = active_sorted
+    passive = np.zeros(n, bool)
+    passive[order] = passive_sorted
+    act_counts = np.bincount(entity_idx[active], minlength=E)
+
+    # -- observed (entity, feature) pairs over ACTIVE data -------------------
+    s_nz = np.repeat(np.arange(n), nnz)              # sample id per nonzero
+    keep_nz = active[s_nz]
+    e_nz = entity_idx[s_nz]
+    pair = e_nz * D + cols                            # int64 composite key
+    uniq = np.unique(pair[keep_nz]) if keep_nz.any() else np.zeros(0, np.int64)
+
+    # -- optional Pearson feature selection (reference: LocalDataset:122) ----
+    if config.features_to_samples_ratio is not None and len(uniq):
+        ratio = config.features_to_samples_ratio
+        k_per_entity = np.maximum((ratio * act_counts).astype(np.int64), 1)
+        scores = _pearson_scores_vectorized(
+            uniq, pair, keep_nz, vals, s_nz, entity_idx, resp, weights,
+            active, E, D)
+        u_e = uniq // D
+        sel_order = np.lexsort((-scores, u_e))
+        u_starts = np.searchsorted(u_e[sel_order], np.arange(E))
+        sel_pos = np.arange(len(uniq)) - u_starts[u_e[sel_order]]
+        need_cap = k_per_entity[u_e[sel_order]]
+        keep_pair = np.zeros(len(uniq), bool)
+        keep_pair[sel_order[sel_pos < need_cap]] = True
+        # entities whose feature count is within bound keep everything
+        feat_counts = np.bincount(u_e, minlength=E)
+        within = feat_counts[u_e] <= np.maximum(
+            (ratio * act_counts[u_e]).astype(np.int64), 1)
+        keep_pair |= within
+        uniq = uniq[keep_pair]
+
+    # -- projection table ----------------------------------------------------
+    u_e = uniq // D
+    u_f = uniq % D
+    d_loc_per_entity = np.bincount(u_e, minlength=E) if len(uniq) else np.zeros(E, np.int64)
+    D_loc = max(int(d_loc_per_entity.max()) if E else 1, 1)
+    u_starts = np.searchsorted(u_e, np.arange(E + 1))
+    slot_of_pair = np.arange(len(uniq)) - u_starts[u_e]
+    projection = np.full((E, D_loc), -1, np.int32)
+    if len(uniq):
+        projection[u_e, slot_of_pair] = u_f.astype(np.int32)
+
+    # -- per-nonzero local slots (kept nonzeros only) ------------------------
+    rank = np.searchsorted(uniq, pair) if len(uniq) else np.zeros(len(pair), np.int64)
+    rank = np.minimum(rank, max(len(uniq) - 1, 0))
+    kept_nz_mask = np.zeros(len(pair), bool)
+    if len(uniq):
+        kept_nz_mask = uniq[rank] == pair
+    slot_nz = slot_of_pair[rank] if len(uniq) else np.zeros(len(pair), np.int64)
+
+    # position of each kept nonzero within its sample
+    def _slot_positions(mask: np.ndarray) -> np.ndarray:
+        if not len(pair):
+            return np.zeros(0, np.int64)
+        kept_i = mask.astype(np.int64)
+        c = np.cumsum(kept_i)
+        excl = c - kept_i
+        # indptr may equal total_nnz for trailing empty rows; those repeat
+        # zero times, so clamp the index to keep the gather in range
+        base = np.repeat(excl[np.minimum(indptr[:-1], len(excl) - 1)], nnz)
+        return excl - base
+
+    # -- bucketed active blocks ---------------------------------------------
+    has_active = act_counts > 0
+    bucket_id = np.where(has_active, _bucket_of(act_counts), -1)
+    uniq_buckets = np.unique(bucket_id[bucket_id >= 0])
+    cap = config.max_entity_buckets
+    if cap and len(uniq_buckets) > cap:
+        # coarsen: merge adjacent pow-2 buckets into at most `cap` groups
+        # (each group pads to its largest member's S_b) — bounded compile
+        # count at the cost of extra padding, both reported below
+        groups = np.array_split(uniq_buckets, cap)
+        lut = np.arange(int(uniq_buckets.max()) + 1)
+        for g in groups:
+            lut[g] = g[-1]
+        bucket_id = np.where(bucket_id >= 0, lut[np.maximum(bucket_id, 0)], -1)
+    blocks = []
+    block_rows = []                       # host sample_rows a bucket
+
+    # active samples sorted by (entity, hash) and within cap
+    act_idx_sorted = order[active_sorted]             # flat rows, grouped
+    act_pos = pos[active_sorted]                      # rank within entity
+    act_entity = entity_idx[act_idx_sorted]
+
+    k_nz_pos_all = _slot_positions(kept_nz_mask & active[s_nz])
+
+    for b in np.unique(bucket_id[bucket_id >= 0]):
+        ents = np.flatnonzero(bucket_id == b)         # global entity rows
+        E_b = len(ents)
+        S_b = int(act_counts[ents].max())
+        # block row per global entity
+        row_of_entity = np.full(E, -1, np.int64)
+        row_of_entity[ents] = np.arange(E_b)
+
+        in_b = row_of_entity[act_entity] >= 0
+        rows_flat = act_idx_sorted[in_b]              # flat sample rows
+        r_idx = row_of_entity[act_entity[in_b]]
+        c_idx = act_pos[in_b]
+
+        labels_b = np.zeros((E_b, S_b), dtype)
+        offsets_b = np.zeros((E_b, S_b), dtype)
+        weights_b = np.zeros((E_b, S_b), dtype)
+        rows_b = np.full((E_b, S_b), n, np.int32)
+        labels_b[r_idx, c_idx] = resp[rows_flat]
+        offsets_b[r_idx, c_idx] = base_offsets[rows_flat]
+        weights_b[r_idx, c_idx] = weights[rows_flat]
+        rows_b[r_idx, c_idx] = rows_flat
+        block_rows.append(rows_b)
+
+        # ELL features: nonzeros of this bucket's active samples
+        nz_mask = kept_nz_mask & active[s_nz] & (row_of_entity[e_nz] >= 0)
+        nz_sample = s_nz[nz_mask]
+        nz_r = row_of_entity[e_nz[nz_mask]]
+        # column of the sample within the block
+        pos_of_sample = np.full(n, -1, np.int64)
+        pos_of_sample[act_idx_sorted[in_b]] = c_idx
+        nz_c = pos_of_sample[nz_sample]
+        nz_k = k_nz_pos_all[nz_mask]
+        K_b = max(int(nz_k.max()) + 1 if len(nz_k) else 1, 1)
+
+        f_idx = np.zeros((E_b, S_b, K_b), np.int32)
+        f_val = np.zeros((E_b, S_b, K_b), dtype)
+        f_idx[nz_r, nz_c, nz_k] = slot_nz[nz_mask].astype(np.int32)
+        f_val[nz_r, nz_c, nz_k] = vals[nz_mask]
+
+        blocks.append(dict(
+            indices=f_idx, values=f_val, labels=labels_b, offsets=offsets_b,
+            weights=weights_b, sample_rows=rows_b,
+            entity_rows=ents.astype(np.int32)))
+
+    # -- passive block (projected through each entity's local map) -----------
+    pas_rows = np.flatnonzero(passive)
+    P = max(len(pas_rows), 1)
+    pas_nz_mask = kept_nz_mask & passive[s_nz]
+    pas_k = _slot_positions(pas_nz_mask)
+    K_p = max(int(pas_k[pas_nz_mask].max()) + 1 if pas_nz_mask.any() else 1, 1)
+    p_idx = np.zeros((P, K_p), np.int32)
+    p_val = np.zeros((P, K_p), dtype)
+    p_entity = np.full(P, E, np.int32)
+    p_rows = np.full(P, n, np.int32)
+    if len(pas_rows):
+        row_rank = np.full(n, -1, np.int64)
+        row_rank[pas_rows] = np.arange(len(pas_rows))
+        p_entity[: len(pas_rows)] = entity_idx[pas_rows]
+        p_rows[: len(pas_rows)] = pas_rows
+        sel = pas_nz_mask
+        p_idx[row_rank[s_nz[sel]], pas_k[sel]] = slot_nz[sel].astype(np.int32)
+        p_val[row_rank[s_nz[sel]], pas_k[sel]] = vals[sel]
+    flat_source = flat_source_map(block_rows, p_rows, n)
+    return dict(blocks=blocks, passive_indices=p_idx, passive_values=p_val,
+                passive_entity=p_entity, passive_rows=p_rows,
+                projection=projection, flat_source=flat_source)
+
+
+def _fill_rows(rng, n, d):
+    """Sparse rows of 0 to ``d`` nonzeros; the last three rows and a few
+    inside are empty."""
+    rows = []
+    for i in range(n):
+        k = 0 if i >= n - 3 or i % 97 == 0 else int(rng.integers(0, d + 1))
+        cols = np.sort(rng.choice(d, size=k, replace=False)).astype(np.int32)
+        rows.append((cols, rng.normal(size=k)))
+    return rows
+
+
+def _fill_case(name):
+    """(frame, configuration keywords, builder keywords, vocabulary
+    factory) of one case of the fill's parity test."""
+    from photon_tpu.game.dataset import CsrRows, EntityVocabulary
+
+    rng = np.random.default_rng(sum(map(ord, name)))
+    n, d, ents = 3000, 6, 200
+    p = 1.0 / np.arange(1, ents + 1) ** 1.3           # a skewed histogram
+    ent = rng.choice(ents, size=n, p=p / p.sum())
+    shard = FeatureShard(_fill_rows(rng, n, d), d)
+    config, build, frame, vocab = {}, {}, {}, EntityVocabulary
+    if name == "dense":
+        x = rng.normal(size=(n, d))
+        x[rng.random((n, d)) < 0.3] = 0.0
+        shard = FeatureShard(x, d)
+    elif name == "csr":
+        rows = _fill_rows(rng, n, d)
+        shard = FeatureShard(CsrRows(
+            np.concatenate([[0], np.cumsum([len(c) for c, _ in rows])]),
+            np.concatenate([c for c, _ in rows]),
+            np.concatenate([v for _, v in rows])), d)
+    elif name == "repeated_columns":
+        rows = [(np.array([0, 2, 2, 5], np.int32), rng.normal(size=4))
+                for _ in range(n)]
+        shard = FeatureShard(rows, d)
+    elif name == "upper_bound_keeps_passive":
+        config = dict(active_data_upper_bound=25)
+    elif name == "upper_bound_drops_the_overflow":
+        config = dict(active_data_upper_bound=25, keep_passive_data=False)
+    elif name == "lower_bound":
+        config = dict(active_data_lower_bound=5)
+    elif name == "both_bounds_drop_the_overflow":
+        config = dict(active_data_lower_bound=5, active_data_upper_bound=25,
+                      keep_passive_data=False)
+    elif name == "pearson_selection":
+        config = dict(features_to_samples_ratio=0.25)
+    elif name.startswith("buckets_"):
+        # sixteen power-of-two sizes: entity k has 2^k rows
+        config = dict(max_entity_buckets=int(name.split("_")[1]))
+        ent = np.repeat(np.arange(16), 2 ** np.arange(16))[
+            rng.permutation(2 ** 16 - 1)]
+        n, d = len(ent), 3
+        x = rng.normal(size=(n, d))
+        x[rng.random((n, d)) < 0.3] = 0.0
+        shard = FeatureShard(x, d)
+    elif name == "uncapped_ladder":
+        config = dict(max_entity_buckets=None)
+    elif name == "entity_without_active_row":
+        config = dict(active_data_lower_bound=3)
+
+        def vocab():
+            # a known entity that this frame has no row of, ahead of the
+            # frame's own: global entity row 0 is in no bucket
+            v = EntityVocabulary()
+            v.build("userId", ["ghost"])
+            return v
+    elif name == "scores_offsets":
+        build = dict(scores_offsets=rng.normal(size=n))
+        frame = dict(offsets=rng.normal(size=n), weights=rng.random(n) + 0.5)
+    elif name == "random_projection":
+        config = dict(projector_type="RANDOM", projected_dimension=3)
+    elif name == "float64":
+        build = dict(dtype=np.float64)
+    else:
+        assert name == "ragged_rows_empty_trailing", name
+    df = GameDataFrame(
+        num_samples=n, response=rng.random(n), feature_shards={"u": shard},
+        id_tags={"userId": [str(e) for e in ent]}, **frame)
+    return df, config, build, vocab
+
+
+@pytest.mark.parametrize("case", [
+    "dense", "ragged_rows_empty_trailing", "csr", "repeated_columns",
+    "upper_bound_keeps_passive", "upper_bound_drops_the_overflow",
+    "lower_bound", "both_bounds_drop_the_overflow", "pearson_selection",
+    "buckets_1", "buckets_4", "buckets_16", "uncapped_ladder",
+    "entity_without_active_row", "scores_offsets", "random_projection",
+    "float64"])
+def test_the_padded_fill_is_the_per_bucket_loops_array_for_array(case):
+    """Every array of the dataset, its shape and its dtype, is what the
+    loop that rescanned the shard once a bucket built: the same blocks in
+    the same order reach the same cached programs."""
+    from photon_tpu.game.random_effect import build_random_effect_dataset
+
+    df, config, build, vocab = _fill_case(case)
+    cfg = RandomEffectDataConfiguration("userId", "u", **config)
+    want = _reference_blocks(df, cfg, vocab(), **build)
+    ds = build_random_effect_dataset(df, cfg, vocab(), **build)
+
+    def same(got, wanted, what):
+        got = np.asarray(got)
+        assert got.dtype == wanted.dtype and got.shape == wanted.shape, what
+        np.testing.assert_array_equal(got, wanted, err_msg=what)
+
+    assert len(ds.blocks) == len(want["blocks"])
+    if case.startswith("buckets_"):
+        assert len(ds.blocks) == cfg.max_entity_buckets
+    for i, (block, ref) in enumerate(zip(ds.blocks, want["blocks"])):
+        same(block.features.indices, ref["indices"], f"indices {i}")
+        same(block.features.values, ref["values"], f"values {i}")
+        for field in ("labels", "offsets", "weights", "sample_rows",
+                      "entity_rows"):
+            same(getattr(block, field), ref[field], f"{field} {i}")
+    same(ds.passive_features.indices, want["passive_indices"], "passive")
+    same(ds.passive_features.values, want["passive_values"], "passive")
+    same(ds.passive_entity, want["passive_entity"], "passive_entity")
+    same(ds.passive_rows, want["passive_rows"], "passive_rows")
+    same(ds.projection, want["projection"], "projection")
+    same(ds.flat_source, want["flat_source"], "flat_source")
+    if case == "entity_without_active_row":
+        assert not any(0 in np.asarray(b.entity_rows) for b in ds.blocks)
+    if "bound" in case:
+        assert len(ds.blocks) > 1
+
+
+@pytest.mark.parametrize("cap", [1, 4, 16])
+def test_the_pad_phase_reads_each_active_nonzero_once(cap):
+    """``ingest.pad_nonzeros{coordinate}`` counts the nonzero positions the
+    ``pad`` phase reads: the active samples' nonzeros, whatever the number
+    of buckets (the loop it replaced read buckets x all of the shard's).
+    The phases keep their names: one ``group``, ``bucket`` and ``passive``,
+    one ``pad`` a bucket."""
+    from photon_tpu.game.dataset import EntityVocabulary
+    from photon_tpu.game.random_effect import build_random_effect_dataset
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.utils import timing
+
+    df, _, _, _ = _fill_case("buckets_16")
+    n = df.num_samples
+    key = 'ingest.pad_nonzeros{coordinate="per-user"}'
+    before = registry.snapshot()["counters"].get(key, 0)
+    timing.clear_timings()
+    ds = build_random_effect_dataset(
+        df, RandomEffectDataConfiguration(
+            "userId", "u", active_data_upper_bound=20000,
+            max_entity_buckets=cap),
+        EntityVocabulary(), coordinate="per-user")
+    labels = [label for label, _ in timing.timing_records()]
+    timing.clear_timings()
+
+    assert len(ds.blocks) == cap
+    held = np.concatenate([np.asarray(b.sample_rows).ravel()
+                           for b in ds.blocks])
+    held = held[held < n]
+    row_nonzeros = np.count_nonzero(df.feature_shards["u"].rows, axis=1)
+    assert len(held) == n - (2 ** 15 - 20000)         # the rest is passive
+    assert 0 < row_nonzeros[held].sum() < row_nonzeros.sum()
+    assert (registry.snapshot()["counters"][key] - before
+            == row_nonzeros[held].sum())
+    for step, count in (("group", 1), ("bucket", 1), ("passive", 1),
+                        ("pad", len(ds.blocks))):
+        assert labels.count(f"ingest/prepare/per-user/{step}") == count, step
+
+
 @pytest.fixture(scope="module")
 def skewed_blocks():
     """A Zipf-skewed random-effect dataset with passive rows (the cap

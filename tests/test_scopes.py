@@ -501,6 +501,8 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              # the kernel's labels under a context, the two readers
              "ingest/feature_stats/", "dense_norm", "dense_hv_norm",
              "feature_stats_s", "standardized_value_gradient_roofline",
+             # the padded fill (PR 39): what the ``pad`` phase read
+             "ingest.pad_nonzeros",
              } | LINESEARCH
     for _, _, steps, dense, sparse in SOLVERS.values():
         names |= steps | dense | (sparse or set())
