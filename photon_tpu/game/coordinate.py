@@ -255,10 +255,13 @@ class FixedEffectCoordinate:
         if self.variance_type != VarianceComputationType.NONE:
             # reference: DistributedOptimizationProblem.run computes
             # variances on the same (residual-injected) data as the solve
-            var = self.problem.compute_variances(
-                batch, model.coefficients.means, self.variance_type,
-                regularization_weight=self.config.regularization_weight)
+            with _obs_annotate("fe/variance"):
+                var = self.problem.compute_variances(
+                    batch, model.coefficients.means, self.variance_type,
+                    regularization_weight=self.config.regularization_weight,
+                    mesh=self.mesh)
             if var is not None:
+                _count_variances(self.feature_shard_id, self.variance_type)
                 model = GeneralizedLinearModel(
                     Coefficients(model.coefficients.means, var), model.task)
         if self._model_sharded and self._dim_padded != self.dim:
@@ -1017,9 +1020,11 @@ class RandomEffectCoordinate:
         from photon_tpu.types import VarianceComputationType
         if (self.variance_type != VarianceComputationType.NONE
                 and self.objective.loss.has_hessian):
-            variances = self._variance_fn(self.dataset, residual_scores,
-                                          coefs, l2)
-            variances = variances[: self._num_entities_orig]
+            with _obs_annotate("re/variance"):
+                variances = self._variance_fn(self.dataset, residual_scores,
+                                              coefs, l2)
+                variances = variances[: self._num_entities_orig]
+            _count_variances(self.random_effect_type, self.variance_type)
         # publish the model at the vocabulary's true entity count; mesh
         # padding stays an internal detail of this coordinate
         return self._model(coefs[: self._num_entities_orig], variances)
@@ -1448,7 +1453,10 @@ class RandomEffectCoordinate:
         FULL = diag(H^-1) via Cholesky — H is each entity's [K, K] Hessian
         (reference: DistributedOptimizationProblem.computeVariances :82-100
         applied per entity; Bayesian output of RandomEffectModel)."""
-        from photon_tpu.types import VarianceComputationType
+        from photon_tpu.optim.problem import (
+            VARIANCE_GRAM_PRECISION,
+            coefficient_variances,
+        )
 
         obj = self.objective
         vtype = self.variance_type
@@ -1457,16 +1465,9 @@ class RandomEffectCoordinate:
             def one(feat_idx, feat_val, labels, offsets, weights, coef, l2):
                 batch = DataBatch(F.SparseFeatures(feat_idx, feat_val),
                                   labels, offsets, weights)
-                hyper = Hyper(l2_weight=l2)
                 has_data = jnp.sum(weights) > 0
-                if vtype == VarianceComputationType.SIMPLE:
-                    d = obj.hessian_diagonal(coef, batch, hyper)
-                    var = 1.0 / jnp.maximum(d, jnp.finfo(d.dtype).tiny)
-                else:
-                    h = obj.hessian_matrix(coef, batch, hyper)
-                    eye = jnp.eye(h.shape[0], dtype=h.dtype)
-                    chol = jax.scipy.linalg.cho_factor(h)
-                    var = jnp.diag(jax.scipy.linalg.cho_solve(chol, eye))
+                var = coefficient_variances(obj, coef, batch,
+                                            Hyper(l2_weight=l2), vtype)
                 return jnp.where(has_data, var, 0.0)
 
             @jax.jit
@@ -1484,7 +1485,8 @@ class RandomEffectCoordinate:
 
             return variance_all
 
-        return jitcache.get_or_build(("re_variance", self.task, vtype), build)
+        return jitcache.get_or_build(
+            ("re_variance", self.task, vtype, VARIANCE_GRAM_PRECISION), build)
 
     def _pad_entity_rows(self, coef_block: Array) -> Array:
         """Match a model's entity rows to this coordinate's (possibly
@@ -1637,3 +1639,13 @@ def _re_score_builder(dense_flags=()):
         return ds.rows_to_flat(margins, pmargin).astype(coef_block.dtype)
 
     return score
+
+
+def _count_variances(coordinate: str, variance_type) -> None:
+    """One tick of ``variance.computed{coordinate, type=SIMPLE|FULL}`` an
+    update that computed variances (always on; an update under NONE, or of
+    a loss with no Hessian, ticks nothing)."""
+    from photon_tpu.obs.metrics import registry
+
+    registry.counter("variance.computed", coordinate=coordinate,
+                     type=variance_type.name).inc()

@@ -300,8 +300,13 @@ def hessian_matrix_from_weights(
     d2: Array,
     norm: NormalizationContext,
     dim: int,
+    precision=jax.lax.Precision.DEFAULT,
+    block_rows: Optional[int] = None,
 ) -> Array:
-    """Full H from precomputed curvature weights: one GEMM (MXU).
+    """Full H from precomputed curvature weights: one GEMM (MXU), at the
+    caller's ``precision`` and summed over ``block_rows`` rows at a time
+    (DEFAULT and all rows at once for NEWTON and TRON, whose gradient is
+    exact; the variances state their own, ``weighted_gram``).
 
     This turns a whole CG solve's data passes into a single
     ``X^T diag(d2) X`` contraction plus O(d^2) matvecs. On a TPU v5e the
@@ -311,7 +316,7 @@ def hessian_matrix_from_weights(
     products at 256-1,024 and 4.5 at 2,000 (PERF.md §5, PR 34):
     ``optim/problem.tron_explicit_hessian`` gates TRON's use of it by
     that."""
-    h = weighted_gram(x, d2, dim)
+    h = weighted_gram(x, d2, dim, precision, block_rows)
     if norm.shifts is not None:
         lin = rmatvec(x, d2, dim)
         outer = jnp.outer(lin, norm.shifts)

@@ -270,9 +270,15 @@ def sq_rmatvec(x: FeatureMatrix, w: Array, dim: int) -> Array:
     return w @ (xf * xf)  # see rmatvec: avoid XLA-CPU's strided .T path
 
 
-def weighted_gram(x: FeatureMatrix, w: Array, dim: int) -> Array:
+def weighted_gram(x: FeatureMatrix, w: Array, dim: int,
+                  precision=jax.lax.Precision.DEFAULT,
+                  block_rows: Optional[int] = None) -> Array:
     """``X^T diag(w) X`` -> [d, d], for small-dim full Hessians
-    (reference: HessianMatrixAggregator.scala:31)."""
+    (reference: HessianMatrixAggregator.scala:31). ``precision`` and
+    ``block_rows`` are the dense contraction's: DEFAULT and one contraction
+    over all rows for the callers to whom the Hessian is a means (below);
+    stated otherwise by the one whose RESULT it is
+    (``optim/problem.py::coefficient_variances``)."""
     if isinstance(x, ModelShardedSparse):
         raise NotImplementedError(
             "model-sharded sparse theta is matrix-free by design: a d x d "
@@ -292,14 +298,44 @@ def weighted_gram(x: FeatureMatrix, w: Array, dim: int) -> Array:
                     wv[:, j][:, None] * x.values)
             return h
         dense = to_dense(x, dim)
-        return dense.T @ (dense * w[:, None])
+        return jnp.matmul(dense.T, dense * w[:, None], precision=precision)
+    if block_rows is not None and x.shape[0] > block_rows:
+        return _gram_in_row_blocks(x, w, precision, block_rows)
     # DEFAULT, stated: on a TPU ONE bfloat16 pass of the MXU. The Hessian's
     # callers (NEWTON, TRON) take their gradient exactly, so an inexact
     # Hessian moves iteration counts, not the optimum, and at DEFAULT it
     # moved none: epsilon's TRON fit is 5 iterations / 8 CG steps at
     # DEFAULT, HIGH and HIGHEST alike, for 25.9 / 69.0 / 141.1 ms a build
     # at 530,000 x 2,000 (PERF.md §5, my chip runs, PR 33)
-    return jnp.matmul(x.T, x * w[:, None], precision=jax.lax.Precision.DEFAULT)
+    return jnp.matmul(x.T, x * w[:, None], precision=precision)
+
+
+def _gram_in_row_blocks(x: Array, w: Array, precision, block_rows: int) -> Array:
+    """The dense ``X^T diag(w) X`` as a float32 SUM of one contraction a
+    block of ``block_rows`` rows. One contraction over n rows adds them
+    into its float32 accumulator one after another, which rounds the sum by
+    some ``sqrt(n) x 3.5e-8`` of itself whatever the products' precision:
+    at 530,000 x 2,000 the variances from a HIGHEST Gram read 2.6e-5 off a
+    float64 oracle in one contraction, nearly what ONE bfloat16 pass costs
+    (3.3e-5), and 5e-7 in blocks of 8,192 rows, for 7% more time (PERF.md
+    §5, my chip runs, PR 40)."""
+    n = x.shape[0]
+    blocks = n // block_rows
+
+    def gram(xb, wb):
+        return jnp.matmul(xb.T, xb * wb[:, None], precision=precision)
+
+    def add_block(i, h):
+        at = i * block_rows
+        return h + gram(jax.lax.dynamic_slice_in_dim(x, at, block_rows),
+                        jax.lax.dynamic_slice_in_dim(w, at, block_rows))
+
+    h = jax.lax.fori_loop(
+        0, blocks, add_block,
+        jnp.zeros((x.shape[1], x.shape[1]), jnp.result_type(x, w)))
+    if n % block_rows:
+        h = h + gram(x[blocks * block_rows:], w[blocks * block_rows:])
+    return h
 
 
 def to_dense(x: FeatureMatrix, dim: int) -> Array:

@@ -16,7 +16,7 @@ single compilation (the warm-start chain of ModelTraining.scala:134-147).
 from __future__ import annotations
 
 import dataclasses
-import functools
+import functools  # noqa: F401 (unused since PR 40; a line taken out here moves ``solve`` and re-keys every kernel-bearing program, PERF.md §6 PR 33)
 from typing import List, NamedTuple, Optional, Tuple
 
 import jax
@@ -670,28 +670,29 @@ class GlmOptimizationProblem:
 
     # -- variances (reference: DistributedOptimizationProblem:82-100) -------
 
-    @functools.cached_property
+    @property
     def _variance_fns(self):
+        """Default variance programs (non-mesh callers / tests)."""
+        return self._variance_fns_for(VARIANCE_GRAM_BLOCK_ROWS)
+
+    def _variance_fns_for(self, block_rows: Optional[int]):
         obj = self._var_objective  # original-space curvature (see __init__)
+        precision = VARIANCE_GRAM_PRECISION
 
         def build():
-            @jax.jit
-            def simple(coef: Array, batch: DataBatch, l2: Array) -> Array:
-                d = obj.hessian_diagonal(coef, batch, Hyper(l2_weight=l2))
-                return 1.0 / jnp.maximum(d, jnp.finfo(d.dtype).tiny)
+            def of(variance_type):
+                @jax.jit
+                def variances(coef: Array, batch: DataBatch, l2: Array) -> Array:
+                    return coefficient_variances(
+                        obj, coef, batch, Hyper(l2_weight=l2), variance_type,
+                        precision, block_rows)
+                return variances
 
-            @jax.jit
-            def full(coef: Array, batch: DataBatch, l2: Array) -> Array:
-                h = obj.hessian_matrix(coef, batch, Hyper(l2_weight=l2))
-                # diag(H^-1) via Cholesky (reference: util/Linalg Cholesky solves)
-                eye = jnp.eye(h.shape[0], dtype=h.dtype)
-                chol = jax.scipy.linalg.cho_factor(h)
-                hinv = jax.scipy.linalg.cho_solve(chol, eye)
-                return jnp.diag(hinv)
+            return (of(VarianceComputationType.SIMPLE),
+                    of(VarianceComputationType.FULL))
 
-            return simple, full
-
-        key = ("glm_variance", self.task, norm_cache_key(self._var_objective.norm))
+        key = ("glm_variance", self.task,
+               norm_cache_key(self._var_objective.norm), precision, block_rows)
         return jitcache.get_or_build(key, build)
 
     def compute_variances(
@@ -700,7 +701,12 @@ class GlmOptimizationProblem:
         coef: Array,
         variance_type: VarianceComputationType,
         regularization_weight: Optional[float] = None,
+        mesh=None,
     ) -> Optional[Array]:
+        """``mesh``: the batch is sample-sharded over it (``run``'s), so
+        FULL's Gram stays ONE contraction whose partial sums the mesh
+        reduces; on one device it is summed in row blocks
+        (``VARIANCE_GRAM_BLOCK_ROWS``)."""
         if variance_type == VarianceComputationType.NONE:
             return None
         if not self.objective.loss.has_hessian:
@@ -708,7 +714,8 @@ class GlmOptimizationProblem:
         lam = (self.config.regularization_weight
                if regularization_weight is None else regularization_weight)
         l2 = jnp.asarray(self.config.regularization.l2_weight(lam), coef.dtype)
-        simple, full = self._variance_fns
+        simple, full = self._variance_fns_for(
+            VARIANCE_GRAM_BLOCK_ROWS if mesh is None else None)
         if variance_type == VarianceComputationType.SIMPLE:
             return simple(coef, batch, l2)
         return full(coef, batch, l2)
@@ -750,3 +757,61 @@ def tron_explicit_hessian(dense: bool, dim: int) -> bool:
     return dense and dim <= (TRON_EXPLICIT_MAX_DIM_CPU
                              if jax.default_backend() == "cpu"
                              else TRON_EXPLICIT_MAX_DIM_TPU)
+
+
+# The Gram whose RESULT is published: ``FULL`` variances are
+# ``diag((X^T D X + l2 I)^-1)``, so here the Hessian is no means to an optimum
+# that an exact gradient corrects (NEWTON's and TRON's stay at DEFAULT, one
+# bfloat16 pass of the MXU in one contraction: ``ops/features.weighted_gram``)
+# but the number a ``BayesianLinearModelAvro`` carries. Its products are
+# taken at ``VARIANCE_GRAM_PRECISION`` and its rows summed
+# ``VARIANCE_GRAM_BLOCK_ROWS`` at a time (``features._gram_in_row_blocks``).
+# At 530,000 x 2,000 on a TPU v5e, against a float32 reference at the fitted
+# means (PERF.md section 5, my chip runs, PR 40; ms a Gram): HIGHEST in blocks
+# 7.4e-7 (149 ms), HIGH in blocks 8.7e-6 (72), DEFAULT 3.3e-5 (27), HIGHEST in
+# one contraction 2.2e-5 (139), bfloat16 features 3.9e-5: only the first is
+# float32's, and the cell's limit (5.4e-6) refuses the rest.
+VARIANCE_GRAM_PRECISION = jax.lax.Precision.HIGHEST
+VARIANCE_GRAM_BLOCK_ROWS = 8192
+
+
+def coefficient_variances(obj: GLMObjective, coef: Array, batch: DataBatch,
+                          hyper: Hyper, variance_type: VarianceComputationType,
+                          precision=None,
+                          block_rows: Optional[int] = None) -> Array:
+    """Coefficient variances at ``coef`` (reference:
+    DistributedOptimizationProblem.computeVariances :82-100): SIMPLE =
+    ``1 / diag(H)``, FULL = ``diag(H^-1)`` by a Cholesky inverse, ``H`` the
+    regularised Hessian of ``obj`` on ``batch``. Traced inside a jitted
+    program (the fixed effect's ``_variance_fns``; under ``vmap`` the
+    per-entity ``RandomEffectCoordinate._variance_fn``), under the scopes
+    ``optim/variance/{hessian,factor_solve,diagonal}``."""
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.ops import aggregators
+    from photon_tpu.ops.features import SparseFeatures
+
+    precision = VARIANCE_GRAM_PRECISION if precision is None else precision
+    if variance_type == VarianceComputationType.SIMPLE:
+        with jax.named_scope("optim/variance/hessian"):
+            d = obj.hessian_diagonal(coef, batch, hyper)
+        with jax.named_scope("optim/variance/diagonal"):
+            return 1.0 / jnp.maximum(d, jnp.finfo(d.dtype).tiny)
+    # ticked at TRACE time: once a traced FULL program, with the precision
+    # its Gram was traced at (a sparse Gram is scatter-adds, exact at any)
+    registry.counter(
+        "kernels.variance_gram", precision=precision.name,
+        path=("sparse" if isinstance(batch.features, SparseFeatures)
+              else "dense")).inc()
+    dim = coef.shape[0]
+    with jax.named_scope("optim/variance/hessian"):
+        h = aggregators.hessian_matrix_from_weights(
+            batch.features, obj.hessian_weights(coef, batch), obj.norm, dim,
+            precision, block_rows)
+        h = h + hyper.l2_weight * jnp.eye(dim, dtype=h.dtype)
+    with jax.named_scope("optim/variance/factor_solve"):
+        # diag(H^-1) via Cholesky (reference: util/Linalg Cholesky solves)
+        chol = jax.scipy.linalg.cho_factor(h)
+        hinv = jax.scipy.linalg.cho_solve(
+            chol, jnp.eye(dim, dtype=h.dtype))
+    with jax.named_scope("optim/variance/diagonal"):
+        return jnp.diag(hinv)

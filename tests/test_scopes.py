@@ -232,6 +232,60 @@ def test_the_spd_solve_lowers_under_factor_solve_and_counts_its_path(
         name for name in added if scope not in name)
 
 
+VARIANCE = {
+    # variance type -> the steps it runs, the aggregators nested in them
+    "FULL": ({"optim/variance/hessian", "optim/variance/factor_solve",
+              "optim/variance/diagonal"},
+             {"agg/hessian_weights", "agg/hessian_matrix", "agg/margins"}),
+    "SIMPLE": ({"optim/variance/hessian", "optim/variance/diagonal"},
+               {"agg/hessian_diagonal", "agg/margins"}),
+}
+
+
+@pytest.mark.parametrize("per_entity", [False, True],
+                         ids=["fixed", "per_entity"])
+@pytest.mark.parametrize("variance", sorted(VARIANCE))
+def test_a_variance_program_lowers_with_every_step_named(variance,
+                                                         per_entity):
+    """PR 40: SIMPLE and FULL, the fixed effect's program
+    (``GlmOptimizationProblem._variance_fns``) and the per-entity one's
+    body under ``vmap`` (``RandomEffectCoordinate._variance_fn`` calls the
+    same ``coefficient_variances``): every operation of the curvature, the
+    factorisation and the inverse sits under ``optim/variance/<step>``, the
+    aggregators keeping their names nested in ``hessian``."""
+    from photon_tpu.function.objective import Hyper
+    from photon_tpu.optim.problem import coefficient_variances
+    from photon_tpu.types import VarianceComputationType
+
+    vtype = VarianceComputationType[variance]
+    steps, aggs = VARIANCE[variance]
+    batch, d = _batch(sparse=per_entity)
+    if per_entity:
+        objective = GlmOptimizationProblem(
+            TaskType.LOGISTIC_REGRESSION).objective
+        one = lambda i, v, y, o, w, c: coefficient_variances(
+            objective, c, DataBatch(F.SparseFeatures(i, v), y, o, w),
+            Hyper(l2_weight=1.0), vtype)
+        lanes = lambda a: jnp.stack([a, a, a])
+        text = jax.jit(jax.vmap(one)).lower(
+            lanes(batch.features.indices), lanes(batch.features.values),
+            lanes(batch.labels), lanes(batch.offsets), lanes(batch.weights),
+            jnp.zeros((3, d))).as_text(debug_info=True)
+    else:
+        simple, full = GlmOptimizationProblem(
+            TaskType.LOGISTIC_REGRESSION)._variance_fns
+        text = (full if variance == "FULL" else simple).lower(
+            jnp.zeros(d), batch, jnp.asarray(1.0)).as_text(debug_info=True)
+    found = scopes_in(text)
+    assert steps | aggs <= found, sorted((steps | aggs) - found)
+    assert not found & (VARIANCE["FULL"][0] - steps)
+    # nothing of a variance program runs outside the variance's scope
+    named = set(re.findall(r'loc\("(jit\([^"]+)"', text))
+    outside = sorted(n for n in named if "optim/variance/" not in n
+                     and "/" in n.split(")", 1)[-1].strip("/"))
+    assert not outside, outside
+
+
 def _skewed_frame():
     """Users with 4, 12, 40 and 130 rows: four size buckets."""
     from photon_tpu.game.dataset import FeatureShard, GameDataFrame
@@ -503,9 +557,16 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              "feature_stats_s", "standardized_value_gradient_roofline",
              # the padded fill (PR 39): what the ``pad`` phase read
              "ingest.pad_nonzeros",
+             # the variances (PR 40): the host spans, the counters, the
+             # three readers
+             "fe/variance", "re/variance", "variance.computed",
+             "kernels.variance_gram", "variance_device_share",
+             "variance_roofline", "variance_factor_ms",
              } | LINESEARCH
     for _, _, steps, dense, sparse in SOLVERS.values():
         names |= steps | dense | (sparse or set())
+    for steps, aggs in VARIANCE.values():
+        names |= steps | aggs
     missing = sorted(n for n in names if n not in text)
     assert not missing, missing
 
