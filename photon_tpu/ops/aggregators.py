@@ -303,19 +303,19 @@ def hessian_matrix_from_weights(
     precision=jax.lax.Precision.DEFAULT,
     block_rows: Optional[int] = None,
 ) -> Array:
-    """Full H from precomputed curvature weights: one GEMM (MXU), at the
-    caller's ``precision`` and summed over ``block_rows`` rows at a time
-    (DEFAULT and all rows at once for NEWTON and TRON, whose gradient is
-    exact; the variances state their own, ``weighted_gram``).
+    """Full H from precomputed curvature weights, at the caller's
+    ``precision`` and ``block_rows`` (``features.weighted_gram``): DEFAULT
+    and ONE full GEMM over all rows for NEWTON and TRON, whose gradient is
+    exact; the variances state HIGHEST in row blocks, and there only the
+    UPPER triangle's column blocks are formed and mirrored once after the
+    last row block (``features.gram_route``; the row blocks stayed, the
+    float32 sum rests on them). A normalisation is folded in afterwards.
 
-    This turns a whole CG solve's data passes into a single
-    ``X^T diag(d2) X`` contraction plus O(d^2) matvecs. On a TPU v5e the
-    contraction is bound by its two reads of X up to some 1,000 features
-    and by the MXU above: what ONE matrix-free product costs where the
-    product is XLA's two passes (under 256 features), 1.5-2.3 one-read
-    products at 256-1,024 and 4.5 at 2,000 (PERF.md §5, PR 34):
-    ``optim/problem.tron_explicit_hessian`` gates TRON's use of it by
-    that."""
+    For a CG solve this is one ``X^T diag(d2) X`` plus O(d^2) matvecs; on
+    a TPU v5e, bound by two reads of X up to some 1,000 features and by
+    the MXU above: ONE matrix-free product's cost under 256 features,
+    1.5-2.3 one-read products at 256-1,024, 4.5 at 2,000 (PERF.md §5,
+    PR 34): ``optim/problem.tron_explicit_hessian`` gates TRON by that."""
     h = weighted_gram(x, d2, dim, precision, block_rows)
     if norm.shifts is not None:
         lin = rmatvec(x, d2, dim)

@@ -275,10 +275,10 @@ def weighted_gram(x: FeatureMatrix, w: Array, dim: int,
                   block_rows: Optional[int] = None) -> Array:
     """``X^T diag(w) X`` -> [d, d], for small-dim full Hessians
     (reference: HessianMatrixAggregator.scala:31). ``precision`` and
-    ``block_rows`` are the dense contraction's: DEFAULT and one contraction
+    ``block_rows`` are the dense contraction's: DEFAULT and ONE full product
     over all rows for the callers to whom the Hessian is a means (below);
-    stated otherwise by the one whose RESULT it is
-    (``optim/problem.py::coefficient_variances``)."""
+    stated otherwise by the one whose RESULT it is (``optim/problem.py::
+    coefficient_variances``: ``_upper_gram_in_row_blocks``, ``gram_route``)."""
     if isinstance(x, ModelShardedSparse):
         raise NotImplementedError(
             "model-sharded sparse theta is matrix-free by design: a d x d "
@@ -299,8 +299,8 @@ def weighted_gram(x: FeatureMatrix, w: Array, dim: int,
             return h
         dense = to_dense(x, dim)
         return jnp.matmul(dense.T, dense * w[:, None], precision=precision)
-    if block_rows is not None and x.shape[0] > block_rows:
-        return _gram_in_row_blocks(x, w, precision, block_rows)
+    if gram_route(x, block_rows) == "dense_upper":
+        return _upper_gram_in_row_blocks(x, w, precision, block_rows)
     # DEFAULT, stated: on a TPU ONE bfloat16 pass of the MXU. The Hessian's
     # callers (NEWTON, TRON) take their gradient exactly, so an inexact
     # Hessian moves iteration counts, not the optimum, and at DEFAULT it
@@ -308,34 +308,6 @@ def weighted_gram(x: FeatureMatrix, w: Array, dim: int,
     # DEFAULT, HIGH and HIGHEST alike, for 25.9 / 69.0 / 141.1 ms a build
     # at 530,000 x 2,000 (PERF.md §5, my chip runs, PR 33)
     return jnp.matmul(x.T, x * w[:, None], precision=precision)
-
-
-def _gram_in_row_blocks(x: Array, w: Array, precision, block_rows: int) -> Array:
-    """The dense ``X^T diag(w) X`` as a float32 SUM of one contraction a
-    block of ``block_rows`` rows. One contraction over n rows adds them
-    into its float32 accumulator one after another, which rounds the sum by
-    some ``sqrt(n) x 3.5e-8`` of itself whatever the products' precision:
-    at 530,000 x 2,000 the variances from a HIGHEST Gram read 2.6e-5 off a
-    float64 oracle in one contraction, nearly what ONE bfloat16 pass costs
-    (3.3e-5), and 5e-7 in blocks of 8,192 rows, for 7% more time (PERF.md
-    §5, my chip runs, PR 40)."""
-    n = x.shape[0]
-    blocks = n // block_rows
-
-    def gram(xb, wb):
-        return jnp.matmul(xb.T, xb * wb[:, None], precision=precision)
-
-    def add_block(i, h):
-        at = i * block_rows
-        return h + gram(jax.lax.dynamic_slice_in_dim(x, at, block_rows),
-                        jax.lax.dynamic_slice_in_dim(w, at, block_rows))
-
-    h = jax.lax.fori_loop(
-        0, blocks, add_block,
-        jnp.zeros((x.shape[1], x.shape[1]), jnp.result_type(x, w)))
-    if n % block_rows:
-        h = h + gram(x[blocks * block_rows:], w[blocks * block_rows:])
-    return h
 
 
 def to_dense(x: FeatureMatrix, dim: int) -> Array:
@@ -526,3 +498,92 @@ def from_rows(rows, dim: int, dtype=np.float32, max_nnz: int | None = None) -> S
         values[i, :m] = np.asarray(val, dtype=dtype)
     del dim  # shape is carried by coefficient vectors, not the ELL arrays
     return SparseFeatures(indices=jnp.asarray(indices), values=jnp.asarray(values))
+
+
+# --------------------------------------------------------------------------
+# the dense Gram summed in row blocks (PR 40), its upper triangle only (PR 41)
+# --------------------------------------------------------------------------
+
+# Columns a block of the upper triangle (a multiple of 128: X is stored
+# rows-major at whole 128-lane tiles, so a block's edge is a tile's). Chosen
+# from PERF.md §5's table (my chip runs, PR 41; ms a Gram at 530,000 x 2,000,
+# seconds to compile the FULL variance program cold): 512 92.0 / 14.4-18.3,
+# 256 91.0 / 15.4, 128 117.4 / 18.1, the full product 149.1 / 13.4), as
+# ``optim/problem.py::VARIANCE_GRAM_BLOCK_ROWS`` was; no function takes it
+# and no configuration sets it.
+GRAM_COLUMN_BLOCK = 256
+
+
+def gram_route(x: FeatureMatrix, block_rows: Optional[int]) -> str:
+    """How ``weighted_gram(x, w, dim, precision, block_rows)`` takes the
+    product, from what it can see: ``sparse`` (scatter-adds), ``dense_upper``
+    (dense and summed in row blocks, that is ``block_rows`` set and exceeded:
+    the upper triangle's column blocks, mirrored once), else ``dense`` (ONE
+    full product over all rows: NEWTON, TRON, DIRECT, a mesh, ``vmap``'s
+    per-entity widths). The Gram and the counter
+    ``kernels.variance_gram{path}`` both ask here."""
+    if isinstance(x, (SparseFeatures, ModelShardedSparse)):
+        return "sparse"
+    if block_rows is not None and x.shape[0] > block_rows:
+        return "dense_upper"
+    return "dense"
+
+
+def _upper_gram_in_row_blocks(x: Array, w: Array, precision,
+                              block_rows: int) -> Array:
+    """The dense ``X^T diag(w) X`` as a float32 SUM over blocks of
+    ``block_rows`` rows, its UPPER triangle only.
+
+    Why row blocks: one contraction over n rows adds them into its float32
+    accumulator one after another, which rounds the sum by some ``sqrt(n) x
+    3.5e-8`` of itself whatever the products' precision: at 530,000 x 2,000
+    the variances from a HIGHEST Gram read 2.6e-5 off a float64 oracle in
+    one contraction, nearly what ONE bfloat16 pass costs (3.3e-5), and 5e-7
+    in blocks of 8,192 rows, for 7% more time (PERF.md §5, my chip runs,
+    PR 40). The variances' exactness rests on the row blocks and on the
+    precision, so both stayed and the speed comes from the work.
+
+    Why the upper triangle: the product is symmetric. The columns are cut
+    into blocks of ``GRAM_COLUMN_BLOCK`` (the last one ragged) and a row
+    block contributes, for column block I, ONE contraction ``xb[:, I].T @
+    (xb[:, I's first column:] * wb)``: the block pairs (I, J), I <= J, side
+    by side in one ``[c, width - start]`` strip with its own float32
+    accumulator, the weights on one operand as ever. Entry (p, q), p <= q,
+    is the very products the full contraction forms for it, summed over the
+    same rows: ``(width^2 + sum c_i^2) / (2 width^2)`` of the
+    multiply-adds, 56.3% at 2,000 columns. A matrix within one column block
+    makes one strip, the full product. The lower triangle is filled ONCE,
+    after the last row block, by mirroring, so the result is EXACTLY
+    symmetric, which the full product's is not.
+
+    The rows over the last whole block ride in one more trip of the SAME
+    loop: its slice starts ``block_rows`` before the end and the rows the
+    trip before summed weigh nothing (exact zeros into the sum). A second
+    set of contractions for them outside the loop cost a cold first fit
+    7.5 s of compiling where this costs 2.0, for 0.6 ms a Gram (PERF.md §5,
+    my chip runs, PR 41)."""
+    n, width = x.shape
+    starts = range(0, width, GRAM_COLUMN_BLOCK)
+
+    def add_block(i, strips):
+        at = jnp.minimum(i * block_rows, n - block_rows)
+        xb = jax.lax.dynamic_slice_in_dim(x, at, block_rows)
+        wb = jax.lax.dynamic_slice_in_dim(w, at, block_rows)
+        wb = jnp.where(at + jnp.arange(block_rows) >= i * block_rows, wb, 0)
+        return [strip + jnp.matmul(xb[:, s:s + GRAM_COLUMN_BLOCK].T,
+                                   xb[:, s:] * wb[:, None],
+                                   precision=precision)
+                for s, strip in zip(starts, strips)]
+
+    dtype = jnp.result_type(x, w)
+    strips = jax.lax.fori_loop(
+        0, -(-n // block_rows), add_block,
+        [jnp.zeros((min(GRAM_COLUMN_BLOCK, width - s), width - s), dtype)
+         for s in starts])
+    # each strip back at its columns: block upper triangular, the diagonal
+    # blocks whole; below the diagonal the mirror image of what is above
+    h = jnp.concatenate([jnp.pad(strip, ((0, 0), (s, 0)))
+                         for s, strip in zip(starts, strips)])
+    upper = (jax.lax.broadcasted_iota(jnp.int32, h.shape, 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, h.shape, 1))
+    return jnp.where(upper, h, h.T)

@@ -765,12 +765,19 @@ def tron_explicit_hessian(dense: bool, dim: int) -> bool:
 # bfloat16 pass of the MXU in one contraction: ``ops/features.weighted_gram``)
 # but the number a ``BayesianLinearModelAvro`` carries. Its products are
 # taken at ``VARIANCE_GRAM_PRECISION`` and its rows summed
-# ``VARIANCE_GRAM_BLOCK_ROWS`` at a time (``features._gram_in_row_blocks``).
+# ``VARIANCE_GRAM_BLOCK_ROWS`` at a time
+# (``features._upper_gram_in_row_blocks``).
 # At 530,000 x 2,000 on a TPU v5e, against a float32 reference at the fitted
 # means (PERF.md section 5, my chip runs, PR 40; ms a Gram): HIGHEST in blocks
 # 7.4e-7 (149 ms), HIGH in blocks 8.7e-6 (72), DEFAULT 3.3e-5 (27), HIGHEST in
 # one contraction 2.2e-5 (139), bfloat16 features 3.9e-5: only the first is
-# float32's, and the cell's limit (5.4e-6) refuses the rest.
+# float32's, and the cell's limit (5.4e-6) refuses the rest. So the speed
+# since PR 41 comes from the work, not from either constant: summed in row
+# blocks, the Gram has only the UPPER triangle's column blocks formed
+# (``features.GRAM_COLUMN_BLOCK`` columns each), the same products over the
+# same 8,192-row blocks, and the lower triangle mirrored ONCE after the last
+# row block (``features.gram_route`` says which way a Gram goes, to the Gram
+# and to ``kernels.variance_gram{path}`` alike).
 VARIANCE_GRAM_PRECISION = jax.lax.Precision.HIGHEST
 VARIANCE_GRAM_BLOCK_ROWS = 8192
 
@@ -788,7 +795,7 @@ def coefficient_variances(obj: GLMObjective, coef: Array, batch: DataBatch,
     ``optim/variance/{hessian,factor_solve,diagonal}``."""
     from photon_tpu.obs.metrics import registry
     from photon_tpu.ops import aggregators
-    from photon_tpu.ops.features import SparseFeatures
+    from photon_tpu.ops.features import gram_route
 
     precision = VARIANCE_GRAM_PRECISION if precision is None else precision
     if variance_type == VarianceComputationType.SIMPLE:
@@ -798,10 +805,11 @@ def coefficient_variances(obj: GLMObjective, coef: Array, batch: DataBatch,
             return 1.0 / jnp.maximum(d, jnp.finfo(d.dtype).tiny)
     # ticked at TRACE time: once a traced FULL program, with the precision
     # its Gram was traced at (a sparse Gram is scatter-adds, exact at any)
+    # and the way it goes: ``dense_upper`` the upper triangle in row blocks,
+    # ``dense`` one full product a contraction, ``sparse``
     registry.counter(
         "kernels.variance_gram", precision=precision.name,
-        path=("sparse" if isinstance(batch.features, SparseFeatures)
-              else "dense")).inc()
+        path=gram_route(batch.features, block_rows)).inc()
     dim = coef.shape[0]
     with jax.named_scope("optim/variance/hessian"):
         h = aggregators.hessian_matrix_from_weights(

@@ -1316,6 +1316,48 @@ def test_swept_lane_solve_compiles_for_a_v5e(v5e, placed):
     _held_to_the_layout(placed, compiled)
 
 
+def test_full_variance_program_compiles_for_a_v5e_on_the_upper_triangle(v5e):
+    """The fe-epsilon-variance FULL program (``_variance_fns``, X as it is
+    placed) as the chip's compiler leaves it: the Gram's loop holds one MXU
+    contraction a column block, each a strip of the upper triangle and none
+    the whole ``[2000, 2000]`` product, reading the stored matrix in place:
+    no temporary of X's size, nor of a row block's."""
+    from photon_tpu.function.objective import L2Regularization
+    from photon_tpu.ops import features
+    from photon_tpu.optim.problem import (
+        VARIANCE_GRAM_BLOCK_ROWS,
+        GLMOptimizationConfiguration,
+        GlmOptimizationProblem,
+        OptimizerConfig,
+    )
+    from photon_tpu.types import TaskType
+    from photon_tpu.utils import jitcache
+
+    jitcache.clear()
+    prob = GlmOptimizationProblem(
+        TaskType.LOGISTIC_REGRESSION, GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(), regularization=L2Regularization,
+            regularization_weight=1.0))
+    try:
+        compiled = prob._variance_fns[1].lower(
+            _shaped(v5e, _EPSILON[1]), _epsilon_batch(v5e, "row_major"),
+            _shaped(v5e)).compile()
+    finally:
+        jitcache.clear()
+    text = compiled.as_text()
+    width, block = _EPSILON[1], features.GRAM_COLUMN_BLOCK
+    strips = {(min(block, width - s), width - s)
+              for s in range(0, width, block)}
+    # the Gram's contractions carry its scope; the factorisation's do not
+    gram = {(int(a), int(b)) for a, b in re.findall(
+        r"= f32\[(\d+),(\d+)\]\S* convolution\([^\n]*agg/hessian_matrix",
+        text)}
+    assert gram == strips, gram
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        VARIANCE_GRAM_BLOCK_ROWS * 2_048 * 4)
+    assert not _X_COPY.findall(text)
+
+
 def _stores_to(jaxpr, refs):
     """The equations of ``jaxpr`` (and of the jaxprs its ``cond`` /
     ``scan`` / ``pjit`` equations hold, their operands matched by
