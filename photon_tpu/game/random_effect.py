@@ -255,6 +255,65 @@ def _bucket_of(sizes: np.ndarray) -> np.ndarray:
     return np.ceil(np.log2(np.maximum(sizes, 1))).astype(np.int64)
 
 
+def pair_route(num_entities: int, dim: int, nonzeros: int) -> str:
+    """How a shard's observed (entity, column) pairs find their local
+    slots: ``"table"``, one int32 a key of the ``num_entities x dim`` key
+    space, where that is no larger than the int64 sorted keys of its
+    ``nonzeros`` active nonzeros (E x D <= 2 nnz); else ``"sort"``, the
+    sorted distinct keys, searched (a wide vocabulary's sparse shard)."""
+    return "table" if num_entities * dim <= 2 * nonzeros else "sort"
+
+
+def _slot_lookup(route: str, uniq: np.ndarray, slot_of_pair: np.ndarray,
+                 size: int):
+    """key ``entity * D + column`` -> the pair's local slot, -1 where the
+    pair is not kept; ``uniq`` holds the kept keys, ascending."""
+    if route == "table":
+        table = np.full(size, -1, np.int32)
+        table[uniq] = slot_of_pair
+        return table.take
+
+    def search(keys: np.ndarray) -> np.ndarray:
+        if not len(uniq):
+            return np.full(len(keys), -1, np.int64)
+        rank = np.minimum(np.searchsorted(uniq, keys), len(uniq) - 1)
+        return np.where(uniq[rank] == keys, slot_of_pair[rank], -1)
+    return search
+
+
+def _read_nonzeros(rows, row_entity, row_cell, indptr, nnz, cols, D, slots,
+                   counter):
+    """The nonzeros of ``rows`` (of entities ``row_entity``), reached
+    through their ``indptr`` ranges and looked up in ``slots``; ``counter``
+    is ticked by the positions read. Returns ``K``, the most kept nonzeros
+    of a row (at least 1), and of the kept nonzeros: where each lies in
+    ``cols`` / ``vals``, its local slot, and its place ``row_cell[row] * K
+    + its position among its row's kept nonzeros``."""
+    lens = nnz[rows]
+    ends = np.cumsum(lens)
+    starts = ends - lens
+    total = int(ends[-1]) if len(ends) else 0
+    counter.inc(total)
+    nz = np.repeat(indptr[rows] - starts, lens)
+    nz += np.arange(total)                            # place in cols/vals
+    key = np.repeat(row_entity * D, lens)
+    key += cols[nz]
+    slot = slots(key)
+    del key
+    if not total or slot.min() >= 0:                  # every one kept
+        K = max(int(lens.max()) if total else 1, 1)
+        place = np.repeat(row_cell * K - starts, lens)
+        place += np.arange(total)
+        return K, nz, slot, place
+    kept = slot >= 0
+    before = np.concatenate([[0], np.cumsum(kept)])   # kept ones before
+    pos = before[:-1] - np.repeat(before[starts], lens)
+    K = max(int(pos[kept].max()) + 1 if kept.any() else 1, 1)
+    place = np.repeat(row_cell * K, lens)
+    place += pos
+    return K, nz[kept], slot[kept], place[kept]
+
+
 def build_random_effect_dataset(
     df: GameDataFrame,
     config: RandomEffectDataConfiguration,
@@ -270,20 +329,35 @@ def build_random_effect_dataset(
     Its host seconds are recorded, telemetry on or off, as ``Timed`` phases
     named for ``coordinate`` (the random-effect type when the caller gives
     none): ``ingest/prepare/<coordinate>/group`` (vocabulary, ordering,
-    active/passive split, projection table, local slots),
-    ``.../bucket`` (the size ladder, and ONE stable sort of the active
-    samples into bucket-major order), ``.../pad`` (one record a bucket: the
-    padded fill of that bucket's block from a contiguous slice of its
-    samples and, through their ``indptr`` ranges, their nonzeros; it reads
-    no other bucket's, and ticks the counter
+    active/passive split, the observed pairs and their projection table,
+    and the map from a pair's key ``entity * D + column`` to its local
+    slot: an int32 table over the E x D keys or the sorted kept keys, as
+    ``pair_route`` says from E, D and the active nonzeros, one tick of
+    ``ingest.pair_route{coordinate, path=table|sort}``; no per-nonzero
+    array of its own outlives it), ``.../bucket`` (the size
+    ladder, and ONE stable sort of the active samples into bucket-major
+    order), ``.../pad`` (one record a bucket: the padded fill of that
+    bucket's block from a contiguous slice of its samples and, through
+    their ``indptr`` ranges, their nonzeros, each looked up in the map
+    where it is read; it reads no other bucket's, and ticks the counter
     ``ingest.pad_nonzeros{coordinate}`` by the nonzero positions it reads:
     over the buckets, the nonzeros of the active samples, once) and
-    ``.../passive``;
+    ``.../passive`` (the same for the passive samples' nonzeros alone,
+    ticking ``ingest.passive_nonzeros{coordinate}``);
     ``ingest/h2d/<coordinate>`` around each placement (what the host
     spends in ``jnp.asarray``: nothing waits for the copy), the placed
     bytes going to the counter ``ingest.h2d_bytes{coordinate}``;
     ``ingest/stats`` around the padding-waste count, which compiles a
     tiny program a bucket shape."""
+    return _build_random_effect_dataset(df, config, vocab, dtype,
+                                        scores_offsets, coordinate)
+
+
+def _build_random_effect_dataset(df, config, vocab, dtype=np.float32,
+                                 scores_offsets=None, coordinate=None,
+                                 route=pair_route):
+    """``build_random_effect_dataset``; ``route`` is ``pair_route`` or, in
+    the tests alone, a function that forces one."""
     re_type = config.random_effect_type
     coordinate = coordinate or re_type
     prepare, h2d = f"ingest/prepare/{coordinate}", f"ingest/h2d/{coordinate}"
@@ -336,19 +410,37 @@ def build_random_effect_dataset(
         act_counts = np.bincount(entity_idx[active], minlength=E)
 
         # -- observed (entity, feature) pairs over ACTIVE data -------------------
-        s_nz = np.repeat(np.arange(n), nnz)              # sample id per nonzero
-        keep_nz = active[s_nz]
-        e_nz = entity_idx[s_nz]
-        pair = e_nz * D + cols                            # int64 composite key
-        uniq = np.unique(pair[keep_nz]) if keep_nz.any() else np.zeros(0, np.int64)
+        pair = np.repeat(entity_idx * D, nnz)
+        pair += cols                                      # int64 composite key
+        keep_nz = None if active.all() else np.repeat(active, nnz)
+        if keep_nz is not None:
+            pair = pair[keep_nz]
+        path = route(E, D, len(pair))
+        registry.counter("ingest.pair_route", coordinate=coordinate,
+                         path=path).inc()
+        if path == "table":
+            # the sorted distinct keys, and (below) a key's rank among them,
+            # by direct address: no sort, no search
+            present = np.zeros(E * D, bool)
+            present[pair] = True
+            uniq = np.flatnonzero(present)
+        else:
+            uniq = np.unique(pair)
 
         # -- optional Pearson feature selection (reference: LocalDataset:122) ----
         if config.features_to_samples_ratio is not None and len(uniq):
             ratio = config.features_to_samples_ratio
             k_per_entity = np.maximum((ratio * act_counts).astype(np.int64), 1)
+            if path == "table":   # exclusive count of present keys below
+                rank = (np.cumsum(present) - present)[pair]
+            else:
+                rank = np.searchsorted(uniq, pair)
+            v, y = vals, np.repeat(resp, nnz)
+            if keep_nz is not None:
+                v, y = v[keep_nz], y[keep_nz]
             scores = _pearson_scores_vectorized(
-                uniq, pair, keep_nz, vals, s_nz, entity_idx, resp, weights,
-                active, E, D)
+                uniq, rank, v, y, entity_idx, resp, weights, active, E, D)
+            del rank, v, y
             u_e = uniq // D
             sel_order = np.lexsort((-scores, u_e))
             u_starts = np.searchsorted(u_e[sel_order], np.arange(E))
@@ -362,6 +454,7 @@ def build_random_effect_dataset(
                 (ratio * act_counts[u_e]).astype(np.int64), 1)
             keep_pair |= within
             uniq = uniq[keep_pair]
+        del keep_nz, pair
 
         # -- projection table ----------------------------------------------------
         u_e = uniq // D
@@ -374,25 +467,8 @@ def build_random_effect_dataset(
         if len(uniq):
             projection[u_e, slot_of_pair] = u_f.astype(np.int32)
 
-        # -- per-nonzero local slots (kept nonzeros only) ------------------------
-        rank = np.searchsorted(uniq, pair) if len(uniq) else np.zeros(len(pair), np.int64)
-        rank = np.minimum(rank, max(len(uniq) - 1, 0))
-        kept_nz_mask = np.zeros(len(pair), bool)
-        if len(uniq):
-            kept_nz_mask = uniq[rank] == pair
-        slot_nz = slot_of_pair[rank] if len(uniq) else np.zeros(len(pair), np.int64)
-
-    # position of each kept nonzero within its sample
-    def _slot_positions(mask: np.ndarray) -> np.ndarray:
-        if not len(pair):
-            return np.zeros(0, np.int64)
-        kept_i = mask.astype(np.int64)
-        c = np.cumsum(kept_i)
-        excl = c - kept_i
-        # indptr may equal total_nnz for trailing empty rows; those repeat
-        # zero times, so clamp the index to keep the gather in range
-        base = np.repeat(excl[np.minimum(indptr[:-1], len(excl) - 1)], nnz)
-        return excl - base
+        # -- local slot of a kept pair, looked up where a nonzero is read -------
+        slots = _slot_lookup(path, uniq, slot_of_pair, E * D)
 
     # -- bucketed active blocks ---------------------------------------------
     with phase(f"{prepare}/bucket"):
@@ -416,8 +492,6 @@ def build_random_effect_dataset(
         act_idx_sorted = order[active_sorted]             # flat rows, grouped
         act_pos = pos[active_sorted]                      # rank within entity
         act_entity = entity_idx[act_idx_sorted]
-
-        k_nz_pos_all = _slot_positions(kept_nz_mask & active[s_nz])
 
         # bucket-major order, ONCE for all buckets: a bucket's entities in
         # ascending global row are its block rows, and a stable sort of the
@@ -467,27 +541,13 @@ def build_random_effect_dataset(
 
             # ELL features: the nonzeros of this bucket's samples, gathered
             # through their ``indptr`` ranges (each is visited by one bucket)
-            lens = nnz[rows_flat]
-            ends = np.cumsum(lens)
-            total = int(ends[-1])
-            pad_nonzeros.inc(total)
-            nz = np.repeat(indptr[rows_flat] - (ends - lens), lens)
-            nz += np.arange(total)                        # place in cols/vals
-            kept = kept_nz_mask[nz]
-            some_dropped = not kept.all()
-            if some_dropped:
-                nz = nz[kept]
-            nz_k = k_nz_pos_all[nz]
-            K_b = max(int(nz_k.max()) + 1 if len(nz_k) else 1, 1)
-
+            K_b, nz, nz_slot, place = _read_nonzeros(   # in [E_b * S_b * K_b]
+                rows_flat, ents[r_sorted[in_b]], cell, indptr, nnz, cols, D,
+                slots, pad_nonzeros)
             f_idx = np.zeros((E_b, S_b, K_b), np.int32)
             f_val = np.zeros((E_b, S_b, K_b), dtype)
-            slot = np.repeat(cell * K_b, lens)            # in [E_b * S_b * K_b]
-            if some_dropped:
-                slot = slot[kept]
-            slot += nz_k
-            f_idx.reshape(-1)[slot] = slot_nz[nz]
-            f_val.reshape(-1)[slot] = vals[nz]
+            f_idx.reshape(-1)[place] = nz_slot
+            f_val.reshape(-1)[place] = vals[nz]
 
         with phase(h2d):
             blocks.append(EntityBlock(
@@ -503,21 +563,19 @@ def build_random_effect_dataset(
     with phase(f"{prepare}/passive"):
         pas_rows = np.flatnonzero(passive)
         P = max(len(pas_rows), 1)
-        pas_nz_mask = kept_nz_mask & passive[s_nz]
-        pas_k = _slot_positions(pas_nz_mask)
-        K_p = max(int(pas_k[pas_nz_mask].max()) + 1 if pas_nz_mask.any() else 1, 1)
+        K_p, pas_nz, pas_slot, place = _read_nonzeros(
+            pas_rows, entity_idx[pas_rows], np.arange(len(pas_rows)), indptr,
+            nnz, cols, D, slots,
+            registry.counter("ingest.passive_nonzeros", coordinate=coordinate))
         p_idx = np.zeros((P, K_p), np.int32)
         p_val = np.zeros((P, K_p), dtype)
         p_entity = np.full(P, E, np.int32)
         p_rows = np.full(P, n, np.int32)
         if len(pas_rows):
-            row_rank = np.full(n, -1, np.int64)
-            row_rank[pas_rows] = np.arange(len(pas_rows))
             p_entity[: len(pas_rows)] = entity_idx[pas_rows]
             p_rows[: len(pas_rows)] = pas_rows
-            sel = pas_nz_mask
-            p_idx[row_rank[s_nz[sel]], pas_k[sel]] = slot_nz[sel].astype(np.int32)
-            p_val[row_rank[s_nz[sel]], pas_k[sel]] = vals[sel]
+            p_idx.reshape(-1)[place] = pas_slot
+            p_val.reshape(-1)[place] = vals[pas_nz]
         flat_source = flat_source_map(block_rows, p_rows, n)
 
     with phase(h2d):
@@ -562,11 +620,13 @@ def _maybe_random_project(shard, config: RandomEffectDataConfiguration):
     return FeatureShard(CsrRows.from_dense(dense), rp.projected_dim)
 
 
-def _pearson_scores_vectorized(uniq, pair, keep_nz, vals, s_nz, entity_idx,
-                               resp, weights, active, E, D) -> np.ndarray:
+def _pearson_scores_vectorized(uniq, rank, v, y, entity_idx, resp, weights,
+                               active, E, D) -> np.ndarray:
     """|Pearson corr(feature, label)| per observed (entity, feature) pair
     over active samples (reference: LocalDataset.computePearsonCorrelation
-    Score :122; constant nonzero columns — intercepts — score 1)."""
+    Score :122; constant nonzero columns — intercepts — score 1). ``rank``,
+    ``v``, ``y``: each active nonzero's pair's index in ``uniq``, its value
+    and its sample's label, in the shard's order."""
     act_counts = np.bincount(entity_idx[active], minlength=E).astype(np.float64)
     # per-entity label stats over active samples
     lab_sum = np.bincount(entity_idx[active], weights=resp[active], minlength=E)
@@ -576,10 +636,6 @@ def _pearson_scores_vectorized(uniq, pair, keep_nz, vals, s_nz, entity_idx,
         lab_var = lab_sq / act_counts - lab_mean ** 2
     lab_sd = np.sqrt(np.maximum(lab_var, 0))
 
-    m = keep_nz
-    rank = np.searchsorted(uniq, pair[m])
-    v = vals[m]
-    y = resp[s_nz[m]]
     nfeat = len(uniq)
     sums = np.bincount(rank, weights=v, minlength=nfeat)
     sqs = np.bincount(rank, weights=v * v, minlength=nfeat)

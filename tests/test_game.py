@@ -331,8 +331,8 @@ def _reference_blocks(df, config, vocab, dtype=np.float32,
         ratio = config.features_to_samples_ratio
         k_per_entity = np.maximum((ratio * act_counts).astype(np.int64), 1)
         scores = _pearson_scores_vectorized(
-            uniq, pair, keep_nz, vals, s_nz, entity_idx, resp, weights,
-            active, E, D)
+            uniq, np.searchsorted(uniq, pair[keep_nz]), vals[keep_nz],
+            resp[s_nz[keep_nz]], entity_idx, resp, weights, active, E, D)
         u_e = uniq // D
         sel_order = np.lexsort((-scores, u_e))
         u_starts = np.searchsorted(u_e[sel_order], np.arange(E))
@@ -559,16 +559,24 @@ def _fill_case(name):
     "buckets_1", "buckets_4", "buckets_16", "uncapped_ladder",
     "entity_without_active_row", "scores_offsets", "random_projection",
     "float64"])
-def test_the_padded_fill_is_the_per_bucket_loops_array_for_array(case):
+@pytest.mark.parametrize("route", ["table", "sort"])
+def test_the_padded_fill_is_the_per_bucket_loops_array_for_array(route, case):
     """Every array of the dataset, its shape and its dtype, is what the
-    loop that rescanned the shard once a bucket built: the same blocks in
-    the same order reach the same cached programs."""
-    from photon_tpu.game.random_effect import build_random_effect_dataset
+    loop that rescanned the shard once a bucket built, whichever way the
+    pairs find their slots (a table over the E x D keys or the sorted
+    keys, searched): the same blocks in the same order reach the same
+    cached programs."""
+    from photon_tpu.game.random_effect import _build_random_effect_dataset
+    from photon_tpu.obs.metrics import registry
 
     df, config, build, vocab = _fill_case(case)
     cfg = RandomEffectDataConfiguration("userId", "u", **config)
     want = _reference_blocks(df, cfg, vocab(), **build)
-    ds = build_random_effect_dataset(df, cfg, vocab(), **build)
+    key = f'ingest.pair_route{{coordinate="userId",path="{route}"}}'
+    before = registry.snapshot()["counters"].get(key, 0)
+    ds = _build_random_effect_dataset(df, cfg, vocab(), **build,
+                                      route=lambda *_: route)
+    assert registry.snapshot()["counters"][key] - before == 1
 
     def same(got, wanted, what):
         got = np.asarray(got)
@@ -633,6 +641,72 @@ def test_the_pad_phase_reads_each_active_nonzero_once(cap):
     for step, count in (("group", 1), ("bucket", 1), ("passive", 1),
                         ("pad", len(ds.blocks))):
         assert labels.count(f"ingest/prepare/per-user/{step}") == count, step
+
+
+@pytest.mark.parametrize("E, D, nnz, path", [
+    (10, 20, 100, "table"), (10, 21, 100, "sort"), (0, 0, 0, "table"),
+    (34624, 20, 100_000_000, "table"), (27278, 8, 40_000_000, "table"),
+    (1000, 1_000_000, 10_000_000, "sort")])
+def test_pair_route_takes_the_table_while_it_is_no_larger_than_the_keys(
+        E, D, nnz, path):
+    """An int32 table over the E x D keys where it is no larger than the
+    int64 sorted keys of the active nonzeros (E x D <= 2 nnz): both GLMix
+    cells' random effects; a wide vocabulary keeps the sorted keys."""
+    from photon_tpu.game.random_effect import pair_route
+
+    assert pair_route(E, D, nnz) == path
+
+
+@pytest.mark.parametrize("passive_rows", [False, True])
+def test_the_pair_route_and_the_passive_phase_are_counted(passive_rows):
+    """``ingest.pair_route{coordinate, path}`` ticks once a coordinate on
+    the path ``pair_route`` chose; ``ingest.passive_nonzeros`` counts the
+    nonzero positions the ``passive`` phase reads: none without a passive
+    set, the passive samples' own under ``active_data_upper_bound``, while
+    ``ingest.pad_nonzeros`` counts the active samples' (PR 39)."""
+    from photon_tpu.game.dataset import CsrRows, EntityVocabulary
+    from photon_tpu.game.random_effect import build_random_effect_dataset
+    from photon_tpu.obs.metrics import registry
+
+    table, sort, pad, passive_nz = (
+        'ingest.pair_route{coordinate="per-user",path="table"}',
+        'ingest.pair_route{coordinate="per-user",path="sort"}',
+        'ingest.pad_nonzeros{coordinate="per-user"}',
+        'ingest.passive_nonzeros{coordinate="per-user"}')
+
+    def counts():
+        c = registry.snapshot()["counters"]
+        return {k: c.get(k, 0) for k in (table, sort, pad, passive_nz)}
+
+    df, _, _, _ = _fill_case("buckets_16")
+    row_nonzeros = np.count_nonzero(df.feature_shards["u"].rows, axis=1)
+    bound = dict(active_data_upper_bound=20000) if passive_rows else {}
+    before = counts()
+    ds = build_random_effect_dataset(
+        df, RandomEffectDataConfiguration("userId", "u", **bound),
+        EntityVocabulary(), coordinate="per-user")
+    after = counts()
+    passive = np.asarray(ds.passive_rows)
+    passive = passive[passive < df.num_samples]
+    assert len(passive) == (2 ** 15 - 20000 if passive_rows else 0)
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved[table] == 1 and moved[sort] == 0
+    assert moved[passive_nz] == row_nonzeros[passive].sum()
+    assert moved[pad] == row_nonzeros.sum() - row_nonzeros[passive].sum()
+
+    # a sparse shard over a wide vocabulary keeps the sorted keys
+    n, d = df.num_samples, 1_000_000
+    cols = np.random.default_rng(0).integers(0, d, size=n)
+    wide = GameDataFrame(
+        num_samples=n, response=df.response, id_tags=df.id_tags,
+        feature_shards={"u": FeatureShard(
+            CsrRows(np.arange(n + 1), cols, np.ones(n)), d)})
+    before = counts()
+    build_random_effect_dataset(
+        wide, RandomEffectDataConfiguration("userId", "u"),
+        EntityVocabulary(), coordinate="per-user")
+    after = counts()
+    assert after[sort] - before[sort] == 1 and after[table] == before[table]
 
 
 @pytest.fixture(scope="module")

@@ -555,8 +555,10 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              # the kernel's labels under a context, the two readers
              "ingest/feature_stats/", "dense_norm", "dense_hv_norm",
              "feature_stats_s", "standardized_value_gradient_roofline",
-             # the padded fill (PR 39): what the ``pad`` phase read
-             "ingest.pad_nonzeros",
+             # the padded fill (PR 39): what the ``pad`` phase read; the
+             # pair map's route and what the ``passive`` phase read (PR 42)
+             "ingest.pad_nonzeros", "ingest.pair_route",
+             "ingest.passive_nonzeros",
              # the variances (PR 40): the host spans, the counters, the
              # three readers
              "fe/variance", "re/variance", "variance.computed",
