@@ -3,8 +3,9 @@
 the TPU it is given?
 
 One process (the chip belongs to it), the entry points a user would call,
-the full width of the supported GLMix headline shape (bench.py
-``config_glmix_logistic`` at scale 1.0), random weights from a seed. It
+the full width of the supported GLMix headline shape (100,000 rows, 256
+global features, 1,000 users of 4 features each), random weights from a
+seed. It
 
   1. trains through ``GameEstimator.fit`` (2 coordinate-descent sweeps,
      then a second fit warm-started from the first) and checks the model
@@ -169,7 +170,7 @@ def _u_name(j: int) -> str:
 
 
 # --------------------------------------------------------------------------
-# data, seeded (same generator as bench.py config_glmix_logistic)
+# data, seeded: a dense global block, one per-user block, logistic labels
 # --------------------------------------------------------------------------
 
 def make_rows(sizes: Sizes, n: int, seed: int):

@@ -22,8 +22,7 @@ Freshness is the pipeline's north-star metric: the histogram
 ``nearline.freshness_seconds`` measures event timestamp -> the moment the
 entity's new row is scoreable (the publish commit), per touched entity.
 
-Run it inline round by round (``run_round``, what the tests and bench
-do), or as a long-lived loop (``run``) with the shared shutdown hook
+Run it inline round by round (``run_round``, what the tests do), or as a long-lived loop (``run``) with the shared shutdown hook
 providing graceful drain: finish the in-flight round, land the final
 checkpoint, exit.  ``cli/nearline`` wraps ``run`` for operators.
 """

@@ -6,7 +6,7 @@ from ``Device.memory_stats()`` (``bytes_in_use`` / ``peak_bytes_in_use``
 where the backend reports them — TPU does, CPU usually returns None).
 
 Sampling is pulled, never pushed: :func:`record_phase` runs where a
-driver closes its root span (``train``, ``score``, a bench config) and at
+driver closes its root span (``train``, ``score``) and at
 RunReport build time — a few /proc reads per driver run, nothing per
 iteration, nothing inside jit. A span samples nothing by itself: whatever
 happens to be outermost (``cd/sweep`` when a library user fits under a

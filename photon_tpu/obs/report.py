@@ -1,10 +1,9 @@
 """RunReport: one machine-readable JSON manifest per driver run.
 
-Written at the end of ``cli/train.py`` / ``cli/score.py`` and emitted by
-``bench.py`` in the same schema: phase spans, the metrics-registry
-snapshot, drained solver trajectories (per-iteration loss/||g||/step
-series and per-entity RE outcomes), mesh/device topology, and host/
-device memory watermarks sampled per phase. The schema is versioned so
+Written at the end of ``cli/train.py`` / ``cli/score.py``: phase spans,
+the metrics-registry snapshot, drained solver trajectories (per-iteration
+loss/||g||/step series and per-entity RE outcomes), mesh/device topology,
+and host/device memory watermarks sampled per phase. The schema is versioned so
 later perf/robustness PRs can extend it without breaking parsers.
 
 Multi-process: :func:`write_run_report` with ``aggregate=True`` gathers
@@ -302,7 +301,7 @@ def _json_fallback(obj):
 
 def validate_run_report(report: Dict[str, Any]) -> List[str]:
     """Structural schema check; returns a list of problems ([] = valid).
-    Used by tests and by bench.py's self-check before emitting."""
+    Used by tests."""
     errors: List[str] = []
     if report.get("schema") != SCHEMA:
         errors.append(f"schema is {report.get('schema')!r}, want {SCHEMA!r}")

@@ -4,7 +4,7 @@ An SLO here is a frozen rule object evaluated against a windowed
 snapshot (``obs.timeseries.WindowedRegistry.snapshot()`` or the merged
 multi-process dict from ``merge_snapshots``). Evaluation emits TYPED
 verdict records — PASS / WARN / BREACH with the exact offending windows
-— rather than a boolean, so a bench gate can assert not just "p99 was
+— rather than a boolean, so a gate can assert not just "p99 was
 fine" but "the breach was localized to the shard-kill windows and every
 survivor window stayed PASS".
 
@@ -26,7 +26,7 @@ after a live swap); more → BREACH.
 
 ``evaluate()`` also records every verdict in a module-level sink so the
 RunReport's ``slo`` section picks them up; ``write_verdicts`` emits the
-machine-readable verdict file bench gates and CI read.
+machine-readable verdict file CI reads.
 """
 
 from __future__ import annotations

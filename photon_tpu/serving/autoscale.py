@@ -21,8 +21,8 @@ provisioning, bucket copy, double-read window open) and ``finish()``
 completes it (reconcile, bitwise-parity cutover, decommission) — the
 window in between is where live traffic flows through the double-read
 comparison, which is the whole point. A deterministic replay
-(`bench.py --mode elastic`) schedules ``step``/``finish`` as virtual-
-clock actions mid-flash-crowd.
+(``tests/test_elastic.py::test_elastic_lifecycle_under_replay``)
+schedules ``step``/``finish`` as virtual-clock actions mid-burst.
 """
 
 from __future__ import annotations

@@ -325,8 +325,8 @@ class TwoTierCoeffStore:
         return m
 
     def drain_prefetch(self, timeout_s: float = 10.0) -> bool:
-        """Block until every queued promotion has landed (tests, bench
-        phase boundaries — never the scoring path). True on quiescence."""
+        """Block until every queued promotion has landed (tests' phase
+        boundaries — never the scoring path). True on quiescence."""
         deadline = time.monotonic() + timeout_s
         while True:
             moved = self.drain_once()

@@ -17,7 +17,7 @@ Request lifecycle::
 Everything observable lands in the process metrics registry under the
 ``serving.*`` namespace; ``stats()`` folds the registry snapshot plus
 compile-phase accounting into the dict that becomes the RunReport's
-``serving`` section and the BENCH_SERVING payload.
+``serving`` section.
 
 Model state is versioned: ``publish_model`` atomically installs a staged
 :class:`~photon_tpu.serving.model_state.DeviceResidentModel` between
@@ -615,7 +615,7 @@ class ServingEngine:
         }
 
     def stats(self) -> dict:
-        """The serving section for RunReport / BENCH_SERVING: model shape,
+        """The serving section for the RunReport: model shape,
         ladder, compile-phase accounting, and the latency quantiles."""
         snap = _metrics.snapshot()
         latencies = {}

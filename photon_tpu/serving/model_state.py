@@ -390,7 +390,7 @@ class DeviceResidentModel:
         return stats or None
 
     def drain_prefetch(self, timeout_s: float = 10.0) -> bool:
-        """Flush every store's pending promotions (tests / bench phase
+        """Flush every store's pending promotions (tests' phase
         boundaries — never the scoring path)."""
         ok = True
         for rs in self.random:

@@ -4,8 +4,7 @@ Photon deployments are inherently multi-model — per-market, per-surface,
 per-experiment GLMix variants served side by side. The scorer refactor
 (serving/scorer.py) made the compiled (mode × bucket) programs
 shape-keyed, so hosting N same-shape tenants costs ONE warmup ladder:
-tenant #2..N warm at near-zero compile cost (the bench asserts ≤1.1×
-the single-tenant program count for 8 tenants).
+tenant #2..N warm at near-zero compile cost.
 
 ``MultiTenantEngine`` hosts one ``ServingEngine`` per tenant under a
 single shared bucket-ladder configuration and routes by the request's

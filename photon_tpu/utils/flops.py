@@ -1,32 +1,18 @@
-"""Model-flop accounting for MFU reporting.
+"""Chip peaks, and the utilization gauges of the streamed and blocked
+pipelines that are read against them.
 
-"Model flops" are the algorithmically-required floating point operations of
-the GLM solves (the useful work), NOT hardware flops: we count the
-aggregator passes the optimizer actually executed, using each solver's
-reported objective-evaluation count. MFU = model_flops / wall_clock /
-chip_peak_flops — a deliberate lower bound, because ancillary work
-(line-search vector ops, convergence checks, scatter/gathers, Hessian-vector
-products inside TRON's CG loop) is not counted.
-
-Per objective evaluation on a batch with NNZ feature slots:
-  * forward margins (matvec / gather-dot):   2 * NNZ
-  * backward gradient (rmatvec / scatter):   2 * NNZ
-so one value-and-gradient pass = 4 * NNZ flops
-(reference hot loop being replaced: ValueAndGradientAggregator.scala:240-255).
-
-For vmapped random-effect solves the per-entity evaluation count is not
-individually tracked; we use 2 evaluations per L-BFGS iteration (one
-accepted step + ~one line-search probe), again a deliberate estimate that
-is labelled as such in the bench output.
+``device_peaks`` is the one table of per-chip figures, keyed by device
+kind; a CPU has none, so every share derived from it is ``None`` there.
+``stream_overlap_utilization`` and ``re_block_overlap`` turn a pipeline's
+reader/consumer clocks into an overlap efficiency and a host->device
+bandwidth share; ``re_peak_hbm`` publishes the blocked random-effect
+planner's predicted peak beside the measured one.
 """
 
 from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
-import numpy as np
-
-from photon_tpu.ops import features as F
 
 class Peaks(NamedTuple):
     flops: float      # bf16/native-matmul FLOP/s per chip
@@ -70,18 +56,6 @@ def device_peaks(device) -> tuple:
         f"source")
 
 
-def peak_flops(device) -> tuple:
-    """(peak FLOP/s | None on CPU, device_kind)."""
-    peaks, kind = device_peaks(device)
-    return (None if peaks is None else peaks.flops), kind
-
-
-def peak_hbm_bw(device) -> tuple:
-    """(peak HBM bytes/s | None on CPU, device_kind)."""
-    peaks, kind = device_peaks(device)
-    return (None if peaks is None else peaks.hbm_bw), kind
-
-
 def peak_h2d_bw(device) -> tuple:
     """(peak host->device bytes/s | None on CPU, device_kind)."""
     peaks, kind = device_peaks(device)
@@ -111,8 +85,8 @@ def stream_overlap_utilization(reader_busy_s: float, consumer_stall_s: float,
     ``h2d_bw_util`` is the achieved host->device byte rate over the pass
     against the chip's nominal transfer peak (``None``, gauge unset, on a
     CPU). Both land as gauges (``perf.stream_overlap`` /
-    ``perf.h2d_bw_util``) so every RunReport snapshot carries them, and
-    the returned dict goes into bench records.
+    ``perf.h2d_bw_util``) so every RunReport snapshot carries them; the
+    same numbers come back as a dict.
     """
     import jax
 
@@ -153,7 +127,7 @@ def re_block_overlap(reader_busy_s: float, consumer_stall_s: float,
     stages bucket b+1 while bucket b solves; the only staging time the
     solver ever saw was its own stalls waiting on the queue. Lands as
     ``perf.re_block_overlap{coordinate}`` / ``perf.re_h2d_bw_util
-    {coordinate}`` gauges and a dict for bench records."""
+    {coordinate}`` gauges and comes back as a dict."""
     import jax
 
     from photon_tpu.obs.metrics import registry
@@ -188,8 +162,8 @@ def re_peak_hbm(coordinate: str, planned_bytes: int,
                 measured_bytes: int) -> dict:
     """Publish a blocked/swept random-effect pass's peak device
     footprint: the ``parallel/memory`` planner's prediction next to the
-    measured peak (on CPU backends the measurement is an array-bytes /
-    RSS proxy — see bench.py --mode re_sweep). Both land as
+    measured peak (on CPU backends the measurement is an array-bytes
+    proxy). Both land as
     ``perf.re_peak_hbm_bytes{coordinate, kind}`` gauges so every
     RunReport snapshot carries the planned-vs-measured pair; the
     acceptance contract is planned >= measured on every bucket."""
@@ -205,110 +179,3 @@ def re_peak_hbm(coordinate: str, planned_bytes: int,
         "measured_peak_bytes": int(measured_bytes),
         "within_plan": bool(int(measured_bytes) <= int(planned_bytes)),
     }
-
-
-def _nnz_slots(features) -> int:
-    """Feature slots touched per objective pass (dense: n*d; ELL: n*K)."""
-    if isinstance(features, F.SparseFeatures):
-        return int(np.prod(features.values.shape))
-    return int(np.prod(features.shape))
-
-
-def value_grad_pass_bytes(features, dim: int, fused: bool = False) -> int:
-    """HBM bytes one value+gradient evaluation must move, from shapes:
-    the feature stream (dense f32 tile or ELL int32 index + f32 value
-    slots), the per-sample vectors (labels, offsets, weights), and the
-    coefficient/gradient vectors. The XLA two-contraction path streams
-    the features TWICE (margins, then the transposed contraction);
-    ``fused=True`` models the single-HBM-pass Pallas kernels
-    (ops/pallas_glm.py). A deliberate lower bound — intermediates that
-    XLA may spill are not counted."""
-    nnz = _nnz_slots(features)
-    if isinstance(features, F.SparseFeatures):
-        n = int(features.values.shape[0])
-        stream = nnz * (4 + 4)            # int32 index + f32 value
-    else:
-        n = int(features.shape[0])
-        stream = nnz * int(np.dtype(features.dtype).itemsize)
-    passes = 1 if fused else 2
-    return passes * stream + 3 * n * 4 + 2 * int(dim) * 4
-
-
-def phase_utilization(model_flops: int, bytes_moved: int, seconds: float,
-                      device=None, phase: str = "solve") -> dict:
-    """MFU and HBM-bandwidth-utilization estimate for one solve phase.
-
-    Both are model-work ratios against chip peaks — deliberate lower
-    bounds computed from shapes, not hardware counters; on a CPU both
-    are ``None`` and the gauges stay unset. The dict lands in bench
-    records, and the two gauges (``perf.mfu`` / ``perf.hbm_bw_util``
-    with a ``phase`` label) put the same numbers in every RunReport via
-    the metrics-registry snapshot."""
-    import jax
-
-    from photon_tpu.obs.metrics import registry
-
-    if device is None:
-        device = jax.devices()[0]
-    peaks, kind = device_peaks(device)
-    seconds = max(float(seconds), 1e-12)
-    mfu = _share(model_flops / seconds, peaks and peaks.flops)
-    bw_util = _share(bytes_moved / seconds, peaks and peaks.hbm_bw)
-    if peaks is not None:
-        registry.gauge("perf.mfu", phase=phase).set(mfu)
-        registry.gauge("perf.hbm_bw_util", phase=phase).set(bw_util)
-    return {
-        "phase": phase,
-        "device_kind": kind,
-        "model_flops": int(model_flops),
-        "bytes_moved": int(bytes_moved),
-        "seconds": float(seconds),
-        "mfu": mfu,
-        "hbm_bw_utilization": bw_util,
-        "peak_flops": peaks and peaks.flops,
-        "peak_hbm_bw": peaks and peaks.hbm_bw,
-    }
-
-
-def fixed_effect_flops(coord) -> int:
-    """Model flops of a FixedEffectCoordinate's last solve."""
-    result = getattr(coord, "last_result", None)
-    if result is None:
-        return 0
-    evals = int(np.asarray(result.num_fun_evals))
-    return evals * 4 * _nnz_slots(coord.batch.features)
-
-
-def random_effect_flops(coord) -> int:
-    """Estimated model flops of a RandomEffectCoordinate's last solve:
-    sum over entities of (2 evals/iter * iters) * 4 * S_b * K_b."""
-    tracker = getattr(coord, "last_tracker", None)
-    if tracker is None:
-        return 0
-    iters = np.maximum(np.asarray(tracker.iterations), 0)
-    total = 0
-    for blk in coord.dataset.blocks:
-        ents = np.asarray(blk.entity_rows)
-        valid = ents < iters.shape[0]
-        it_b = int(iters[ents[valid]].sum())
-        per_eval = 4 * blk.max_samples * blk.features.values.shape[-1]
-        total += 2 * it_b * per_eval
-    return total
-
-
-def estimator_sweep_flops(estimator) -> int:
-    """Model flops of the LAST coordinate-descent sweep of a fitted
-    GameEstimator (each coordinate's trackers reflect its final update)."""
-    from photon_tpu.game.coordinate import (
-        FixedEffectCoordinate,
-        RandomEffectCoordinate,
-    )
-
-    coords = getattr(estimator, "_coordinates", None) or {}
-    total = 0
-    for coord in coords.values():
-        if isinstance(coord, FixedEffectCoordinate):
-            total += fixed_effect_flops(coord)
-        elif isinstance(coord, RandomEffectCoordinate):
-            total += random_effect_flops(coord)
-    return total

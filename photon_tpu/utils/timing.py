@@ -14,8 +14,8 @@ spans.py): when telemetry is enabled, every Timed block additionally
 records a nested trace span (Perfetto-exportable, aligned with device
 traces via jax.profiler.TraceAnnotation) and lands in the RunReport's
 phase list. The legacy ``_TIMINGS`` registry keeps its exact behavior —
-and is now thread-safe, so concurrent RE solves and the bench harness
-can't corrupt or interleave the summary.
+and is now thread-safe, so concurrent RE solves can't corrupt or
+interleave the summary.
 
 It is the one primitive that records with telemetry OFF, which is why
 the set-up phases of a fit are ``Timed`` (``ingest/prepare/<coordinate>

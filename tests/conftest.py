@@ -20,7 +20,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 # is keyed on the HLO hash (same executable bytes, bitwise-same results)
 # and trace/compile COUNTS (jitcache, compile monitors) are unaffected;
 # only backend-compile wall time shrinks. Env vars (not jax.config) so
-# subprocess tests (cli/serve, bench --quick smokes) inherit it too.
+# subprocess tests (cli/serve, the no-recompile script) inherit it too.
 os.environ.setdefault(
     "JAX_COMPILATION_CACHE_DIR",
     os.path.normpath(os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -2,8 +2,8 @@
 LRU/promotion mechanics, lazy serving loads, and the blocked
 (cold-tier-streaming) training mode.
 
-Engine-level tier-boundary parity and the coldtier bench smoke live in
-tests/test_serving.py; this file covers the store and training layers
+Engine-level tier-boundary parity and the two-tier store under Zipf
+traffic live in tests/test_serving.py; this file covers the store and training layers
 directly.
 """
 

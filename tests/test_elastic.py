@@ -31,14 +31,14 @@ Covers the elastic contract end to end on CPU:
     ``decommission_shard`` manifest discipline, v1 refusal,
   * the autoscaler: gauge-share decisions on synthetic snapshots and a
     full split -> drain round trip under traffic,
-  * the tier-1 ``--mode elastic --quick`` bench smoke.
+  * the whole lifecycle under replayed traffic: a gauge-driven split
+    and a drain back down mid-replay, then a chaos kill mid-copy and
+    its resume, one case a gate.
 """
 
 import json
 import os
 import shutil
-import subprocess
-import sys
 import tempfile
 import time
 import zlib
@@ -47,8 +47,6 @@ import numpy as np
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from photon_tpu.io.cold_store import (
     ColdStore,
@@ -730,28 +728,302 @@ class TestAutoscaler:
             fleet.shutdown()
 
 
-# -- the tier-1 elastic bench smoke ------------------------------------------
+# -- the elastic lifecycle under replayed traffic, end to end ---------------
+#
+# A v2 virtual-bucket fleet (two-tier stores) serves a deterministic
+# Zipf+burst stream on a virtual clock while scheduled actions drive a
+# gauge-driven hot-shard split and a drain back down mid-replay; then a
+# chaos kill mid-copy resumes. One run; each gate is one case.
+
+_Q_E, _Q_K, _Q_D, _Q_NB = 64, 2, 16, 32
+_Q_INTERVAL, _Q_TICK = 0.25, 0.05
 
 
-def test_elastic_quick_bench_smoke():
-    """Tier-1 smoke: the elastic bench's quick shape end to end —
-    replayed traffic, a live split and drain, chaos kill + resume — no
-    artifact write."""
-    bench = os.path.join(REPO, "bench.py")
-    proc = subprocess.run(
-        [sys.executable, bench, "--mode", "elastic", "--quick"],
-        capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rec = json.loads(proc.stdout.splitlines()[-1])
-    assert rec["metric"] == "elastic_migration_gates_passed"
-    assert rec["quick"] is True
-    assert rec["value"] == 1.0
-    gates = rec["gates"]
-    assert gates["scale_out_completed"] is True
-    assert gates["scale_in_completed"] is True
-    assert gates["zero_downtime"] is True
-    assert gates["double_read_parity"] is True
-    assert gates["zero_steady_state_compiles"] is True
-    assert gates["survivor_bitwise_parity"] is True
-    assert gates["chaos_kill_resume"] is True
+def _quick_model_dir(out_dir, seed):
+    """Saved GAME model whose entity ids match the replay generator's
+    ``e{:09d}``: a fixed effect on shard ``g`` plus a cold-backed
+    ``per_user`` coordinate. Returns the entity ids."""
+    import jax.numpy as jnp
+
+    from photon_tpu.game.dataset import EntityVocabulary
+    from photon_tpu.game.model import (
+        FixedEffectModel,
+        GameModel,
+        RandomEffectModel,
+    )
+    from photon_tpu.io.index_map import IndexMap, feature_key
+    from photon_tpu.io.model_io import save_game_model
+    from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
+    from photon_tpu.types import TaskType
+
+    rng = np.random.default_rng(seed)
+    imap = IndexMap({feature_key(f"f{j}", ""): j for j in range(_Q_D)})
+    ids = [f"e{i:09d}" for i in range(_Q_E)]
+    coef = rng.normal(size=(_Q_E, _Q_K)).astype(np.float32)
+    proj = np.zeros((_Q_E, _Q_K), np.int32)
+    for e in range(_Q_E):
+        proj[e] = np.sort(rng.choice(_Q_D, size=_Q_K, replace=False))
+    fixed = FixedEffectModel(
+        GeneralizedLinearModel(
+            Coefficients(jnp.asarray(
+                rng.normal(size=_Q_D).astype(np.float32))),
+            TaskType.LINEAR_REGRESSION), "g")
+    rem = RandomEffectModel(
+        coefficients=jnp.asarray(coef), random_effect_type="userId",
+        feature_shard_id="g", task=TaskType.LINEAR_REGRESSION)
+    vocab = EntityVocabulary()
+    vocab.build("userId", ids)
+    save_game_model(out_dir, GameModel({"global": fixed, "per_user": rem}),
+                    {"g": imap}, vocab=vocab,
+                    projections={"per_user": proj}, sparsity_threshold=0.0)
+    return ids
+
+
+def _compile_monitors(fleet):
+    """Steady-state compile events, jitcache misses and per-program
+    trace counts over every engine of the fleet."""
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.serving.scorer import get_scorer, serving_modes
+
+    engines = [fleet.front] + [c.engine for c in fleet.clients]
+    programs = [get_scorer(e.model, mode, b) for e in engines
+                for mode in serving_modes(e.model)
+                for b in e.ladder.buckets]
+    jitted = [p if hasattr(p, "_cache_size")
+              else getattr(p, "__wrapped__", p) for p in programs]
+    return (compile_cache.compile_counts()["steady_state"],
+            registry.counter("jitcache.misses").value,
+            [f._cache_size() for f in jitted if hasattr(f, "_cache_size")])
+
+
+@pytest.fixture(scope="module")
+def elastic_lifecycle():
+    from photon_tpu import obs
+    from photon_tpu.obs import slo
+    from photon_tpu.obs import timeseries as tsmod
+    from photon_tpu.serving import CoeffStoreConfig, ScoreRequest
+    from photon_tpu.serving import ServingConfig, SLOConfig
+    from photon_tpu.serving.replay import (
+        Replayer,
+        TrafficProfile,
+        VirtualClock,
+        generate,
+    )
+
+    seed = 32
+    max_batch, n_requests, base_qps, n_probe = 16, 1_000, 150.0, 24
+    interval0 = tsmod.series.interval_s
+    # every windowed series shares one window grid on the virtual clock
+    tsmod.series.interval_s = _Q_INTERVAL
+    obs.reset()
+    td = tempfile.mkdtemp(prefix="elastic_q_")
+    try:
+        profile = TrafficProfile(
+            kind="burst", n_requests=n_requests, entities=_Q_E, zipf_a=1.5,
+            base_qps=base_qps, feature_dim=_Q_D, nnz=4, burst_at_s=1.0,
+            burst_len_s=1.0, burst_factor=3.0)
+        records = generate(profile, seed)
+        ts_all = [t for t, _ in records]
+        # split opens inside the burst, drains after it
+        t_split = ts_all[int(0.25 * n_requests)]
+        t_split_done = ts_all[int(0.45 * n_requests)]
+        t_drain = ts_all[int(0.65 * n_requests)]
+        t_drain_done = ts_all[int(0.80 * n_requests)]
+
+        mdir, fdir = os.path.join(td, "model"), os.path.join(td, "fleet")
+        ids = _quick_model_dir(mdir, seed)
+        build_fleet_dir(mdir, fdir, 2, num_buckets=_Q_NB)
+        clk = VirtualClock()
+        serving_cfg = ServingConfig(
+            max_batch=max_batch, max_wait_s=0.0,
+            slo=SLOConfig(shed_queue_depth=5_000, reject_queue_depth=10_000),
+            coeff_store=CoeffStoreConfig(hot_capacity=256, transfer_batch=8))
+        fleet = ShardedServingFleet.from_fleet_dir(
+            fdir, FleetConfig(serving=serving_cfg), clock=clk)
+        fleet.warmup()
+
+        frng = np.random.default_rng(seed)
+        id_bucket = {eid: entity_bucket(eid, _Q_NB) for eid in ids}
+
+        def req(uid, eid):
+            cols = frng.choice(_Q_D, size=4, replace=False)
+            return ScoreRequest(
+                uid, {"g": [(f"f{c}", "", float(frng.normal()))
+                            for c in cols]}, {"userId": eid})
+
+        # promote every entity first: degradation gates then measure the
+        # migrations, not promotion cold misses
+        all_reqs = [req(f"s{i}", eid) for i, eid in enumerate(ids)]
+        _settle(fleet, all_reqs, rounds=10)
+        probes = [req(f"p{i}", ids[i]) for i in range(n_probe)]
+        base_bits = _score_bits(_settle(fleet, probes, rounds=10))
+        mon0 = _compile_monitors(fleet)
+        scaler = HotShardAutoscaler(
+            fleet,
+            AutoscaleConfig(hot_factor=1.02, cold_factor=0.25, min_shards=2,
+                            max_shards=3, buckets_per_step=2,
+                            lookback_windows=8, min_total=1.0),
+            serving=serving_cfg)
+        st = {"parity": [], "windows": [], "split": {}, "drain": {}}
+
+        def migrated_reqs(buckets):
+            bset = {int(b) for b in buckets}
+            sub = [r for r, eid in zip(all_reqs, ids)
+                   if id_bucket[eid] in bset]
+            return sub[:max_batch * 4] or probes
+
+        def window_counts():
+            wins = fleet.migration_windows().values()
+            return {"double_reads": sum(w["double_reads"] for w in wins),
+                    "mismatches": sum(w["mismatches"] for w in wins)}
+
+        def open_window(plan, phase):
+            # warm the destination through the double-read mirrors, so
+            # replayed traffic compares bitwise instead of cold-missing
+            st[phase].update(buckets=[int(b) for b in plan["buckets"]],
+                             t_open=clk.now())
+            warm = migrated_reqs(plan["buckets"])
+            for _ in range(4):
+                fleet.serve(warm)
+                _drain(fleet)
+            st["parity"].append(_score_bits(fleet.serve(probes)))
+
+        def close_window(phase):
+            st["windows"].append(window_counts())
+            done = scaler.finish()
+            st[phase].update(t_cutover=clk.now(),
+                             results=len(done["results"]),
+                             num_shards=fleet.num_shards)
+            _settle(fleet, migrated_reqs(st[phase]["buckets"]), rounds=10)
+            st["parity"].append(_score_bits(
+                _settle(fleet, probes, rounds=10)))
+
+        def act_split():
+            dec = scaler.decide()
+            st["gauge_decision"] = dict(dec) if dec else None
+            if not (dec and dec["action"] == "split"):
+                shares = scaler.shard_shares()
+                dec = {"action": "split",
+                       "shard": max(shares, key=lambda s: (shares[s], -s))}
+            plan = scaler.step(dec)
+            st["split"]["new_shard"] = int(plan["new_shard"])
+            open_window(plan, "split")
+
+        def act_split_done():
+            close_window("split")
+            sp = st["split"]
+            sp["owners_moved"] = all(
+                fleet.bucket_map.shard_of(b) == sp["new_shard"]
+                for b in sp["buckets"])
+
+        def act_drain():
+            st["drain"]["shard"] = st["split"]["new_shard"]
+            open_window(scaler.step({"action": "drain",
+                                     "shard": st["drain"]["shard"]}),
+                        "drain")
+
+        def act_drain_done():
+            close_window("drain")
+            dr = st["drain"]
+            dr["owners_off"] = all(fleet.bucket_map.shard_of(b) != dr["shard"]
+                                   for b in dr["buckets"])
+
+        res = Replayer(fleet, clk, tick_s=_Q_TICK).run(
+            records, [(t_split, act_split), (t_split_done, act_split_done),
+                      (t_drain, act_drain), (t_drain_done, act_drain_done)])
+        mon1 = _compile_monitors(fleet)
+        compile_delta = (
+            (mon1[0] - mon0[0]) + (mon1[1] - mon0[1])
+            + sum(max(0, b - a) for a, b in zip(mon0[2], mon1[2])))
+
+        # chaos: kill the copy of the busiest bucket mid-flight, resume
+        loads = {b: sum(1 for eid in ids if id_bucket[eid] == b)
+                 for b in fleet.bucket_map.buckets_on(0)}
+        b2 = max(loads, key=lambda b: (loads[b], -b))
+        dst2 = next(s for s in fleet.bucket_map.shard_ids if s != 0)
+        killed = False
+        with chaos.active(chaos.ChaosConfig(
+                kill_publish_ops=("bucket_copy",))):
+            try:
+                BucketMigrator(fleet, b2, dst2).copy()
+            except chaos.SimulatedKill:
+                killed = True
+        j_kill = read_migration_journal(fdir)
+        served_during = _score_bits(fleet.serve(probes)) == base_bits
+        out = resume_migration(fleet)
+        ColdStore(shard_store_path(fdir, dst2, "per_user")).verify()
+        resumed = (out is not None and fleet.bucket_map.shard_of(b2) == dst2
+                   and read_migration_journal(fdir) is None)
+        _settle(fleet, migrated_reqs([b2]), rounds=10)
+        post_bits = _score_bits(_settle(fleet, probes, rounds=10))
+
+        # SLO verdicts: p99 breaches may only sit in migration windows
+        mig_idx = set()
+        for ph in (st["split"], st["drain"]):
+            if "t_open" in ph and "t_cutover" in ph:
+                mig_idx.update(range(
+                    int(ph["t_open"] // _Q_INTERVAL),
+                    int((ph["t_cutover"] + _Q_TICK) // _Q_INTERVAL) + 2))
+        rules = [
+            slo.P99Ceiling(
+                rule_id="p99", series="replay.latency",
+                ceiling_s=4 * _Q_TICK, qps_series="replay.responses",
+                qps_floor=0.25 * base_qps),
+            slo.MaxDegradationRate(
+                rule_id="no_shard_unavailable",
+                degraded_series="replay.degraded",
+                total_series="replay.responses", max_rate=0.0,
+                degraded_labels={"reason": "shard_unavailable"}),
+            slo.ZeroSteadyStateCompiles(rule_id="compiles"),
+        ]
+        by_rule = {v.rule_id: v for v in slo.evaluate(
+            slo.SLOSpec(rules), tsmod.series.snapshot(),
+            compile_delta=compile_delta)}
+        sp, dr = st["split"], st["drain"]
+        wins = st["windows"] + [{}, {}]
+        gates = {
+            "scale_out_completed": bool(
+                sp.get("owners_moved") and sp.get("results", 0) >= 1
+                and sp.get("num_shards") == 3),
+            "scale_in_completed": bool(
+                dr.get("owners_off") and dr.get("num_shards") == 2
+                and read_fleet_manifest(fdir)["num_shards"] == 2),
+            "gauge_driven_split": bool(
+                st.get("gauge_decision")
+                and st["gauge_decision"].get("action") == "split"),
+            "zero_downtime": bool(
+                all(b is not None for b in base_bits) and res.refusals == 0
+                and set(res.degraded_reasons) <= {"bucket_migrating"}
+                and by_rule["no_shard_unavailable"].status == slo.PASS),
+            "double_read_parity": all(
+                w.get("double_reads", 0) > 0 and w.get("mismatches", 1) == 0
+                for w in wins[:2]),
+            "zero_steady_state_compiles": bool(
+                compile_delta == 0
+                and by_rule["compiles"].status == slo.PASS),
+            "survivor_bitwise_parity": bool(
+                st["parity"]
+                and all(pb == base_bits for pb in st["parity"])),
+            "p99_outside_migration_windows": (
+                by_rule["p99"].status == slo.PASS
+                or {w["idx"] for w in by_rule["p99"].offending_windows}
+                <= mig_idx),
+            "chaos_kill_resume": bool(
+                killed and j_kill is not None and j_kill["phase"] == "copy"
+                and served_during and resumed and post_bits == base_bits),
+        }
+        fleet.shutdown()
+        yield gates
+    finally:
+        shutil.rmtree(td, ignore_errors=True)
+        tsmod.series.interval_s = interval0
+        obs.reset()
+
+
+@pytest.mark.parametrize("gate", [
+    "scale_out_completed", "scale_in_completed", "gauge_driven_split",
+    "zero_downtime", "double_read_parity", "zero_steady_state_compiles",
+    "survivor_bitwise_parity", "p99_outside_migration_windows",
+    "chaos_kill_resume"])
+def test_elastic_lifecycle_under_replay(elastic_lifecycle, gate):
+    assert elastic_lifecycle[gate] is True, elastic_lifecycle

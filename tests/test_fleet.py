@@ -19,8 +19,9 @@ Covers the fleet contract end to end on CPU:
   * hedging: a chaos-slowed shard is overtaken by the hedged second
     attempt,
   * obs: per-shard snapshots merge through ``merge_snapshots``,
-  * the shard-mode CLI entrypoint and the tier-1 ``--mode fleet
-    --quick`` bench smoke.
+  * the shard-mode CLI entrypoint,
+  * split -> per-shard serving -> one shard killed behind the router,
+    end to end.
 """
 
 import json
@@ -448,7 +449,7 @@ class TestFleetObs:
         fleet.shutdown()
 
 
-# -- CLI + bench smoke -------------------------------------------------------
+# -- CLI ---------------------------------------------------------------------
 
 
 class TestFleetCli:
@@ -492,20 +493,179 @@ class TestFleetCli:
         assert proc.returncode != 0
 
 
-def test_fleet_quick_bench_smoke():
-    """Tier-1 smoke: the fleet bench's quick shape end to end — split,
-    scaling curve, router kill segment — no artifact write."""
-    bench = os.path.join(REPO, "bench.py")
-    proc = subprocess.run(
-        [sys.executable, bench, "--mode", "fleet", "--quick"],
-        capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rec = json.loads(proc.stdout.splitlines()[-1])
-    assert rec["metric"] == "fleet_aggregate_qps_speedup"
-    assert rec["quick"] is True
-    assert rec["scaling_curve"]["2"]["aggregate_qps"] > 0
-    assert rec["scaling_curve"]["2"][
-        "zero_steady_state_compiles_all_shards"] is True
-    assert rec["kill_one_shard"]["typed_shard_unavailable"] > 0
-    assert rec["kill_one_shard"]["survivors_within_10pct"] is True
+# -- split -> per-shard serving -> kill one shard, end to end ----------------
+#
+# A 20,000-entity cold store is split across two shard stores by the
+# canonical partitioner; each shard engine serves the Zipf traffic it
+# owns under the compile monitors; then the router serves identical
+# traffic before and during a chaos kill of one shard. One run; each
+# gate is one case.
+
+_Q_E, _Q_K, _Q_D, _Q_NNZ, _Q_SEED = 20_000, 2, 32, 16, 13
+
+
+def _quick_row_ids(rows):
+    return np.char.add(b"e", np.char.zfill(
+        np.asarray(rows).astype("S9"), 9))
+
+
+def _quick_shard_engine(store_path, names, theta=None):
+    from photon_tpu.io.index_map import IndexMap, feature_key
+    from photon_tpu.io.model_io import (
+        ServingFixedEffect,
+        ServingGameModel,
+        ServingRandomEffect,
+    )
+    from photon_tpu.serving import DeviceResidentModel
+    from photon_tpu.types import TaskType
+
+    imap = IndexMap({feature_key(n, ""): i for i, n in enumerate(names)})
+    res = ([ServingRandomEffect("per_user", "userId", "g",
+                                cold_store_path=store_path)]
+           if store_path else [])
+    fixed = ([ServingFixedEffect("fixed", "g", theta)]
+             if theta is not None else [])
+    cs = (CoeffStoreConfig(hot_capacity=512, transfer_batch=64)
+          if store_path else None)
+    m = ServingGameModel(TaskType.LINEAR_REGRESSION, fixed, res,
+                         {"g": imap}, {})
+    return ServingEngine(DeviceResidentModel(m, coeff_store=cs),
+                         ServingConfig(max_batch=64, max_wait_s=0.001,
+                                       coeff_store=cs))
+
+
+def _quick_serve_owned(engine, names, rows, seed, n_warm=250,
+                       n_steady=400):
+    """Warm, then serve ``n_steady`` owned requests under the three
+    compile monitors. Returns (responses served, zero compiles)."""
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.serving.scorer import get_scorer, serving_modes
+    from photon_tpu.utils import compile_cache
+
+    rng = np.random.default_rng(seed)
+    rows = list(rows) * ((n_warm + n_steady) // max(len(rows), 1) + 1)
+
+    def req(i):
+        cols = rng.choice(_Q_D, size=_Q_NNZ, replace=False)
+        return ScoreRequest(f"q{i}", {"g": [(names[c], "", float(
+            rng.normal())) for c in cols]}, {"userId": f"e{rows[i]:09d}"})
+
+    for i in range(n_warm):
+        engine.submit(req(i))
+        if i % 256 == 255:
+            engine.pump()
+    engine.drain()
+    engine.model.drain_prefetch()
+    jitted = [get_scorer(engine.model, mode, b)
+              for mode in serving_modes(engine.model)
+              for b in engine.ladder.buckets]
+    jitted = [f if hasattr(f, "_cache_size")
+              else getattr(f, "__wrapped__", f) for f in jitted]
+    jitted = [f for f in jitted if hasattr(f, "_cache_size")]
+    compiles0 = compile_cache.compile_counts()["steady_state"]
+    misses0 = registry.counter("jitcache.misses").value
+    traces0 = [f._cache_size() for f in jitted]
+    done = 0
+    for i in range(n_warm, n_warm + n_steady):
+        engine.submit(req(i))
+        done += len(engine.pump())
+    done += len(engine.drain())
+    zero = (compile_cache.compile_counts()["steady_state"] == compiles0
+            and registry.counter("jitcache.misses").value == misses0
+            and all(t1 <= t0 for t0, t1 in zip(
+                traces0, [f._cache_size() for f in jitted])))
+    return done, zero
+
+
+@pytest.fixture(scope="module")
+def fleet_quick_run():
+    from photon_tpu.io.cold_store import COLD_STORE_DIR, write_cold_store
+    from photon_tpu.serving import LocalShardClient
+
+    rng = np.random.default_rng(_Q_SEED)
+    names = [f"g{j}" for j in range(_Q_D)]
+    coef = rng.normal(size=(_Q_E, _Q_K)).astype(np.float32)
+    lo = rng.integers(0, _Q_D - 1, size=_Q_E)
+    hi = rng.integers(lo + 1, _Q_D)
+    proj = np.stack([lo, hi], axis=1).astype(np.int32)
+    theta = rng.normal(size=_Q_D).astype(np.float32)
+    with tempfile.TemporaryDirectory(prefix="fleet_q_") as td:
+        mdir, fdir = os.path.join(td, "model"), os.path.join(td, "fleet")
+        os.makedirs(os.path.join(mdir, COLD_STORE_DIR))
+        write_cold_store(cold_store_path(mdir, "per_user"), "per_user",
+                         "userId", "g", coef, proj,
+                         _quick_row_ids(np.arange(_Q_E)))
+        build_fleet_dir(mdir, fdir, 2)
+        manifest = read_fleet_manifest(fdir)
+
+        trng = np.random.default_rng(_Q_SEED)
+        rows = (trng.zipf(1.5, size=2 * 680 + 64) - 1) % _Q_E
+        owners = entity_shards(_quick_row_ids(rows), 2)
+        served, zero = [], []
+        for s in range(2):
+            eng = _quick_shard_engine(shard_store_path(fdir, s, "per_user"),
+                                      names)
+            eng.warmup()
+            done, ok = _quick_serve_owned(eng, names, rows[owners == s],
+                                          seed=_Q_SEED + 1000 + s)
+            eng.shutdown()
+            served.append(done)
+            zero.append(ok)
+
+        # the router over both shards; identical traffic before and
+        # during the kill
+        cs = CoeffStoreConfig(hot_capacity=512, transfer_batch=64)
+        cfg = ServingConfig(max_batch=64, max_wait_s=0.001, coeff_store=cs)
+        clients = []
+        for s in range(2):
+            eng = _quick_shard_engine(shard_store_path(fdir, s, "per_user"),
+                                      names)
+            clients.append(LocalShardClient(s, eng))
+        fleet = ShardedServingFleet(
+            _quick_shard_engine(None, names, theta), clients,
+            [("per_user", "userId")], FleetConfig(serving=cfg))
+        fleet.warmup()
+        frng = np.random.default_rng(_Q_SEED + 7)
+        krows = (frng.zipf(1.5, size=20 * 64) - 1) % _Q_E
+        batches = [[ScoreRequest(
+            f"k{b * 64 + i}",
+            {"g": [(names[c], "", float(frng.normal()))
+                   for c in frng.choice(_Q_D, size=_Q_NNZ, replace=False)]},
+            {"userId": f"e{krows[b * 64 + i]:09d}"}) for i in range(64)]
+            for b in range(20)]
+
+        def segment():
+            scored = degraded = 0
+            for batch in batches:
+                for r in fleet.serve(batch):
+                    scored += r.score is not None
+                    degraded += any(
+                        f.reason == FallbackReason.SHARD_UNAVAILABLE
+                        for f in r.fallbacks)
+            return scored, degraded
+
+        segment()                          # promote the kill rows
+        pre_scored, pre_degraded = segment()
+        with chaos.active(chaos.ChaosConfig(shard_kill_id=1)):
+            post_scored, post_degraded = segment()
+        counters = fleet.stats()["merged"]["counters"]
+        fleet.shutdown()
+    n = 20 * 64
+    yield {
+        "manifest_verified": manifest["num_shards"] == 2,
+        "every_shard_served_its_traffic": served == [400, 400],
+        "zero_steady_state_compiles_all_shards": all(zero),
+        "no_degradation_before_kill": pre_degraded == 0,
+        "typed_shard_unavailable": (
+            post_degraded > 0
+            and counters["fleet.shard.unavailable"] > 0),
+        "no_score_dropped": pre_scored == n and post_scored == n,
+    }
+
+
+@pytest.mark.parametrize("gate", [
+    "manifest_verified", "every_shard_served_its_traffic",
+    "zero_steady_state_compiles_all_shards", "no_degradation_before_kill",
+    "typed_shard_unavailable", "no_score_dropped"])
+def test_fleet_split_serve_kill(fleet_quick_run, gate):
+    assert fleet_quick_run[gate] is True, fleet_quick_run

@@ -13,13 +13,12 @@ Covers the whole loop against live engines on CPU:
     recovery), kill mid cold-store delta (torn-update refusal + heal by
     replay from the unadvanced watermark),
   * admission lookahead: pending-publish rows are never prefetched,
-  * obs (RunReport section), the CLI driver, and the quick bench smoke.
+  * obs (RunReport section), the CLI driver, and the closed loop:
+    delta rounds published while a thread serves the same engine.
 """
 
 import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -27,8 +26,6 @@ import numpy as np
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from photon_tpu.io.cold_store import (
     ColdStore,
@@ -585,7 +582,7 @@ def test_on_admit_defers_prefetch_of_pending_publish_rows():
             engine.shutdown()
 
 
-# -- obs + cli + bench wiring ------------------------------------------------
+# -- obs + cli ---------------------------------------------------------------
 
 
 def test_run_report_has_nearline_section():
@@ -643,25 +640,172 @@ def test_cli_nearline_end_to_end(tmp_path):
     set_active(None)
 
 
-def test_bench_nearline_quick_smoke():
-    """The quick nearline bench is the closed-loop smoke: model dir ->
-    two-tier engine -> concurrent serving + delta rounds -> freshness /
-    compile / qps-ratio checks, all CPU-sized. Asserts the record's
-    pass/fail fields rather than the timing numbers."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--mode", "nearline", "--quick"],
-        capture_output=True, text=True, timeout=300,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rec = json.loads([l for l in proc.stdout.splitlines()
-                      if l.startswith("{")][-1])
-    assert rec["metric"] == "nearline_freshness_lag_p50"
-    assert rec["publishes"] >= 1
-    assert rec["rows_published"] > 0
-    assert rec["zero_steady_state_compiles"] is True
-    assert rec["publish_parity_ok"] is True
-    assert rec["quick"] is True
+# -- closed loop: serving on one thread, delta rounds on another ------------
+#
+# A two-tier engine over 200 entities scores closed-loop traffic from a
+# serving thread while the nearline loop (event log -> delta train ->
+# row-level live publish, appends included) runs rounds against it. One
+# run; each gate is one case.
+
+
+def _quick_model_dir(out_dir, E=200, K=2, d=32, seed=29):
+    import jax.numpy as jnp
+
+    from photon_tpu.game.dataset import EntityVocabulary
+    from photon_tpu.game.model import (
+        FixedEffectModel,
+        GameModel,
+        RandomEffectModel,
+    )
+    from photon_tpu.io.index_map import IndexMap, feature_key
+    from photon_tpu.io.model_io import save_game_model
+    from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
+    from photon_tpu.types import TaskType
+
+    rng = np.random.default_rng(seed)
+    names = [f"g{j}" for j in range(d)]
+    imap = IndexMap({feature_key(n, ""): i for i, n in enumerate(names)})
+    ids = [f"e{e:09d}" for e in range(E)]
+    lo = rng.integers(0, d - 1, size=E)
+    proj = np.stack([lo, rng.integers(lo + 1, d)], axis=1).astype(np.int32)
+    fixed = FixedEffectModel(GeneralizedLinearModel(
+        Coefficients(jnp.asarray(rng.normal(size=d).astype(np.float32))),
+        TaskType.LINEAR_REGRESSION), "g")
+    rem = RandomEffectModel(
+        coefficients=jnp.asarray(rng.normal(size=(E, K)).astype(np.float32)),
+        random_effect_type="userId", feature_shard_id="g",
+        task=TaskType.LINEAR_REGRESSION)
+    vocab = EntityVocabulary()
+    vocab.build("userId", ids)
+    save_game_model(out_dir, GameModel({"global": fixed, "per_user": rem}),
+                    {"g": imap}, vocab=vocab,
+                    projections={"per_user": proj}, sparsity_threshold=0.0)
+    return names, ids
+
+
+@pytest.fixture(scope="module")
+def nearline_closed_loop():
+    import threading
+
+    from photon_tpu.serving.scorer import MODES, get_scorer
+    from photon_tpu.utils import compile_cache
+
+    n_rounds, ents_per_round, max_batch, nnz = 3, 16, 8, 8
+    rng = np.random.default_rng(29)
+    with tempfile.TemporaryDirectory(prefix="nearline_q_") as td:
+        mdir = os.path.join(td, "model")
+        names, ids = _quick_model_dir(mdir)
+        engine = ServingEngine.from_model_dir(mdir, config=ServingConfig(
+            max_batch=max_batch, max_wait_s=0.0,
+            slo=SLOConfig(shed_queue_depth=200, reject_queue_depth=400),
+            coeff_store=CoeffStoreConfig(hot_capacity=64,
+                                         transfer_batch=16)))
+        engine.warmup()
+        zipf_rows = (rng.zipf(1.4, size=1 << 16) - 1) % len(ids)
+        zi = [0]
+
+        def req(i):
+            row = int(zipf_rows[zi[0] % len(zipf_rows)])
+            zi[0] += 1
+            cols = rng.choice(len(names), size=nnz, replace=False)
+            return ScoreRequest(f"q{i}", {"g": [
+                (names[c], "", float(rng.normal())) for c in cols]},
+                {"userId": ids[row]})
+
+        def event(user, ts):
+            cols = rng.choice(len(names), size=nnz, replace=False)
+            return {"ts": ts, "response": float(rng.normal()),
+                    "features": {"g": [[names[c], "", float(rng.normal())]
+                                       for c in cols]},
+                    "entities": {"userId": user}}
+
+        log_dir = os.path.join(td, "events")
+        writer = EventLogWriter(log_dir)
+        pipe = _pipeline(engine, log_dir, mdir)
+        # warm rounds: the trainer's programs at the measured rounds'
+        # entity count, and the publisher end to end, appends included
+        for i in range(256):
+            engine.submit(req(i))
+            if i % 64 == 63:
+                engine.pump()
+        engine.drain()
+        engine.model.drain_prefetch()
+        uniq = sorted({ids[int(r)] for r in zipf_rows[:512]})
+        writer.append([event(u, time.time()) for u in uniq[:ents_per_round]])
+        warm_ok = pipe.run_round().get("publish", {}).get("accepted")
+        writer.append([event(u, time.time()) for u in ("nb_new0", "nb_new1")])
+        warm_ok = warm_ok and pipe.run_round().get(
+            "publish", {}).get("accepted")
+
+        jitted = [get_scorer(engine.model, mode, b)
+                  for mode in MODES for b in engine.ladder.buckets]
+        jitted = [p if hasattr(p, "_cache_size")
+                  else getattr(p, "__wrapped__", p) for p in jitted]
+        jitted = [f for f in jitted if hasattr(f, "_cache_size")]
+        compiles0 = compile_cache.compile_counts()["steady_state"]
+        misses0 = _metrics.counter("jitcache.misses").value
+        traces0 = [f._cache_size() for f in jitted]
+
+        stop, served = threading.Event(), [0]
+
+        def serve_loop():
+            i = 1 << 20
+            while not stop.is_set():
+                engine.serve([req(i + j) for j in range(max_batch)])
+                served[0] += max_batch
+                i += max_batch
+
+        th = threading.Thread(target=serve_loop, daemon=True)
+        th.start()
+        accepted = rows_pub = 0
+        verify_ok = True
+        try:
+            for rnd in range(n_rounds):
+                users = sorted({uniq[(rnd * ents_per_round + j) % len(uniq)]
+                                for j in range(ents_per_round)})
+                writer.append([event(u, time.time()) for u in users])
+                pub = pipe.run_round().get("publish")
+                if pub and pub.get("accepted"):
+                    accepted += 1
+                    rows_pub += pub["rows_updated"] + pub["rows_appended"]
+                    verify_ok &= pub["gates"].get("verify") == "pass"
+                else:
+                    verify_ok = False
+        finally:
+            stop.set()
+            th.join()
+        zero = (compile_cache.compile_counts()["steady_state"] == compiles0
+                and _metrics.counter("jitcache.misses").value == misses0
+                and all(t1 <= t0 for t0, t1 in zip(
+                    traces0, [f._cache_size() for f in jitted])))
+
+        # a touched entity's served row is its cold-tier row, bitwise
+        rs = engine.model.random[0]
+        served_row = current_entity_row(rs, uniq[0],
+                                        engine.model.shard_dims["g"])
+        r = rs.store.cold.entity_row(uniq[0])
+        parity = (served_row is not None
+                  and served_row[0].tobytes()
+                  == np.array(rs.store.cold.coef[r], np.float32).tobytes()
+                  and served_row[1].tobytes()
+                  == np.array(rs.store.cold.proj[r], np.int32).tobytes())
+        engine.shutdown()
+    yield {
+        "warm_publishes_accepted": bool(warm_ok),
+        "every_round_published": accepted == n_rounds,
+        "rows_published": rows_pub > 0,
+        "served_while_publishing": served[0] > 0,
+        "zero_steady_state_compiles": bool(zero),
+        "publish_parity_ok": bool(parity and verify_ok),
+    }
+
+
+@pytest.mark.parametrize("gate", [
+    "warm_publishes_accepted", "every_round_published", "rows_published",
+    "served_while_publishing", "zero_steady_state_compiles",
+    "publish_parity_ok"])
+def test_nearline_rounds_under_concurrent_serving(nearline_closed_loop, gate):
+    assert nearline_closed_loop[gate] is True, nearline_closed_loop
 
 
 # -- int8 serving arm: publish consistency + rollback ------------------------

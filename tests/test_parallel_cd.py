@@ -15,8 +15,6 @@ photon_tpu.resilience.chaos — no monkeypatching of library internals.
 
 import json
 import os
-import subprocess
-import sys
 import types
 
 import jax
@@ -569,23 +567,74 @@ class TestRunReportSection:
 
 
 # ---------------------------------------------------------------------------
-# bench smoke: the tier-1 wiring for bench.py --mode game_cd
+# one fixed effect + three random effects, sequential vs parallel sweeps
 # ---------------------------------------------------------------------------
+#
+# The workload whose sequential sweep is the sum of four solves: the
+# parallel mode groups the three random effects into one concurrency
+# group (frozen-score solves, ordered reconciliation, staleness guard
+# on), validating as it goes. One run of two sweeps each way; each gate
+# is one case.
 
 
-class TestBenchSmoke:
-    def test_bench_game_cd_quick(self):
-        bench = os.path.join(os.path.dirname(__file__), os.pardir, "bench.py")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, bench, "--mode", "game_cd", "--quick"],
-            capture_output=True, text=True, timeout=300, env=env)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        rec = json.loads([l for l in proc.stdout.splitlines()
-                          if l.startswith("{")][-1])
-        assert rec["metric"] == "game_cd_sweep_speedup"
-        assert rec["quick"] is True
-        assert rec["staleness_fallbacks"] == 0
-        assert rec["value"] > 0
-        assert rec["groups"] == [["fixed"],
-                                 ["per_user", "per_item", "per_ctx"]]
+@pytest.fixture(scope="module")
+def four_coordinate_sweeps():
+    n, d_g, d_u = 1_200, 16, 4
+    res = [("per_user", "userId", 24), ("per_item", "itemId", 18),
+           ("per_ctx", "ctxId", 12)]
+    rng = np.random.default_rng(7)
+    theta = rng.normal(size=d_g)
+    w_ents = {cid: rng.normal(size=(n_ent, d_u)) for cid, _t, n_ent in res}
+
+    def frame(m):
+        Xg = rng.normal(size=(m, d_g))
+        logits = Xg @ theta
+        shards, id_tags = {"g": FeatureShard(Xg, d_g)}, {}
+        iu = np.arange(d_u, dtype=np.int32)
+        for cid, tag, n_ent in res:
+            Xe = rng.normal(size=(m, d_u))
+            ent = rng.integers(0, n_ent, size=m)
+            logits = logits + np.einsum("ij,ij->i", Xe, w_ents[cid][ent])
+            shards[cid] = FeatureShard([(iu, Xe[i]) for i in range(m)], d_u)
+            id_tags[tag] = [str(v) for v in ent]
+        y = (rng.random(m) < 1.0 / (1.0 + np.exp(-logits))).astype(np.float64)
+        return GameDataFrame(num_samples=m, response=y, feature_shards=shards,
+                             id_tags=id_tags)
+
+    df, val_df = frame(n), frame(n)
+    opt = GLMOptimizationConfiguration(
+        optimizer=OptimizerConfig(max_iterations=40, tolerance=1e-8),
+        regularization=L2Regularization, regularization_weight=1.0)
+    configs = {"fixed": CoordinateConfiguration(
+        FixedEffectDataConfiguration("g"), opt)}
+    for cid, tag, _n in res:
+        configs[cid] = CoordinateConfiguration(
+            RandomEffectDataConfiguration(tag, cid), opt)
+    seq_ids = ["fixed"] + [cid for cid, _t, _n in res]
+    est = GameEstimator(TaskType.LOGISTIC_REGRESSION, configs,
+                        update_sequence=seq_ids, num_iterations=1)
+    est.fit(df, validation_df=val_df)
+    vocab, _c, re_datasets = est._prep_cache[2]
+    vfn = est._validation_fn(est._build_scorer(val_df, vocab, re_datasets),
+                             val_df)
+    cfg = CoordinateDescentConfig(update_sequence=seq_ids, num_iterations=2)
+    run_coordinate_descent(est._coordinates, cfg, n, validation_fn=vfn)
+    parallel_cd.reset()
+    run_coordinate_descent(
+        est._coordinates, CoordinateDescentConfig(
+            update_sequence=seq_ids, num_iterations=2, parallel=True),
+        n, validation_fn=vfn)
+    stats = (parallel_cd.report_section() or {}).get("parallel", {})
+    parallel_cd.reset()
+    return {
+        "zero_staleness_fallbacks": int(stats.get("fallbacks", -1)) == 0,
+        "random_effects_share_one_group": stats.get("groups") == [
+            ["fixed"], ["per_user", "per_item", "per_ctx"]],
+    }
+
+
+@pytest.mark.parametrize("gate", [
+    "zero_staleness_fallbacks", "random_effects_share_one_group"])
+def test_four_coordinates_parallel_vs_sequential(four_coordinate_sweeps,
+                                                 gate):
+    assert four_coordinate_sweeps[gate] is True, four_coordinate_sweeps

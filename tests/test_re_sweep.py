@@ -21,10 +21,6 @@ Contract under test (the random-effect half of the sweep machinery):
 """
 
 import dataclasses
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -36,8 +32,6 @@ from photon_tpu.optim.problem import (  # noqa: F401  (import order)
 )
 from photon_tpu.function.objective import L2Regularization
 from photon_tpu.parallel import memory as hbm
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 GRID = [0.1, 0.5, 2.0, 10.0]  # includes the λ=10 convergence knife edge
 
@@ -561,24 +555,108 @@ class TestSpanNesting:
                 None, [0.5, 2.0], on_block=hook))
 
 
-# -- bench smoke: tier-1 wiring for bench.py --mode re_sweep ----------------
+# -- planner -> lane sweep -> forced degradation, end to end ----------------
+#
+# A 4-point λ sweep over a 3-bucket ladder (2,500 rows, 80 Zipf-skewed
+# entities) against 4 sequential blocked fits: staging passes, bitwise
+# lanes on both the blocked and the all-at-once path, the planner's
+# per-bucket bound, a forced small budget, and a second grid through the
+# same programs. One run; each gate is one case.
 
 
-class TestBenchSmoke:
-    def test_bench_re_sweep_quick(self):
-        bench = os.path.join(REPO, "bench.py")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, bench, "--mode", "re_sweep", "--quick"],
-            capture_output=True, text=True, timeout=420, env=env)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        rec = json.loads([l for l in proc.stdout.splitlines()
-                          if l.startswith("{")][-1])
-        assert rec["metric"] == "re_sweep_data_passes"
-        assert rec["quick"] is True
-        assert rec["data_passes"]["within_bound"] is True
-        assert rec["bitwise_all_lanes"] is True
-        assert rec["planner"]["planned_ge_measured_all_buckets"] is True
-        assert rec["degradation"]["degraded"] is True
-        assert all(rec["degradation"]["models_identical_to_full_k"])
-        assert rec["zero_recompiles"] is True
+@pytest.fixture(scope="module")
+def re_sweep_quick_run():
+    from photon_tpu.game.coordinate import RandomEffectCoordinate
+    from photon_tpu.game.dataset import (
+        EntityVocabulary,
+        FeatureShard,
+        GameDataFrame,
+    )
+    from photon_tpu.game.random_effect import (
+        RandomEffectDataConfiguration,
+        build_random_effect_dataset,
+    )
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.types import TaskType
+
+    n, d, ents, K = 2_500, 4, 80, 4
+    grid = np.logspace(-1.0, 1.0, K)
+    rng = np.random.default_rng(23)
+    ent = rng.zipf(1.35, size=n) % ents
+    idx = np.arange(d, dtype=np.int32)
+    rows = [(idx, rng.normal(size=d)) for _ in range(n)]
+    y = (rng.random(n) > 0.5).astype(np.float64)
+    df = GameDataFrame(num_samples=n, response=y,
+                       feature_shards={"u": FeatureShard(rows, d)},
+                       id_tags={"userId": [str(e) for e in ent]})
+    ds = build_random_effect_dataset(
+        df, RandomEffectDataConfiguration("userId", "u",
+                                          max_entity_buckets=3),
+        EntityVocabulary(), dtype=np.float64)
+    coord = RandomEffectCoordinate(
+        ds, n, "userId", "u", TaskType.LOGISTIC_REGRESSION,
+        GLMOptimizationConfiguration(
+            optimizer=OptimizerConfig(max_iterations=25, tolerance=1e-8),
+            regularization=L2Regularization, regularization_weight=1.0))
+    base = coord.config
+
+    def at(w):
+        coord.config = dataclasses.replace(base, regularization_weight=w)
+
+    seq, seq_passes = [], 0
+    for w in grid:
+        at(float(w))
+        seq.append(np.asarray(coord.update_model_blocked(None).coefficients))
+        seq_passes += coord.last_blocks_staged
+    swept = [np.asarray(m.coefficients)
+             for m in coord.update_model_blocked_swept(None, grid)]
+    swept_passes = coord.last_blocks_staged
+    measured = list(coord.last_block_measured)
+    plan = coord.last_block_plan
+    flat_refs = []
+    for w in grid:
+        at(float(w))
+        flat_refs.append(np.asarray(coord.update_model(None, None)
+                                    .coefficients))
+    flat = coord.update_model_swept(None, None, grid)
+
+    # a budget that fits one staged bucket but not its K lanes at once
+    tiny = max(3 * b.data_bytes + b.lane_bytes for b in plan.buckets)
+    small = coord.update_model_blocked_swept(None, grid,
+                                             hbm_budget_bytes=tiny)
+    small_plan = coord.last_block_plan
+
+    solvers = {coord._block_solve_swept_fn(bool(f))
+               for f in set(coord._dense_local_blocks)}
+    traces0 = sum(f._cache_size() for f in solvers)
+    recompiles0 = registry.snapshot()["counters"].get(
+        "jitcache.recompiles", 0)
+    coord.update_model_blocked_swept(None, np.logspace(-2.0, 2.0, K))
+    new_traces = sum(f._cache_size() for f in solvers) - traces0
+    new_recompiles = registry.snapshot()["counters"].get(
+        "jitcache.recompiles", 0) - recompiles0
+    hbm.reset_plan_stats()
+    return {
+        "passes_within_1_over_k_plus_ladder":
+            swept_passes <= seq_passes / K + len(ds.blocks),
+        "bitwise_all_lanes": all(
+            np.array_equal(swept[k], seq[k])
+            and np.array_equal(np.asarray(flat[k].coefficients),
+                               flat_refs[k]) for k in range(K)),
+        "planned_ge_measured_all_buckets": bool(measured) and all(
+            m["planned_peak_bytes"] >= m["measured_peak_bytes"]
+            for m in measured),
+        "forced_budget_degrades": bool(small_plan.degraded),
+        "degraded_models_identical_to_full_k": all(
+            np.array_equal(np.asarray(small[k].coefficients), swept[k])
+            for k in range(K)),
+        "zero_recompiles": new_traces == 0 and new_recompiles == 0,
+    }
+
+
+@pytest.mark.parametrize("gate", [
+    "passes_within_1_over_k_plus_ladder", "bitwise_all_lanes",
+    "planned_ge_measured_all_buckets", "forced_budget_degrades",
+    "degraded_models_identical_to_full_k", "zero_recompiles"])
+def test_planner_lanes_degradation(re_sweep_quick_run, gate):
+    assert re_sweep_quick_run[gate] is True, re_sweep_quick_run

@@ -1,6 +1,5 @@
 """Traffic capture & deterministic replay tests (photon_tpu/serving/
-replay.py, photon_tpu/obs/slo.py, the chaos injectors, and the tier-1
-``--mode replay --quick`` bench smoke).
+replay.py, photon_tpu/obs/slo.py and the chaos injectors).
 
 Covers the replay-harness contract:
 
@@ -18,21 +17,18 @@ Covers the replay-harness contract:
     not pollute another tenant's windowed p99 (the PR 12 regression),
   * SLO verdicts: PASS/WARN/BREACH ladder, offending-window capture,
     qps-floor masking, the compile-delta rule, verdict file round-trip,
-  * the quick replay bench end to end (subprocess).
+  * the harness end to end: capture, two replays, then a live swap and
+    a shard kill whose breach stays in the kill windows.
 """
 
 import json
 import math
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from photon_tpu import obs
 from photon_tpu.io.index_map import IndexMap, feature_key
@@ -440,31 +436,207 @@ def test_evaluate_records_and_verdict_file_roundtrip(tmp_path):
     assert slo.recorded_verdicts() == []
 
 
-# -- quick bench smoke -------------------------------------------------------
+# -- capture -> two replays -> kill/swap, end to end -------------------------
+#
+# A Zipf+burst profile is captured and read back, replayed twice through
+# two independently built sharded fleets on fresh virtual clocks, then
+# replayed a third time with a live front swap and a shard kill/revive
+# scheduled mid-stream; the SLO rules must localise the breach to the
+# kill windows. One run; each gate is one case.
+
+_Q_E, _Q_K, _Q_D, _Q_SHARDS, _Q_BATCH = 3_000, 2, 16, 2, 32
+_Q_INTERVAL, _Q_TICK = 0.25, 0.05
 
 
-def test_replay_quick_bench_smoke():
-    """Tier-1 smoke: the replay bench's quick shape end to end — capture
-    round-trip, two bitwise-identical replays, the kill/swap segment
-    with localized SLO breach — no artifact write."""
-    bench = os.path.join(REPO, "bench.py")
-    proc = subprocess.run(
-        [sys.executable, bench, "--mode", "replay", "--quick"],
-        capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rec = json.loads(proc.stdout.splitlines()[-1])
-    assert rec["metric"] == "replay_harness_gates_passed"
-    assert rec["quick"] is True
-    assert rec["value"] == 1.0, rec["gates"]
-    assert rec["replay_1"]["result"]["response_digest"] \
-        == rec["replay_2"]["result"]["response_digest"]
-    assert rec["replay_1"]["timeline_digest"] \
-        == rec["replay_2"]["timeline_digest"]
-    ks = rec["kill_swap"]
-    assert ks["result"]["degraded_reasons"]["shard_unavailable"] > 0
-    deg = [v for v in ks["verdicts"]
-           if v["rule_id"] == "no_typed_degradation"][0]
-    assert deg["status"] == "BREACH"
-    assert set(w["idx"] for w in deg["offending_windows"]) \
-        <= set(ks["kill_windows"])
+def _quick_fleet_models(seed):
+    """A fixed-effect front model plus one RE-only model a shard, with
+    fully resident tables (cold-miss promotion is wall-clock state a
+    bitwise timeline cannot admit). Entities are owned by the canonical
+    partitioner over their id strings, as the router hashes them."""
+    from photon_tpu.parallel.partition import entity_shards
+
+    rng = np.random.default_rng(seed)
+    imap = IndexMap({feature_key(f"f{j}", ""): j for j in range(_Q_D)})
+    theta = rng.normal(size=_Q_D).astype(np.float32)
+    coef = rng.normal(size=(_Q_E, _Q_K)).astype(np.float32)
+    lo = rng.integers(0, _Q_D - 1, size=_Q_E)
+    hi = rng.integers(lo + 1, _Q_D)
+    proj = np.stack([lo, hi], axis=1).astype(np.int32)
+    names = [f"e{i:09d}" for i in range(_Q_E)]
+    owners = entity_shards(np.array(names, dtype="S10"), _Q_SHARDS)
+    front = ServingGameModel(
+        TaskType.LINEAR_REGRESSION,
+        [ServingFixedEffect("fixed", "g", theta)], [], {"g": imap}, {})
+    shards = []
+    for s in range(_Q_SHARDS):
+        rows = np.flatnonzero(owners == s)
+        re = ServingRandomEffect(
+            "per_user", "userId", "g",
+            coefficients=np.ascontiguousarray(coef[rows]),
+            projection=np.ascontiguousarray(proj[rows]),
+            entity_rows={names[i]: j for j, i in enumerate(rows)})
+        shards.append(ServingGameModel(
+            TaskType.LINEAR_REGRESSION, [], [re], {"g": imap}, {}))
+    return front, shards
+
+
+def _quick_fleet(front_model, shard_models, clock):
+    """Front + shard engines + router, all on one virtual clock."""
+    from photon_tpu.serving import (
+        FleetConfig,
+        LocalShardClient,
+        ShardedServingFleet,
+    )
+
+    cfg = ServingConfig(max_batch=_Q_BATCH, max_wait_s=0.001)
+    front = ServingEngine(DeviceResidentModel(front_model), cfg,
+                          clock=clock, obs_labels={"shard": "front"})
+    clients = [LocalShardClient(s, ServingEngine(
+        DeviceResidentModel(m), cfg, clock=clock,
+        obs_labels={"shard": str(s)})) for s, m in enumerate(shard_models)]
+    fleet = ShardedServingFleet(front, clients, [("per_user", "userId")],
+                                FleetConfig(serving=cfg), clock=clock)
+    fleet.warmup()
+    return fleet
+
+
+def _compile_monitors(fleet):
+    """Steady-state compile events, jitcache misses and per-program
+    trace counts over every engine of the fleet."""
+    from photon_tpu.obs.metrics import registry
+    from photon_tpu.serving.scorer import get_scorer, serving_modes
+    from photon_tpu.utils import compile_cache
+
+    engines = [fleet.front] + [c.engine for c in fleet.clients]
+    programs = [get_scorer(e.model, mode, b) for e in engines
+                for mode in serving_modes(e.model)
+                for b in e.ladder.buckets]
+    jitted = [p if hasattr(p, "_cache_size")
+              else getattr(p, "__wrapped__", p) for p in programs]
+    return (compile_cache.compile_counts()["steady_state"],
+            registry.counter("jitcache.misses").value,
+            [f._cache_size() for f in jitted if hasattr(f, "_cache_size")])
+
+
+@pytest.fixture(scope="module")
+def replay_incident(tmp_path_factory):
+    from photon_tpu.obs.report import build_run_report, validate_run_report
+    from photon_tpu.serving.scorer import warmup_scorers
+
+    seed = 31
+    n_requests, base_qps = 300, 150.0
+    t_swap, t_kill, t_revive = 0.4, 0.6, 1.1
+    interval0 = ts.series.interval_s
+    # engine, router and replayer series share one window grid
+    ts.series.interval_s = _Q_INTERVAL
+    obs.reset()
+    try:
+        profile = TrafficProfile(
+            kind="burst", n_requests=n_requests, entities=_Q_E, zipf_a=1.5,
+            base_qps=base_qps, feature_dim=_Q_D, nnz=4, burst_at_s=1.0,
+            burst_len_s=0.6, burst_factor=3.0)
+        records = generate(profile, seed)
+        sdig = stream_digest(records)
+        cap_path = str(tmp_path_factory.mktemp("replay_q") / "capture.jsonl")
+        record_capture(cap_path, records)
+        cap_records, cap_stats = read_capture(cap_path)
+        front_model, shard_models = _quick_fleet_models(seed)
+
+        runs = []
+        for _ in (1, 2):
+            clk = VirtualClock()
+            fleet = _quick_fleet(front_model, shard_models, clk)
+            reg = ts.WindowedRegistry(interval_s=_Q_INTERVAL)
+            res = Replayer(fleet, clk, registry=reg, tick_s=_Q_TICK).run(
+                cap_records)
+            runs.append((res.response_digest,
+                         timeline_digest(reg.snapshot())))
+            fleet.shutdown()
+
+        # the incident: live front swap, then a shard killed and revived
+        ts.clear()
+        clk = VirtualClock()
+        fleet = _quick_fleet(front_model, shard_models, clk)
+        staged = DeviceResidentModel(front_model)
+        warmup_scorers(staged, fleet.front.ladder.buckets)
+        victim = _Q_SHARDS // 2
+        mon0 = _compile_monitors(fleet)
+        swap_info = {}
+        actions = [
+            (t_swap, lambda: swap_info.update(fleet.front.publish_model(
+                staged, "replay-live-swap"))),
+            (t_kill, lambda: fleet.kill_shard(victim)),
+            (t_revive, lambda: fleet.revive_shard(victim)),
+        ]
+        res_kill = Replayer(fleet, clk, tick_s=_Q_TICK).run(
+            cap_records, actions)
+        mon1 = _compile_monitors(fleet)
+        compile_delta = (
+            (mon1[0] - mon0[0]) + (mon1[1] - mon0[1])
+            + sum(max(0, b - a) for a, b in zip(mon0[2], mon1[2])))
+        snap_kill = ts.series.snapshot()
+        fleet.shutdown()
+
+        # every window the victim could have been dead in
+        kill_idx = set(range(int(t_kill // _Q_INTERVAL),
+                             int((t_revive + _Q_TICK) // _Q_INTERVAL) + 1))
+        rules = [
+            slo.P99Ceiling(
+                rule_id="p99", series="replay.latency",
+                ceiling_s=4 * _Q_TICK, qps_series="replay.responses",
+                qps_floor=0.25 * base_qps),
+            slo.MaxDegradationRate(
+                rule_id="no_typed_degradation",
+                degraded_series="replay.degraded",
+                total_series="replay.responses", max_rate=0.0,
+                degraded_labels={"reason": "shard_unavailable"}),
+            slo.ZeroSteadyStateCompiles(rule_id="compiles"),
+        ] + [slo.MaxDegradationRate(
+            rule_id=f"shard{s}", degraded_series="fleet.shard.unavailable",
+            total_series="replay.responses", max_rate=0.0,
+            degraded_labels={"shard": str(s)}) for s in range(_Q_SHARDS)]
+        by_rule = {v.rule_id: v for v in slo.evaluate(
+            slo.SLOSpec(rules), snap_kill, compile_delta=compile_delta)}
+        deg, vic = by_rule["no_typed_degradation"], by_rule[f"shard{victim}"]
+        report = build_run_report("replay")
+        yield {
+            "stream_digest_stable":
+                stream_digest(generate(profile, seed)) == sdig,
+            "capture_roundtrip": (
+                len(cap_records) == n_requests
+                and cap_stats["capture_truncated"] == 0
+                and stream_digest([(r.t, r.request)
+                                   for r in cap_records]) == sdig),
+            "response_digest_identical": runs[0][0] == runs[1][0],
+            "timeline_digest_identical": runs[0][1] == runs[1][1],
+            "kill_breach_registered": (
+                deg.status == slo.BREACH and vic.status == slo.BREACH
+                and res_kill.degraded_reasons.get(
+                    "shard_unavailable", 0) > 0),
+            "breach_localized_to_kill_windows": (
+                {w["idx"] for w in deg.offending_windows} <= kill_idx
+                and {w["idx"] for w in vic.offending_windows} <= kill_idx),
+            "survivor_shards_pass": all(
+                by_rule[f"shard{s}"].status == slo.PASS
+                for s in range(_Q_SHARDS) if s != victim),
+            "p99_slo_held": by_rule["p99"].status != slo.BREACH,
+            "zero_steady_state_compiles":
+                by_rule["compiles"].status == slo.PASS,
+            "live_swap_published": swap_info.get("version") == 2,
+            "runreport_roundtrip": (validate_run_report(report) == []
+                                    and "timeline" in report
+                                    and "slo" in report),
+        }
+    finally:
+        ts.series.interval_s = interval0
+        obs.reset()
+
+
+@pytest.mark.parametrize("gate", [
+    "stream_digest_stable", "capture_roundtrip",
+    "response_digest_identical", "timeline_digest_identical",
+    "kill_breach_registered", "breach_localized_to_kill_windows",
+    "survivor_shards_pass", "p99_slo_held", "zero_steady_state_compiles",
+    "live_swap_published", "runreport_roundtrip"])
+def test_replay_capture_twice_then_incident(replay_incident, gate):
+    assert replay_incident[gate] is True, replay_incident

