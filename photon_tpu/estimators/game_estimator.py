@@ -37,6 +37,7 @@ from photon_tpu.game.coordinate import FixedEffectCoordinate, RandomEffectCoordi
 from photon_tpu.game.dataset import (
     EntityVocabulary,
     GameDataFrame,
+    count_placed,
     store_rows_major,
 )
 from photon_tpu.game.descent import (
@@ -52,6 +53,7 @@ from photon_tpu.game.random_effect import (
 )
 from photon_tpu.game.scoring import GameScorer
 from photon_tpu.obs import solver as _obs_solver
+from photon_tpu.obs.metrics import registry
 from photon_tpu.optim.problem import GLMOptimizationConfiguration
 from photon_tpu.types import TaskType
 from photon_tpu.utils.timing import Timed
@@ -198,16 +200,27 @@ class GameEstimator:
         ``ingest/prepare/<coordinate id>/<step>`` for host work,
         ``ingest/h2d/<coordinate id>`` for placements, ``ingest/stats``
         (game/random_effect.py, game/dataset.py; PERF.md §3). A fit on a
-        frame ``_prepare_cached`` already holds records none."""
+        frame ``_prepare_cached`` already holds records none.
+
+        With a mesh every training array stays on the host until the
+        coordinate places it, each device given its shard alone
+        (``parallel/mesh.shard_batch`` / ``shard_entity_blocks``): no
+        device holds a whole one. The coordinate is then built inside
+        ``ingest/h2d/<coordinate id>``, and the host arrays it was handed
+        are counted as placed."""
         coordinates: Dict[str, object] = {}
         re_datasets: Dict[str, RandomEffectDataset] = {}
         # original (pre-RANDOM-projection) feature dims per RE coordinate —
         # persistable_artifacts needs them to back-project trained models
         self._original_dims: Dict[str, int] = {}
+        # one device: the builders place; a mesh: the coordinate does
+        place = self.mesh is None
         for i, (cid, cfg) in enumerate(self.coordinate_configs.items()):
             shard_id = cfg.data.feature_shard_id
             norm = self.normalization_contexts.get(shard_id)
             icpt = self.intercept_indices.get(shard_id)
+            build = Timed(f"ingest/prepare/{cid}/coordinate" if place
+                          else f"ingest/h2d/{cid}", level=logging.DEBUG)
             if cfg.is_random_effect:
                 if norm is not None and cfg.data.projector_type == "RANDOM":
                     # contexts are defined in the original feature space;
@@ -219,36 +232,45 @@ class GameEstimator:
                         "RANDOM projector", cid)
                     norm, icpt = None, None
                 self._original_dims[cid] = df.feature_shards[shard_id].dim
-                ds = build_random_effect_dataset(
+                data = build_random_effect_dataset(
                     df, cfg.data, vocab, dtype=np.dtype(self.dtype).type,
-                    coordinate=cid)
-                re_datasets[cid] = ds
-                with Timed(f"ingest/prepare/{cid}/coordinate",
-                           level=logging.DEBUG):
+                    coordinate=cid, place=place)
+                with build:
                     coordinates[cid] = RandomEffectCoordinate(
-                        ds, df.num_samples, cfg.data.random_effect_type,
+                        data, df.num_samples, cfg.data.random_effect_type,
                         cfg.data.feature_shard_id, self.task,
                         cfg.optimization, mesh=self.mesh,
                         variance_type=self.variance_computation_type,
                         norm=norm, intercept_index=icpt)
+                re_datasets[cid] = coordinates[cid].dataset
             else:
-                batch = df.fixed_effect_batch(
+                data = df.fixed_effect_batch(
                     shard_id, dtype=np.dtype(self.dtype).type,
-                    feature_dtype=self.feature_dtype, coordinate=cid)
-                # this X is solved on again and again: on one device it is
-                # stored in the layout the solves read (a mesh re-places it)
-                with Timed(f"ingest/h2d/{cid}", level=logging.DEBUG):
-                    batch = batch._replace(features=store_rows_major(
-                        batch.features, cid, on_mesh=self.mesh is not None))
-                with Timed(f"ingest/prepare/{cid}/coordinate",
-                           level=logging.DEBUG):
+                    feature_dtype=self.feature_dtype, coordinate=cid,
+                    place=place)
+                if place:
+                    # this X is solved on again and again: on one device
+                    # it is stored in the layout the solves read
+                    with Timed(f"ingest/h2d/{cid}", level=logging.DEBUG):
+                        data = data._replace(features=store_rows_major(
+                            data.features, cid))
+                elif isinstance(data.features, np.ndarray):
+                    # a mesh lays the dense X out as it shards it
+                    registry.counter("ingest.row_major", coordinate=cid,
+                                     outcome="mesh").inc()
+                with build:
                     key = jax.random.PRNGKey(sampling_seed + i)
                     coordinates[cid] = FixedEffectCoordinate(
-                        batch, df.feature_shards[shard_id].dim, shard_id,
+                        data, df.feature_shards[shard_id].dim, shard_id,
                         self.task, cfg.optimization, sampling_key=key,
                         mesh=self.mesh,
                         variance_type=self.variance_computation_type,
                         norm=norm, intercept_index=icpt)
+            if not place:
+                # what the coordinate sent from the host (a sparse X was
+                # placed, and counted, by fixed_effect_batch)
+                count_placed(cid, [a for a in jax.tree_util.tree_leaves(data)
+                                   if isinstance(a, np.ndarray)])
         return coordinates, re_datasets
 
     def _prepare_cached(self, df: GameDataFrame):

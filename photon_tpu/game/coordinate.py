@@ -163,7 +163,7 @@ class FixedEffectCoordinate:
             else:
                 # sample-shard once at construction; every solve and score
                 # pass then runs SPMD over the data axis
-                batch = M.shard_batch(batch, mesh)
+                batch = M.shard_batch(batch, mesh, coordinate=feature_shard_id)
         self.batch = batch
         self.dim = dim
         self.feature_shard_id = feature_shard_id
@@ -228,7 +228,7 @@ class FixedEffectCoordinate:
         sweep) or a frozen group-entry snapshot (parallel sweep) — the
         solve is a pure function of it either way."""
         with _obs_annotate("fe/args"):
-            batch, init = self._solve_args(prev, residual_scores)
+            batch, init = self._placed(*self._solve_args(prev, residual_scores))
         with _obs_annotate("fe/solve"):
             model, result = self.problem.run(
                 batch, initial=init, dim=self.dim, dtype=batch.labels.dtype,
@@ -297,17 +297,39 @@ class FixedEffectCoordinate:
     def score(self, model: FixedEffectModel) -> Array:
         """Training-data scores WITHOUT offsets — coordinate-descent score
         algebra sums raw model scores (reference: scoreForCoordinateDescent).
-        Mesh pad rows are sliced off so score algebra stays [n]."""
+        Mesh pad rows are sliced off so score algebra stays [n]; over a
+        data-parallel mesh the scores come back whole on every device."""
         coef = model.model.coefficients.means
         if self._model_sharded:
             from photon_tpu.parallel import mesh as M
             coef = M.shard_coef_model_parallel(jnp.asarray(coef), self.mesh,
                                                padded_dim=self._dim_padded)
+        elif self.mesh is not None:
+            with _obs_annotate("fe/score"):
+                return _fixed_score_whole(self.batch.features, coef,
+                                          self._n_orig, self.mesh)
         with _obs_annotate("fe/score"):
             s = _fixed_score(self.batch.features, coef)
         if s.shape[0] != self._n_orig:
             s = s[: self._n_orig]
         return s
+
+    def _placed(self, batch: DataBatch, init: Optional[Array]):
+        """Over a data-parallel mesh, a solve's arguments placed the same
+        way in every update: the offsets sharded as the rows are, the warm
+        start replicated (zeros on the first update). An update's offsets
+        and warm start arrive uncommitted on the first sweep and placed by
+        the programs that made them later on, and the solve would be
+        traced and compiled once for each."""
+        if self.mesh is None or self._model_sharded:
+            return batch, init
+        from photon_tpu.parallel import mesh as M
+        if batch.offsets is not None:
+            batch = batch._replace(offsets=jax.device_put(
+                batch.offsets, batch.labels.sharding))
+        if init is None:
+            init = jnp.zeros((self.dim,), batch.labels.dtype)
+        return batch, jax.device_put(init, M.replicated(self.mesh))
 
     def update_model_swept(self, prev: Optional[FixedEffectModel],
                            residual_scores: Optional[Array],
@@ -509,7 +531,13 @@ class RandomEffectCoordinate:
             # entity-shard once at construction (the co-partitioning
             # replacement); the vmapped solves are independent per entity,
             # so this axis runs collective-free
-            dataset = M.shard_entity_blocks(dataset, mesh)
+            # the dense flags, read from the blocks as they arrive (from
+            # the estimator: on the host): the mesh's pad rows, indices
+            # and values 0, change no flag, and no sharded block has to
+            # come back to the host for them
+            self.__dict__["_dense_local_blocks"] = _dense_flags(dataset)
+            dataset = M.shard_entity_blocks(dataset, mesh,
+                                            coordinate=random_effect_type)
         self.dataset = dataset
         self.n = num_flat_samples
         self.random_effect_type = random_effect_type
@@ -557,37 +585,9 @@ class RandomEffectCoordinate:
 
     @functools.cached_property
     def _dense_local_blocks(self) -> Tuple[bool, ...]:
-        """Per-block static flag: the ELL slots are exactly the local
-        feature space (every nonzero sits at slot == its local index and
-        the ELL width equals the projected dim), so the block's per-entity
-        solves can treat values as a DENSE [S, K] matrix — margins/Gram/
-        gradient become plain dot_generals (MXU) instead of gather/scatter
-        kernels. Common case: per-entity feature vectors observed in full
-        (the MovieLens-style GLMix workload). Computed once from the host
-        copy at solve-build time; trace-time static."""
-        import numpy as np
-
-        D = self.dataset.projected_dim
-        flags = []
-        for blk in self.dataset.blocks:
-            k = blk.features.values.shape[-1]
-            if k != D or not getattr(blk.features.indices,
-                                     "is_fully_addressable", True):
-                # multi-host entity sharding: the host copy isn't
-                # reachable — skip the optimization, never crash
-                flags.append(False)
-                continue
-            idx = np.asarray(blk.features.indices)
-            slot = np.broadcast_to(np.arange(k, dtype=idx.dtype), idx.shape)
-            idx_ok = idx == slot
-            if idx_ok.all():
-                # the common from_dense layout: indices alone prove it —
-                # skip the device-to-host copy of the (much larger) values
-                flags.append(True)
-                continue
-            val = np.asarray(blk.features.values)
-            flags.append(bool(np.all((val == 0) | idx_ok)))
-        return tuple(flags)
+        """``_dense_flags`` of this coordinate's dataset, computed once at
+        solve-build time; trace-time static."""
+        return _dense_flags(self.dataset)
 
     def _validate_solver(self) -> None:
         opt = self.config.optimizer
@@ -920,9 +920,15 @@ class RandomEffectCoordinate:
         entity count, zeros from scratch."""
         ds = self.dataset
         dtype = self._solve_dtype(prev)
-        coef0 = (prev.coefficients if prev is not None
-                 else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
-        return dtype, self._pad_entity_rows(coef0)
+        coef0 = self._pad_entity_rows(
+            prev.coefficients if prev is not None
+            else jnp.zeros((ds.num_entities, ds.projected_dim), dtype))
+        if self.mesh is not None:
+            # placed the same way in every update (zeros on the first
+            # come uncommitted), so the ladder compiles once
+            from photon_tpu.parallel import mesh as M
+            coef0 = jax.device_put(coef0, M.replicated(self.mesh))
+        return dtype, coef0
 
     def _solve_dtype(self, prev: Optional[RandomEffectModel] = None):
         """A solve runs in its warm start's dtype, else the dataset's — the
@@ -1500,12 +1506,10 @@ class RandomEffectCoordinate:
 
     @functools.cached_property
     def _score_fn(self):
-        dense_flags = self._dense_local_blocks
-
-        def build():
-            return jax.jit(_re_score_builder(dense_flags))
-
-        return jitcache.get_or_build(("re_score", dense_flags), build)
+        dense_flags, mesh = self._dense_local_blocks, self.mesh
+        return jitcache.get_or_build(
+            ("re_score", dense_flags, mesh),
+            lambda: jax.jit(_re_score_builder(dense_flags, mesh)))
 
     def score(self, model: RandomEffectModel) -> Array:
         with _obs_annotate("re/score"):
@@ -1602,6 +1606,37 @@ class RandomEffectCoordinate:
         return self._data_loss_fn(self.dataset, total_scores)
 
 
+def _dense_flags(dataset: RandomEffectDataset) -> Tuple[bool, ...]:
+    """Per-block static flag: the ELL slots are exactly the local feature
+    space (every nonzero sits at slot == its local index and the ELL width
+    equals the projected dim), so the block's per-entity solves can treat
+    values as a DENSE [S, K] matrix — margins/Gram/gradient become plain
+    dot_generals (MXU) instead of gather/scatter kernels. Common case:
+    per-entity feature vectors observed in full (the MovieLens-style GLMix
+    workload). Read from the host copy of the blocks."""
+    D = dataset.projected_dim
+    flags = []
+    for blk in dataset.blocks:
+        k = blk.features.values.shape[-1]
+        if k != D or not getattr(blk.features.indices,
+                                 "is_fully_addressable", True):
+            # multi-host entity sharding: the host copy isn't
+            # reachable — skip the optimization, never crash
+            flags.append(False)
+            continue
+        idx = np.asarray(blk.features.indices)
+        slot = np.broadcast_to(np.arange(k, dtype=idx.dtype), idx.shape)
+        idx_ok = idx == slot
+        if idx_ok.all():
+            # the common from_dense layout: indices alone prove it —
+            # skip the device-to-host copy of the (much larger) values
+            flags.append(True)
+            continue
+        val = np.asarray(blk.features.values)
+        flags.append(bool(np.all((val == 0) | idx_ok)))
+    return tuple(flags)
+
+
 def _residual_offsets(blk: EntityBlock,
                       residual_flat: Optional[Array]) -> Array:
     """A bucket's ``[E_b, S_b]`` offsets with a flat score vector injected
@@ -1612,7 +1647,9 @@ def _residual_offsets(blk: EntityBlock,
     return blk.offsets + blk.rows_from_flat(residual_flat)
 
 
-def _re_score_builder(dense_flags=()):
+def _re_score_builder(dense_flags=(), mesh=None):
+    """The score program; over a ``mesh`` its flat scores come back whole
+    on every device (``RandomEffectDataset.rows_to_flat``)."""
     @jax.named_scope("re/score")
     def score(ds: RandomEffectDataset, coef_block: Array) -> Array:
         flags = (dense_flags if len(dense_flags) == len(ds.blocks)
@@ -1636,9 +1673,21 @@ def _re_score_builder(dense_flags=()):
         pmargin = jnp.sum(ds.passive_features.values
                           * jnp.take_along_axis(pcoef, ds.passive_features.indices, axis=1),
                           axis=-1)
-        return ds.rows_to_flat(margins, pmargin).astype(coef_block.dtype)
+        return ds.rows_to_flat(margins, pmargin, mesh).astype(
+            coef_block.dtype)
 
     return score
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _fixed_score_whole(feats, coef: Array, n: int, mesh) -> Array:
+    """``_fixed_score`` over a data-parallel mesh: the sample-sharded
+    scores made whole on every device, the mesh's pad rows cut off."""
+    from photon_tpu.parallel.mesh import made_whole
+
+    with jax.named_scope("fe/score"):
+        s = F.matvec(feats, coef)
+    return made_whole(s, mesh)[:n]
 
 
 def _count_variances(coordinate: str, variance_type) -> None:

@@ -227,7 +227,8 @@ class GameDataFrame:
 
     def fixed_effect_batch(self, shard_id: str, dtype=np.float32,
                            feature_dtype=None,
-                           coordinate: Optional[str] = None) -> DataBatch:
+                           coordinate: Optional[str] = None,
+                           place: bool = True) -> DataBatch:
         """Reference: FixedEffectDataset — flat uid-major batch over one
         feature shard.
 
@@ -247,8 +248,22 @@ class GameDataFrame:
         passes a dense X it will solve on many times, on one device,
         through ``store_rows_major``: it is then a COMMITTED rows-major
         array whose layout the solves compile for, and
-        ``ingest.row_major{coordinate, outcome}`` says which way it went."""
+        ``ingest.row_major{coordinate, outcome}`` says which way it went.
+
+        ``place=False`` leaves a dense X and the per-row vectors on the
+        host, for a caller that places them itself (a mesh:
+        ``parallel/mesh.shard_batch`` sends each device its shard); no
+        ``ingest/h2d`` phase, nothing counted. A sparse X is placed as
+        ever."""
         coordinate = coordinate or shard_id
+        if not place and self.feature_shards[shard_id].is_dense:
+            def host(a, dt=dtype):
+                return None if a is None else np.asarray(a, dt)
+            return DataBatch(
+                features=host(self.feature_shards[shard_id].rows,
+                              feature_dtype or dtype),
+                labels=host(self.response), offsets=host(self.offsets),
+                weights=host(self.weights))
         with Timed(f"ingest/h2d/{coordinate}", level=logging.DEBUG):
             batch = DataBatch(
                 features=self.shard_features(shard_id, feature_dtype or dtype),

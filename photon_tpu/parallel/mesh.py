@@ -27,10 +27,25 @@ size, so `pad_batch` / `pad_entities` append zero-weight rows / empty
 entity blocks. Zero-weight pads contribute exactly nothing to any
 aggregator (every per-sample term is multiplied by its weight) or metric
 (all evaluators are weighted).
+
+Placement from the host: `shard_batch` and `shard_entity_blocks` given
+HOST (numpy) arrays pad them there and send each device its own shard, so
+no device ever holds more than its share of a training array (what
+`GameEstimator(mesh=...)` does). Given arrays already on ONE device they
+re-place them, as they always did: the bytes so staged are the always-on
+counter ``mesh.staged_bytes{coordinate}``, 0 on the host path. The slots
+each size bucket holds over the mesh are ``mesh.entity_slots{coordinate,
+kind=real|pad}``, ticked by `pad_entities`. ``coordinate`` is the fixed
+effect's feature shard or the random effect's type.
+
+The cross-chip step of a coordinate update, the flat score made whole on
+every device for the next residual (`made_whole`), runs under the scope
+``cd/whole_score``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -39,6 +54,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from photon_tpu.data.dataset import DataBatch
+from photon_tpu.obs.metrics import registry
 from photon_tpu.ops import features as F
 
 # the repo's one import of shard_map: solver modules, tests and bench
@@ -122,6 +138,38 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+WHOLE_SCOPE = "cd/whole_score"
+
+
+def made_whole(x, mesh: Mesh, axis: str = DATA_AXIS):
+    """``x``, sharded over ``axis`` along its leading dim, whole on every
+    device of ``mesh``: ONE all-gather, under ``cd/whole_score``. Inside a
+    jitted program; stated as a collective (``shard_map``) so the compiler
+    neither moves the gather into the producer's inputs nor names it after
+    them."""
+    gather = shard_map(
+        functools.partial(jax.lax.all_gather, axis_name=axis, tiled=True),
+        mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
+    with jax.named_scope(WHOLE_SCOPE):
+        return gather(x)
+
+
+def gathered_whole(source, index, mesh: Mesh, axis: str = DATA_AXIS):
+    """``source[index]`` whole on every device of ``mesh``, inside a jitted
+    program, for a ``source`` and an ``index`` whole on every device: each
+    device gathers its share of the rows (the pad rows that make them
+    divide read ``source``'s last element), and the result is made whole
+    (``made_whole``). A gather moves one element at a time, so a quarter of
+    the rows on each of four devices is a quarter of the time."""
+    n = index.shape[0]
+    index = jnp.pad(index, (0, pad_to_multiple(n, axis_size(mesh, axis)) - n),
+                    constant_values=source.shape[0] - 1)
+    index = jax.lax.with_sharding_constraint(
+        index, NamedSharding(mesh, P(axis)))
+    part = source.at[index].get(mode="promise_in_bounds")
+    return made_whole(part, mesh, axis)[:n]
+
+
 def create_two_level_mesh(
     n_devices: int,
     dcn_factor: int,
@@ -159,6 +207,13 @@ def staged_psum(x, ici_axis: str = DATA_AXIS, dcn_axis: str = DCN_AXIS):
 
 def axis_size(mesh: Mesh, axis: str) -> int:
     return mesh.shape[axis]
+
+
+def entity_axis(mesh: Mesh) -> str:
+    """The axis entity blocks shard over: "entity" where the mesh has one,
+    else "data" (entity solves are independent, so reusing the data-axis
+    devices is valid and the common single-axis-mesh case)."""
+    return ENTITY_AXIS if ENTITY_AXIS in mesh.axis_names else DATA_AXIS
 
 
 def pad_to_multiple(n: int, k: int) -> int:
@@ -200,13 +255,54 @@ def pad_batch(batch: DataBatch, multiple: int) -> DataBatch:
     )
 
 
-def shard_batch(batch: DataBatch, mesh: Mesh, axis=DATA_AXIS) -> DataBatch:
+def _on_host(tree) -> bool:
+    leaves = jax.tree_util.tree_leaves(tree)
+    return bool(leaves) and all(isinstance(a, np.ndarray) for a in leaves)
+
+
+def _count_staged(tree, mesh: Mesh, coordinate: str) -> None:
+    """Tick ``mesh.staged_bytes{coordinate}`` by the bytes of the device
+    arrays in ``tree`` that sit whole on one device, about to be re-placed
+    over ``mesh`` (0 where there are none)."""
+    staged = sum(a.nbytes for a in jax.tree_util.tree_leaves(tree)
+                 if isinstance(a, jax.Array) and mesh.size > 1
+                 and len(a.sharding.device_set) == 1)
+    registry.counter("mesh.staged_bytes", coordinate=coordinate).inc(staged)
+
+
+def _put_rows(a: np.ndarray, rows: int, sharding: NamedSharding, fill=0):
+    """A host array placed by ``sharding`` with its leading dim padded to
+    ``rows`` by rows of ``fill``: each device's shard is cut from the host
+    array and sent to that device alone, and only a shard that holds pad
+    rows is copied on the host."""
+    a = np.asarray(a, jax.dtypes.canonicalize_dtype(a.dtype))
+
+    def shard(index):
+        lo, hi, _ = index[0].indices(rows)
+        piece = a[lo:min(hi, len(a))][(slice(None),) + tuple(index[1:])]
+        if hi > len(a):
+            tail = np.full((hi - max(lo, len(a)),) + piece.shape[1:], fill,
+                           a.dtype)
+            piece = np.concatenate([piece, tail])
+        return piece
+
+    return jax.make_array_from_callback((rows,) + a.shape[1:], sharding,
+                                        shard)
+
+
+def shard_batch(batch: DataBatch, mesh: Mesh, axis=DATA_AXIS,
+                coordinate: str = "") -> DataBatch:
     """Pad + place a DataBatch with its sample dim sharded over ``axis``.
 
     ``axis`` may be a tuple of mesh axis names (e.g. ``(DCN_AXIS,
     DATA_AXIS)`` on a two-level mesh) — the sample dim then shards over
     their product, slice-major, matching ``staged_psum``'s reduction
     order.
+
+    A batch of host arrays is padded as ``pad_batch`` pads (zero rows,
+    weight 0 on them, the weights materialised) while it is on the host,
+    and each device is sent its shard alone; a batch on one device is
+    padded there and re-placed, counted in ``mesh.staged_bytes``.
 
     The treeAggregate replacement: once inputs are placed this way, the
     jitted aggregator kernels' reductions compile to all-reduce over ICI.
@@ -215,14 +311,23 @@ def shard_batch(batch: DataBatch, mesh: Mesh, axis=DATA_AXIS) -> DataBatch:
     mult = 1
     for a in axes:
         mult *= axis_size(mesh, a)
-    batch = pad_batch(batch, mult)
     spec_axis = axes if len(axes) > 1 else axes[0]
+
+    def sharding(a):
+        return NamedSharding(mesh, P(spec_axis, *([None] * (a.ndim - 1))))
+
+    _count_staged(batch, mesh, coordinate)
+    if _on_host(batch):
+        rows = pad_to_multiple(batch.num_samples, mult)
+        if batch.weights is None:
+            batch = batch._replace(weights=np.ones_like(batch.labels))
+        return jax.tree.map(lambda a: _put_rows(a, rows, sharding(a)), batch)
+    batch = pad_batch(batch, mult)
 
     def put(a):
         if a is None:
             return None
-        spec = P(spec_axis, *([None] * (a.ndim - 1)))
-        return jax.device_put(a, NamedSharding(mesh, spec))
+        return jax.device_put(a, sharding(a))
 
     return jax.tree.map(put, batch)
 
@@ -336,18 +441,23 @@ def replicate_from_process_local(x, mesh: Mesh):
 
 # -- entity-block padding + placement (random-effect path) -------------------
 
-def pad_entities(ds, multiple: int):
+def pad_entities(ds, multiple: int, coordinate: str = ""):
     """Pad each entity block's row dim (and the passive rows) of a
     RandomEffectDataset so all shard evenly; pad rows carry zero weights,
     out-of-range entity rows, and flat rows at ``n`` (the 'n on pads'
     invariant of sample_rows). Every slot after a padded bucket moves, so
-    the flat-order map is derived anew."""
+    the flat-order map is derived anew. The padding is done on the host:
+    the dataset comes back as host arrays. Ticks
+    ``mesh.entity_slots{coordinate, kind}`` by each bucket's slots: its
+    entities' rows (``real``) and the rows added here (``pad``), each a
+    whole row of the bucket's samples."""
     from photon_tpu.game.random_effect import (
         EntityBlock,
         RandomEffectDataset,
         flat_source_map,
     )
 
+    ds = jax.tree.map(np.asarray, ds)
     E = ds.num_entities
     n = ds.num_flat_samples
     Ppas = ds.passive_entity.shape[0]
@@ -355,13 +465,16 @@ def pad_entities(ds, multiple: int):
 
     def pad0(a, rows, fill=0):
         widths = [(0, rows)] + [(0, 0)] * (a.ndim - 1)
-        return jnp.pad(a, widths, constant_values=fill)
+        return np.pad(a, widths, constant_values=fill)
 
     blocks = []
     changed = P_pad != Ppas
     for blk in ds.blocks:
         E_b = blk.num_rows
         E_b_pad = pad_to_multiple(E_b, multiple)
+        for kind, rows in (("real", E_b), ("pad", E_b_pad - E_b)):
+            registry.counter("mesh.entity_slots", coordinate=coordinate,
+                             kind=kind).inc(rows * blk.max_samples)
         if E_b_pad == E_b:
             blocks.append(blk)
             continue
@@ -388,7 +501,7 @@ def pad_entities(ds, multiple: int):
         passive_entity=pad0(ds.passive_entity, eP, fill=E),
         passive_rows=passive_rows,
         projection=ds.projection,
-        flat_source=jnp.asarray(flat_source_map(
+        flat_source=np.asarray(flat_source_map(
             [b.sample_rows for b in blocks], passive_rows, n)),
     )
 
@@ -405,25 +518,30 @@ def entity_axis_assignment(entity_ids: Sequence, mesh: Mesh,
     rows by this assignment first (the serving fleet depends only on the
     hash, not on any one training layout)."""
     from photon_tpu.parallel.partition import entity_shards
-    if axis is None:
-        axis = ENTITY_AXIS if ENTITY_AXIS in mesh.axis_names else DATA_AXIS
-    return entity_shards(entity_ids, axis_size(mesh, axis))
+    return entity_shards(entity_ids,
+                         axis_size(mesh, axis or entity_axis(mesh)))
 
 
-def shard_entity_blocks(ds, mesh: Mesh, axis: Optional[str] = None):
+def shard_entity_blocks(ds, mesh: Mesh, axis: Optional[str] = None,
+                        coordinate: str = ""):
     """Pad + place a RandomEffectDataset with entities (and passive rows)
     sharded over ``axis`` — the static replacement for the reference's
     entity co-partitioning (RandomEffectDatasetPartitioner.scala:44).
+    It is padded on the host (``pad_entities``) and each device is sent
+    its shard alone; arrays that were on one device come back to the host
+    for it and are counted in ``mesh.staged_bytes``. A dataset already
+    placed over ``mesh`` comes back as it is.
 
-    Default axis: the mesh's "entity" axis when it has one, else "data"
-    (entity solves are independent, so reusing the data-axis devices is
-    valid and the common single-axis-mesh case). For placement that lines
-    up with the serving fleet's shard ownership, order entity rows by
+    Default axis: ``entity_axis(mesh)``. For placement that lines up with
+    the serving fleet's shard ownership, order entity rows by
     `entity_axis_assignment` (the canonical `parallel/partition` hash)
     before calling this."""
-    if axis is None:
-        axis = ENTITY_AXIS if ENTITY_AXIS in mesh.axis_names else DATA_AXIS
-    ds = pad_entities(ds, axis_size(mesh, axis))
+    axis = axis or entity_axis(mesh)
+    if (isinstance(ds.flat_source, jax.Array)
+            and ds.flat_source.sharding == replicated(mesh)):
+        return ds
+    _count_staged(ds, mesh, coordinate)
+    ds = pad_entities(ds, axis_size(mesh, axis), coordinate)
 
     def put(a):
         spec = P(axis, *([None] * (a.ndim - 1)))

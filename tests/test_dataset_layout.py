@@ -74,12 +74,14 @@ def test_the_eighth(width, admitted):
 def _columns_first(monkeypatch):
     """A device whose DEFAULT layout is column-major, stood in for by the
     ONE function that reads an array's layout: what ``jnp.asarray`` places
-    (uncommitted) reads column-major, what the relayout program hands out
-    (committed) reads as it lies."""
+    (uncommitted) and what a mesh places from the host (over several
+    devices) read column-major, what the relayout program hands out
+    (committed, on one device) reads as it lies."""
     real = dataset._major_to_minor
     monkeypatch.setattr(
         dataset, "_major_to_minor",
-        lambda x: real(x) if x.committed else COLUMN_MAJOR)
+        lambda x: real(x) if x.committed and len(x.sharding.device_set) == 1
+        else COLUMN_MAJOR)
 
 
 def _frame(n=600, d=48, seed=3):

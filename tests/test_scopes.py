@@ -408,6 +408,36 @@ def test_the_score_programs_are_named(glmix_fit):
     assert "re/score" in scopes_in(text)
 
 
+def test_a_meshed_score_is_made_whole_under_its_scope(devices8):
+    """Over a mesh each coordinate's score program ends in the cross-chip
+    step, the flat score made whole on every device for the next residual:
+    every all-gather of the fixed and the random effect's score programs
+    stands under ``cd/whole_score`` (``parallel/mesh.made_whole``), where
+    ``collective_device_share``'s split by scope finds it."""
+    from photon_tpu.game.coordinate import _fixed_score_whole
+    from photon_tpu.parallel import mesh as M
+    from tests.test_game import glmix_estimator, make_glmix_frame
+
+    frame, _, _ = make_glmix_frame(np.random.default_rng(4), n=300,
+                                   n_users=6)
+    est = glmix_estimator(num_iterations=1)
+    est.mesh = M.create_mesh(4)
+    est.fit(frame)
+    fe, re_ = est._coordinates["fixed"], est._coordinates["per-user"]
+    ds = re_.dataset
+    texts = [
+        _fixed_score_whole.lower(fe.batch.features, jnp.zeros(fe.dim),
+                                 fe._n_orig, fe.mesh).compile().as_text(),
+        re_._score_fn.lower(ds, jnp.zeros(
+            (ds.num_entities, ds.projected_dim))).compile().as_text()]
+    for text in texts:
+        gathers = [line for line in text.splitlines()
+                   if " all-gather(" in line]
+        assert gathers
+        for line in gathers:
+            assert "/cd/whole_score/" in line, line
+
+
 def test_preparation_records_its_phases_once_with_telemetry_off(glmix_fit):
     _, first, second, _, _ = glmix_fit
     labels = [label for label, _ in first]
@@ -564,6 +594,10 @@ def test_perf_md_lists_every_scope_and_phase_the_tests_hold():
              "fe/variance", "re/variance", "variance.computed",
              "kernels.variance_gram", "variance_device_share",
              "variance_roofline", "variance_factor_ms",
+             # the mesh: the cross-chip step's scope, the
+             # counters, the two readers
+             "cd/whole_score", "mesh.staged_bytes", "mesh.entity_slots",
+             "collective_device_share", "mesh_padding_share",
              } | LINESEARCH
     for _, _, steps, dense, sparse in SOLVERS.values():
         names |= steps | dense | (sparse or set())

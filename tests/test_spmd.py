@@ -128,8 +128,13 @@ def test_entity_sharded_blocks_cover_all_devices(glmix, devices8):  # noqa: F811
     est.mesh = mesh
     model = est.fit(train)[0].model["per-user"]
     from photon_tpu.game.coordinate import RandomEffectCoordinate
-    # rebuild a coordinate directly to inspect placement
-    ds = est._re_datasets["per-user"]
+    from photon_tpu.game.random_effect import build_random_effect_dataset
+    # the estimator keeps the dataset its coordinate trains on, placed
+    assert est._re_datasets["per-user"] is (
+        est._coordinates["per-user"].dataset)
+    # rebuild a coordinate directly, from one device, to inspect placement
+    ds = build_random_effect_dataset(
+        train, est.coordinate_configs["per-user"].data, est._vocab)
     coord = RandomEffectCoordinate(ds, train.num_samples, "userId",
                                    "user_feats", TaskType.LOGISTIC_REGRESSION,
                                    mesh=mesh)
