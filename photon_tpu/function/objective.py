@@ -239,7 +239,7 @@ class GLMObjective:
         return hv + hyper.l2_weight * vector
 
     def hessian_weights(self, coef: Array, batch: DataBatch) -> Array:
-        """Per-sample curvature weights, constant over one TRON CG solve."""
+        """Per-sample curvature weights at ``coef``, by a pass of their own."""
         return aggregators.hessian_weights(
             self.loss, batch.features, batch.labels, batch.offsets, batch.weights,
             coef, self.norm,
@@ -274,3 +274,17 @@ class GLMObjective:
             coef, self.norm,
         )
         return h + hyper.l2_weight * jnp.eye(coef.shape[0], dtype=h.dtype)
+
+    def value_gradient_and_weights(
+        self, coef: Array, batch: DataBatch, hyper: Hyper
+    ) -> Tuple[Array, Array, Array]:
+        """``value_and_gradient`` and the per-sample curvature weights at
+        ``coef`` (``hessian_weights``) from ONE evaluation: TRON's
+        operator input, taken where the value and gradient were."""
+        v, g, d2 = aggregators.value_gradient_and_weights(
+            self.loss, batch.features, batch.labels, batch.offsets, batch.weights,
+            coef, self.norm,
+        )
+        v = v + 0.5 * hyper.l2_weight * jnp.dot(coef, coef)
+        g = g + hyper.l2_weight * coef
+        return v, g, d2

@@ -680,14 +680,14 @@ class RandomEffectCoordinate:
                 if explicit is None:
                     explicit = K <= 64
                 if explicit:
-                    hs = lambda c: obj_e.hessian_matrix_from_weights(
-                        obj_e.hessian_weights(c, batch), K, batch, hyper)
+                    hs = lambda d2: obj_e.hessian_matrix_from_weights(
+                        d2, K, batch, hyper)
                     ha = lambda h, v: h @ v
                 else:
-                    hs = lambda c: obj_e.hessian_weights(c, batch)
-                    ha = lambda d2, v: obj_e.hessian_vector_from_weights(
-                        d2, v, batch, hyper)
-                r = tron.minimize(vg, None, x0, config=solver_cfg,
+                    hs, ha = None, lambda d2, v: (
+                        obj_e.hessian_vector_from_weights(d2, v, batch, hyper))
+                vgw = lambda c: obj_e.value_gradient_and_weights(c, batch, hyper)
+                r = tron.minimize(vgw, None, x0, config=solver_cfg,
                                   hess_setup=hs, hess_apply=ha)
             else:
                 r = lbfgs.minimize(vg, x0, config=solver_cfg)

@@ -239,10 +239,10 @@ def hessian_weights(
     """Per-sample Gauss-Newton curvature weights ``w_i l''(margin_i)``.
 
     The Hessian at a fixed coefficient point is fully determined by these
-    weights; they are constant across an entire truncated-CG solve, so TRON
-    computes them ONCE per outer iteration instead of re-deriving margins
-    inside every Hv product (the reference pays one extra treeAggregate per
-    CG step for exactly this — HessianVectorAggregator.scala:37)."""
+    weights: NEWTON, DIRECT and the variances take them here, one pass of
+    their own; TRON takes them from the evaluation that computed the same
+    margins (``value_gradient_and_weights``), where the reference re-derives
+    them in every Hv product (HessianVectorAggregator.scala:37)."""
     margins = compute_margins(x, coef, offsets, norm)
     d2 = loss.d2z(margins, labels)
     if weights is not None:
@@ -389,3 +389,57 @@ def hessian_matrix(
     dim = coef.shape[0]
     d2 = hessian_weights(loss, x, labels, offsets, weights, coef, norm)
     return hessian_matrix_from_weights(x, d2, norm, dim)
+
+
+@jax.named_scope("agg/value_and_gradient")
+def value_gradient_and_weights(
+    loss: PointwiseLoss,
+    x: FeatureMatrix,
+    labels: Array,
+    offsets: Optional[Array],
+    weights: Optional[Array],
+    coef: Array,
+    norm: NormalizationContext,
+) -> Tuple[Array, Array, Array]:
+    """``value_and_gradient`` and, from the same margins, the curvature
+    weights ``hessian_weights`` computes at ``coef``: what TRON builds its
+    operator from at a point it has just evaluated, with no pass over X of
+    its own (``optim/tron.py``).
+
+    Routed as ``value_and_gradient`` is, under its scope: where
+    ``pallas_glm.dense_route`` admits the matrix the kernel hands the
+    weights out beside value and gradient (``with_weights``), a
+    normalisation folded in by ``_through_kernel`` so that its margin shift
+    is inside the margins the weights are taken at; elsewhere (``vmap``, a
+    mesh, narrow or sparse features, no TPU) XLA's two passes, and the
+    weights from the margins the first computed. Counted under
+    ``kernels.pallas_hits{path}`` / ``kernels.xla_fallbacks{path,
+    reason}``, ``path`` ``dense_curv`` under an identity context and
+    ``dense_curv_norm`` under factors or shifts: once a traced evaluation,
+    and nothing under ``dense`` / ``dense_norm``."""
+    from photon_tpu.ops import pallas_glm
+    route = pallas_glm.dense_route(x, coef, labels, offsets, weights,
+                                   norm.factors, norm.shifts)
+    path = "dense_curv" if norm.is_identity else "dense_curv_norm"
+    if route == pallas_glm.KERNEL:
+        _kernel_counter("pallas_hits", path)
+
+        def fused(off, c, dz_sum):
+            value, *rest, d2 = pallas_glm.fused_dense_value_grad(
+                loss, x, labels, off, weights, c, with_dz_sum=dz_sum,
+                with_weights=True)
+            # the weights ride in the value's slot, which _through_kernel
+            # hands back as it is
+            return ((value, d2), *rest)
+
+        (value, d2), grad = _through_kernel(fused, x.shape[0], offsets,
+                                            coef, norm)
+        return value, grad, d2
+    if route is not None:
+        _kernel_counter("xla_fallbacks", path, reason=route)
+    margins = compute_margins(x, coef, offsets, norm)
+    value, dz = _weighted_loss_and_dz(loss, labels, weights, margins)
+    grad = _apply_factor_and_shift(rmatvec(x, dz, coef.shape[0]),
+                                   jnp.sum(dz), norm)
+    d2 = loss.d2z(margins, labels)
+    return value, grad, d2 if weights is None else d2 * weights

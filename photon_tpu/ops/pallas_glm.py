@@ -243,18 +243,18 @@ def _lane_chunks(d: int):
     return [(c0, min(_LANES, d - c0)) for c0 in range(0, d, _LANES)]
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5, 6, 8))
+@functools.partial(jax.jit, static_argnums=(0, 5, 6, 8, 9))
 def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
-           interpret: bool, coef, dz_sum: bool = False):
+           interpret: bool, coef, dz_sum: bool = False, d2z=None):
     """x [n, d] AS PLACED (no pad, no copy: any n >= tile_n, any d), of
     which the kernel reads the ``n // tile_n`` whole tiles; labels/
     offsets/weights [steps, tile_n / 128, 128] (row t of step i at
     [i, t // 128, t % 128]); coef [1, d]; tile_n % 128 == 0. Returns the
     value's per-lane partial sums [tile_n / 128, 128] and the gradient's
-    per-sublane partial sums [8, d]; with ``dz_sum`` a third output, the
-    per-lane partial sums [tile_n / 128, 128] of ``w * dz`` (what a
-    normalisation's shifts multiply: the scratch the gradient is fed from,
-    added up on its way). Without it the program is the two-output one."""
+    per-sublane partial sums [8, d]; with ``dz_sum`` the per-lane partial
+    sums [tile_n / 128, 128] of ``w * dz`` (what a normalisation's shifts
+    multiply); with ``d2z`` (the loss's second derivative) every row's
+    ``w * d2z`` [steps, tile_n / 128, 128], laid out as the labels are."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -266,7 +266,7 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
 
     def kernel(x_ref, y_ref, off_ref, w_ref, coef_ref, val_ref, grad_ref,
                *rest):
-        # rest: the third output where asked for, then the two scratches
+        # rest: the outputs asked for (sum, weights), then the two scratches
         m_ref, wdz_ref = rest[-2:]
         i = pl.program_id(0)
 
@@ -313,7 +313,7 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
         wdz_ref[...] = w * dz
         if dz_sum:
             rest[0][...] += wdz_ref[...]
-
+        _curvature_weights(d2z, m_ref, off_ref, y_ref, w, rest[dz_sum:-2])
         def gradient(b, acc):
             col = jnp.sum(jnp.where(eye, wdz_ref[pl.ds(b, 1), :], zero),
                           axis=1, keepdims=True)                # [128, 1]
@@ -334,9 +334,9 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
     rows = pl.BlockSpec((None, blocks, _LANES), lambda i: (i, 0, 0))
     lanes = pl.BlockSpec((blocks, _LANES), lambda i: (0, 0))
     lanes_shape = jax.ShapeDtypeStruct((blocks, _LANES), f32)
-    # Mosaic lowers no 64-bit type, and under x64 a ``fori_loop`` counts
-    # in int64 whatever its bounds: the kernel is traced with x64 off
-    # (its operands and results are float32 either way)
+    rows_shape = jax.ShapeDtypeStruct(labels.shape, f32)
+    # Mosaic lowers no 64-bit type, and under x64 a ``fori_loop`` counts in
+    # int64: the kernel is traced with x64 off (its arrays are float32)
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
@@ -350,11 +350,11 @@ def _fused(loss_and_dz, x, labels, offsets, weights, tile_n: int,
             out_specs=[
                 lanes,
                 pl.BlockSpec((_MXU_ROWS, d), lambda i: (0, 0)),
-            ] + [lanes] * dz_sum,
+            ] + [lanes] * dz_sum + [rows] * (d2z is not None),
             out_shape=[
                 lanes_shape,
                 jax.ShapeDtypeStruct((_MXU_ROWS, d), f32),
-            ] + [lanes_shape] * dz_sum,
+            ] + [lanes_shape] * dz_sum + [rows_shape] * (d2z is not None),
             scratch_shapes=[pltpu.VMEM((blocks, _LANES), f32),
                             pltpu.VMEM((blocks, _LANES), f32)],
             compiler_params=pltpu.CompilerParams(
@@ -393,9 +393,9 @@ def fused_dense_value_grad(
     weights: Optional[Array],
     coef: Array,
     *,
-    tile_n: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    tile_n: Optional[int] = None, interpret: Optional[bool] = None,
     with_dz_sum: bool = False,
+    with_weights: bool = False,
 ) -> Tuple[Array, ...]:
     """Weighted loss value and gradient, X streamed from HBM once.
 
@@ -405,16 +405,16 @@ def fused_dense_value_grad(
     EFFECTIVE coefficients and the margin shift on the offsets, asks
     ``with_dz_sum`` for a third result, ``sum_i w_i dz_i`` (what the
     shifts multiply in the gradient), and applies factors and shifts to
-    what comes out. X goes to the kernel as it is placed, for
-    any ``n`` and ``d``: a block spans its full width, the kernel takes
-    the whole tiles, and the rows left over (fewer than a tile: 80 of
-    epsilon's 530,000) are the same sums as plain float32 array
-    operations over a slice. Nothing is padded and no copy of X is made.
+    what comes out. ``with_weights`` adds a last result, each row's
+    curvature weight ``w_i l''(m_i)`` [n], from the margins the kernel
+    holds anyway (``aggregators.value_gradient_and_weights``). X goes in
+    as placed, for any ``n`` and ``d``: the kernel takes the whole tiles;
+    the rows left over (fewer than a tile: 80 of epsilon's 530,000) are
+    the same sums as array operations over a slice. No copy of X is made.
     The kernel is never shown a row past ``n``: a block past the end of
     X holds unspecified bits that zero weights would not silence, and
-    where XLA has placed a small X in VMEM the block IS the operand, so
-    it cannot be cleaned in place either (PERF.md §6, PR 32).
-    """
+    where XLA has placed a small X in VMEM the block IS the operand
+    (PERF.md §6, PR 32)."""
     if interpret is None:
         interpret = _default_interpret()
     n, d = x.shape
@@ -430,23 +430,26 @@ def fused_dense_value_grad(
     cap = max(_LANES, _X_TILE_BYTES // (_round_up(d, _LANES) * 4))
     tile = min(cap if tile_n is None else tile_n, cap, n) // _LANES * _LANES
     whole = n // tile * tile if tile else 0
-    # the rows past the last whole tile (all of them below 128 rows; an
-    # empty sum at n = 0)
+    # the rows past the last whole tile (all of them below 128 rows)
     xt = x[whole:].astype(f32)
-    lt, dzt = loss.loss_and_dz(jnp.sum(xt * coef, axis=1) + off[whole:],
-                               y[whole:])
+    zt = jnp.sum(xt * coef, axis=1) + off[whole:]
+    lt, dzt = loss.loss_and_dz(zt, y[whole:])
     value = jnp.sum(lt * w[whole:])
     grad = jnp.sum(xt * (dzt * w[whole:])[:, None], axis=0)
     sums = (jnp.sum(dzt * w[whole:]),) if with_dz_sum else ()
+    curv = loss.d2z if with_weights else None
     if whole:
         shape = (whole // tile, tile // _LANES, _LANES)
         v, g, *rest = _fused(loss.loss_and_dz, x,
                           *(r[:whole].reshape(shape) for r in (y, off, w)),
                           tile, bool(interpret), coef.reshape(1, d),
-                          bool(with_dz_sum))
+                          with_dz_sum, curv)
         value, grad = value + jnp.sum(v), grad + jnp.sum(g, axis=0)
         sums = tuple(a + jnp.sum(b) for a, b in zip(sums, rest))
-    return (value, grad) + sums
+    d2 = () if curv is None else (curv(zt, y[whole:]) * w[whole:],)
+    if whole and d2:        # the kernel's rows first, then the rest
+        d2 = (jnp.concatenate([rest[-1].reshape(whole), d2[0]]),)
+    return (value, grad) + sums + d2
 
 
 def fused_dense_hessian_vector(
@@ -455,8 +458,7 @@ def fused_dense_hessian_vector(
     vector: Array,
     *,
     offsets: Optional[Array] = None,
-    tile_n: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    tile_n: Optional[int] = None, interpret: Optional[bool] = None,
     with_dz_sum: bool = False,
 ) -> Tuple[Array, ...]:
     """``(v . Hv / 2, Hv)`` for ``H = X^T diag(d2) X``, X streamed from HBM
@@ -467,14 +469,12 @@ def fused_dense_hessian_vector(
     labels 0 and offsets 0, with the curvature weights for sample weights
     and the vector for coefficients. The per-row function is then ``t ->
     (t^2 / 2, t)`` at ``t = X v``, the kernel's ``w * dz`` is ``d2 * Xv``,
-    its gradient ``X^T (d2 * Xv)`` and its value the quadratic form, free.
-    So this is ``fused_dense_value_grad``: the same kernel body, tile and
-    left-over rows; XLA's path reads X twice (``X v``, then ``X^T (d2 *
-    Xv)``). The labels and the offsets are zeros the compiler makes once a
-    solve, outside its loops: 2 MB each at 530,000 rows, 0.02 ms a fit.
-    Under a normalisation the aggregator hands in the effective vector,
-    ``offsets`` = the margin shift, and asks ``with_dz_sum`` for
-    ``sum_i d2_i t_i`` (``fused_dense_value_grad``)."""
+    its gradient ``X^T (d2 * Xv)`` and its value the quadratic form, free:
+    ``fused_dense_value_grad``'s kernel body, tile and left-over rows,
+    where XLA's path reads X twice. The zero labels and offsets are made
+    once a solve, outside its loops (2 MB each at 530,000 rows). Under a
+    normalisation the aggregator hands in the effective vector, ``offsets``
+    = the margin shift, and asks ``with_dz_sum`` for ``sum_i d2_i t_i``."""
     from photon_tpu.ops.losses import SquaredLoss
     zeros = jnp.zeros((x.shape[0],), jnp.float32)
     return fused_dense_value_grad(
@@ -724,3 +724,15 @@ def fused_gather_margin(
                         tile, bool(interpret),
                         _coef_lhs(theta, _MAX_SPARSE_DIM, "serving"))
     return out[:n]
+
+
+def _curvature_weights(d2z, m_ref, off_ref, y_ref, w, out) -> None:
+    """In ``_fused``'s kernel, asked for the curvature weights (``d2z`` the
+    loss's second derivative, ``out`` the one output block of them): each
+    row's ``l''(m + offset) * w`` of the tile, from the margins it holds in
+    VMEM, written once a grid step. Not asked for, nothing is traced. It
+    stands here and not in the kernel's body because the body's line
+    numbers are serialised with the kernel, and the compile cache of every
+    cell that runs it keys on them (ROADMAP D13)."""
+    if d2z is not None:
+        out[0][...] = d2z(m_ref[...] + off_ref[...], y_ref[...]) * w

@@ -101,8 +101,8 @@ class SolverResult(NamedTuple):
     failure: Optional[Array] = None
     # TRON's curvature work, int32 scalars; None (no leaves: no other
     # solver's program carries them) everywhere else. ``cg_steps``: one
-    # operator product each; ``hessian_builds``: ``hess_setup`` calls that
-    # ran (one at the start, one after each accepted step that is not the
+    # operator product each; ``hessian_builds``: operators taken at a new
+    # point (one at the start, one after each accepted step that is not the
     # last); ``rejected_steps``: trial points the trust region refused
     cg_steps: Optional[Array] = None
     hessian_builds: Optional[Array] = None

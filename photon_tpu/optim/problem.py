@@ -240,11 +240,11 @@ class GlmOptimizationProblem:
                 if opt.optimizer_type == OptimizerType.OWLQN:
                     return owlqn.minimize(vg, x0, l1_weight=l1, config=solver_cfg)
                 if opt.optimizer_type == OptimizerType.TRON:
-                    # Hessian operator split: curvature weights once per
-                    # operator build; the explicit d x d Gauss-Newton
-                    # matrix (one MXU contraction a build, no pass over X
-                    # a CG step) or matrix-free products (a read of X a
-                    # step, two off the kernel), by ``tron_explicit_hessian``
+                    # the operator from the curvature weights each
+                    # evaluation hands back: the explicit d x d Gauss-Newton
+                    # matrix (one MXU contraction an accepted step, no pass
+                    # over X a CG step) or the weights, matrix-free (a read
+                    # of X a step, two off the kernel), by tron_explicit_hessian
                     from photon_tpu.ops.features import (
                         ModelShardedSparse,
                         SparseFeatures,
@@ -262,14 +262,14 @@ class GlmOptimizationProblem:
                         "kernels.tron_hessian",
                         path="explicit" if explicit else "matrix_free").inc()
                     if explicit:
-                        hs = lambda c: obj.hessian_matrix_from_weights(
-                            obj.hessian_weights(c, batch), dim, batch, hyper)
+                        hs = lambda d2: obj.hessian_matrix_from_weights(
+                            d2, dim, batch, hyper)
                         ha = lambda h, v: h @ v
                     else:
-                        hs = lambda c: obj.hessian_weights(c, batch)
-                        ha = lambda d2, v: obj.hessian_vector_from_weights(
-                            d2, v, batch, hyper)
-                    return tron.minimize(vg, None, x0, config=solver_cfg,
+                        hs, ha = None, lambda d2, v: (
+                            obj.hessian_vector_from_weights(d2, v, batch, hyper))
+                    vgw = lambda c: obj.value_gradient_and_weights(c, batch, hyper)
+                    return tron.minimize(vgw, None, x0, config=solver_cfg,
                                          hess_setup=hs, hess_apply=ha)
                 from photon_tpu.ops.features import ModelShardedSparse
                 if (isinstance(batch.features, ModelShardedSparse)

@@ -8,21 +8,28 @@ truncatedConjugateGradientMethod :278): outer trust-region loop with
 non-improvement capped at ``max_improvement_failures`` (5). Defaults
 maxIter=15, tol=1e-5, CG cap 20 (TRON.scala:256-262).
 
-What a Hessian-vector product costs depends on the operator the caller
-hands in (``optim/problem.py`` chooses it): matrix-free, one product
-``X^T (d2 * Xv)`` under ``agg/hessian_vector`` where the reference pays a
-treeAggregate (ONE read of X through the fused kernel where
-``pallas_glm.dense_route`` admits the matrix, XLA's two passes elsewhere);
-explicit, NO pass over X (one ``[d, d] @ [d]`` product under
-``optim/tron/direction``) after one ``X^T D X`` contraction an operator
-build (``agg/hessian_matrix``). The operator belongs to a POINT: it is
-built once at the start and again only after an accepted step, never after
-a rejected one (the point did not move), and ``SolverResult`` counts the
-builds, the CG steps and the rejected steps of a solve.
+The GLM Hessian at a point is fixed by its per-sample curvature weights
+``w_i l''(m_i)``, and the evaluation at that point has the margins they are
+taken from: the objective hands the weights back beside value and gradient
+(``GLMObjective.value_gradient_and_weights``), the solver carries those of
+the point it stands at (the trial point's, once accepted), and no operator
+reads X of its own. What a Hessian-vector product costs depends on the
+operator the caller builds from them (``optim/problem.py`` chooses it):
+matrix-free, the weights themselves and one product ``X^T (d2 * Xv)`` under
+``agg/hessian_vector`` where the reference pays a treeAggregate (ONE read of
+X through the fused kernel where ``pallas_glm.dense_route`` admits the
+matrix, XLA's two passes elsewhere); explicit, NO pass over X (one
+``[d, d] @ [d]`` product under ``optim/tron/direction``) after one
+``X^T D X`` contraction an operator build (``agg/hessian_matrix``). The
+operator belongs to a POINT: it is taken at the start and again only after
+an accepted step, never after a rejected one (the point did not move), and
+``SolverResult`` counts the operators taken, the CG steps and the rejected
+steps of a solve.
 
 Each step of an iteration runs under a ``jax.named_scope``
-``optim/tron/<step>``: ``init``, ``hessian`` (the once-an-iteration
-operator set-up: the call into ``agg/``), ``direction`` (the truncated CG,
+``optim/tron/<step>``: ``init``, ``hessian`` (taking the operator at the
+point: the explicit matrix built from the carried weights, and the weights
+carried on from an accepted trial), ``direction`` (the truncated CG,
 Hessian-vector products included), ``trial`` (the evaluation at the trial
 point), ``update`` (trust radius and acceptance), ``converged``, and
 ``loop`` around the outer ``while_loop`` itself (PERF.md §3; the names are
@@ -128,9 +135,10 @@ class _Carry(NamedTuple):
     failure: Array    # int32 FailureMode (non-zero terminates the loop)
     trk: "Optional[StateTracking]"  # per-iteration ring buffer (None = off)
     hstate: object    # hess_setup's operator at x (None without hess_setup)
-    stale: Array      # bool: x has moved since hstate was built
+    d2: object        # the curvature weights at x (None without hess_apply)
+    stale: Array      # bool: x has moved since its operator was taken
     cg_steps: Array   # int32, summed over the outer iterations
-    builds: Array     # int32 hess_setup calls that ran
+    builds: Array     # int32 operators taken at a new point
     rejected: Array   # int32 trial steps refused
 
 
@@ -147,17 +155,21 @@ def minimize(
     """Minimize with ``value_and_grad(x, *args)`` and
     ``hess_vec(x, v, *args)`` (Hessian at x applied to v).
 
-    When ``hess_setup``/``hess_apply`` are given, the Hessian operator is
-    split into a once-per-outer-iteration ``hstate = hess_setup(x, *args)``
-    (e.g. Gauss-Newton curvature weights, or the explicit d x d matrix for
-    small dims) and a cheap per-CG-step ``hess_apply(hstate, v, *args)``.
-    The GLM Hessian at fixed x is fully determined by per-sample curvature
-    weights, so this removes one full data pass from every CG step
-    (reference pays it: HessianVectorAggregator.scala:37). ``hess_setup``
-    runs under a ``lax.cond`` on "x moved since the last build": a rejected
-    step keeps the operator it was computed with."""
+    When ``hess_apply`` is given, the Hessian comes from curvature weights
+    instead: ``value_and_grad`` returns a third result, the per-sample
+    weights at x, and a CG step applies ``hess_apply(op, v, *args)`` to
+    the operator of the point the solver stands at. That operator is the
+    weights themselves (matrix-free: no product re-derives the margins, as
+    the reference's does, HessianVectorAggregator.scala:37), or,
+    with ``hess_setup``, ``hess_setup(d2, *args)`` (e.g. the explicit
+    d x d matrix for small dims), built under a ``lax.cond`` on "x moved
+    since the last build": a rejected step keeps the operator it was
+    computed with. Either way no operator evaluates anything at x: the
+    weights are those of the evaluation that took x, the first or an
+    accepted trial."""
+    curvature = hess_apply is not None
     with jax.named_scope("optim/tron/init"):
-        f0, g0 = value_and_grad(x0, *args)
+        f0, g0, d0 = _evaluate(value_and_grad, x0, args, curvature)
         tols = absolute_tolerances(f0, g0, config.tolerance)
     dtype = x0.dtype
 
@@ -166,14 +178,16 @@ def minimize(
                 & (c.failure == FailureMode.NONE))
 
     def body(c: _Carry) -> _Carry:
+        hstate = None
         if hess_setup is not None:
             with jax.named_scope("optim/tron/hessian"):
                 hstate = lax.cond(c.stale,
-                                  lambda: hess_setup(c.x, *args),
+                                  lambda: hess_setup(c.d2, *args),
                                   lambda: c.hstate)
             hv = lambda v: hess_apply(hstate, v, *args)
+        elif curvature:
+            hv = lambda v: hess_apply(c.d2, v, *args)
         else:
-            hstate = None
             hv = lambda v: hess_vec(c.x, v, *args)
         with jax.named_scope("optim/tron/direction"):
             s, r, cg = _trcg(lambda v, *_: hv(v), c.g, c.delta,
@@ -183,7 +197,8 @@ def minimize(
             gs = jnp.dot(c.g, s)
             prered = -0.5 * (gs - jnp.dot(s, r))
             x_try = c.x + s
-            f_try, g_try = value_and_grad(x_try, *args)
+            f_try, g_try, d_try = _evaluate(value_and_grad, x_try, args,
+                                            curvature)
             actred = c.f - f_try
             snorm = jnp.linalg.norm(s)
 
@@ -231,6 +246,10 @@ def minimize(
                 jnp.asarray(FailureMode.NONE, jnp.int32),
             )
             trk = None if c.trk is None else c.trk.record(c.it, f_new, g_new)
+        with jax.named_scope("optim/tron/hessian"):
+            # the next operator's input: the trial point's weights if the
+            # solver moved there
+            d2 = None if d_try is None else jnp.where(accept, d_try, c.d2)
 
         with jax.named_scope("optim/tron/converged"):
             it = c.it + 1
@@ -251,7 +270,8 @@ def minimize(
         return _Carry(x=x_new, f=f_new, g=g_new, f_prev=c.f, delta=delta,
                       it=it, failures=failures, reason=reason,
                       n_evals=c.n_evals + 1, nf_count=nf_count,
-                      failure=failure, trk=trk, hstate=hstate, stale=accept,
+                      failure=failure, trk=trk, hstate=hstate, d2=d2,
+                      stale=accept,
                       cg_steps=c.cg_steps + cg,
                       builds=c.builds + c.stale.astype(jnp.int32),
                       rejected=c.rejected + (~accept).astype(jnp.int32))
@@ -275,8 +295,9 @@ def minimize(
             # a placeholder of the operator's shape: the first trip builds
             hstate=None if hess_setup is None else jax.tree.map(
                 lambda a: jnp.zeros(a.shape, a.dtype),
-                jax.eval_shape(hess_setup, x0, *args)),
-            stale=jnp.asarray(hess_setup is not None),
+                jax.eval_shape(hess_setup, d0, *args)),
+            d2=d0,
+            stale=jnp.asarray(curvature),
             cg_steps=zero, builds=zero, rejected=zero,
         )
 
@@ -292,3 +313,10 @@ def minimize(
         cg_steps=out.cg_steps, hessian_builds=out.builds,
         rejected_steps=out.rejected,
     )
+
+
+def _evaluate(value_and_grad, x, args, curvature: bool):
+    """``(f, g, d2)`` at x: the curvature weights where the evaluation hands
+    them back (``curvature``), else None."""
+    out = value_and_grad(x, *args)
+    return out if curvature else (*out, None)

@@ -808,9 +808,12 @@ def test_normalised_solves_repeat_xlas_counts(on_tpu):
             return (np.asarray(model.coefficients.means),
                     int(result.iterations))
 
-        before = _ticks("dense_norm")
+        # TRON's evaluations hand out the curvature weights too
+        label = "dense_norm" if kind == OptimizerType.LBFGS else (
+            "dense_curv_norm")
+        before = _ticks(label)
         routed, its = run()
-        assert _ticked(before, "dense_norm").get("hit", 0) >= 1
+        assert _ticked(before, label).get("hit", 0) >= 1
         with on_tpu.disabled():
             xla, its_xla = run()
         assert its == its_xla, (kind, its, its_xla)
@@ -1198,9 +1201,13 @@ def test_routed_tron_solve_compiles_for_a_v5e_with_one_kernel_a_product(
         v5e, monkeypatch, placed):
     """The fe-epsilon-tron solve as the chip's compiler leaves it: the
     kernel three times (the first evaluation, the trial point's, and ONE
-    under ``agg/hessian_vector`` inside the CG ``while``), and the XLA
-    program's temporaries: the one re-layout copy of a default-layout X
-    (none for X as it is placed), none of the product's own."""
+    under ``agg/hessian_vector`` inside the CG ``while``), and no temporary
+    the XLA program does not hold: the one re-layout copy of a
+    default-layout X (none for X as it is placed), none of the product's
+    own. The routed program holds fewer since its evaluations hand out the
+    curvature weights (no pass of XLA's for them: 1.45 against 4.30 MB
+    for X as placed), XLA's more (it carries the trial point's weights
+    beside the point's: 7.75 against 3.72 MB)."""
     from photon_tpu.optim.problem import OptimizerConfig
     from photon_tpu.types import OptimizerType
 
@@ -1218,7 +1225,7 @@ def test_routed_tron_solve_compiles_for_a_v5e_with_one_kernel_a_product(
                for name in names) == 2, names
     assert "tpu_custom_call" not in xla.as_text()
     temp = [_held_to_the_layout(placed, c) for c in (fused, xla)]
-    assert abs(temp[0] - temp[1]) < 0.001 * _EPSILON[0] * 2_048 * 4, temp
+    assert temp[0] - temp[1] < 0.001 * _EPSILON[0] * 2_048 * 4, temp
 
 
 @pytest.mark.parametrize("solver", ["LBFGS", "TRON"])
@@ -1228,7 +1235,8 @@ def test_normalised_solve_compiles_for_a_v5e(v5e, monkeypatch, solver):
     it: the SAME kernel under the same names, each call with its third
     result (``sum(w dz)``: 81 ragged lanes and three outputs are Mosaic's
     to take or refuse), no copy of X and no X-sized temporary; and under
-    TRON one more call under ``agg/hessian_vector``."""
+    TRON one more call under ``agg/hessian_vector``, and the evaluations'
+    fourth result, every whole tile's curvature weights."""
     from photon_tpu.function.objective import L2Regularization
     from photon_tpu.game.dataset import ROW_MAJOR
     from photon_tpu.ops import pallas_glm
@@ -1275,10 +1283,14 @@ def test_normalised_solve_compiles_for_a_v5e(v5e, monkeypatch, solver):
     assert len(evaluations) == 2, names
     assert len(products) == (1 if solver == "TRON" else 0), names
     assert len(names) == len(evaluations) + len(products)
-    # value's lanes, the gradient's sublanes, sum(w dz)'s lanes
+    # value's lanes, the gradient's sublanes, sum(w dz)'s lanes; TRON's
+    # evaluations add the weights of 2,070 tiles of 256 rows
     results = re.findall(r"= \((f32\[2,128\]\S*, f32\[8,2001\]\S*, "
-                         r"f32\[2,128\]\S*)\) custom-call\(", text)
+                         r"f32\[2,128\]\S*)(, f32\[2070,2,128\]\S*)?\) "
+                         r"custom-call\(", text)
     assert len(results) == len(names), results
+    assert sum(bool(weights) for _, weights in results) == (
+        len(evaluations) if solver == "TRON" else 0), results
     assert not re.findall(r"= f32\[530000,2001\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * n * 2_048 * 4
 
@@ -1406,3 +1418,232 @@ def test_dense_kernel_stores_to_no_input(call):
     inputs, rest = set(kernel.invars[:5]), set(kernel.invars[5:])
     assert not _stores_to(kernel, inputs)
     assert _stores_to(kernel, rest)       # the walk does see a store
+
+
+# ---------------------------------------------------------------------------
+# the curvature weights beside value and gradient (TRON's operator input):
+# the kernel hands out each whole tile's w * l''(m) from the margins it
+# holds, the rows left over are the same arithmetic beside it
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("context", ["identity", "STANDARDIZATION"])
+@pytest.mark.parametrize("sample_vectors", [True, False],
+                         ids=["offsets_weights", "bare"])
+@pytest.mark.parametrize("loss", [LogisticLoss, PoissonLoss],
+                         ids=lambda l: l.name)
+@pytest.mark.parametrize("n", [100, 700, 4000],
+                         ids=["no_whole_tile", "one_tile_60_over",
+                              "three_tiles_160_over"])
+def test_the_evaluation_hands_out_hessian_weights(n, loss, sample_vectors,
+                                                  context, on_tpu):
+    """``value_gradient_and_weights`` through the kernel (interpret mode):
+    its weights are ``hessian_weights`` at the same point within float32
+    rounding (two summation orders of the margins: read 1.0e-6 of the
+    largest weight, 9.5e-7 of each under an identity context), its value
+    and gradient are the routed ``value_and_gradient``'s to the bit. At 300
+    features the kernel's tile is 1,280 rows: the rows are all left over,
+    one tile and 60 over, three tiles and 160 over. Counted once, as
+    ``dense_curv`` / ``dense_curv_norm``, nothing under the evaluations'
+    labels."""
+    X, y, off, w, coef = _raw_problem(n=n, d=300, seed=n)
+    if loss is PoissonLoss:
+        y = jnp.round(jnp.exp(0.5 * y))
+    norm = _IDN if context == "identity" else _context(context, X)
+    if context == "identity":       # raw rows' margins reach 1e2: scale
+        X = X / jnp.max(jnp.abs(X), axis=0)
+    off, w = (off, w) if sample_vectors else (None, None)
+    with on_tpu.disabled():
+        want = aggregators.hessian_weights(loss, X, y, off, w, coef, norm)
+    labels = ("dense", "dense_norm", "dense_curv", "dense_curv_norm")
+    before = {p: _ticks(p) for p in labels}
+    value, grad, got = aggregators.value_gradient_and_weights(
+        loss, X, y, off, w, coef, norm)
+    path = "dense_curv" if context == "identity" else "dense_curv_norm"
+    assert {p: _ticked(before[p], p) for p in labels} == {
+        p: {"hit": 1} if p == path else {} for p in labels}
+    v0, g0 = aggregators.value_and_gradient(loss, X, y, off, w, coef, norm)
+    assert float(value) == float(v0)
+    np.testing.assert_array_equal(np.asarray(grad), np.asarray(g0))
+    assert got.shape == (n,) and got.dtype == jnp.float32
+    got, want = np.asarray(got), np.asarray(want)
+    scale = np.abs(want).max()
+    np.testing.assert_allclose(got / scale, want / scale, rtol=0, atol=5e-6)
+    if context == "identity":
+        np.testing.assert_allclose(got, want, rtol=5e-6)
+
+
+@pytest.mark.parametrize("reason", ["vmap", "mesh", "shape", "not_a_tpu"])
+def test_weights_off_the_kernel_come_from_the_evaluations_margins(
+        reason, wide_problem, on_tpu, monkeypatch):
+    """Where the gate turns the matrix away the weights come from the
+    margins XLA's first pass computed: two contractions over X (``X
+    theta``, ``X^T (w dz)``) and no third, the weights ``hessian_weights``'
+    to the bit (batched alike under ``vmap``). ``kernels.xla_fallbacks{path=dense_curv, reason}`` ticks
+    once a traced evaluation; a backend that is no TPU ticks nothing."""
+    X, y, off, w, coef = wide_problem
+    if reason == "shape":
+        narrow = on_tpu._DENSE_MIN_WIDTH - 1
+        X, coef = X[:, :narrow], coef[:narrow]
+    if reason == "not_a_tpu":
+        monkeypatch.setattr(on_tpu, "_on_tpu", lambda: False)
+    vgw = lambda c: aggregators.value_gradient_and_weights(
+        LogisticLoss, X, y, off, w, c, _IDN)
+    before = {p: _ticks(p) for p in ("dense", "dense_curv")}
+    if reason == "vmap":
+        got = jax.vmap(vgw)(jnp.stack([coef, coef]))[2][1]
+    elif reason == "mesh":
+        with on_tpu.disabled():
+            got = vgw(coef)[2]
+    else:
+        got = vgw(coef)[2]
+    assert _ticked(before["dense_curv"], "dense_curv") == (
+        {} if reason == "not_a_tpu" else {reason: 1})
+    assert _ticked(before["dense"]) == {}
+    hw = lambda c: aggregators.hessian_weights(LogisticLoss, X, y, off, w, c,
+                                               _IDN)
+    with on_tpu.disabled():
+        want = (jax.vmap(hw)(jnp.stack([coef, coef]))[1] if reason == "vmap"
+                else hw(coef))
+        jaxpr = jax.make_jaxpr(vgw)(coef)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    over_x = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"
+              and any(getattr(v.aval, "shape", ()) == X.shape for v in e.invars)]
+    assert len(over_x) == 2, over_x
+
+
+# sha256 of the jaxprs of the kernel's two- and three-result evaluations and
+# of its Hessian-vector product at 700 x 300, traced with x64 on as the
+# tests run, from f93ae62 (before the kernel could hand out curvature
+# weights) with this very function: what the four cells that run the kernel
+# without them trace stays what it was.
+PARENT_KERNEL_JAXPR = {
+    "two": "0d44f1f5a0879cc4cabb2e535805e358345bf1258d070df6b1945d6793276d29",
+    "three": "c4ed8889a3b07dee99dc75236b35ef6a2300946e7c054b5790afa6fe4926bac9",
+    "product": "0eec1a0c15885c286c5fe9069fec41a9db04f5d9f18b5a30618d94cc4ebda32f",
+}
+
+
+def kernel_jaxpr_digest(call):
+    import hashlib
+
+    from photon_tpu.ops import pallas_glm
+
+    n, d = 700, 300
+    args = ([jnp.zeros((n, d), jnp.float32)]
+            + [jnp.zeros(n, jnp.float32)] * 3 + [jnp.zeros(d, jnp.float32)])
+    calls = {
+        "two": lambda x, y, off, w, c: pallas_glm.fused_dense_value_grad(
+            LogisticLoss, x, y, off, w, c, interpret=True),
+        "three": lambda x, y, off, w, c: pallas_glm.fused_dense_value_grad(
+            LogisticLoss, x, y, off, w, c, interpret=True, with_dz_sum=True),
+        "product": lambda x, y, off, w, c: (
+            pallas_glm.fused_dense_hessian_vector(x, w, c, interpret=True)),
+    }
+    text = str(jax.make_jaxpr(calls[call])(*args))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("call", sorted(PARENT_KERNEL_JAXPR))
+def test_the_kernel_without_weights_traces_as_it_did(call):
+    """The weights are an output asked for: a call that does not ask traces
+    the jaxpr it traced before there was one, to the byte."""
+    assert kernel_jaxpr_digest(call) == PARENT_KERNEL_JAXPR[call]
+
+
+def test_the_kernel_with_weights_adds_one_output():
+    """Asked for, the same kernel with ONE more output, laid out as the
+    labels are: [tiles, tile / 128, 128], written once a step."""
+    from photon_tpu.ops import pallas_glm
+
+    n, d = 700, 300
+    x = jnp.zeros((n, d), jnp.float32)
+    y = jnp.zeros(n, jnp.float32)
+    c = jnp.zeros(d, jnp.float32)
+    calls = {}
+    for weights in (False, True):
+        jaxpr = jax.make_jaxpr(lambda x, c: pallas_glm.fused_dense_value_grad(
+            LogisticLoss, x, y, y, y, c, with_weights=weights))(x, c)
+        calls[weights], = [e for e in _eqns(jaxpr.jaxpr)
+                           if e.primitive.name == "pallas_call"]
+    assert len(calls[True].outvars) == len(calls[False].outvars) + 1 == 3
+    assert calls[True].outvars[-1].aval.shape == (1, 5, 128)
+
+
+def kernel_body_digest(call):
+    """sha256 of the kernel a TPU program carries (the Mosaic module inside
+    the custom call, decoded), lowered for a TPU from here, for the
+    identity and the normalised evaluation and the product at 700 x 300
+    through ``aggregators``, as the cells' solves call them. Its locations
+    hold file paths, line and column numbers, and the persistent compile
+    cache keys on it: a line that moves in the kernel or in the aggregator
+    frames that call it re-keys every cell's solve (ROADMAP D13). Paths are
+    cut to ``photon_tpu/...`` and every frame outside the package (this
+    file's, pytest's) is one placeholder. JAX's caches are cleared first:
+    an inner jitted function (``jax.nn.sigmoid``, ``jnp.where``) keeps the
+    locations of the call that traced it first, in whatever test that
+    was."""
+    import base64
+    import hashlib
+
+    from jax._src.lib.mlir import ir
+
+    from photon_tpu.ops.normalization import NormalizationContext
+
+    jax.clear_caches()
+
+    n, d = 700, 300
+    x = jnp.zeros((n, d), jnp.float32)
+    y = jnp.zeros(n, jnp.float32)
+    c = jnp.zeros(d, jnp.float32)
+    shifted = NormalizationContext(jnp.ones(d, jnp.float32),
+                                   jnp.zeros(d, jnp.float32))
+    calls = {
+        "two": lambda x, c: aggregators.value_and_gradient(
+            LogisticLoss, x, y, y, y, c, _IDN),
+        "three": lambda x, c: aggregators.value_and_gradient(
+            LogisticLoss, x, y, y, y, c, shifted),
+        "product": lambda x, c: aggregators.hessian_vector_from_weights(
+            x, y, c, _IDN, d),
+    }
+    text = jax.jit(calls[call]).trace(x, c).lower(
+        lowering_platforms=("tpu",)).as_text()
+    body, = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]*)\\22', text)
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body))
+        text = module.operation.get_asm(enable_debug_info=True)
+    text = re.sub(r'"[^"]*/photon_tpu/', '"photon_tpu/', text)
+    outside = set()
+    lines = []
+    for line in text.splitlines():
+        m = re.match(r"(#loc\d+) = loc\((.*)\)$", line)
+        if m and (re.match(r'"(?!photon_tpu/)[^"]*\.py":', m[2]) or any(
+                ref in outside for ref in re.findall(r"#loc\d+", m[2])
+                if not m[2].startswith("callsite"))):
+            outside.add(m[1])
+            line = f'{m[1]} = loc("caller")'
+        lines.append(line)
+    return hashlib.sha256("\n".join(lines).encode()).hexdigest()
+
+
+# ``kernel_body_digest`` of f93ae62 (before the kernel could hand out
+# curvature weights): the cells that run the kernel without them lower the
+# kernel, and key their compile cache on it, as they did.
+PARENT_KERNEL_BODY = {
+    "two": "d70e67d98c5cca22ac4599803a5fcc51ac114552ca6ff8553382a5eef9bc8f04",
+    "three": "e81645aeebe0a9353aca2a03aecb3c014135b968023b70d3622d616af0614268",
+    "product": "ae39e8e2003b33562c5a440d8097f3d84b64962ddc87a59e276a3f5b9fea6a03",
+}
+
+
+@pytest.mark.parametrize("call", sorted(PARENT_KERNEL_BODY))
+def test_the_kernel_without_weights_lowers_as_it_did(call, on_tpu,
+                                                      monkeypatch):
+    """The serialised kernel of the calls that do not ask for the weights,
+    locations included, is the one they lowered before (a blank line added
+    above ``_fused`` changes all three digests; the jaxprs above do not
+    see it)."""
+    monkeypatch.setattr(on_tpu, "_default_interpret", lambda: False)
+    assert kernel_body_digest(call) == PARENT_KERNEL_BODY[call]

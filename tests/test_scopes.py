@@ -53,14 +53,14 @@ SOLVERS = {
                       "loop"),
                {"agg/value_and_gradient", "agg/margins",
                 "agg/hessian_weights", "agg/hessian_matrix"}, None),
-    # dense and small: the explicit Gauss-Newton matrix; sparse: matrix-free
+    # dense and small: the explicit Gauss-Newton matrix; sparse: matrix-free;
+    # either way from the weights the evaluations hand out, and no
+    # ``agg/hessian_weights`` pass of its own
     "TRON": (TaskType.LOGISTIC_REGRESSION, L2Regularization,
              _steps("tron", "init", "hessian", "direction", "trial",
                     "update", "converged", "loop"),
-             {"agg/value_and_gradient", "agg/margins", "agg/hessian_weights",
-              "agg/hessian_matrix"},
-             {"agg/value_and_gradient", "agg/margins", "agg/hessian_weights",
-              "agg/hessian_vector"}),
+             {"agg/value_and_gradient", "agg/margins", "agg/hessian_matrix"},
+             {"agg/value_and_gradient", "agg/margins", "agg/hessian_vector"}),
     "OWLQN": (TaskType.LOGISTIC_REGRESSION, L1Regularization,
               _steps("owlqn", "init", "direction", "linesearch", "update",
                      "converged", "loop"),
@@ -101,6 +101,8 @@ def test_a_solve_lowers_with_every_step_and_aggregator_named(solver, sparse):
     found = scopes_in(text)
     want = steps | ((sparse_aggs if sparse else None) or dense_aggs)
     assert want <= found, sorted(want - found)
+    if "agg/hessian_weights" not in want:
+        assert "agg/hessian_weights" not in found
     # and nothing of another solver's leaked in under this one's name
     others = {s for name, spec in SOLVERS.items() if name != solver
               for s in spec[2]} - steps

@@ -25,7 +25,7 @@ from photon_tpu.obs.metrics import registry
 from photon_tpu.ops import features as F
 from photon_tpu.optim import problem as P
 from photon_tpu.optim import tron
-from photon_tpu.optim.base import SolverConfig
+from photon_tpu.optim.base import ConvergenceReason, SolverConfig
 from photon_tpu.optim.problem import (
     GLMOptimizationConfiguration,
     GlmOptimizationProblem,
@@ -363,15 +363,19 @@ def test_no_operator_build_after_a_refused_step():
     ``lax.cond``) once at the start and once after each accepted step that
     another iteration follows, on a problem made to refuse steps: f(x) =
     sum(log(cosh(x))) from a far start, where the curvature is next to
-    nothing, the gradient is not, and the quadratic model overshoots."""
+    nothing, the gradient is not, and the quadratic model overshoots. Its
+    input is the curvature each evaluation hands back beside value and
+    gradient: the operator is built from the weights of the point the
+    solver stands at, never from an evaluation of its own."""
     ran = []
 
     def value_and_grad(x):
-        return jnp.sum(jnp.log(jnp.cosh(x))), jnp.tanh(x)
+        return (jnp.sum(jnp.log(jnp.cosh(x))), jnp.tanh(x),
+                1.0 / jnp.cosh(x) ** 2)
 
-    def hess_setup(x):
+    def hess_setup(d2):
         jax.debug.callback(lambda: ran.append(1))
-        return 1.0 / jnp.cosh(x) ** 2
+        return d2
 
     result = jax.jit(lambda x0: tron.minimize(
         value_and_grad, None, x0,
@@ -437,7 +441,9 @@ def test_a_solve_through_the_kernel_takes_the_xla_paths_steps(rows, on_tpu):
 
 def test_the_routed_cg_step_reads_x_once(rows, on_tpu):
     """The traced solve holds ONE kernel call in the CG step and no
-    contraction over X there."""
+    contraction over X outside its three kernel calls: the operator is
+    taken from the curvature weights the evaluations hand back, with no
+    pass over X of its own."""
     x, y, offsets = rows
     batch = DataBatch(jnp.asarray(x), jnp.asarray(y), jnp.asarray(offsets),
                       jnp.ones(N, jnp.float32))
@@ -469,11 +475,48 @@ def test_the_routed_cg_step_reads_x_once(rows, on_tpu):
     calls = [inside for e, inside in eqns if e.primitive.name == "pallas_call"]
     assert len(calls) == 3, calls
     assert sum(inside.count("while") == 2 for inside in calls) == 1, calls
-    # the one contraction over X left is the weights' pass (X theta under
-    # the operator build's ``cond``); none in the CG loop
+    # the weights' pass (X theta under the operator build's ``cond``) is
+    # gone: no contraction over X anywhere outside the kernel
     dots = [inside for e, inside in eqns
             if e.primitive.name == "dot_general" and over_x(e)]
-    assert len(dots) == 1 and "cond" in dots[0], dots
+    assert dots == [], dots
+    assert not any(e.primitive.name == "cond" for e, _ in eqns)
+
+
+# The matrix-free fit of the file's problem at tolerance 1e-4 as the solver
+# took it while every operator build read X for its weights (f93ae62, on
+# the CPU), on XLA's path and through the kernel alike: 19 CG steps, 5
+# operators, one refused step, six iterations ending on the objective's
+# change, seven evaluations.
+PARENT_FIT = ({"cg_steps": 19, "hessian_builds": 5, "rejected_steps": 1},
+              [True, False, True, True, True, True], 6,
+              ConvergenceReason.FUNCTION_VALUES_CONVERGED, 7)
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_the_weights_from_the_evaluations_take_the_parents_steps(
+        rows, on_tpu, route):
+    """A matrix-free fit whose operator is the trial point's curvature
+    weights (the kernel's per-row output, or the margins of XLA's first
+    pass) takes the steps it took when every operator build read X for
+    them: the same counts, accepted / refused sequence, iterations, reason
+    and evaluations, and one evaluation routed a trace under
+    ``dense_curv``, none under ``dense``."""
+    before = {path: _ticks(path) for path in ("dense", "dense_curv")}
+    if route == "xla":
+        with on_tpu.disabled():
+            _, k = _fit(rows, explicit=False, tolerance=1e-4)
+        curv = {"mesh": 2}
+    else:
+        _, k = _fit(rows, explicit=False, tolerance=1e-4)
+        curv = {"hit": 2}
+    r = k.last_result
+    assert (k.tron_counts(), _accepted(k.last_tracker.losses),
+            int(r.iterations), int(r.reason), int(r.num_fun_evals)
+            ) == PARENT_FIT
+    # two evaluation call sites a traced solve: the first, the trial's
+    assert _ticked(before["dense_curv"], "dense_curv") == curv
+    assert _ticked(before["dense"], "dense") == {}
 
 
 def test_per_entity_tron_under_vmap_keeps_xlas_products(on_tpu, monkeypatch):
